@@ -140,8 +140,8 @@ def main() -> int:
     art = {
         "provenance": {
             "date": time.strftime("%Y-%m-%d"),
-            "command": "scripts/assemble_block_sweep.py (legs from "
-                       "scripts/tpu_window_runner.py sweep.* ids)",
+            "command": "scripts/assemble_block_sweep.py (the sweep.* "
+                       "legs of artifacts/tpu_window_runs.jsonl)",
             "noise_margin": NOISE_MARGIN,
         },
         "shapes": shapes,

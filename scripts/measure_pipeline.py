@@ -19,8 +19,7 @@ Quantifies, for the pipelined trainer (`PipelinedTrainer`):
    step time, everything about the schedule's shape).
 
 Writes ``artifacts/pipeline_measurements.json``; the structural half is
-asserted by tests/test_pipeline_perf.py; BASELINE.md carries the summary
-table. Run: XLA_FLAGS=--xla_force_host_platform_device_count=8
+asserted by tests/test_pipeline_perf.py. Run: XLA_FLAGS=--xla_force_host_platform_device_count=8
 JAX_PLATFORMS=cpu python scripts/measure_pipeline.py
 """
 

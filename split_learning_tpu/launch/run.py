@@ -304,22 +304,43 @@ def _assemble_full_params(layout: str, raw: Dict[str, Any]):
         "or fused to checkpoint the joint state)")
 
 
-def _server_mesh(args):
-    """Build the sharded-server mesh from ``--mesh-data``/``--mesh-model``
-    (train in-process server + serve). 1x1 — the default — returns None:
-    the ServerRuntime keeps the legacy single-device programs byte-for-
-    byte. Raises ValueError (the CLI config-error type both callers
-    already map to exit 2) when the backend has too few devices, with
-    the host-platform remedy in the message."""
+def _server_mesh(args, stage_index: int = 0, num_stages: int = 1):
+    """Build the sharded-party mesh from ``--mesh-data``/``--mesh-model``
+    (train in-process server or stage parties + serve). 1x1 — the
+    default — returns None: the runtime keeps the legacy single-device
+    programs byte-for-byte. Stage *i* of an in-process chain takes the
+    *i*-th block of ``data*model`` devices when the backend has one per
+    stage (parallel.mesh.stage_devices); a lone server takes the first.
+    Raises ValueError (the CLI config-error type both callers already
+    map to exit 2) when the backend has too few devices, with the
+    host-platform remedy in the message."""
     data = int(getattr(args, "mesh_data", 1) or 1)
     model = int(getattr(args, "mesh_model", 1) or 1)
     if data * model <= 1:
         return None
     from split_learning_tpu.parallel.mesh import make_host_mesh
     try:
-        return make_host_mesh(data=data, model=model)
+        return make_host_mesh(data=data, model=model,
+                              stage_index=stage_index,
+                              num_stages=num_stages)
     except RuntimeError as e:
         raise ValueError(str(e)) from e
+
+
+def _stage_placement(args, stage_index: int, num_stages: int) -> dict:
+    """Where stage ``stage_index`` of the in-process chain lives — the
+    one place that is decided, as the StageRuntime kwargs that say it.
+    When the backend has a block of devices for every stage, stage *i*
+    takes the *i*-th (parallel.mesh.stage_devices): a ``mesh`` over it
+    when ``--mesh-data/--mesh-model`` ask for one, else the one
+    ``device``. Otherwise every stage shares the first block with the
+    hub. A party that is alone in its process (``serve``) is not placed
+    here: mesh or not, it takes the backend's first devices."""
+    mesh = _server_mesh(args, stage_index, num_stages)
+    if mesh is not None:
+        return {"mesh": mesh}
+    from split_learning_tpu.parallel.mesh import stage_devices
+    return {"device": stage_devices(stage_index, num_stages)[0]}
 
 
 def _density_arg(v: str):
@@ -648,6 +669,14 @@ def cmd_train(args) -> int:
                 transports.append(t)
         else:
             from split_learning_tpu.runtime.replica import maybe_replicate
+            per_stage = chain_mesh_data * chain_mesh_model
+            if 1 < len(jax.devices()) < plan.num_stages * per_stage:
+                # _stage_placement's other arm, said once: devices sit
+                # idle and the user did not ask for that
+                print(f"[pipeline] {len(jax.devices())} devices < "
+                      f"{plan.num_stages} stages x {per_stage} per stage: "
+                      f"every stage shares the first {per_stage} "
+                      "device(s) with the hub", file=sys.stderr)
             for i in range(1, plan.num_stages):
                 def _make_stage(_ridx: int = 0, _i: int = i):
                     # same PRNGKey per replica: one stage model, N
@@ -656,8 +685,9 @@ def cmd_train(args) -> int:
                                         jax.random.PRNGKey(cfg.seed),
                                         sample, microbatches=M,
                                         apply_lag=lag,
-                                        mesh=_server_mesh(args),
-                                        ef_mode=chain_ef_mode)
+                                        ef_mode=chain_ef_mode,
+                                        **_stage_placement(
+                                            args, _i, plan.num_stages))
                 srt = maybe_replicate(_make_stage, chain_replicas)
                 stage_rts.append(srt)
                 if args.transport == "device":
@@ -812,13 +842,22 @@ def cmd_train(args) -> int:
         for i, t in enumerate(transports):
             print(f"[transport] hop {i + 1}: {t.stats.summary()}",
                   file=sys.stderr)
+        print(f"[pipeline] stage 0 (hub): "
+              f"devices={chain_meta.get('hub_devices')}", file=sys.stderr)
+        # the stage -> device-ids map as a record, not only as prose
+        # (chip_smoke.py reads it from the jsonl tracker)
+        logger.log_params({"stage_devices": {
+            0: chain_meta.get("hub_devices"),
+            **{st["stage"]: st.get("devices")
+               for st in chain_meta.get("stages", [])}}})
         for st in chain_meta.get("stages", []):
             bf = st.get("bubble_fraction")
             print(f"[pipeline] stage {st['stage']} "
                   f"[{st.get('schedule', 'gpipe')}]: bubble="
                   f"{bf if bf is None else round(bf, 3)} "
                   f"(ideal {st['bubble_theoretical']:.3f}) "
-                  f"reply_p50={st['reply_p50_ms']:.1f}ms",
+                  f"reply_p50={st['reply_p50_ms']:.1f}ms "
+                  f"devices={st.get('devices')}",
                   file=sys.stderr)
         dc_snap = chain_meta.get("density")
         if dc_snap is not None:
@@ -2026,8 +2065,8 @@ def _run_with_flight(args) -> int:
 
 
 def main(argv: Optional[list] = None) -> int:
-    from split_learning_tpu.utils import ensure_pinned_platform_hermetic
-    ensure_pinned_platform_hermetic()  # JAX_PLATFORMS=cpu must never dial
+    from split_learning_tpu.utils import configure_compile_cache
+    configure_compile_cache()  # before the first compile of the process
     ap = argparse.ArgumentParser(prog="split_learning_tpu")
     sub = ap.add_subparsers(dest="cmd", required=True)
 

@@ -283,18 +283,14 @@ def install() -> None:
 
 
 def uninstall() -> None:
-    """Best-effort teardown for tests/bench: drop the listener and
-    restore the permissive guard."""
+    """Teardown for tests/bench: drop the listener and restore the
+    permissive guard."""
     global _installed
     import jax
     with _install_lock:
         if not _installed:
             return
-        try:
-            from jax._src import monitoring as _mon
-            _mon._unregister_event_duration_listener_by_callback(_on_event)
-        except Exception:
-            pass  # private API moved: the listener no-ops once cleared
+        jax.monitoring.unregister_event_duration_listener(_on_event)
         jax.config.update("jax_transfer_guard_device_to_host", "allow")
         _installed = False
 

@@ -20,10 +20,9 @@ NEG_BIG = -1e30
 
 @functools.lru_cache(maxsize=None)
 def _default_backend_platform() -> str:
-    try:
-        return jax.default_backend()
-    except Exception:
-        return "cpu"
+    # a backend that fails to initialize raises here: answering "cpu"
+    # would silently turn every kernel to interpret mode on a broken chip
+    return jax.default_backend()
 
 
 def use_interpret() -> bool:

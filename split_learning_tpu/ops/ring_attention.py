@@ -39,17 +39,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.8 public API; the experimental home is deprecated
-    from jax import shard_map as _shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs, check_rep=False):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=check_rep)
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
 
 from split_learning_tpu.ops.common import NEG_BIG as _NEG_BIG
 from split_learning_tpu.parallel.mesh import DATA_AXIS, SEQ_AXIS
@@ -261,7 +252,7 @@ def _sharded(mesh: Mesh, body, causal: bool, axis_name: str, **body_kw):
         functools.partial(body, axis_name=axis_name, causal=causal,
                           **body_kw),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False)
+        check_vma=False)
 
 
 def _resolve_block_impl(block_impl: str, b: int, t_q: int, t_kv: int,

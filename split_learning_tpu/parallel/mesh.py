@@ -5,9 +5,10 @@ runtime replaces pod scheduling").
 Axes:
 - ``data``: data-parallel client replicas (the reference's `split-client`
   Deployment replica count, pinned to 1 at ``k8s/split-learning.yaml:49``;
-  here a real axis with psum gradient aggregation — BASELINE.md config 3),
+  here a real axis with psum gradient aggregation — BASELINE.json
+  configs[2]),
 - ``pipe``: pipeline stages (the client/server cut generalized to N stages
-  — BASELINE.md configs 2, 4, 5),
+  — BASELINE.json configs[1], [3], [4]),
 - ``model``: intra-layer tensor parallelism (SURVEY.md §2 parallelism
   table: "out of scope unless cheap via pjit sharding specs" — it is:
   weight matrices shard their output-feature dim, XLA's sharding
@@ -138,25 +139,41 @@ def ensure_host_device_count(n: Optional[int] = None) -> int:
     return n
 
 
-def make_host_mesh(data: int = 1, model: int = 1) -> Mesh:
-    """A (data × 1[, model]) mesh over forced host-platform CPU devices —
-    the validated path for CPU CI and local testing of the sharded server.
+def stage_devices(stage_index: int, num_stages: int,
+                  per_stage: int = 1) -> list:
+    """The devices stage ``stage_index`` of an in-process chain lives on:
+    its own block of ``per_stage`` devices when the backend has a block
+    for every stage (stage *i* takes the *i*-th), else the first block —
+    every stage co-located, which on one device is the only layout."""
+    devices = jax.devices()
+    lo = (stage_index * per_stage
+          if len(devices) >= num_stages * per_stage else 0)
+    return devices[lo:lo + per_stage]
+
+
+def make_host_mesh(data: int = 1, model: int = 1, stage_index: int = 0,
+                   num_stages: int = 1) -> Mesh:
+    """A (data × 1[, model]) mesh for one party — over
+    :func:`stage_devices`' block for ``stage_index`` (the first
+    ``data*model`` devices for a lone server), and the validated path for
+    CPU CI of the sharded server on forced host-platform devices.
 
     Unlike :func:`make_mesh`'s generic "not enough devices" error, this
     diagnoses the usual cause (the forcing flag was absent or set too
     late) and names the fix.
     """
     need = data * model
-    devices = jax.devices()
-    if len(devices) < need:
+    n_dev = len(jax.devices())
+    if n_dev < need:
         raise RuntimeError(
             f"host mesh needs {need} devices but the backend exposes "
-            f"{len(devices)}. Set XLA_FLAGS="
+            f"{n_dev}. Set XLA_FLAGS="
             f"{host_device_count_flags(max(need, 8))} (or {HOST_DEVICES_ENV}="
             f"{max(need, 8)} + parallel.mesh.ensure_host_device_count()) "
             "BEFORE the first jax call — the flag is read once at backend "
             "initialization")
-    return make_mesh(num_clients=data, model_parallel=model, devices=devices)
+    return make_mesh(num_clients=data, model_parallel=model,
+                     devices=stage_devices(stage_index, num_stages, need))
 
 
 def host_gather(x: Any, rows: Optional[int] = None) -> np.ndarray:

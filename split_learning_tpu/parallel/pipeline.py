@@ -30,9 +30,8 @@ Design notes (TPU-first):
   M+S-1 tick schedule (the standard GPipe bubble), and (c) per-tick
   lockstep at the ppermute, i.e. ticks run at the slowest stage's pace —
   quantified in artifacts/pipeline_measurements.json (produced by
-  scripts/measure_pipeline.py, summarized in BASELINE.md, consistency
-  asserted by tests/test_pipeline_perf.py). Gradients of unselected
-  branches are
+  scripts/measure_pipeline.py, consistency asserted by
+  tests/test_pipeline_perf.py). Gradients of unselected branches are
   structurally zero, and the cross-stage ``psum`` reassembles the full
   gradient tree.
 - Data parallelism composes orthogonally: the batch is sharded over the
@@ -49,20 +48,8 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.8 public API; the experimental home is deprecated
-    from jax import shard_map as _jax_shard_map
-
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return _jax_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=False)
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _xp_shard_map
-
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return _xp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False)
 
 from split_learning_tpu.core.losses import cross_entropy
 from split_learning_tpu.core.stage import SplitPlan, remat_plan
@@ -254,11 +241,12 @@ class PipelinedTrainer:
         state_spec = jax.tree_util.tree_map(lambda _: P(), self.state)
 
         def step(state, x, y):
-            return _shard_map(
+            return shard_map(
                 sharded_step,
                 mesh=mesh,
                 in_specs=(state_spec, x_spec, P(DATA_AXIS)),
                 out_specs=(state_spec, P()),
+                check_vma=False,
             )(state, x, y)
 
         rep = NamedSharding(mesh, P())
@@ -312,9 +300,10 @@ def make_hop_shift(mesh: Mesh, src: int, dst: int):
         buf = jnp.zeros((n,) + x.shape, x.dtype).at[src].set(x)
         buf = jax.lax.with_sharding_constraint(
             buf, NamedSharding(mesh, P(PIPE_AXIS)))
-        moved = _shard_map(
+        moved = shard_map(
             lambda b: jax.lax.ppermute(b, PIPE_AXIS, [(src, dst)]),
             mesh=mesh, in_specs=P(PIPE_AXIS), out_specs=P(PIPE_AXIS),
+            check_vma=False,
         )(buf)
         return moved[dst]
 

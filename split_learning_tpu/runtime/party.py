@@ -83,6 +83,16 @@ def mesh_axes(mesh: Optional[Any]) -> Dict[str, int]:
             **{str(k): int(v) for k, v in dict(mesh.shape).items()}}
 
 
+def state_device_ids(state: TrainState) -> list:
+    """Ids of the devices a TrainState lives on, read off its step
+    scalar (pinned: one id; on a mesh the scalar is replicated, so every
+    mesh device). ``[]`` for a host-restored tree not yet stepped on."""
+    step = state.step
+    if not isinstance(step, jax.Array):
+        return []
+    return sorted(d.id for d in step.devices())
+
+
 class PartyRuntime:
     """Base class: one party's shared runtime machinery. Subclasses own
     their protocol ops and jitted-program tables; everything those lean
@@ -121,6 +131,9 @@ class PartyRuntime:
         self._mesh = mesh
         self._layout = None
         self._mesh_data = 1
+        # the one device a meshless party pinned its state to
+        # (_install_layout); None = left to jax's default placement
+        self._device = None
         # per-program MFU accounting (traced-only, under the lock):
         # program name -> [matmul flops total, dispatch seconds, calls];
         # the flops of a (program, arg-shapes) pair are traced once and
@@ -167,14 +180,14 @@ class PartyRuntime:
         self._t_start = time.monotonic()
 
     # -- mesh layout + program compilation ------------------------------ #
-    def _install_layout(self, pin_single_device: bool = False) -> None:
+    def _install_layout(self, pin_device: Optional[Any] = None) -> None:
         """Install the PR-11 sharded layout over ``self.state`` (call
         after the subclass builds its TrainState, before compiling): the
         state tree moves onto the mesh (weights along ``model``,
         optimizer mirrors with their weights, scalars replicated) and
         ``_jit`` reads these shardings into every program's in/out
-        specs. Without a mesh, ``pin_single_device`` optionally pins the
-        state to device 0 up front — device-native hop payloads arrive
+        specs. Without a mesh, ``pin_device`` optionally pins the state
+        to that device up front — device-native hop payloads arrive
         committed (transport/device.py), and a committed-ness flip after
         the first apply would retrace every program on the next step."""
         if self._mesh is not None:
@@ -184,8 +197,9 @@ class PartyRuntime:
             self._params_sharding = self._state_sharding.params
             self._batch_sharding = self._layout.batch()
             self.state = jax.device_put(self.state, self._state_sharding)
-        elif pin_single_device:
-            self.state = jax.device_put(self.state, jax.devices()[0])
+        elif pin_device is not None:
+            self._device = pin_device
+            self.state = jax.device_put(self.state, pin_device)
 
     def _jit(self, fn: Any, in_sh: Any, out_sh: Any,
              donate: Tuple[int, ...] = ()) -> Any:
@@ -206,11 +220,16 @@ class PartyRuntime:
         hop payloads (transport/device.py, PR 16) arrive as jax.Arrays
         and move device-to-device — ``np.asarray`` on one would force
         the very D2H the device transport exists to remove. Without a
-        mesh it is exactly the legacy ``jnp.asarray``."""
+        mesh it is the legacy ``jnp.asarray`` — except that a
+        device-native payload from a neighbour stage's device moves onto
+        this party's pinned one (D2D; the same buffer when it is already
+        there, which on one device is always)."""
         if self._mesh is not None:
             if not isinstance(x, jax.Array):
                 x = np.asarray(x)
             return jax.device_put(x, self._batch_sharding)
+        if self._device is not None and isinstance(x, jax.Array):
+            return jax.device_put(x, self._device)
         return jnp.asarray(x)
 
     def _check_batch_rows(self, rows: int) -> None:
@@ -295,10 +314,7 @@ class PartyRuntime:
         ``None`` on CPU (utils/flops.device_peak_flops), which is the
         honest answer, not a zero."""
         from split_learning_tpu.utils.flops import device_peak_flops, mfu
-        try:
-            peak = device_peak_flops(jax.devices()[0])
-        except Exception:
-            peak = None
+        peak = device_peak_flops(jax.devices()[0])
         with self._lock:
             stats = {k: tuple(v) for k, v in self._prog_stats.items()}
             gather = self._metrics.snapshot()["counters"].get(
@@ -428,7 +444,8 @@ class PartyRuntime:
                 # D2D, never through host) so the legacy programs keep
                 # one stable placement. Host/np restores pass through
                 # untouched: the legacy path, bit for bit.
-                dev0 = jax.devices()[0]
+                dev0 = (self._device if self._device is not None
+                        else jax.devices()[0])
 
                 def _unshard(x: Any) -> Any:
                     if isinstance(x, jax.Array) \

@@ -70,6 +70,7 @@ import numpy as np
 from split_learning_tpu.core.stage import SplitPlan
 from split_learning_tpu.obs import dispatch_debug as obs_dispatch
 from split_learning_tpu.obs import spans
+from split_learning_tpu.runtime.party import state_device_ids
 from split_learning_tpu.runtime.server import ProtocolError
 from split_learning_tpu.runtime.state import (
     TrainState, apply_grads, make_state, make_tx)
@@ -466,10 +467,12 @@ class PipelineRunner:
             p50 = durs[len(durs) // 2] if durs else 0.0
             depth = None
             mesh_info = None
+            devices = None
             try:
                 h = t.health()
                 depth = h.get("counters", {}).get("deferred_apply_depth")
                 mesh_info = h.get("mesh")
+                devices = h.get("devices")
             except Exception:  # noqa: BLE001 — report stays best-effort
                 pass
             # per-stage MFU (ISSUE 20): the party's traced-only program
@@ -502,6 +505,9 @@ class PipelineRunner:
                 # report's sharding column — meshless stages report the
                 # honest 1-device layout, matching mesh_axes(None)
                 "mesh": mesh_info or {"devices": 1, "data": 1},
+                # device ids the stage's state lives on (None when the
+                # party does not say, e.g. an older remote stage)
+                "devices": devices,
                 "mfu": mfu_val,
             }
             # compressed hop wire accounting (PR 18): cumulative ratio
@@ -532,6 +538,7 @@ class PipelineRunner:
                              else onefb_warmup(self.microbatches,
                                                self.plan.num_stages)),
             "device_native": self._device_native,
+            "hub_devices": state_device_ids(self.state),
             "ticks_per_step": pipeline_ticks(self.microbatches,
                                              self.plan.num_stages),
             "steps": self.steps_done,

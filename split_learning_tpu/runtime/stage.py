@@ -68,7 +68,8 @@ from split_learning_tpu.obs import flight as obs_flight
 from split_learning_tpu.obs import spans
 from split_learning_tpu.obs import trace as obs_trace
 from split_learning_tpu.runtime.party import (
-    PartyRuntime, ProtocolError, _DeferredApply, mesh_axes)
+    PartyRuntime, ProtocolError, _DeferredApply, mesh_axes,
+    state_device_ids)
 from split_learning_tpu.runtime.state import (
     TrainState, apply_grads, make_state, make_tx)
 from split_learning_tpu.utils.config import Config
@@ -108,7 +109,8 @@ class StageRuntime(PartyRuntime):
                  quota: Optional[Any] = None,
                  slo_ms: Optional[Any] = None,
                  mesh: Optional[Any] = None,
-                 ef_mode: str = "topk8") -> None:
+                 ef_mode: str = "topk8",
+                 device: Optional[Any] = None) -> None:
         """``rng``/``sample_input`` are the SHARED plan-level seed and
         stage-0 sample every party initializes the full plan from
         (keeping only its own stage) — the same convention the client
@@ -121,7 +123,10 @@ class StageRuntime(PartyRuntime):
         steps (bounds compose per stage across the chain, arXiv:
         1910.05104). ``mesh`` shards THIS stage (per-stage pjit; stages
         of one chain may carry different meshes — the hop wire reshards
-        between them)."""
+        between them). ``device`` is where a meshless stage lives (the
+        backend's first by default). Which stage gets which devices is
+        the launcher's decision (launch/run.py ``_stage_placement``),
+        never derived here."""
         if not 0 < stage_index < plan.num_stages:
             raise ValueError(
                 f"stage_index must be in [1, {plan.num_stages - 1}] "
@@ -145,11 +150,12 @@ class StageRuntime(PartyRuntime):
         all_params = plan.init(rng, jnp.asarray(sample_input))
         self._tx = make_tx(cfg)
         self.state = make_state(all_params[self.stage_index], self._tx)
-        # sharded layout (or, meshless, pin to device 0 up front:
+        # sharded layout (or, meshless, pin to one device up front:
         # device-native hop payloads arrive committed, and a
         # committed-ness flip after this stage's first apply would
         # retrace every stage program on the next step)
-        self._install_layout(pin_single_device=True)
+        self._install_layout(
+            pin_device=device if device is not None else jax.devices()[0])
         self._build_jitted()
 
         self._deferred = _DeferredApply(
@@ -603,6 +609,9 @@ class StageRuntime(PartyRuntime):
             "uptime_seconds": uptime,
             "version": __version__,
             "counters": self.counters(),
+            # where this stage's state lives: one id when pinned, the
+            # mesh's ids when sharded
+            "devices": state_device_ids(self.state),
         }
         if self._mesh is not None:
             info["mesh"] = mesh_axes(self._mesh)

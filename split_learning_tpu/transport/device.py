@@ -59,8 +59,10 @@ class DeviceTransport(Transport):
     chain's stages. When given, each hop payload rides a ``ppermute``
     between the sending and receiving pipe ranks (forward: stage-1 ->
     stage; backward: stage+1 -> stage — the hub relays, the collective
-    moves the bytes). Without it, placement is left to jax: co-located
-    single-device chains pass the identical buffer through.
+    moves the bytes). Without it each stage receives a payload onto its
+    own device (``PartyRuntime._to_dev``: a plain D2D ``device_put``, ICI
+    on a multi-chip host) and this wire returns stage 1's cotangents to
+    the hub's; on one device the identical buffer passes through.
     """
 
     device_native = True
@@ -72,12 +74,6 @@ class DeviceTransport(Transport):
         self._num_stages = int(server.plan.num_stages) \
             if hasattr(server, "plan") else 0
         self._mesh = mesh
-        # a sharded peer (per-stage pjit, ISSUE 20) replies mesh-sharded
-        # jax.Arrays: the hop wire reshards them D2D (device_put), and
-        # stage-1 replies must land on the hub's device even without a
-        # pipe mesh — read the peer's mesh once here. ReplicaGroup
-        # exposes its primary's mesh under the same name.
-        self._stage_mesh = getattr(server, "_mesh", None)
         if mesh is not None:
             from split_learning_tpu.parallel.mesh import PIPE_AXIS
             if PIPE_AXIS not in mesh.axis_names:
@@ -87,13 +83,12 @@ class DeviceTransport(Transport):
                 raise ValueError(
                     f"pipe axis size {mesh.shape[PIPE_AXIS]} < "
                     f"{self._num_stages} stages")
-        # the hub's device (pipe rank 0): replies consumed by the
-        # DRIVER's own programs (wire-to-stage-1 cotangents) get
-        # device_put here so the hub's jits keep one stable placement —
-        # D2D only, never through host
+        # the hub's device (pipe rank 0; PipelineRunner pins its state
+        # there): replies consumed by the DRIVER's own programs
+        # (wire-to-stage-1 cotangents) get device_put here so the hub's
+        # jits keep one stable placement — D2D only, never through host
         self._hub_dev = (mesh.devices.flat[0] if mesh is not None
-                         else (jax.devices()[0]
-                               if self._stage_mesh is not None else None))
+                         else jax.devices()[0])
         # one jitted shuttle per (src, dst, shape, dtype) — cached so
         # steady state never recompiles (the watchdog step_scope below
         # pins that)
@@ -137,14 +132,13 @@ class DeviceTransport(Transport):
 
     def _to_hub(self, g: Any) -> Any:
         """Replies the DRIVER's own programs consume (the stage-1
-        wire's cotangents) move to the hub's rank-0 device: without
-        this the mesh-sharded reply would re-lay the hub's params after
-        the first apply and retrace every hub program at step 2. Pure
-        D2D — device_put across devices is the sanctioned move. A
-        sharded stage 1 (its own pjit mesh) needs the same gather-to-hub
-        even without a pipe mesh: its reply spans the stage's devices."""
-        if (self._mesh is not None or self._stage_mesh is not None) \
-                and self.stage_index == 1 and isinstance(g, jax.Array):
+        wire's cotangents) move to the hub's device: stage 1 lives on
+        its own device (or spans its own pjit mesh) whenever the backend
+        has one per stage, and a reply left there would re-lay the hub's
+        params after the first apply and retrace every hub program at
+        step 2. Pure D2D — device_put across devices is the sanctioned
+        move, and the same buffer when it is already on the hub's."""
+        if self.stage_index == 1 and isinstance(g, jax.Array):
             return jax.device_put(g, self._hub_dev)
         return g
 

@@ -1,6 +1,5 @@
 from split_learning_tpu.utils.backend import (
-    ensure_pinned_platform_hermetic, reexec_pinned_cpu)
+    configure_compile_cache, reexec_pinned_cpu)
 from split_learning_tpu.utils.config import Config
 
-__all__ = ["Config", "ensure_pinned_platform_hermetic",
-           "reexec_pinned_cpu"]
+__all__ = ["Config", "configure_compile_cache", "reexec_pinned_cpu"]

@@ -13,8 +13,8 @@ matter on TPU hardware:
   more honest than the usual "3x forward" heuristic — it is exact for
   the matmul/conv work XLA will schedule onto the MXU.
 - :func:`device_peak_flops` — per-chip bf16 matmul peak from the public
-  spec sheets, keyed on ``jax.Device.device_kind`` (None when unknown —
-  MFU is then reported as null rather than guessed).
+  spec sheets, keyed on ``jax.Device.device_kind`` (None on CPU — MFU is
+  then reported as null rather than guessed; an unlisted TPU raises).
 - :func:`mfu` — achieved model FLOP/s over peak.
 
 Elementwise work (relu, pooling, optimizer updates) is deliberately NOT
@@ -44,20 +44,21 @@ _PEAK_BF16_FLOPS = (
 
 
 def device_peak_flops(device: Optional[jax.Device] = None) -> Optional[float]:
-    """Per-chip bf16 matmul peak in FLOP/s, or None when unknown (CPU,
-    unrecognized TPU generation)."""
+    """Per-chip bf16 matmul peak in FLOP/s; None off-TPU (a CPU has no
+    published MXU peak). A TPU whose ``device_kind`` is not in the table
+    is an error, not a default: a utilization against an assumed peak is
+    worse than none."""
     if device is None:
-        devs = jax.devices()
-        if not devs:
-            return None
-        device = devs[0]
+        device = jax.devices()[0]
     kind = (getattr(device, "device_kind", "") or "").lower()
     if "tpu" not in kind and device.platform != "tpu":
         return None
     for key, peak in _PEAK_BF16_FLOPS:
         if key in kind:
             return peak
-    return None
+    raise ValueError(
+        f"unknown TPU device_kind {device.device_kind!r}: add its bf16 "
+        "peak (with source) to utils/flops._PEAK_BF16_FLOPS")
 
 
 def _dot_flops(eqn) -> float:

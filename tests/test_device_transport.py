@@ -43,17 +43,18 @@ def _cfg(microbatches, schedule="gpipe"):
 
 
 def _chain(microbatches, schedule="gpipe", transport="device",
-           apply_lag=0, mesh=None):
+           apply_lag=0, mesh=None, devices=(None, None)):
     """One 3-stage chain: client stage 0 + two in-process StageRuntime
     parties, wired by DeviceTransport (device buffers end to end) or
-    LocalTransport (the PR-14 host-numpy contract)."""
+    LocalTransport (the PR-14 host-numpy contract). ``devices`` places
+    stages 1 and 2 (None: the backend's first)."""
     cfg = _cfg(microbatches, schedule)
     plan = get_plan(model="split_cnn_chain3", mode="split")
     sample = np.zeros((BATCH, 28, 28, 1), np.float32)
     stages = [StageRuntime(plan, i, cfg, jax.random.PRNGKey(SEED),
                            sample, microbatches=microbatches,
-                           apply_lag=apply_lag, mesh=mesh)
-              for i in (1, 2)]
+                           apply_lag=apply_lag, mesh=mesh, device=dev)
+              for i, dev in zip((1, 2), devices)]
     if transport == "device":
         transports = [DeviceTransport(s, mesh=mesh) for s in stages]
     else:
@@ -177,6 +178,31 @@ def test_device_chain_zero_host_copies_and_watchdog_clean():
         assert g1["steady_state_recompiles"] == g0["steady_state_recompiles"]
     finally:
         dispatch_debug.force(False)
+
+
+def test_stage_i_lives_on_device_i_with_the_one_device_loss_series():
+    """The launcher's placement on a backend with a device per stage
+    (this 8-device virtual mesh; a four-chip host): stage i of the device
+    chain on jax.devices()[i], the hub on device 0 — and where the
+    stages live changes nothing they compute: the loss series is
+    bit-identical to every stage on device 0."""
+    from split_learning_tpu.parallel.mesh import stage_devices
+
+    def run(devices):
+        runner, stages, _ = _chain(4, "1f1b", "device", devices=devices)
+        try:
+            losses = [runner.step(*_batch(i), i) for i in range(4)]
+            placed = ([runner.trace_metadata()["hub_devices"]]
+                      + [s.health()["devices"] for s in stages])
+        finally:
+            _close(runner, stages)
+        return losses, placed
+
+    spread, placed = run([stage_devices(i, 3)[0] for i in (1, 2)])
+    assert placed == [[jax.devices()[i].id] for i in range(3)]
+    together, placed = run((None, None))
+    assert placed == [[jax.devices()[0].id]] * 3
+    assert spread == together
 
 
 def test_local_transport_hop_payload_passthrough():

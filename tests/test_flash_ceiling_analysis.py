@@ -1,73 +1,12 @@
-"""The committed flash MFU ceiling analysis
-(``artifacts/flash_ceiling_analysis.json``, VERDICT r4 #8's
-documented-ceiling closure) stays self-consistent with the measurement
-artifact it derives from."""
+"""scripts/flash_ceiling_analysis.py stays importable in a checkout that
+holds no assembled long-context artifact (its own committed output went
+with the measurement it derived from)."""
 
-import json
 import os
 
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-ARTIFACT = os.path.join(REPO, "artifacts", "flash_ceiling_analysis.json")
-
-
-@pytest.fixture(scope="module")
-def art():
-    if not os.path.exists(ARTIFACT):
-        pytest.skip(f"missing {ARTIFACT}; run "
-                    "scripts/flash_ceiling_analysis.py")
-    with open(ARTIFACT) as f:
-        return json.load(f)
-
-
-def test_internally_consistent(art):
-    share = art["attention_share_of_dense_flops"]
-    rec = art["flash_recompute_factor"]
-    assert 0 < share < 1
-    assert rec == pytest.approx(14 / 12, rel=1e-3)
-    d = art["derived"]
-    m = art["measured"]
-    est = d["attention_free_estimate_equal_efficiency"]
-    cap = d["attention_free_hard_cap"]
-    # both attention-free figures dominate the measurement, and the
-    # assumption-free cap dominates the assumption-laden estimate
-    # (the cap deliberately has no reported-MFU form: the ratio
-    # exceeds 1.0 once unexecuted attention FLOPs stay in the
-    # numerator — a metric artifact, not a utilization)
-    assert est["steps_per_sec"] > m["flash_steps_per_sec"]
-    assert cap["steps_per_sec"] > est["steps_per_sec"]
-    assert "reported_mfu" not in cap
-    assert est["reported_mfu"] > m["flash_reported_mfu"]
-    # each figure states what it assumes — the estimate is NOT a bound
-    assert "assumption" in est and "profiled" in est["assumption"]
-    assert cap["assumption"].startswith("none")
-    # hardware MFU counts MORE flops at the same steps/s than reported
-    assert d["hardware_mfu_counting_executed_flops"] > \
-        m["flash_reported_mfu"]
-    # executed-FLOP share folds the recompute into the dense share
-    expect = share * rec / (1 - share + share * rec)
-    assert d["attention_share_of_executed_flops"] == \
-        pytest.approx(expect, rel=1e-3)
-    # the conclusion's dense comparator comes from the artifact's own
-    # data, never a hardcoded literal
-    if m["dense_steps_per_sec"]:
-        assert f"{m['dense_steps_per_sec']:.1f}" in art["conclusion"]
-
-
-def test_derives_from_committed_measurement(art):
-    src = os.path.join(REPO, art["provenance"]["measured_from"])
-    with open(src) as f:
-        measured = json.load(f)
-    t = art["provenance"]["shape"]["seq_len"]
-    leg = next(l for l in measured["legs"]
-               if l.get("seq_len") == t and l.get("attn") == "flash")
-    assert art["measured"]["flash_steps_per_sec"] == \
-        leg["steps_per_sec"]
-    # the traced step is the leg's step (the script enforces <=1% at
-    # generation time; pin it here too so a stale artifact fails)
-    assert art["flops_per_step_dense_equivalent"] == \
-        pytest.approx(leg["flops_per_step"], rel=0.01)
 
 
 def test_import_is_safe_without_artifacts(tmp_path):

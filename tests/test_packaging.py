@@ -8,12 +8,9 @@ import glob
 import os
 import subprocess
 import sys
+import tomllib
 
 import pytest
-
-# requires-python is >=3.10 but tomllib is 3.11+: skip the metadata
-# pins (not the whole suite) on 3.10 rather than failing collection
-tomllib = pytest.importorskip("tomllib")
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
