@@ -10,8 +10,8 @@ can run against a job that is still training.
 
 Output: per-phase count/total/mean/p50/p90 table; the client-level
 phase mix (client_fwd / transport / client_bwd / opt_apply — the same
-denominator as ``PhaseProfiler.fraction``, so ``transport_fraction``
-here reproduces ``fraction('transport')`` on the same run); the
+denominator as ``Tracer.fraction``, so ``transport_fraction`` here
+reproduces ``obs.recorder().fraction('transport')`` on the same run); the
 transport decomposition (encode / wire / server queue_wait + dispatch);
 and a per-step accounting check (client phases summed vs the measured
 ``step_total`` wall clock — the 10%-agreement acceptance gate of the
@@ -276,7 +276,7 @@ def render(rep: Dict[str, Any]) -> str:
         lines.append(f"  {name:<12} {frac:>7.1%}")
     lines.append(f"  -> transport fraction: "
                  f"{rep['transport_fraction']:.3f} "
-                 f"(== PhaseProfiler.fraction('transport'))")
+                 f"(== Tracer.fraction('transport'))")
     lines.append("")
     lines.append("transport decomposition (total seconds):")
     for name, s in rep["transport_decomposition_s"].items():

@@ -372,7 +372,7 @@ def check_slt002(src: Src) -> Iterator[Finding]:
 # SLT003: span names come from obs/spans.py
 # ---------------------------------------------------------------------- #
 
-_SPAN_SINKS = ("record", "record_span", "observe")
+_SPAN_SINKS = ("record", "record_span", "observe", "span", "span_at")
 
 
 def check_slt003(src: Src) -> Iterator[Finding]:

@@ -480,14 +480,17 @@ def cmd_train(args) -> int:
                 yield xy
         return gen()
 
-    from split_learning_tpu.utils.profiling import PhaseProfiler, device_trace
+    # --profile-dir: a jax.profiler session around the run. The
+    # program's own spans (obs/trace.py) are host events in that trace,
+    # beside the device operations and on their clock, and are recorded
+    # for as long as the session runs.
+    from split_learning_tpu.utils.profiling import device_trace
     profile_dir = getattr(args, "profile_dir", None)
-    phase_prof = PhaseProfiler() if profile_dir else None
     trace_ctx = device_trace(profile_dir)
 
-    # --trace: per-step span tracing (obs/) — orthogonal to --profile-dir
-    # (host-side spans vs the XLA device trace); off by default and
-    # zero-overhead when off
+    # --trace: record the spans for the whole run and export them as a
+    # Chrome trace (no profiler session needed); off by default, and off
+    # a span is an annotation and nothing else
     from split_learning_tpu import obs
     trace_path = getattr(args, "trace", None)
     step_tracer = obs.enable() if trace_path else None
@@ -1237,11 +1240,6 @@ def cmd_train(args) -> int:
             breaker = CircuitBreaker(transport.health, seed=cfg.seed)
         if cfg.mode == "split":
             if depth > 1:
-                if phase_prof is not None:
-                    print("[warn] --profile-dir phase accounting is not "
-                          "supported with --pipeline-depth > 1 (phases "
-                          "overlap by design); the XLA trace still "
-                          "records", file=sys.stderr)
                 from split_learning_tpu.runtime import (
                     PipelinedSplitClientTrainer)
                 client = PipelinedSplitClientTrainer(
@@ -1252,7 +1250,7 @@ def cmd_train(args) -> int:
                     plan, cfg, rng, transport,
                     failure_policy=fail_policy,
                     max_retries=getattr(args, "max_retries", 3),
-                    logger=logger, profiler=phase_prof, breaker=breaker)
+                    logger=logger, breaker=breaker)
             layout = "split_local" if server is not None else "client_only"
         elif cfg.mode == "u_split":
             client = USplitClientTrainer(plan, cfg, rng, transport,
@@ -1385,15 +1383,19 @@ def cmd_train(args) -> int:
                 full_params = [client.state.params,
                                server.export_state().params]
 
-    if phase_prof is not None and phase_prof.summary():
-        print(f"[profile] {json.dumps(phase_prof.summary())}", file=sys.stderr)
-        frac = phase_prof.fraction("transport")
-        if frac > 0:  # 0.0 = no transport phase (fused/single-program)
-            print(f"[profile] transport fraction: {frac:.3f}",
-                  file=sys.stderr)
     if profile_dir:
+        # the spans recorded while the profiler session ran
+        rec = obs.recorder()
+        if rec is not None and rec.phase_summary():
+            print(f"[profile] {json.dumps(rec.phase_summary())}",
+                  file=sys.stderr)
+            frac = rec.fraction(obs.spans.TRANSPORT)
+            if frac > 0:  # 0.0 = no transport phase (fused/single-program)
+                print(f"[profile] transport fraction: {frac:.3f}",
+                      file=sys.stderr)
         print(f"[profile] XLA trace written to {profile_dir} "
-              "(view in TensorBoard/Perfetto)", file=sys.stderr)
+              "(device operations and the program's spans on one "
+              "clock; view in TensorBoard/Perfetto)", file=sys.stderr)
     if step_tracer is not None:
         obs.disable()
         out_path = step_tracer.export_chrome(

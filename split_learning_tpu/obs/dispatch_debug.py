@@ -45,7 +45,6 @@ import itertools
 import os
 import sys
 import threading
-import time
 from typing import Any, Callable, Dict, Hashable, List, Optional
 
 from split_learning_tpu.obs import spans
@@ -167,11 +166,12 @@ class DispatchTracker:
         if rec is None:
             return  # setup/bench-harness compiles outside any step
         if is_backend:
-            tr = obs_trace.get_tracer()
-            if tr is not None:
-                tr.record(spans.COMPILE,
-                          time.perf_counter() - secs, secs,
-                          party="server", step=rec["ordinal"])
+            end = obs_trace.stamp()  # None unless something records
+            if end is not None:
+                # the event arrives when the compile is over
+                obs_trace.span_at(spans.COMPILE, end - int(secs * 1e9),
+                                  end, party="server",
+                                  step=rec["ordinal"])
         if rec["ordinal"] < _STEADY_ORDINAL or rec["fresh"]:
             return
         mark = (rec["key"], rec["ordinal"])

@@ -24,6 +24,15 @@ TRANSPORT = "transport"
 CLIENT_BWD = "client_bwd"
 OPT_APPLY = "opt_apply"
 STEP_TOTAL = "step_total"
+# children, never phases: a host<->device copy inside client_fwd /
+# client_bwd (and the fused step), with its ``bytes``; a record's
+# ``party`` tells the client's ``d2h`` from the server's
+H2D = "h2d"
+# the parent-less root of one MultiClientSplitRunner round, on the
+# driving thread (the clients' threads name it in an attribute)
+ROUND = "round"
+# the fused step's blocking read of the loss (runtime/fused.py)
+LOSS_WAIT = "loss_wait"
 
 # -- server-party spans ------------------------------------------------ #
 QUEUE_WAIT = "queue_wait"
@@ -31,8 +40,9 @@ DISPATCH = "dispatch"
 D2H = "d2h"
 
 # metrics-histogram-only name (never a trace span — it would
-# double-cover ``dispatch`` on a timeline); fed by the traced runtime
-# and, under SLT_LOCK_DEBUG=1, by obs/locks.py InstrumentedLock
+# double-cover ``dispatch`` on a timeline); fed from the ``dispatch``
+# span while recording and, under SLT_LOCK_DEBUG=1, by obs/locks.py
+# InstrumentedLock
 LOCK_HOLD = "lock_hold"
 
 # -- admission control (runtime/admission.py) -------------------------- #
@@ -219,4 +229,4 @@ TRANSPORT_SUB = (ENCODE, WIRE, QUEUE_WAIT, DISPATCH, D2H)
 
 ALL_SPANS = (CLIENT_FWD, ENCODE, WIRE, TRANSPORT, CLIENT_BWD, OPT_APPLY,
              STEP_TOTAL, QUEUE_WAIT, DISPATCH, D2H, REPLY_GRAD,
-             DEFERRED_APPLY)
+             DEFERRED_APPLY, ROUND, H2D, LOSS_WAIT)

@@ -69,7 +69,6 @@ class SplitClientTrainer:
                  max_retries: int = 3,
                  retry_backoff: float = 0.5,
                  logger: Optional[Any] = None,
-                 profiler: Optional[Any] = None,
                  client_id: int = 0,
                  breaker: Optional[Any] = None) -> None:
         """retry_backoff: base seconds for exponential backoff between
@@ -90,9 +89,6 @@ class SplitClientTrainer:
         self.breaker = breaker
         self.logger = logger
         self.client_id = client_id
-        self.profiler = profiler  # PhaseProfiler: compute-vs-transport split
-        self._phase = (profiler.phase if profiler is not None
-                       else (lambda _name: contextlib.nullcontext()))
         self.dropped_batches = 0
 
         client_idx = plan.stages_of("client")
@@ -134,37 +130,45 @@ class SplitClientTrainer:
             params = self.plan.init(self._rng, jnp.asarray(sample_x))[0]
             self.state = make_state(params, self._tx)
 
-    def train_step(self, x: np.ndarray, y: np.ndarray,
-                   step: int) -> Optional[float]:
+    def train_step(self, x: np.ndarray, y: np.ndarray, step: int,
+                   round_no: Optional[int] = None) -> Optional[float]:
         """One split step; returns the loss, or None if the batch was
-        dropped under the 'skip' policy.
+        dropped under the 'skip' policy. ``round_no`` is the
+        MultiClientSplitRunner round this step belongs to, kept as the
+        ``round`` attribute of the step's root span.
 
-        Tracing (obs/trace.py): with the global tracer off (`tr is
-        None`, the default) every instrumentation branch below is dead —
-        no clock reads, no allocations, the untraced hot path. With it
-        on, the step gets a trace id (propagated to the server through
-        the transport via CTX) and spans client_fwd / transport /
-        client_bwd / opt_apply / step_total; the extra block_until_ready
-        syncs exist only so span boundaries measure device work, and are
-        the documented tracing overhead."""
-        prof = self.profiler
-        phase = self._phase
-        tr = obs_trace.get_tracer()
-
+        Tracing (obs/trace.py): the step is one ``step_total`` span
+        tiled by ``client_fwd`` (the inputs' ``h2d``, the jitted
+        forward, the cut tensor's ``d2h``), ``transport``,
+        ``client_bwd`` (the ``h2d`` of the inputs again and of the cut
+        gradient, the jitted backward) and ``opt_apply``. Off (the
+        default) a span is an annotation and nothing else: no record,
+        no trace id, no payload key. On, the step gets a trace id
+        (propagated to the server through the transport via CTX) and
+        one record a span. Tracing adds no sync either way: a span
+        measures what this thread did, including the waits the program
+        itself makes (``np.asarray(acts)`` blocks on the device, and is
+        where the previous step's still-running optimizer work shows
+        up); what the device did meanwhile is the device trace's to
+        say."""
         self.ensure_init(x)
-        tid = tr.new_trace_id(self.client_id, step) if tr is not None else None
-        t_step0 = time.perf_counter() if tr is not None else 0.0
-        with phase("compute_fwd"):
+        with obs_trace.span(spans.STEP_TOTAL, tid=self.client_id, step=step,
+                            trace=(self.client_id, step), round=round_no):
+            return self._train_step(x, y, step)
+
+    def _train_step(self, x: np.ndarray, y: np.ndarray,
+                    step: int) -> Optional[float]:
+        with obs_trace.span(spans.CLIENT_FWD):
             with obs_dispatch.step_scope(
                     self._dd, (self._ddtok, "client_fwd"),
                     sig_fn=lambda: (x.shape, str(x.dtype))):
-                acts = self._fwd(self.state.params, jnp.asarray(x))
-            with obs_dispatch.expected_d2h(self._dd):
+                with obs_trace.span(spans.H2D, bytes=obs_trace.nbytes(x)):
+                    x_dev = jnp.asarray(x)
+                acts = self._fwd(self.state.params, x_dev)
+                del x_dev  # a temporary, as when the call made it inline
+            with obs_dispatch.expected_d2h(self._dd), \
+                    obs_trace.span(spans.D2H, bytes=obs_trace.nbytes(acts)):
                 acts_host = np.asarray(acts)
-        if tr is not None:
-            tr.record(spans.CLIENT_FWD, t_step0,
-                      time.perf_counter() - t_step0, trace_id=tid,
-                      tid=self.client_id, step=step)
 
         attempt = 0
         while True:
@@ -176,22 +180,11 @@ class SplitClientTrainer:
                     # open budget is spent, handled below like any wire
                     # failure
                     self.breaker.before_attempt()
-                if tid is not None:
-                    obs_trace.CTX.trace_id = tid
-                t_tr0 = time.perf_counter() if tr is not None else 0.0
-                try:
-                    with phase("transport"):
-                        g_acts, loss = self.transport.split_step(
-                            acts_host, np.asarray(y), step, self.client_id)
-                finally:
-                    if tid is not None:
-                        obs_trace.CTX.trace_id = None
+                with obs_trace.span(spans.TRANSPORT):
+                    g_acts, loss = self.transport.split_step(
+                        acts_host, np.asarray(y), step, self.client_id)
                 if self.breaker is not None:
                     self.breaker.record_success()
-                if tr is not None:
-                    tr.record(spans.TRANSPORT, t_tr0,
-                              time.perf_counter() - t_tr0, trace_id=tid,
-                              tid=self.client_id, step=step)
                 break
             except Backpressure as exc:
                 # explicit 429/Retry-After: flow control from a healthy
@@ -231,31 +224,18 @@ class SplitClientTrainer:
                     return None
                 raise
 
-        with phase("compute_bwd"):
-            t_b0 = time.perf_counter() if tr is not None else 0.0
+        with obs_trace.span(spans.CLIENT_BWD):
             with obs_dispatch.step_scope(
                     self._dd, (self._ddtok, "client_bwd"),
                     sig_fn=lambda: (x.shape, str(x.dtype),
                                     np.asarray(g_acts).shape)):
-                g_params = self._bwd(self.state.params, jnp.asarray(x),
-                                     jnp.asarray(g_acts))
-            if tr is not None:
-                jax.block_until_ready(g_params)
-                t_b1 = time.perf_counter()
-                tr.record(spans.CLIENT_BWD, t_b0, t_b1 - t_b0, trace_id=tid,
-                          tid=self.client_id, step=step)
-            t_o0 = time.perf_counter() if tr is not None else 0.0
+                with obs_trace.span(spans.H2D,
+                                    bytes=obs_trace.nbytes(x, g_acts)):
+                    x_dev, g_dev = jnp.asarray(x), jnp.asarray(g_acts)
+                g_params = self._bwd(self.state.params, x_dev, g_dev)
+                del x_dev, g_dev
+        with obs_trace.span(spans.OPT_APPLY):
             self.state = apply_grads(self._tx, self.state, g_params)
-            if prof is not None or tr is not None:
-                # sync only when timing accuracy matters
-                jax.block_until_ready(self.state.params)
-            if tr is not None:
-                tr.record(spans.OPT_APPLY, t_o0, time.perf_counter() - t_o0,
-                          trace_id=tid, tid=self.client_id, step=step)
-        if tr is not None:
-            tr.record(spans.STEP_TOTAL, t_step0,
-                      time.perf_counter() - t_step0, trace_id=tid,
-                      tid=self.client_id, step=step)
         return loss
 
     def train(self, data_iter: Callable[[], Iterable[Tuple[np.ndarray, np.ndarray]]],

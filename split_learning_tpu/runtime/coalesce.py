@@ -86,12 +86,13 @@ class CoalesceRequest:
     # redeems on the waiter thread — see ServerRuntime._GroupD2H
     result: Optional[Any] = None
     error: Optional[BaseException] = None
-    # obs (obs/trace.py), set by submit() only while tracing is enabled:
-    # the caller's trace id, the enqueue timestamp (queue_wait =
-    # enqueue -> group pickup, window wait included), and the dispatcher's
-    # span timings written back for the waiter to surface
+    # obs (obs/trace.py), set by submit() only while something records:
+    # the caller's trace id, the enqueue stamp in nanoseconds on the
+    # spans' clock (queue_wait = enqueue -> group pickup, window wait
+    # included), and the dispatcher's span timings written back for the
+    # waiter to surface
     trace_id: Optional[str] = None
-    t_enqueue: Optional[float] = None
+    t_enqueue: Optional[int] = None
     server_spans: Optional[dict] = None
     # EDF priority (continuous mode): the monotonic-clock SLO deadline
     # the admission layer stamped, None = no SLO (sorts last, FIFO)
@@ -157,7 +158,7 @@ class RequestCoalescer:
     def submit(self, acts: np.ndarray, labels: np.ndarray, step: int,
                client_id: int, timeout: float = 120.0,
                trace_id: Optional[str] = None,
-               t_enqueue: Optional[float] = None,
+               t_enqueue: Optional[int] = None,
                deadline: Optional[float] = None
                ) -> Tuple[np.ndarray, float]:
         """Enqueue one request and block until its group's dispatch
@@ -166,7 +167,8 @@ class RequestCoalescer:
         identical to the serialized path.
 
         ``trace_id``/``t_enqueue`` (obs): set by the runtime only while
-        tracing is on; the dispatcher's span timings come back via
+        something records (``obs.stamp()``: nanoseconds on the spans'
+        clock); the dispatcher's span timings come back via
         ``req.server_spans`` and are republished on this caller thread's
         CTX so the transport can return them to the client."""
         req = CoalesceRequest(np.asarray(acts), np.asarray(labels),

@@ -32,6 +32,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from split_learning_tpu.core.losses import cross_entropy
 from split_learning_tpu.core.stage import SplitPlan, remat_plan
 from split_learning_tpu.obs import dispatch_debug as obs_dispatch
+from split_learning_tpu.obs import spans
+from split_learning_tpu.obs import trace as obs_trace
 from split_learning_tpu.parallel.mesh import (
     DATA_AXIS, SEQ_AXIS, batch_sharding, replicated, tp_param_sharding)
 from split_learning_tpu.runtime.state import (
@@ -181,18 +183,28 @@ class FusedSplitTrainer:
         self._ddtok = obs_dispatch.token()
 
     def train_step(self, x: np.ndarray, y: np.ndarray) -> float:
-        """One fused step on the global batch (sharded over clients)."""
-        x = jnp.asarray(x)
-        y = jnp.asarray(y)
-        if self._x_sharding is not None:
-            x = jax.device_put(x, self._x_sharding)
-            y = jax.device_put(y, self._y_sharding)
-        with obs_dispatch.step_scope(
+        """One fused step on the global batch (sharded over clients).
+        Spans (obs/trace.py): ``step_total`` > ``h2d`` (the inputs),
+        ``dispatch`` (the call of the jitted step), ``loss_wait`` (the
+        blocking read of the loss, where the device's time shows)."""
+        with obs_trace.span(spans.STEP_TOTAL):
+            loss = self._dispatch_step(x, y)
+            with obs_dispatch.expected_d2h(self._dd), \
+                    obs_trace.span(spans.LOSS_WAIT):
+                return float(loss)
+
+    def _dispatch_step(self, x, y) -> jax.Array:
+        with obs_trace.span(spans.H2D, bytes=obs_trace.nbytes(x, y)):
+            x = jnp.asarray(x)
+            y = jnp.asarray(y)
+            if self._x_sharding is not None:
+                x = jax.device_put(x, self._x_sharding)
+                y = jax.device_put(y, self._y_sharding)
+        with obs_trace.span(spans.DISPATCH), obs_dispatch.step_scope(
                 self._dd, (self._ddtok, "fused_step"),
                 sig_fn=lambda: (x.shape, str(x.dtype), y.shape)):
             self.state, loss = self._step(self.state, x, y)
-        with obs_dispatch.expected_d2h(self._dd):
-            return float(loss)
+        return loss
 
     def train_epoch(self, xs, ys) -> jax.Array:
         """Run ``xs.shape[0]`` steps in one device dispatch; returns the
@@ -212,16 +224,8 @@ class FusedSplitTrainer:
     def train_step_async(self, x, y) -> jax.Array:
         """Like train_step but does not block on the loss transfer —
         use in throughput benchmarks to keep the device queue full."""
-        x = jnp.asarray(x)
-        y = jnp.asarray(y)
-        if self._x_sharding is not None:
-            x = jax.device_put(x, self._x_sharding)
-            y = jax.device_put(y, self._y_sharding)
-        with obs_dispatch.step_scope(
-                self._dd, (self._ddtok, "fused_step"),
-                sig_fn=lambda: (x.shape, str(x.dtype), y.shape)):
-            self.state, loss = self._step(self.state, x, y)
-        return loss
+        with obs_trace.span(spans.STEP_TOTAL):
+            return self._dispatch_step(x, y)
 
     def step_flops(self, x, y) -> float:
         """MXU-relevant FLOPs of one optimizer step (fwd + bwd + update),

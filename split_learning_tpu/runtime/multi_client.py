@@ -22,6 +22,8 @@ import jax
 import numpy as np
 
 from split_learning_tpu.core.stage import SplitPlan
+from split_learning_tpu.obs import spans
+from split_learning_tpu.obs import trace as obs_trace
 from split_learning_tpu.runtime.client import SplitClientTrainer
 from split_learning_tpu.runtime.state import TrainState
 from split_learning_tpu.transport.base import Transport
@@ -37,7 +39,6 @@ class MultiClientSplitRunner:
                  sync_bottoms_every: int = 0,
                  logger: Optional[Any] = None,
                  concurrent: bool = False,
-                 profiler: Optional[Any] = None,
                  sync_compress: Optional[str] = None,
                  sync_density: float = 0.1) -> None:
         """transport_factory(client_id) -> a Transport for that client.
@@ -48,9 +49,6 @@ class MultiClientSplitRunner:
         traffic in front of a coalescing server (ServerRuntime
         coalesce_max > 1). Round-robin stays the default: it is the
         deterministic relay schedule the interleaving tests pin.
-        profiler: one PhaseProfiler shared by every client (it is
-        thread-safe, so concurrent=True rounds aggregate correctly) —
-        the pooled compute-vs-transport split across the fleet.
         sync_compress: None (default) keeps sync_bottoms dense and
         bit-for-bit legacy. "topk8"/"clapping" route each client's
         contribution through the wire codec as a delta from the last
@@ -70,7 +68,7 @@ class MultiClientSplitRunner:
         self.clients: List[SplitClientTrainer] = [
             SplitClientTrainer(
                 plan, cfg, jax.random.fold_in(rng, i) if n > 1 else rng,
-                transport_factory(i), client_id=i, profiler=profiler)
+                transport_factory(i), client_id=i)
             for i in range(n)
         ]
         self._steps = [0] * n
@@ -99,29 +97,36 @@ class MultiClientSplitRunner:
                 f"expected {len(self.clients)} batches, "
                 f"got {len(batches_per_client)}")
 
+        # ``round`` is the parent-less root of the round on the driving
+        # thread; a client's step (its own thread when concurrent) names
+        # it by number in its first span, not as parent
+        rnd = self._rounds
+
         def one(i: int, client: SplitClientTrainer,
                 x: np.ndarray, y: np.ndarray) -> float:
             step = self._steps[i]
-            loss = client.train_step(x, y, step)
+            loss = client.train_step(x, y, step, round_no=rnd)
             self._steps[i] += 1
             if loss is not None and self.logger is not None:
                 self.logger.log_metric(f"loss_client{i}", loss, step=step)
             return loss
 
-        if self.concurrent and len(self.clients) > 1:
-            if self._pool is None:
-                self._pool = ThreadPoolExecutor(
-                    max_workers=len(self.clients),
-                    thread_name_prefix="slt-client")
-            futures = [
-                self._pool.submit(one, i, client, x, y)
-                for i, (client, (x, y)) in enumerate(
-                    zip(self.clients, batches_per_client))]
-            losses = [f.result() for f in futures]
-        else:
-            losses = [one(i, client, x, y)
-                      for i, (client, (x, y)) in enumerate(
-                          zip(self.clients, batches_per_client))]
+        with obs_trace.span(spans.ROUND, step=rnd,
+                            clients=len(self.clients)):
+            if self.concurrent and len(self.clients) > 1:
+                if self._pool is None:
+                    self._pool = ThreadPoolExecutor(
+                        max_workers=len(self.clients),
+                        thread_name_prefix="slt-client")
+                futures = [
+                    self._pool.submit(one, i, client, x, y)
+                    for i, (client, (x, y)) in enumerate(
+                        zip(self.clients, batches_per_client))]
+                losses = [f.result() for f in futures]
+            else:
+                losses = [one(i, client, x, y)
+                          for i, (client, (x, y)) in enumerate(
+                              zip(self.clients, batches_per_client))]
         self._rounds += 1
         if (self.sync_bottoms_every
                 and self._rounds % self.sync_bottoms_every == 0):

@@ -35,7 +35,6 @@ batch-drop semantics; wrap the transport in retries if the link flakes.
 from __future__ import annotations
 
 import contextlib
-import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Callable, Iterable, List, Optional, Tuple
 
@@ -106,27 +105,14 @@ class PipelinedSplitClientTrainer:
         # batches later, and np.asarray of a caller-recycled buffer would
         # hand it different data (same hazard as x, fixed in train())
         y_copy = np.array(y, copy=True)
-        tr = obs_trace.get_tracer()
-        if tr is None:  # untraced hot path: submit the bare call
-            return self._pool.submit(
-                transport.split_step, acts, y_copy, step, self.client_id)
-
-        # traced: the trace id must ride the LANE thread's CTX (thread-
-        # local), so wrap the call; the tid doubles as the Chrome-trace
-        # row, making the W-deep overlap visible per lane
-        tid = tr.new_trace_id(self.client_id, step)
-
+        # the trace id must ride the LANE thread's CTX (thread-local),
+        # so the transport span opens there; its tid is the lane, the
+        # Chrome-trace row that makes the W-deep overlap visible
         def call():
-            obs_trace.CTX.trace_id = tid
-            t0 = time.perf_counter()
-            try:
-                out = transport.split_step(acts, y_copy, step,
-                                           self.client_id)
-            finally:
-                obs_trace.CTX.trace_id = None
-            tr.record(spans.TRANSPORT, t0, time.perf_counter() - t0,
-                      trace_id=tid, tid=lane, step=step)
-            return out
+            with obs_trace.span(spans.TRANSPORT, tid=lane, step=step,
+                                trace=(self.client_id, step)):
+                return transport.split_step(acts, y_copy, step,
+                                            self.client_id)
 
         return self._pool.submit(call)
 
@@ -135,14 +121,9 @@ class PipelinedSplitClientTrainer:
         under the params the forward used, update current state."""
         params_then, xd, future = entry
         g_acts, loss = future.result()
-        tr = obs_trace.get_tracer()
-        t0 = time.perf_counter() if tr is not None else 0.0
-        g_params = self._bwd(params_then, xd, jnp.asarray(g_acts))
-        self.state = apply_grads(self._tx, self.state, g_params)
-        if tr is not None:
-            jax.block_until_ready(self.state.params)
-            tr.record(spans.CLIENT_BWD, t0, time.perf_counter() - t0,
-                      tid=self.client_id)
+        with obs_trace.span(spans.CLIENT_BWD, tid=self.client_id):
+            g_params = self._bwd(params_then, xd, jnp.asarray(g_acts))
+            self.state = apply_grads(self._tx, self.state, g_params)
         return loss
 
     def train(self, data_iter: Callable[[], Iterable[Tuple[np.ndarray, np.ndarray]]],
@@ -173,14 +154,10 @@ class PipelinedSplitClientTrainer:
                     # buffer: the remat backward re-reads it up to depth-1
                     # batches later, and a loader that recycles one numpy
                     # buffer per batch would silently hand it different data
-                    tr = obs_trace.get_tracer()
-                    t_f0 = time.perf_counter() if tr is not None else 0.0
-                    xd = jnp.asarray(x)
-                    acts = np.asarray(self._fwd(self.state.params, xd))
-                    if tr is not None:
-                        tr.record(spans.CLIENT_FWD, t_f0,
-                                  time.perf_counter() - t_f0,
-                                  tid=self.client_id, step=step)
+                    with obs_trace.span(spans.CLIENT_FWD,
+                                        tid=self.client_id, step=step):
+                        xd = jnp.asarray(x)
+                        acts = np.asarray(self._fwd(self.state.params, xd))
                     lane = step % self.depth
                     window.append((self.state.params, xd,
                                    self._submit(lane, acts, y, step), step))

@@ -420,10 +420,9 @@ class ServerRuntime(PartyRuntime):
             entry, owner = self.replay.begin(client_id, "split_step", step)
             if not owner:
                 return self.replay.wait(entry)
-        # obs: tr stays None by default, and every timing site below is
-        # gated on it — the untraced serialized path takes no extra
-        # locks and allocates nothing (the zero-overhead-off contract)
-        tr = obs_trace.get_tracer()
+        # obs: every span below is made by obs_trace.span — off, an
+        # annotation and nothing else (no record, no clock read, no
+        # lock); on, one record a span. Tracing adds no sync.
         admitted = False
         deadline = None
         try:
@@ -436,17 +435,13 @@ class ServerRuntime(PartyRuntime):
             if self._coalescer is not None:
                 # block on the group's future; the handshake runs at
                 # dispatch-admission time so a replayed step 409s its own
-                # client without poisoning the group
-                if tr is None:
-                    res = self._coalescer.submit(activations, labels,
-                                                 step, client_id,
-                                                 deadline=deadline)
-                else:
-                    res = self._coalescer.submit(
-                        activations, labels, step, client_id,
-                        trace_id=obs_trace.CTX.trace_id,
-                        t_enqueue=time.perf_counter(),
-                        deadline=deadline)
+                # client without poisoning the group. The trace id and
+                # the enqueue stamp are None unless something records.
+                res = self._coalescer.submit(
+                    activations, labels, step, client_id,
+                    trace_id=obs_trace.CTX.trace_id,
+                    t_enqueue=obs_trace.stamp(),
+                    deadline=deadline)
                 if entry is not None:
                     self.replay.resolve(entry, res)
                 if admitted:
@@ -458,97 +453,96 @@ class ServerRuntime(PartyRuntime):
                               client_id=client_id, party="server",
                               op="split_step", coalesced=True)
                 return res
-            t_q0 = time.perf_counter() if tr is not None else 0.0
-            with self._lock:
-                t_d0 = time.perf_counter() if tr is not None else 0.0
-                self._check_step(step, client_id)
-                self._check_batch_rows(int(np.shape(activations)[0]))
-                if self._deferred is not None:
-                    # 2BP: dispatch the reply program on the current
-                    # (<= apply_lag steps stale) weights, queue the
-                    # weight update with its on-device residuals, and
-                    # drain only the over-lag tail. The drained applies
-                    # dispatch AFTER the reply, so the device runs the
-                    # client-visible work first; a replayed duplicate
-                    # never reaches here (the begin() claim above), so
-                    # it can never re-enqueue an apply.
-                    acts_dev = self._to_dev(activations)
-                    labels_dev = self._to_dev(labels)
-                    with obs_dispatch.step_scope(
-                            self._dd, (self._ddtok, "reply_grad"),
-                            sig_fn=lambda: (activations.shape,
-                                            str(activations.dtype),
-                                            labels.shape,
-                                            str(labels.dtype))):
-                        g_acts, loss = self._reply_step(
-                            self.state.params, acts_dev, labels_dev)
-                    self._deferred.push({
-                        "kind": "single", "step": step,
-                        "client_id": client_id,
-                        "fwd_params": self.state.params,
-                        "acts": acts_dev, "labels": labels_dev})
-                    self._deferred.drain_over_lag()
-                    if tr is not None:
-                        self._note_flops(
-                            "reply_grad", self._reply_step,
-                            (self.state.params, acts_dev, labels_dev),
-                            time.perf_counter() - t_d0)
-                else:
-                    acts_dev = self._to_dev(activations)
-                    labels_dev = self._to_dev(labels)
-                    with obs_dispatch.step_scope(
-                            self._dd, (self._ddtok, "split_step"),
-                            sig_fn=lambda: (activations.shape,
-                                            str(activations.dtype),
-                                            labels.shape,
-                                            str(labels.dtype))):
-                        self.state, g_acts, loss = self._split_step(
-                            self.state, acts_dev, labels_dev)
-                    if tr is not None:
-                        self._note_flops(
-                            "split_step", self._split_step,
-                            (self.state, acts_dev, labels_dev),
-                            time.perf_counter() - t_d0)
-                if not self.overlap:
-                    # legacy placement: the transfer rides inside the
-                    # lock (and inside the dispatch span — the old span
-                    # taxonomy, where dispatch = jit + materialization)
-                    self._sleep_d2h()
-                    with obs_dispatch.expected_d2h(self._dd):
-                        g_host = self._host_gather(g_acts)
-                        loss_f = float(loss)
-                # max(): with strict_steps off (pipelined clients) steps
-                # can arrive out of order, and the acknowledged step —
-                # what /health reports and checkpoints are labeled with —
-                # must never regress below state the server has absorbed
-                acked = max(self._last_step.get(client_id, -1), step)
-                self._last_step[client_id] = acked
-                if self.on_step is not None:
-                    self.on_step(acked)
-                t_d1 = time.perf_counter() if tr is not None else 0.0
+            who = {"party": "server", "tid": client_id, "step": step,
+                   "registry": self._metrics}
+            wait = obs_trace.span(spans.QUEUE_WAIT, **who)
+            with wait, self._lock:
+                wait.close()  # queue_wait ends where the lock is held
+                with obs_trace.span(spans.DISPATCH, **who) as disp:
+                    self._check_step(step, client_id)
+                    self._check_batch_rows(int(np.shape(activations)[0]))
+                    with obs_trace.span(
+                            spans.H2D,
+                            bytes=obs_trace.nbytes(activations, labels)):
+                        acts_dev = self._to_dev(activations)
+                        labels_dev = self._to_dev(labels)
+                    if self._deferred is not None:
+                        # 2BP: dispatch the reply program on the current
+                        # (<= apply_lag steps stale) weights, queue the
+                        # weight update with its on-device residuals, and
+                        # drain only the over-lag tail. The drained applies
+                        # dispatch AFTER the reply, so the device runs the
+                        # client-visible work first; a replayed duplicate
+                        # never reaches here (the begin() claim above), so
+                        # it can never re-enqueue an apply.
+                        with obs_dispatch.step_scope(
+                                self._dd, (self._ddtok, "reply_grad"),
+                                sig_fn=lambda: (activations.shape,
+                                                str(activations.dtype),
+                                                labels.shape,
+                                                str(labels.dtype))):
+                            g_acts, loss = self._reply_step(
+                                self.state.params, acts_dev, labels_dev)
+                        self._deferred.push({
+                            "kind": "single", "step": step,
+                            "client_id": client_id,
+                            "fwd_params": self.state.params,
+                            "acts": acts_dev, "labels": labels_dev})
+                        self._deferred.drain_over_lag()
+                        if obs_trace.enabled():
+                            self._note_flops(
+                                "reply_grad", self._reply_step,
+                                (self.state.params, acts_dev, labels_dev),
+                                disp.elapsed_s())
+                    else:
+                        with obs_dispatch.step_scope(
+                                self._dd, (self._ddtok, "split_step"),
+                                sig_fn=lambda: (activations.shape,
+                                                str(activations.dtype),
+                                                labels.shape,
+                                                str(labels.dtype))):
+                            self.state, g_acts, loss = self._split_step(
+                                self.state, acts_dev, labels_dev)
+                        if obs_trace.enabled():
+                            self._note_flops(
+                                "split_step", self._split_step,
+                                (self.state, acts_dev, labels_dev),
+                                disp.elapsed_s())
+                    if not self.overlap:
+                        # legacy placement: the transfer rides inside the
+                        # lock (and inside the dispatch span — the old span
+                        # taxonomy, where dispatch = jit + materialization)
+                        self._sleep_d2h()
+                        with obs_dispatch.expected_d2h(self._dd):
+                            g_host = self._host_gather(g_acts)
+                            loss_f = float(loss)
+                    # max(): with strict_steps off (pipelined clients) steps
+                    # can arrive out of order, and the acknowledged step —
+                    # what /health reports and checkpoints are labeled with —
+                    # must never regress below state the server has absorbed
+                    acked = max(self._last_step.get(client_id, -1), step)
+                    self._last_step[client_id] = acked
+                    if self.on_step is not None:
+                        self.on_step(acked)
             fl = obs_flight.get_recorder()
             if fl is not None:
                 fl.record(spans.FL_DISPATCH, step=step,
                           client_id=client_id, party="server",
                           program=("reply_grad" if self._deferred
                                    is not None else "split_step"))
+            d2h = None
             if self.overlap:
                 # off the lock: the jitted call above returned device
                 # futures (async dispatch), so forcing the transfer here
                 # lets step t's D2H overlap step t+1's device compute
-                self._sleep_d2h()
-                with obs_dispatch.expected_d2h(self._dd):
-                    g_host = self._host_gather(g_acts)
-                    loss_f = float(loss)
-            if tr is not None and self._deferred is not None:
-                # the client-visible reply window: reply dispatch ->
-                # cut-layer gradient on host (what the 2BP bench leg
-                # compares against the coupled dispatch+d2h)
-                rw = time.perf_counter() - t_d0
-                tr.record(spans.REPLY_GRAD, t_d0, rw,
-                          trace_id=obs_trace.CTX.trace_id,
-                          party="server", tid=client_id, step=step)
-                self._metrics.observe(spans.REPLY_GRAD, rw)
+                with obs_trace.span(spans.D2H, bytes=obs_trace.nbytes(g_acts, loss),
+                                    **who) as d2h:
+                    self._sleep_d2h()
+                    with obs_dispatch.expected_d2h(self._dd):
+                        g_host = self._host_gather(g_acts)
+                        loss_f = float(loss)
+            if disp.recording:
+                self._publish_server_spans(wait, disp, d2h, who)
             res = (g_host, loss_f)
             if entry is not None:
                 self.replay.resolve(entry, res)
@@ -559,11 +553,6 @@ class ServerRuntime(PartyRuntime):
                 fl.record(spans.FL_REPLY, step=step, client_id=client_id,
                           party="server", op="split_step",
                           coalesced=False)
-            if tr is not None:
-                self._record_server_spans(
-                    tr, t_q0, t_d0 - t_q0, t_d0, t_d1 - t_d0, t_d1,
-                    (time.perf_counter() - t_d1) if self.overlap else 0.0,
-                    obs_trace.CTX.trace_id, step, client_id)
             return res
         except BaseException as exc:
             # the apply never produced a reply (admission 409, quota
@@ -581,34 +570,24 @@ class ServerRuntime(PartyRuntime):
                 self.replay.fail(entry, exc)
             raise
 
-    def _record_server_spans(self, tr, t_q0: float, qw: float,
-                             t_d0: float, dw: float,
-                             t_h0: float, hw: float,
-                             trace_id: Optional[str], step: int,
-                             client_id: int) -> None:
-        """Record one step's server-party spans into the tracer and the
-        /metrics histograms, and publish them to CTX.server_spans so the
-        transport can hand them back to the client (wire accounting).
-
-        ``dispatch`` is the lock-held window (admission + jitted call;
-        with overlap off it also contains the materialization — the old
-        taxonomy); ``d2h`` (hw > 0, overlap on) is the off-lock
-        materialization. ``lock_hold`` goes to the metrics histogram
-        only (``slt_lock_hold_seconds``) — as a trace span it would
-        double-cover the dispatch window."""
-        tr.record(spans.QUEUE_WAIT, t_q0, qw, trace_id=trace_id,
-                  party="server", tid=client_id, step=step)
-        tr.record(spans.DISPATCH, t_d0, dw, trace_id=trace_id,
-                  party="server", tid=client_id, step=step)
-        self._metrics.observe(spans.QUEUE_WAIT, qw)
-        self._metrics.observe(spans.DISPATCH, dw)
-        self._metrics.observe(spans.LOCK_HOLD, dw)
-        srv_spans = {spans.QUEUE_WAIT: qw, spans.DISPATCH: dw}
-        if hw > 0.0:
-            tr.record(spans.D2H, t_h0, hw, trace_id=trace_id,
-                      party="server", tid=client_id, step=step)
-            self._metrics.observe(spans.D2H, hw)
-            srv_spans[spans.D2H] = hw
+    def _publish_server_spans(self, wait, disp, d2h, who: dict) -> None:
+        """What one recorded serialized step adds beyond its spans: the
+        ``lock_hold`` histogram (fed from ``dispatch``, the lock-held
+        window — as a span it would double-cover it), the step counter,
+        on a decoupled server the ``reply_grad`` window (reply dispatch
+        -> cut-layer gradient on host, what the 2BP bench leg compares
+        against the coupled dispatch + d2h), and ``CTX.server_spans``,
+        so the transport can hand the server's seconds back to the
+        client (wire accounting). ``d2h`` is None with overlap off:
+        ``dispatch`` then contains the materialization."""
+        srv_spans = {spans.QUEUE_WAIT: wait.duration_s,
+                     spans.DISPATCH: disp.duration_s}
+        if d2h is not None:
+            srv_spans[spans.D2H] = d2h.duration_s
+        if self._deferred is not None:
+            obs_trace.span_at(spans.REPLY_GRAD, disp.t0,
+                              (d2h if d2h is not None else disp).t1, **who)
+        self._metrics.observe(spans.LOCK_HOLD, disp.duration_s)
         self._metrics.incr("split_steps_total")
         obs_trace.CTX.server_spans = srv_spans
 
@@ -618,32 +597,28 @@ class ServerRuntime(PartyRuntime):
         materialized here, so draining inside a lock-held window is
         legal (SLT001) and cheap: the jitted call returns device futures
         and the lock is released long before they resolve."""
-        tr = obs_trace.get_tracer()
-        t0 = time.perf_counter() if tr is not None else 0.0
-        if entry["kind"] == "group":
-            # freshness captured at reply time holds here too: entries
-            # drain FIFO, so the first apply of a padded signature is
-            # exactly the apply of the first reply that saw it
-            with obs_dispatch.step_scope(
-                    self._dd, (self._ddtok, "group_deferred_apply"),
-                    fresh=entry["fresh"]):
-                self.state = self._group_deferred_apply(
-                    self.state, entry["fwd_params"], entry["acts"],
-                    entry["labels"], entry["weights"])
-        else:
-            acts, labels = entry["acts"], entry["labels"]
-            with obs_dispatch.step_scope(
-                    self._dd, (self._ddtok, "deferred_apply"),
-                    sig_fn=lambda: (acts.shape, str(acts.dtype),
-                                    labels.shape, str(labels.dtype))):
-                self.state = self._deferred_apply(
-                    self.state, entry["fwd_params"], acts, labels)
-        if tr is not None:
-            dw = time.perf_counter() - t0
-            tr.record(spans.DEFERRED_APPLY, t0, dw,
-                      trace_id=obs_trace.CTX.trace_id, party="server",
-                      tid=entry["client_id"], step=entry["step"])
-            self._metrics.observe(spans.DEFERRED_APPLY, dw)
+        with obs_trace.span(spans.DEFERRED_APPLY, party="server",
+                            tid=entry["client_id"], step=entry["step"],
+                            registry=self._metrics):
+            if entry["kind"] == "group":
+                # freshness captured at reply time holds here too:
+                # entries drain FIFO, so the first apply of a padded
+                # signature is exactly the apply of the first reply
+                # that saw it
+                with obs_dispatch.step_scope(
+                        self._dd, (self._ddtok, "group_deferred_apply"),
+                        fresh=entry["fresh"]):
+                    self.state = self._group_deferred_apply(
+                        self.state, entry["fwd_params"], entry["acts"],
+                        entry["labels"], entry["weights"])
+            else:
+                acts, labels = entry["acts"], entry["labels"]
+                with obs_dispatch.step_scope(
+                        self._dd, (self._ddtok, "deferred_apply"),
+                        sig_fn=lambda: (acts.shape, str(acts.dtype),
+                                        labels.shape, str(labels.dtype))):
+                    self.state = self._deferred_apply(
+                        self.state, entry["fwd_params"], acts, labels)
         fl = obs_flight.get_recorder()
         if fl is not None:
             fl.record(spans.FL_DEFER_APPLY, step=entry["step"],
@@ -659,172 +634,184 @@ class ServerRuntime(PartyRuntime):
         rows — exact, because the loss is per-example) and its
         segment-mean loss, so a group of one reproduces the serialized
         semantics and the client-side math never changes."""
-        tr = obs_trace.get_tracer()
-        # group pickup time: each request's queue_wait (enqueue -> here)
-        # includes the coalescer window wait by construction
-        t_pick = time.perf_counter() if tr is not None else 0.0
-        with self._lock:
-            t_lk0 = time.perf_counter() if tr is not None else 0.0
-            admitted = []
-            # a retry can land in the same flush window as its original:
-            # leaders compute, followers of the same (client, step) share
-            # the leader's reply. (With replay enabled, duplicates are
-            # already deduplicated upstream — split_step's begin() claim —
-            # so followers only arise on replay-disabled servers.)
-            leaders: Dict[Tuple[int, int], CoalesceRequest] = {}
-            followers: Dict[Tuple[int, int], list] = {}
+        # group pickup: each request's queue_wait runs from its enqueue
+        # (stamped in split_step, on the waiter's thread) to here, the
+        # coalescer window included — one span a request, both ends on
+        # the same clock
+        t_pick = obs_trace.stamp()
+        if t_pick is not None:
             for r in group:
-                key = (r.client_id, r.step)
-                if key in leaders:
-                    followers.setdefault(key, []).append(r)
-                    continue
-                try:
-                    self._check_step(r.step, r.client_id)
-                    leaders[key] = r
-                    admitted.append(r)
-                except ProtocolError as exc:
-                    r.error = exc
-                    r.done.set()
-            if not admitted:
-                return
-            sizes = [int(r.acts.shape[0]) for r in admitted]
-            total = sum(sizes)
-            padded = pow2_bucket(total)
-            if self._mesh_data > 1:
-                # mesh-aware group sizing: the padded group must tile the
-                # ``data`` axis exactly. pow2 buckets are already
-                # multiples when data is a power of two >= the bucket;
-                # the ceil covers small buckets and non-pow2 axes. Padded
-                # rows keep weight 0, so the objective is untouched.
-                padded = -(-max(padded, self._mesh_data)
-                           // self._mesh_data) * self._mesh_data
-            acts = np.concatenate([r.acts for r in admitted], axis=0)
-            labels = np.concatenate([r.labels for r in admitted], axis=0)
-            if padded > total:
-                acts = np.concatenate(
-                    [acts, np.zeros((padded - total,) + acts.shape[1:],
-                                    acts.dtype)])
-                labels = np.concatenate(
-                    [labels, np.zeros((padded - total,) + labels.shape[1:],
-                                      labels.dtype)])
-            weights = np.zeros((padded,), np.float32)
-            weights[:total] = 1.0 / total
-            sig = (acts.shape, acts.dtype.str, labels.dtype.str)
-            fresh = sig not in self._coalesce_shapes
-            if fresh:
-                self._coalesce_shapes.add(sig)
-                self._coalescer.stats.incr("compile_count")
-            t_d0 = time.perf_counter() if tr is not None else 0.0
-            # the coalescer already tracks padded-shape signatures (the
-            # compile_count counter above) — hand its freshness verdict
-            # to the watchdog instead of double-tracking
-            deferred_entry = None
-            acts_dev = self._to_dev(acts)
-            labels_dev = self._to_dev(labels)
-            w_dev = self._to_dev(weights)
-            if self._deferred is not None:
-                # 2BP group dispatch: reply program first (on the
-                # current weights), the group's single weight update
-                # queued and drained only after every member below holds
-                # its reply — replies before apply, by construction
-                with obs_dispatch.step_scope(
-                        self._dd, (self._ddtok, "group_reply"),
-                        fresh=fresh):
-                    g_acts, per_ex = self._group_reply_step(
-                        self.state.params, acts_dev, labels_dev, w_dev)
-                deferred_entry = {
-                    "kind": "group",
-                    "step": max(r.step for r in admitted),
-                    "client_id": -1,
-                    "fwd_params": self.state.params,
-                    "acts": acts_dev, "labels": labels_dev,
-                    "weights": w_dev, "fresh": fresh}
-                if tr is not None:
-                    self._note_flops(
-                        "group_reply", self._group_reply_step,
-                        (self.state.params, acts_dev, labels_dev, w_dev),
-                        time.perf_counter() - t_d0)
-            else:
-                with obs_dispatch.step_scope(
-                        self._dd, (self._ddtok, "coalesced_step"),
-                        fresh=fresh):
-                    self.state, g_acts, per_ex = self._coalesced_step(
-                        self.state, acts_dev, labels_dev, w_dev)
-                if tr is not None:
-                    self._note_flops(
-                        "coalesced_step", self._coalesced_step,
-                        (self.state, acts_dev, labels_dev, w_dev),
-                        time.perf_counter() - t_d0)
-            if not self.overlap:
-                # legacy placement: the whole group's transfer inside
-                # the lock (dispatch span = jit + materialization).
-                # ``rows=total`` gathers only the real rows — the padded
-                # tail (zero-weight, possibly on other devices) never
-                # crosses D2H, and the segment loop below never reads it.
-                self._sleep_d2h()
-                with obs_dispatch.expected_d2h(self._dd):
-                    g_acts = self._host_gather(g_acts, rows=total)
-                    per_ex = self._host_gather(per_ex, rows=total)
-            dw = time.perf_counter() - t_d0 if tr is not None else 0.0
-            fl = obs_flight.get_recorder()
-            if fl is not None:
-                # one causal event for the whole batched dispatch; the
-                # per-member replies are journaled by split_step
-                fl.record(spans.FL_DISPATCH,
-                          step=max(r.step for r in admitted),
-                          party="server",
-                          program=("group_reply" if self._deferred
-                                   is not None else "coalesced_step"),
-                          size=len(admitted), rows=total, padded=padded,
-                          reason=reason)
-            pg = (_GroupD2H(self, g_acts, per_ex, tr, rows=total)
-                  if self.overlap else None)
-            off = 0
-            for r, b in zip(admitted, sizes):
-                if self.overlap:
-                    # deferred: the flusher thread hands each waiter a
-                    # thunk instead of a value, so it is free to collect
-                    # group t+1 while group t's waiters share one D2H
-                    # (the first to arrive materializes; see _GroupD2H)
-                    r.result = pg.segment(r, off, b, total)
+                if r.t_enqueue is not None:
+                    obs_trace.span_at(
+                        spans.QUEUE_WAIT, r.t_enqueue, t_pick,
+                        party="server", tid=r.client_id, step=r.step,
+                        trace_id=r.trace_id, registry=self._metrics)
+        with self._lock:
+            # ONE dispatch span for the group's one lock-held window
+            # (not a copy per request: that counts the lock len(group)
+            # times in any sum); it names its requests in ``traces``
+            with obs_trace.span(spans.DISPATCH, party="server", tid=-1,
+                                step=max(r.step for r in group),
+                                registry=self._metrics,
+                                reason=reason) as disp:
+                admitted = []
+                # a retry can land in the same flush window as its original:
+                # leaders compute, followers of the same (client, step) share
+                # the leader's reply. (With replay enabled, duplicates are
+                # already deduplicated upstream — split_step's begin() claim —
+                # so followers only arise on replay-disabled servers.)
+                leaders: Dict[Tuple[int, int], CoalesceRequest] = {}
+                followers: Dict[Tuple[int, int], list] = {}
+                for r in group:
+                    key = (r.client_id, r.step)
+                    if key in leaders:
+                        followers.setdefault(key, []).append(r)
+                        continue
+                    try:
+                        self._check_step(r.step, r.client_id)
+                        leaders[key] = r
+                        admitted.append(r)
+                    except ProtocolError as exc:
+                        r.error = exc
+                        r.done.set()
+                if not admitted:
+                    return
+                sizes = [int(r.acts.shape[0]) for r in admitted]
+                total = sum(sizes)
+                padded = pow2_bucket(total)
+                if self._mesh_data > 1:
+                    # mesh-aware group sizing: the padded group must tile the
+                    # ``data`` axis exactly. pow2 buckets are already
+                    # multiples when data is a power of two >= the bucket;
+                    # the ceil covers small buckets and non-pow2 axes. Padded
+                    # rows keep weight 0, so the objective is untouched.
+                    padded = -(-max(padded, self._mesh_data)
+                               // self._mesh_data) * self._mesh_data
+                acts = np.concatenate([r.acts for r in admitted], axis=0)
+                labels = np.concatenate([r.labels for r in admitted], axis=0)
+                if padded > total:
+                    acts = np.concatenate(
+                        [acts, np.zeros((padded - total,) + acts.shape[1:],
+                                        acts.dtype)])
+                    labels = np.concatenate(
+                        [labels, np.zeros((padded - total,) + labels.shape[1:],
+                                          labels.dtype)])
+                weights = np.zeros((padded,), np.float32)
+                weights[:total] = 1.0 / total
+                sig = (acts.shape, acts.dtype.str, labels.dtype.str)
+                fresh = sig not in self._coalesce_shapes
+                if fresh:
+                    self._coalesce_shapes.add(sig)
+                    self._coalescer.stats.incr("compile_count")
+                # the coalescer already tracks padded-shape signatures (the
+                # compile_count counter above) — hand its freshness verdict
+                # to the watchdog instead of double-tracking
+                deferred_entry = None
+                with obs_trace.span(spans.H2D, bytes=obs_trace.nbytes(
+                        acts, labels, weights)):
+                    acts_dev = self._to_dev(acts)
+                    labels_dev = self._to_dev(labels)
+                    w_dev = self._to_dev(weights)
+                if self._deferred is not None:
+                    # 2BP group dispatch: reply program first (on the
+                    # current weights), the group's single weight update
+                    # queued and drained only after every member below holds
+                    # its reply — replies before apply, by construction
+                    with obs_dispatch.step_scope(
+                            self._dd, (self._ddtok, "group_reply"),
+                            fresh=fresh):
+                        g_acts, per_ex = self._group_reply_step(
+                            self.state.params, acts_dev, labels_dev, w_dev)
+                    deferred_entry = {
+                        "kind": "group",
+                        "step": max(r.step for r in admitted),
+                        "client_id": -1,
+                        "fwd_params": self.state.params,
+                        "acts": acts_dev, "labels": labels_dev,
+                        "weights": w_dev, "fresh": fresh}
+                    if obs_trace.enabled():
+                        self._note_flops(
+                            "group_reply", self._group_reply_step,
+                            (self.state.params, acts_dev, labels_dev, w_dev),
+                            disp.elapsed_s())
                 else:
-                    seg = (g_acts[off:off + b] * (total / b)).astype(
-                        g_acts.dtype, copy=False)
-                    r.result = (seg, float(per_ex[off:off + b].mean()))
-                off += b
-                for f in followers.get((r.client_id, r.step), ()):
-                    f.result = r.result
-                    f.done.set()
-                acked = max(self._last_step.get(r.client_id, -1), r.step)
-                self._last_step[r.client_id] = acked
-                if self.on_step is not None:
-                    self.on_step(acked)
-                if tr is not None and r.t_enqueue is not None:
-                    # per-request queue wait (incl. window); the batched
-                    # dispatch is one event shared by the whole group
-                    qw = max(t_pick - r.t_enqueue, 0.0)
-                    r.server_spans = {spans.QUEUE_WAIT: qw,
-                                      spans.DISPATCH: dw}
-                    tr.record(spans.QUEUE_WAIT, r.t_enqueue, qw,
-                              trace_id=r.trace_id, party="server",
-                              tid=r.client_id, step=r.step)
-                    tr.record(spans.DISPATCH, t_d0, dw,
-                              trace_id=r.trace_id, party="server",
-                              tid=r.client_id, step=r.step)
-                    self._metrics.observe(spans.QUEUE_WAIT, qw)
-                    self._metrics.observe(spans.DISPATCH, dw)
-                    self._metrics.incr("split_steps_total")
-                r.done.set()
-            if deferred_entry is not None:
-                # every member above already holds its result (or D2H
-                # thunk) and its done event is set; only now does the
-                # group's weight update enter the queue, and only the
-                # over-lag tail dispatches behind the replies
-                self._deferred.push(deferred_entry)
-                self._deferred.drain_over_lag()
-            if tr is not None:
-                self._metrics.observe(
-                    spans.LOCK_HOLD, time.perf_counter() - t_lk0)
+                    with obs_dispatch.step_scope(
+                            self._dd, (self._ddtok, "coalesced_step"),
+                            fresh=fresh):
+                        self.state, g_acts, per_ex = self._coalesced_step(
+                            self.state, acts_dev, labels_dev, w_dev)
+                    if obs_trace.enabled():
+                        self._note_flops(
+                            "coalesced_step", self._coalesced_step,
+                            (self.state, acts_dev, labels_dev, w_dev),
+                            disp.elapsed_s())
+                if not self.overlap:
+                    # legacy placement: the whole group's transfer inside
+                    # the lock (dispatch span = jit + materialization).
+                    # ``rows=total`` gathers only the real rows — the padded
+                    # tail (zero-weight, possibly on other devices) never
+                    # crosses D2H, and the segment loop below never reads it.
+                    self._sleep_d2h()
+                    with obs_dispatch.expected_d2h(self._dd):
+                        g_acts = self._host_gather(g_acts, rows=total)
+                        per_ex = self._host_gather(per_ex, rows=total)
+                # what a waiter is told of the group's one dispatch: the
+                # lock-held window up to the jitted call's return
+                dw = disp.elapsed_s()
+                if disp.recording:
+                    disp.set(group=len(admitted), rows=total,
+                             padded=padded,
+                             traces=[r.trace_id for r in admitted])
+                fl = obs_flight.get_recorder()
+                if fl is not None:
+                    # one causal event for the whole batched dispatch; the
+                    # per-member replies are journaled by split_step
+                    fl.record(spans.FL_DISPATCH,
+                              step=max(r.step for r in admitted),
+                              party="server",
+                              program=("group_reply" if self._deferred
+                                       is not None else "coalesced_step"),
+                              size=len(admitted), rows=total, padded=padded,
+                              reason=reason)
+                pg = (_GroupD2H(self, g_acts, per_ex, rows=total)
+                      if self.overlap else None)
+                off = 0
+                for r, b in zip(admitted, sizes):
+                    if self.overlap:
+                        # deferred: the flusher thread hands each waiter a
+                        # thunk instead of a value, so it is free to collect
+                        # group t+1 while group t's waiters share one D2H
+                        # (the first to arrive materializes; see _GroupD2H)
+                        r.result = pg.segment(r, off, b, total)
+                    else:
+                        seg = (g_acts[off:off + b] * (total / b)).astype(
+                            g_acts.dtype, copy=False)
+                        r.result = (seg, float(per_ex[off:off + b].mean()))
+                    off += b
+                    for f in followers.get((r.client_id, r.step), ()):
+                        f.result = r.result
+                        f.done.set()
+                    acked = max(self._last_step.get(r.client_id, -1), r.step)
+                    self._last_step[r.client_id] = acked
+                    if self.on_step is not None:
+                        self.on_step(acked)
+                    if t_pick is not None and r.t_enqueue is not None:
+                        # what this waiter hands back to its transport
+                        # (wire accounting); d2h is back-filled by the thunk
+                        qw = max(t_pick - r.t_enqueue, 0) * 1e-9
+                        r.server_spans = {spans.QUEUE_WAIT: qw,
+                                          spans.DISPATCH: dw}
+                        self._metrics.incr("split_steps_total")
+                    r.done.set()
+                if deferred_entry is not None:
+                    # every member above already holds its result (or D2H
+                    # thunk) and its done event is set; only now does the
+                    # group's weight update enter the queue, and only the
+                    # over-lag tail dispatches behind the replies
+                    self._deferred.push(deferred_entry)
+                    self._deferred.drain_over_lag()
+            if disp.recording:
+                self._metrics.observe(spans.LOCK_HOLD, disp.duration_s)
 
     def predict(self, activations: np.ndarray,
                 client_id: int = 0) -> np.ndarray:
@@ -1092,60 +1079,56 @@ class _GroupD2H:
     else reads the cached host arrays. The device references are dropped
     after the transfer so the group's buffers are not pinned past it."""
 
-    __slots__ = ("_runtime", "_g_dev", "_per_ex_dev", "_tr", "_rows",
-                 "_lock", "g", "per_ex", "t_h0", "hw")
+    __slots__ = ("_runtime", "_g_dev", "_per_ex_dev", "_rows",
+                 "_lock", "g", "per_ex", "hw")
 
     def __init__(self, runtime: "ServerRuntime", g_dev, per_ex_dev,
-                 tr, rows: Optional[int] = None) -> None:
+                 rows: Optional[int] = None) -> None:
         self._runtime = runtime
         self._g_dev = g_dev
         self._per_ex_dev = per_ex_dev
-        self._tr = tr
         # only the group's real rows cross D2H; the padded tail (zero
         # weight, possibly resident on other mesh devices) stays put
         self._rows = rows
         self._lock = obs_locks.make_lock("_GroupD2H._lock", reentrant=False)
         self.g: Optional[np.ndarray] = None
         self.per_ex: Optional[np.ndarray] = None
-        self.t_h0 = 0.0
         self.hw = 0.0
 
-    def _materialize(self) -> None:
+    def _materialize(self, req: CoalesceRequest) -> None:
         with self._lock:
             if self.g is None:
-                t_h0 = time.perf_counter() if self._tr is not None else 0.0
-                self._runtime._sleep_d2h()
-                with obs_dispatch.expected_d2h(self._runtime._dd):
-                    g = self._runtime._host_gather(
-                        self._g_dev, rows=self._rows)
-                    per_ex = self._runtime._host_gather(
-                        self._per_ex_dev, rows=self._rows)
-                if self._tr is not None:
-                    self.t_h0 = t_h0
-                    self.hw = time.perf_counter() - t_h0
+                # the group's ONE d2h span, on the waiter that pays it
+                with obs_trace.span(
+                        spans.D2H, party="server", tid=req.client_id,
+                        step=req.step, trace_id=req.trace_id,
+                        registry=self._runtime._metrics) as d2h:
+                    self._runtime._sleep_d2h()
+                    with obs_dispatch.expected_d2h(self._runtime._dd):
+                        g = self._runtime._host_gather(
+                            self._g_dev, rows=self._rows)
+                        per_ex = self._runtime._host_gather(
+                            self._per_ex_dev, rows=self._rows)
+                    d2h.set(bytes=obs_trace.nbytes(g, per_ex))
+                self.hw = d2h.duration_s
                 self.g, self.per_ex = g, per_ex
                 self._g_dev = self._per_ex_dev = None
 
     def segment(self, req: CoalesceRequest, off: int, b: int, total: int):
         """The thunk ``RequestCoalescer.submit`` redeems on the waiter
         thread: materialize (once), slice + rescale this request's
-        segment, and back-fill the ``d2h`` span into the request's
-        server spans (unknown at dispatch time — the transfer had not
-        happened yet)."""
+        segment, and back-fill the group's ``d2h`` seconds into the
+        request's server spans (unknown at dispatch time — the transfer
+        had not happened yet)."""
         def _seg() -> Tuple[np.ndarray, float]:
-            self._materialize()
+            self._materialize(req)
             g, per_ex = self.g, self.per_ex
             seg = (g[off:off + b] * (total / b)).astype(g.dtype,
                                                         copy=False)
             res = (seg, float(per_ex[off:off + b].mean()))
-            if self._tr is not None:
-                if req.server_spans is not None:
-                    req.server_spans = dict(req.server_spans,
-                                            **{spans.D2H: self.hw})
-                self._tr.record(spans.D2H, self.t_h0, self.hw,
-                                trace_id=req.trace_id, party="server",
-                                tid=req.client_id, step=req.step)
-                self._runtime._metrics.observe(spans.D2H, self.hw)
+            if req.server_spans is not None:
+                req.server_spans = dict(req.server_spans,
+                                        **{spans.D2H: self.hw})
             return res
         return _seg
 

@@ -301,21 +301,17 @@ class StageRuntime(PartyRuntime):
         self._deferred.drain_over_lag()
 
     def _apply_deferred_entry(self, entry: Dict[str, Any]) -> None:
-        tr = obs_trace.get_tracer()
-        t0 = time.perf_counter() if tr is not None else 0.0
         xs, cts = entry["xs"], entry["cts"]
-        with obs_dispatch.step_scope(
-                self._dd, (self._ddtok, f"stage{self.stage_index}_apply"),
-                sig_fn=lambda: tuple((x.shape, str(x.dtype))
-                                     for x in xs + cts)):
+        with obs_trace.span(spans.DEFERRED_APPLY, party=self.party,
+                            tid=entry["client_id"], step=entry["step"],
+                            registry=self._metrics), \
+                obs_dispatch.step_scope(
+                    self._dd,
+                    (self._ddtok, f"stage{self.stage_index}_apply"),
+                    sig_fn=lambda: tuple((x.shape, str(x.dtype))
+                                         for x in xs + cts)):
             self.state = self._deferred_apply_fn(
                 self.state, entry["fwd_params"], xs, cts)
-        if tr is not None:
-            dw = time.perf_counter() - t0
-            tr.record(spans.DEFERRED_APPLY, t0, dw,
-                      trace_id=obs_trace.CTX.trace_id, party=self.party,
-                      tid=entry["client_id"], step=entry["step"])
-            self._metrics.observe(spans.DEFERRED_APPLY, dw)
         fl = obs_flight.get_recorder()
         if fl is not None:
             fl.record(spans.FL_DEFER_APPLY, step=entry["step"],
@@ -345,14 +341,13 @@ class StageRuntime(PartyRuntime):
             entry, owner = self.replay.begin(client_id, "hop_fwd", seq)
             if not owner:
                 return self.replay.wait(entry)
-        tr = obs_trace.get_tracer()
         admitted = False
         try:
             if self._admission is not None:
                 self._admission.admit(client_id)
                 admitted = True
             with self._lock:
-                t0 = time.perf_counter() if tr is not None else 0.0
+                t0 = obs_trace.stamp()  # None unless something records
                 self._check_seq("hop_fwd", seq, client_id)
                 self._check_batch_rows(int(np.shape(x)[0]))
                 x_dev = self._to_dev(x)
@@ -378,11 +373,11 @@ class StageRuntime(PartyRuntime):
             else:
                 with obs_dispatch.expected_d2h(self._dd):
                     y_host = self._host_gather(y)
-            if tr is not None:
+            if t0 is not None:
                 # the stage's forward compute window (dispatch through
                 # materialization) — /telemetry's critical-path input
                 self._metrics.observe(spans.DISPATCH,
-                                      time.perf_counter() - t0)
+                                      (obs_trace.now_ns() - t0) * 1e-9)
             if entry is not None:
                 self.replay.resolve(entry, y_host)
             if admitted:
@@ -422,10 +417,9 @@ class StageRuntime(PartyRuntime):
             entry, owner = self.replay.begin(client_id, "hop_bwd", seq)
             if not owner:
                 return self.replay.wait(entry)
-        tr = obs_trace.get_tracer()
         try:
             with self._lock:
-                t0 = time.perf_counter() if tr is not None else 0.0
+                t0 = obs_trace.stamp()  # None unless something records
                 self._check_seq("hop_bwd", seq, client_id)
                 self._check_batch_rows(int(np.shape(g_out)[0]))
                 rec = self._recs.get((int(client_id), int(step)))
@@ -451,12 +445,10 @@ class StageRuntime(PartyRuntime):
             else:
                 with obs_dispatch.expected_d2h(self._dd):
                     g_host = self._host_gather(g_in)
-            if tr is not None:
-                rw = time.perf_counter() - t0
-                tr.record(spans.REPLY_GRAD, t0, rw,
-                          trace_id=obs_trace.CTX.trace_id,
-                          party=self.party, tid=client_id, step=step)
-                self._metrics.observe(spans.REPLY_GRAD, rw)
+            # the reply window: lock held -> cut gradient off the lock
+            obs_trace.span_at(spans.REPLY_GRAD, t0, obs_trace.stamp(),
+                              party=self.party, tid=client_id, step=step,
+                              registry=self._metrics)
             if entry is not None:
                 self.replay.resolve(entry, g_host)
             fl = obs_flight.get_recorder()
@@ -492,14 +484,13 @@ class StageRuntime(PartyRuntime):
             entry, owner = self.replay.begin(client_id, "hop_loss", seq)
             if not owner:
                 return self.replay.wait(entry)
-        tr = obs_trace.get_tracer()
         admitted = False
         try:
             if self._admission is not None:
                 self._admission.admit(client_id)
                 admitted = True
             with self._lock:
-                t0 = time.perf_counter() if tr is not None else 0.0
+                t0 = obs_trace.stamp()  # None unless something records
                 self._check_seq("hop_loss", seq, client_id)
                 self._check_batch_rows(int(np.shape(x)[0]))
                 rec = self._rec_for(client_id, step)
@@ -525,12 +516,10 @@ class StageRuntime(PartyRuntime):
                 with obs_dispatch.expected_d2h(self._dd):
                     g_host = self._host_gather(g_x)
                     loss_f = float(loss)
-            if tr is not None:
-                rw = time.perf_counter() - t0
-                tr.record(spans.REPLY_GRAD, t0, rw,
-                          trace_id=obs_trace.CTX.trace_id,
-                          party=self.party, tid=client_id, step=step)
-                self._metrics.observe(spans.REPLY_GRAD, rw)
+            # the reply window: lock held -> cut gradient off the lock
+            obs_trace.span_at(spans.REPLY_GRAD, t0, obs_trace.stamp(),
+                              party=self.party, tid=client_id, step=step,
+                              registry=self._metrics)
             res = (g_host, loss_f)
             if entry is not None:
                 self.replay.resolve(entry, res)

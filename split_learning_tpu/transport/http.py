@@ -553,16 +553,23 @@ class HttpTransport(Transport):
 
     def _post(self, path: str, payload: Dict[str, Any]) -> Dict[str, Any]:
         from split_learning_tpu.runtime.server import ProtocolError
-        # tracing (obs/trace.py): with the tracer off this method is
-        # bit-for-bit the untraced wire — no trace_id key, no extra
-        # timing calls. With it on, the trace id travels in the payload
-        # and the server echoes its span timings back as server_spans.
-        tr = obs_trace.get_tracer()
-        tid = None
-        if tr is not None and path in _TRACED_PATHS:
-            tid = obs_trace.CTX.trace_id or tr.new_trace_id(
-                int(payload.get("client_id", 0)),
-                int(payload.get("step", -1)))
+        # tracing (obs/trace.py): ``wire`` spans the whole exchange and
+        # accounts for what is left of it once the codec (its two
+        # ``encode`` children) and the server's own seconds (echoed back
+        # as server_spans) are taken out. With nothing recording this is
+        # bit-for-bit the untraced wire: no trace_id key, no clock read.
+        step = int(payload.get("step", -1))
+        cid = int(payload.get("client_id", 0))
+        with obs_trace.span(spans.WIRE, tid=cid, step=step,
+                            trace=(cid, step)) as wire:
+            return self._exchange(path, payload, wire, step, cid)
+
+    def _exchange(self, path: str, payload: Dict[str, Any],
+                  wire: obs_trace.Span, step: int,
+                  cid: int) -> Dict[str, Any]:
+        from split_learning_tpu.runtime.server import ProtocolError
+        tid = wire.trace_id if wire.recording else None
+        if tid is not None:
             payload = dict(payload, trace_id=tid)
         if self.compress != "none":
             payload = dict(payload, compress=self.compress)
@@ -575,13 +582,10 @@ class HttpTransport(Transport):
                     self._dc.note_ratio(self.wire_id, raw_b, wire_b)
         fl = obs_flight.get_recorder()
         if fl is not None and path in _TRACED_PATHS:
-            fl.record(spans.FL_SEND, step=int(payload.get("step", -1)),
-                      client_id=int(payload.get("client_id", 0)),
+            fl.record(spans.FL_SEND, step=step, client_id=cid,
                       party="client", trace_id=tid, path=path)
-        t_enc0 = time.perf_counter() if tid is not None else 0.0
-        body = codec.encode(payload)
-        enc_s = time.perf_counter() - t_enc0 if tid is not None else 0.0
-        t_wire0 = time.perf_counter() if tid is not None else 0.0
+        with obs_trace.span(spans.ENCODE) as up:
+            body = codec.encode(payload)
         try:
             resp = self._session.post(
                 f"{self.base_url}{path}", data=body, timeout=self.timeout,
@@ -589,7 +593,6 @@ class HttpTransport(Transport):
                          CRC_HEADER: str(codec.checksum(body))})
         except requests.RequestException as exc:
             raise TransportError(f"POST {path} failed: {exc}") from exc
-        t_wire1 = time.perf_counter() if tid is not None else 0.0
         self.stats.add_bytes(sent=len(body), received=len(resp.content))
         resp_crc = resp.headers.get(CRC_HEADER)
         if resp_crc is not None:
@@ -615,19 +618,18 @@ class HttpTransport(Transport):
             raise TransportError(
                 f"POST {path} -> {resp.status_code}: {resp.content[:200]!r}")
         if fl is not None and path in _TRACED_PATHS:
-            fl.record(spans.FL_RECV, step=int(payload.get("step", -1)),
-                      client_id=int(payload.get("client_id", 0)),
+            fl.record(spans.FL_RECV, step=step, client_id=cid,
                       party="client", trace_id=tid, path=path)
-        t_dec0 = time.perf_counter() if tid is not None else 0.0
         try:
-            tree = codec.decode(resp.content)
-            if self.compress != "none":
-                raw_b, wire_b = codec.compressed_leaf_bytes(tree)
-                if wire_b:
-                    self.stats.record_compression(raw_b, wire_b)
-                    if self._dc is not None:
-                        self._dc.note_ratio(self.wire_id, raw_b, wire_b)
-            out = codec.decompress_tree(tree)
+            with obs_trace.span(spans.ENCODE) as down:
+                tree = codec.decode(resp.content)
+                if self.compress != "none":
+                    raw_b, wire_b = codec.compressed_leaf_bytes(tree)
+                    if wire_b:
+                        self.stats.record_compression(raw_b, wire_b)
+                        if self._dc is not None:
+                            self._dc.note_ratio(self.wire_id, raw_b, wire_b)
+                out = codec.decompress_tree(tree)
         except codec.CodecError as exc:
             # a frame that passed the CRC gate but fails codec
             # validation (truncated bitmap, out-of-range indices) is a
@@ -640,17 +642,13 @@ class HttpTransport(Transport):
                 f"POST {path}: reply failed codec validation: "
                 f"{exc}") from exc
         if tid is not None:
-            enc_s += time.perf_counter() - t_dec0  # client codec, both ways
             srv = out.pop("server_spans", None) or {}
-            step = int(payload.get("step", -1))
-            cid = int(payload.get("client_id", 0))
-            wire = max((t_wire1 - t_wire0) - sum(srv.values()), 0.0)
-            tr.record(spans.ENCODE, t_enc0, enc_s,
-                      trace_id=tid, party="client", tid=cid, step=step)
-            tr.record(spans.WIRE, t_wire0, wire,
-                      trace_id=tid, party="client", tid=cid, step=step)
+            enc_s = up.duration_s + down.duration_s  # codec, both ways
+            others = enc_s + sum(srv.values())
+            wire.subtract(others)
             self.stats.record_span(spans.ENCODE, enc_s)
-            self.stats.record_span(spans.WIRE, wire)
+            self.stats.record_span(spans.WIRE,
+                                   max(wire.elapsed_s() - others, 0.0))
             # server-reported spans fold into this transport's stats so
             # merged() carries the full cross-party phase breakdown
             for name, secs in srv.items():

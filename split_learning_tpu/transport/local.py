@@ -8,7 +8,6 @@ wire codec so serialization is covered even in-process.
 
 from __future__ import annotations
 
-import time
 from typing import Any, Dict, Optional, Tuple
 
 import numpy as np
@@ -155,17 +154,8 @@ class LocalTransport(Transport):
             res = self._split_step_wire(activations, labels, step,
                                         client_id)
         else:
-            tr = obs_trace.get_tracer()
-            if tr is None:  # the untraced hot path, unchanged
-                with timed(self.stats):
-                    acts = self._roundtrip(np.asarray(activations))
-                    labs = self._roundtrip(np.asarray(labels))
-                    grads, loss = self._call(self.server.split_step,
-                                             acts, labs, step, client_id)
-                    res = self._roundtrip(grads), float(loss)
-            else:
-                res = self._split_step_traced(tr, activations, labels,
-                                              step, client_id)
+            res = self._split_step_plain(activations, labels, step,
+                                         client_id)
         if fl is not None:
             fl.record(spans.FL_RECV, step=int(step),
                       client_id=int(client_id), party="client",
@@ -192,41 +182,35 @@ class LocalTransport(Transport):
             self.stats.add_bytes(sent=up, received=down)
             return resp["grads"], float(resp["loss"])
 
-    def _split_step_traced(self, tr, activations, labels, step, client_id):
-        """Traced variant: in-process, so the server reads CTX.trace_id
-        directly (same thread) and writes CTX.server_spans back; "wire"
-        here is pure call overhead (server time subtracted), the
-        in-process floor the HTTP wire numbers compare against."""
+    def _split_step_plain(self, activations, labels, step, client_id):
+        """The uncompressed in-process exchange, one path traced or not:
+        the server runs on this thread, reads ``CTX.trace_id`` directly
+        and (only while recording) writes ``CTX.server_spans`` back;
+        ``wire`` is then pure call overhead (server time subtracted),
+        the in-process floor the HTTP wire numbers compare against."""
         with timed(self.stats):
-            tid = obs_trace.CTX.trace_id or tr.new_trace_id(client_id, step)
-            prev = obs_trace.CTX.trace_id
-            obs_trace.CTX.trace_id = tid
-            obs_trace.CTX.server_spans = None
-            try:
-                t0 = time.perf_counter()
+            with obs_trace.span(spans.ENCODE, tid=client_id,
+                                step=step) as up:
                 acts = self._roundtrip(np.asarray(activations))
                 labs = self._roundtrip(np.asarray(labels))
-                t1 = time.perf_counter()
-                grads, loss = self._call(self.server.split_step, acts, labs,
-                                         step, client_id)
-                t2 = time.perf_counter()
-                out = self._roundtrip(grads), float(loss)
-                t3 = time.perf_counter()
-                enc_s = (t1 - t0) + (t3 - t2)  # codec both ways
+            with obs_trace.span(spans.WIRE, tid=client_id, step=step,
+                                trace=(client_id, step)) as wire:
+                obs_trace.CTX.server_spans = None
+                grads, loss = self._call(self.server.split_step, acts,
+                                         labs, step, client_id)
                 srv = obs_trace.CTX.server_spans or {}
-                wire = max((t2 - t1) - sum(srv.values()), 0.0)
-                tr.record(spans.ENCODE, t0, enc_s, trace_id=tid,
-                          party="client", tid=client_id, step=step)
-                tr.record(spans.WIRE, t1, wire, trace_id=tid,
-                          party="client", tid=client_id, step=step)
-                self.stats.record_span(spans.ENCODE, enc_s)
-                self.stats.record_span(spans.WIRE, wire)
+                obs_trace.CTX.server_spans = None
+                wire.subtract(sum(srv.values()))
+            with obs_trace.span(spans.ENCODE, tid=client_id,
+                                step=step) as down:
+                out = self._roundtrip(grads), float(loss)
+            if wire.recording:
+                self.stats.record_span(
+                    spans.ENCODE, up.duration_s + down.duration_s)
+                self.stats.record_span(spans.WIRE, wire.duration_s)
                 for name, secs in srv.items():
                     self.stats.record_span(str(name), float(secs))
-                return out
-            finally:
-                obs_trace.CTX.trace_id = prev
-                obs_trace.CTX.server_spans = None
+            return out
 
     def u_forward(self, activations: np.ndarray, step: int,
                   client_id: int = 0) -> np.ndarray:

@@ -2,78 +2,18 @@
 (SURVEY.md §5: the only timing signal is a per-10-step print,
 ``src/client_part.py:135-136``).
 
-Two layers:
-- :class:`PhaseProfiler`: cheap wall-clock accounting of named step phases
-  (compute vs transport — the split that decides the north-star metric),
-  with percentile summaries.
-- :func:`device_trace`: a context manager around ``jax.profiler`` emitting
-  an XLA trace viewable in TensorBoard/Perfetto, for on-chip analysis.
+:func:`device_trace`: a context manager around ``jax.profiler`` emitting
+an XLA trace viewable in TensorBoard/Perfetto, for on-chip analysis.
+The program's own spans (obs/trace.py) are host events in that trace and
+are recorded while the session runs: the phase accounting (compute vs
+transport, the split that decides the north-star metric) is
+``obs.recorder().fraction("transport")``.
 """
 
 from __future__ import annotations
 
 import contextlib
-import threading
-import time
-from collections import defaultdict
-from typing import Dict, Iterator, Optional
-
-import numpy as np
-
-
-class PhaseProfiler:
-    """Accumulates wall-clock per named phase across steps.
-
-    Thread-safe: one profiler may be shared across the thread-pool
-    workers of ``MultiClientSplitRunner(concurrent=True)`` (each
-    ``phase()`` exit appends under a lock; the defaultdict alone is not
-    safe against concurrent first-touch of a phase name)."""
-
-    def __init__(self) -> None:
-        self._samples: Dict[str, list] = defaultdict(list)
-        self._lock = threading.Lock()
-
-    @contextlib.contextmanager
-    def phase(self, name: str) -> Iterator[None]:
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0
-            with self._lock:
-                self._samples[name].append(dt)
-
-    def summary(self) -> Dict[str, Dict[str, float]]:
-        with self._lock:
-            items = [(name, list(xs)) for name, xs in self._samples.items()]
-        out = {}
-        for name, xs in items:
-            arr = np.asarray(xs)
-            out[name] = {
-                "count": int(arr.size),
-                "total_s": float(arr.sum()),
-                "mean_ms": float(arr.mean() * 1e3),
-                "p50_ms": float(np.percentile(arr, 50) * 1e3),
-                "p90_ms": float(np.percentile(arr, 90) * 1e3),
-                "p99_ms": float(np.percentile(arr, 99) * 1e3),
-            }
-        return out
-
-    def fraction(self, name: str) -> float:
-        """Share of total accounted time spent in ``name`` — e.g.
-        fraction('transport') answers the north-star question directly.
-        Returns 0.0 when no samples are recorded (an empty profiler has
-        spent no accounted time anywhere, so every share is zero — not
-        the NaN it used to return, which poisoned downstream
-        arithmetic)."""
-        with self._lock:
-            totals = {k: sum(v) for k, v in self._samples.items()}
-        denom = sum(totals.values())
-        return totals.get(name, 0.0) / denom if denom else 0.0
-
-    def reset(self) -> None:
-        with self._lock:
-            self._samples.clear()
+from typing import Iterator, Optional
 
 
 @contextlib.contextmanager

@@ -253,6 +253,21 @@ def test_slt003_flags_literal_and_accepts_constant(tmp_path):
     assert _rules(findings) == ["SLT003", "SLT003", "SLT003"]
 
 
+def test_slt003_flags_literal_names_at_the_span_primitive(tmp_path):
+    findings = _lint(tmp_path, "runtime/worker.py", """
+        from split_learning_tpu import obs
+        from split_learning_tpu.obs import spans
+        def go(t0, t1):
+            with obs.span("client_fwd"):
+                pass
+            obs.span_at("queue_wait", t0, t1)
+            with obs.span(spans.CLIENT_FWD, bytes=3):
+                pass
+            obs.span_at(spans.QUEUE_WAIT, t0, t1)
+    """)
+    assert _rules(findings) == ["SLT003", "SLT003"]
+
+
 def test_slt003_waiver(tmp_path):
     findings = _lint(tmp_path, "runtime/worker.py", """
         def go(tr, dt):

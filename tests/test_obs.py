@@ -4,6 +4,7 @@ contract across LocalTransport, HttpTransport, and the coalescer."""
 
 import json
 import threading
+import time
 import urllib.request
 
 import jax
@@ -19,7 +20,6 @@ from split_learning_tpu.runtime.multi_client import MultiClientSplitRunner
 from split_learning_tpu.transport import LocalTransport
 from split_learning_tpu.transport.http import HttpTransport, SplitHTTPServer
 from split_learning_tpu.utils import Config
-from split_learning_tpu.utils.profiling import PhaseProfiler
 
 
 @pytest.fixture(autouse=True)
@@ -270,7 +270,7 @@ def test_coalescer_records_queue_wait_spans_under_burst():
 
 
 # --------------------------------------------------------------------- #
-# Chrome export + trace_report.py agreement with PhaseProfiler
+# Chrome export + trace_report.py agreement with the recorder
 
 
 def _load_trace_report():
@@ -289,9 +289,8 @@ def test_chrome_export_and_trace_report_reproduce_fraction(tmp_path):
     plan = get_plan(mode="split")
     x, y = _data()
     server = ServerRuntime(plan, cfg, jax.random.PRNGKey(0), x)
-    prof = PhaseProfiler()
     client = SplitClientTrainer(plan, cfg, jax.random.PRNGKey(0),
-                                LocalTransport(server), profiler=prof)
+                                LocalTransport(server))
     tr = obs.enable()
     try:
         for i in range(4):
@@ -301,13 +300,24 @@ def test_chrome_export_and_trace_report_reproduce_fraction(tmp_path):
     path = tr.export_chrome(str(tmp_path / "trace.json"))
 
     # the export is a valid Chrome trace: whole-file JSON, complete
-    # events with µs timestamps, per-party process metadata
+    # events with absolute µs timestamps on the spans' clock, per-party
+    # process metadata, span and parent ids in args
     events = json.load(open(path))
     metas = [e for e in events if e.get("ph") == "M"]
     assert {m["args"]["name"] for m in metas} == {"slt-client", "slt-server"}
     xs = [e for e in events if e.get("ph") == "X"]
     assert xs and all(e["dur"] >= 0 and e["ts"] >= 0 for e in xs)
     assert {e["pid"] for e in xs} == {1, 2}
+    by_id = {e["args"]["span_id"]: e for e in xs}
+    now_us = time.time_ns() / 1e3
+    assert all(now_us - 600e6 < e["ts"] <= now_us for e in xs)
+    for e in xs:
+        parent = by_id.get(e["args"]["parent_id"])
+        if e["name"] in ("client_fwd", "transport", "client_bwd",
+                         "opt_apply"):
+            assert parent["name"] == "step_total"
+        if e["name"] == "step_total":
+            assert parent is None
 
     # ...and line-parseable (the tolerant path trace_report also takes)
     report = _load_trace_report()
@@ -315,12 +325,17 @@ def test_chrome_export_and_trace_report_reproduce_fraction(tmp_path):
     assert len(lines_events) == len(events)
 
     rep = report.summarize(lines_events)
-    # the report's transport fraction reproduces both the tracer's and
-    # the PhaseProfiler's view of the same run
+    # the report's transport fraction reproduces the recorder's view of
+    # the same run, and both are the transport spans' share of the four
+    # phases that tile a step
     assert rep["transport_fraction"] == pytest.approx(
         tr.fraction("transport"), abs=1e-9)
-    assert rep["transport_fraction"] == pytest.approx(
-        prof.fraction("transport"), abs=0.1)
+    recs = tr.spans()
+    total = {n: sum(r["duration"] for r in recs if r["name"] == n)
+             for n in obs.CLIENT_PHASES}
+    assert tr.fraction("transport") == pytest.approx(
+        total["transport"] / sum(total.values()), abs=1e-9)
+    assert 0.0 < tr.fraction("transport") < 1.0
     # acceptance gate: per-step client spans sum to within 10% of the
     # measured step_total wall clock
     assert rep["steps_with_wall_clock"] == 4
@@ -336,9 +351,10 @@ def test_trace_report_tolerates_truncated_file(tmp_path):
     yields every complete event."""
     tr = obs.enable()
     try:
-        t0 = 0.0
+        t0 = time.time_ns()
         for i in range(5):
-            tr.record("client_fwd", t0 + i, 0.01, trace_id=f"t{i}")
+            obs.span_at("client_fwd", t0 + i * 10**9, t0 + i * 10**9 + 10**7,
+                        trace_id=f"t{i}")
     finally:
         obs.disable()
     full = tr.export_chrome(str(tmp_path / "full.json"))
