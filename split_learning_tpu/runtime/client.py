@@ -41,7 +41,7 @@ from split_learning_tpu.obs import dispatch_debug as obs_dispatch
 from split_learning_tpu.obs import spans
 from split_learning_tpu.obs import trace as obs_trace
 from split_learning_tpu.runtime.state import (
-    TrainState, apply_grads, make_state, make_tx)
+    TrainState, apply_grads, jit_apply_grads, make_state, make_tx)
 from split_learning_tpu.transport.base import (
     Backpressure, Transport, TransportError)
 from split_learning_tpu.utils.config import Config
@@ -106,6 +106,7 @@ class SplitClientTrainer:
         self._fwd = jax.jit(stage.apply)
         self._bwd = jax.jit(
             lambda p, x, g: stage_backward(stage, p, x, g))
+        self._apply_grads = jit_apply_grads(self._tx)
         # dispatch watchdog (slt-lint phase 2): None unless enabled
         self._dd = obs_dispatch.attach()
         self._ddtok = obs_dispatch.token()
@@ -141,16 +142,18 @@ class SplitClientTrainer:
         tiled by ``client_fwd`` (the inputs' ``h2d``, the jitted
         forward, the cut tensor's ``d2h``), ``transport``,
         ``client_bwd`` (the ``h2d`` of the inputs again and of the cut
-        gradient, the jitted backward) and ``opt_apply``. Off (the
+        gradient, the jitted backward) and ``opt_apply`` (the call of
+        the jitted optimizer step, ``jit_apply_grads``: one dispatch,
+        which donates the optimizer state and the gradients). Off (the
         default) a span is an annotation and nothing else: no record,
         no trace id, no payload key. On, the step gets a trace id
         (propagated to the server through the transport via CTX) and
         one record a span. Tracing adds no sync either way: a span
         measures what this thread did, including the waits the program
         itself makes (``np.asarray(acts)`` blocks on the device, and is
-        where the previous step's still-running optimizer work shows
-        up); what the device did meanwhile is the device trace's to
-        say."""
+        where the previous step's backward and optimizer programs, both
+        dispatched without a wait, show up); what the device did
+        meanwhile is the device trace's to say."""
         self.ensure_init(x)
         with obs_trace.span(spans.STEP_TOTAL, tid=self.client_id, step=step,
                             trace=(self.client_id, step), round=round_no):
@@ -235,7 +238,7 @@ class SplitClientTrainer:
                 g_params = self._bwd(self.state.params, x_dev, g_dev)
                 del x_dev, g_dev
         with obs_trace.span(spans.OPT_APPLY):
-            self.state = apply_grads(self._tx, self.state, g_params)
+            self.state = self._apply_grads(self.state, g_params)
         return loss
 
     def train(self, data_iter: Callable[[], Iterable[Tuple[np.ndarray, np.ndarray]]],
@@ -305,6 +308,8 @@ class USplitClientTrainer:
         self._head_step = jax.jit(head_step)
         self._bwd_a = jax.jit(
             lambda p, x, g: stage_backward(stage_a, p, x, g))
+        # one function for both owned stages: a trace per state structure
+        self._apply_grads = jit_apply_grads(self._tx)
         # dispatch watchdog (slt-lint phase 2): None unless enabled
         self._dd = obs_dispatch.attach()
         self._ddtok = obs_dispatch.token()
@@ -334,7 +339,7 @@ class USplitClientTrainer:
                                      sig_fn=lambda: sig):
             loss, g_c, g_feats = self._head_step(
                 self.state_c.params, jnp.asarray(feats), jnp.asarray(y))
-        self.state_c = apply_grads(self._tx, self.state_c, g_c)
+        self.state_c = self._apply_grads(self.state_c, g_c)
         # hop 2: feature grads -> activation grads (server updates trunk)
         with obs_dispatch.expected_d2h(dd):
             g_feats_host = np.asarray(g_feats)
@@ -344,7 +349,7 @@ class USplitClientTrainer:
                                      sig_fn=lambda: sig):
             g_a = self._bwd_a(self.state_a.params, jnp.asarray(x),
                               jnp.asarray(g_acts))
-        self.state_a = apply_grads(self._tx, self.state_a, g_a)
+        self.state_a = self._apply_grads(self.state_a, g_a)
         with obs_dispatch.expected_d2h(dd):
             return float(loss)
 

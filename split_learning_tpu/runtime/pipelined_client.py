@@ -47,7 +47,7 @@ from split_learning_tpu.obs import spans
 from split_learning_tpu.obs import trace as obs_trace
 from split_learning_tpu.runtime.client import StepRecord
 from split_learning_tpu.runtime.state import (
-    TrainState, apply_grads, make_state, make_tx)
+    TrainState, jit_apply_grads, make_state, make_tx)
 from split_learning_tpu.transport.base import Transport
 from split_learning_tpu.utils.config import Config
 
@@ -90,6 +90,7 @@ class PipelinedSplitClientTrainer:
         self._fwd = jax.jit(stage.apply)
         self._bwd = jax.jit(
             lambda p, x, g: stage_backward(stage, p, x, g))
+        self._apply_grads = jit_apply_grads(self._tx)
 
     def ensure_init(self, sample_x: np.ndarray) -> None:
         if self.state is None:
@@ -123,7 +124,7 @@ class PipelinedSplitClientTrainer:
         g_acts, loss = future.result()
         with obs_trace.span(spans.CLIENT_BWD, tid=self.client_id):
             g_params = self._bwd(params_then, xd, jnp.asarray(g_acts))
-            self.state = apply_grads(self._tx, self.state, g_params)
+            self.state = self._apply_grads(self.state, g_params)
         return loss
 
     def train(self, data_iter: Callable[[], Iterable[Tuple[np.ndarray, np.ndarray]]],

@@ -9,7 +9,7 @@ visible ordering decision instead of an accident.
 
 from __future__ import annotations
 
-from typing import Any, NamedTuple, Tuple
+from typing import Any, Callable, NamedTuple, Tuple
 
 import jax
 import optax
@@ -96,6 +96,28 @@ def apply_grads(tx: optax.GradientTransformation, state: TrainState,
     updates, opt_state = tx.update(grads, state.opt_state, state.params)
     params = optax.apply_updates(state.params, updates)
     return TrainState(params=params, opt_state=opt_state, step=state.step + 1)
+
+
+def jit_apply_grads(tx: optax.GradientTransformation
+                    ) -> Callable[[TrainState, Params], TrainState]:
+    """``apply_grads`` as one XLA program a call, for the trainers whose
+    gradients come out of a program of their own (the split clients,
+    whose backward stays a program, and a span, of its own). Build it
+    once per trainer; it traces once per state structure.
+
+    The optimizer state and the gradients are donated: nothing else
+    holds them, and the new parameters take the gradients' buffers. The
+    parameters are a plain argument, because others may hold them:
+    ``MultiClientSplitRunner.sync_bottoms`` hands one mean tree to every
+    client (and keeps it as its compressed-sync reference), the
+    pipelined client keeps them as an in-flight step's ``params_then``,
+    and callers read ``client.state.params`` between steps."""
+    def apply(params, opt_state, step, grads):
+        return apply_grads(tx, TrainState(params, opt_state, step), grads)
+
+    jitted = jax.jit(apply, donate_argnums=(1, 3))
+    return lambda state, grads: jitted(
+        state.params, state.opt_state, state.step, grads)
 
 
 def compressed_sync_contribution(ef, tag, params, ref, density
