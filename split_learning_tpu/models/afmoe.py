@@ -1,0 +1,426 @@
+"""Split AFMoE — routed experts beside a shared one, window beside full
+attention over grouped heads (the Trinity family's ``afmoe`` layer).
+
+Nothing of models/transformer.py's ``Block`` fits but the stage layout:
+
+- split:   client(embedding + N_c layers) -> server(the rest + norm + head)
+- u_split: client(embedding + N_c layers) -> server(the rest)
+           -> client(norm + head)
+- federated: the composition of the split plan.
+
+One layer (RMSNorm(x) = x / sqrt(mean(x^2) + eps) * scale, no biases):
+
+- attention: ``u = norm_in(h)``; q ``[T, H, D]``, k, v ``[T, H_kv, D]``
+  and an output gate ``[T, H * D]`` from ``u``; q and k RMS-normed over
+  ``D``; rotary positions (rotate-half) on ``sliding_attention`` layers
+  only, none on ``full_attention`` ones; query head ``n`` reads
+  key/value head ``n // (H // H_kv)``; causal, and on a sliding layer
+  only keys ``0 <= i - j < window``; ``a = attention * sigmoid(gate)``;
+  ``h = h + norm_post_attn(a @ Wo)``.
+- a dense layer: ``h = h + norm_post_mlp(SwiGLU(norm_pre_mlp(h)))``.
+- an expert layer: ``m = norm_pre_mlp(h)``; the router scores all
+  ``experts_total`` in float32 (``sigmoid(m @ Wr)``), picks
+  ``experts_per_token`` by score plus a constant selection bias,
+  normalises the picked scores over all of them and scales by
+  ``route_scale``; ``h = h + norm_post_mlp(shared(m) + routed(m))``.
+
+**The routed layer is told its share.** It holds experts
+``[expert_offset, expert_offset + experts_held)`` of ``experts_total``
+as three stacked leaves, routes over all of them and computes the part
+its own give: the (token, expert) pairs routed here are sorted by
+expert into a buffer sized for the worst case (tokens x experts per
+token rows), the grouped products (ops/grouped_matmul.py) run over the
+rows that are filled, and the result is gathered back per pair. No
+token is dropped, shapes are static, one program serves every routing.
+What the absent experts would add is left out; no code stands in for
+absent chips. ``experts_held == experts_total`` is the whole layer.
+
+The selection bias ``expert_bias`` is a float32 leaf under
+``stop_gradient``: its published update (from per-expert token counts)
+would have to leave the step beside the activations, which a pure
+``Stage`` cannot give (ROADMAP.md M4), so it stays where ``init`` put
+it. Decoding through a KV cache is not built for this family.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Any, Optional, Sequence
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from split_learning_tpu.core.stage import SplitPlan, from_flax
+from split_learning_tpu.obs import spans
+from split_learning_tpu.ops.flash_attention import (
+    flash_attention, select_attention)
+from split_learning_tpu.ops.grouped_matmul import grouped_matmul
+from split_learning_tpu.ops.ring_attention import full_attention
+
+_ATTN_IMPLS = ("auto", "full", "flash")
+_LAYER_TYPES = ("sliding_attention", "full_attention")
+_HI = jax.lax.Precision.HIGHEST
+_INIT = nn.initializers.normal(0.02)
+
+
+class RMSNorm(nn.Module):
+    """Statistics in float32 whatever the compute type; the parameter is
+    ``scale`` (benchmarks/weights.py draws leaves of that name around 1)."""
+
+    eps: float = 1e-5
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        x = x.astype(jnp.float32)
+        y = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + self.eps)
+        return (y * scale).astype(self.dtype)
+
+
+def _linear(features: int, dtype, name: str) -> nn.Dense:
+    return nn.Dense(features, use_bias=False, dtype=dtype, kernel_init=_INIT,
+                    name=name)
+
+
+def rope(x: jax.Array, theta: float) -> jax.Array:
+    """Rotary positions, rotate-half form, position = index along axis 1
+    of ``[B, T, H, D]``; computed in float32."""
+    d = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv[None]
+    cos = jnp.concatenate([jnp.cos(ang)] * 2, -1)[None, :, None, :]
+    sin = jnp.concatenate([jnp.sin(ang)] * 2, -1)[None, :, None, :]
+    x32 = x.astype(jnp.float32)
+    x1, x2 = jnp.split(x32, 2, axis=-1)
+    return (x32 * cos + jnp.concatenate([-x2, x1], -1) * sin).astype(x.dtype)
+
+
+class AfmoeAttention(nn.Module):
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    window: Optional[int]     # None: a full_attention layer
+    rope_theta: float = 10000.0
+    eps: float = 1e-5
+    attn: str = "auto"
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, u):
+        b, t, e = u.shape
+        h, hk, d = self.num_heads, self.num_kv_heads, self.head_dim
+        q = _linear(h * d, self.dtype, "q")(u).reshape(b, t, h, d)
+        k = _linear(hk * d, self.dtype, "k")(u).reshape(b, t, hk, d)
+        v = _linear(hk * d, self.dtype, "v")(u).reshape(b, t, hk, d)
+        gate = _linear(h * d, self.dtype, "gate")(u)
+        q = RMSNorm(self.eps, self.dtype, name="q_norm")(q)
+        k = RMSNorm(self.eps, self.dtype, name="k_norm")(k)
+        if self.window is not None:
+            q, k = rope(q, self.rope_theta), rope(k, self.rope_theta)
+        impl = self.attn
+        if impl == "auto":
+            # the dense-against-flash rule counts dense residency at
+            # [B, H, T, T] whatever the window (select_attention's note)
+            impl = select_attention(b, t, h, jnp.dtype(self.dtype).itemsize)
+        fn = {"flash": flash_attention, "full": full_attention}[impl]
+        # the scope names the kernels' calls in a device trace
+        scope = spans.ATTN_FULL if self.window is None else spans.ATTN_WINDOW
+        with jax.named_scope(scope):
+            o = fn(q, k, v, causal=True, window=self.window)
+        a = o.reshape(b, t, h * d) * jax.nn.sigmoid(gate)
+        return _linear(e, self.dtype, "out")(a)
+
+
+class SwiGLU(nn.Module):
+    width: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, m):
+        g = _linear(self.width, self.dtype, "gate")(m)
+        u = _linear(self.width, self.dtype, "up")(m)
+        return _linear(m.shape[-1], self.dtype, "down")(jax.nn.silu(g) * u)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _take_rows(x, idx, inv, fan: int):
+    """``x[idx // fan]`` for a permutation ``idx`` of the ``fan * len(x)``
+    pair slots with inverse ``inv``: the gradient is a gather through
+    ``inv`` and a sum over each row's ``fan`` slots, never a scatter.
+    With ``fan`` 1 it is the permutation itself."""
+    return jnp.take(x, idx // fan, axis=0)
+
+
+def _take_rows_fwd(x, idx, inv, fan):
+    return _take_rows(x, idx, inv, fan), inv
+
+
+def _take_rows_bwd(fan, inv, g):
+    back = jnp.take(g, inv, axis=0).reshape(-1, fan, g.shape[-1])
+    return back.sum(1, dtype=jnp.float32).astype(g.dtype), None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def route(m32, router_kernel, bias, per_token: int, route_scale: float):
+    """(chosen ``[N, k]`` int32, weights ``[N, k]`` float32) over all the
+    router's outputs; product, scores and top-k in float32."""
+    logits = jnp.dot(m32, router_kernel.astype(jnp.float32), precision=_HI)
+    scores = jax.nn.sigmoid(logits)
+    _, chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), per_token)
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    weights = picked / (picked.sum(-1, keepdims=True) + 1e-20) * route_scale
+    return chosen, weights
+
+
+def held_pairs(chosen, offset: int, held: int):
+    """Sort the (token, slot) pairs by the expert held here that they
+    chose; pairs of absent experts go last. Returns (``order``: pair
+    index at each sorted row, ``inverse``: sorted row of each pair,
+    ``group_sizes [held]``)."""
+    local = chosen.reshape(-1) - offset
+    gid = jnp.where((local >= 0) & (local < held), local, held)
+    iota = jnp.arange(gid.shape[0], dtype=jnp.int32)
+    _, order = jax.lax.sort((gid, iota), num_keys=1, is_stable=True)
+    _, inverse = jax.lax.sort((order, iota), num_keys=1)
+    sizes = (gid[:, None] == jnp.arange(held)[None]).sum(0, dtype=jnp.int32)
+    return order, inverse, sizes
+
+
+class RoutedExperts(nn.Module):
+    """The part of a routed layer that the experts held here give."""
+
+    width: int
+    experts_total: int
+    experts_held: int
+    expert_offset: int
+    per_token: int
+    route_scale: float
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, m32):
+        n, d = m32.shape
+        held, k = self.experts_held, self.per_token
+        router = self.param("router", _INIT, (d, self.experts_total))
+        bias = self.param("expert_bias", nn.initializers.zeros,
+                          (self.experts_total,))
+        gate = self.param("gate", _INIT, (held, d, self.width))
+        up = self.param("up", _INIT, (held, d, self.width))
+        down = self.param("down", _INIT, (held, self.width, d))
+        with jax.named_scope(spans.MOE_ROUTE):
+            chosen, weights = route(m32, router, bias, k, self.route_scale)
+            order, inverse, sizes = held_pairs(chosen, self.expert_offset,
+                                               held)
+            m = m32.astype(self.dtype)
+            rows = _take_rows(m, order, inverse, k)
+        with jax.named_scope(spans.MOE_EXPERTS):
+            act = jax.nn.silu(grouped_matmul(rows, gate, sizes)) * \
+                grouped_matmul(rows, up, sizes)
+            out = grouped_matmul(act, down, sizes)
+        with jax.named_scope(spans.MOE_ROUTE):
+            # rows of absent experts are zero, so their weights count
+            # for the normalisation above and for nothing here
+            per_pair = _take_rows(out, inverse, order, 1).reshape(n, k, d)
+            return jnp.einsum("nkd,nk->nd", per_pair.astype(jnp.float32),
+                              weights).astype(self.dtype)
+
+
+class AfmoeLayer(nn.Module):
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    layer_type: str
+    window: int
+    dense_width: int          # > 0: a dense layer of this width
+    expert_width: int
+    experts_total: int
+    experts_held: int
+    expert_offset: int
+    experts_per_token: int
+    shared_experts: int
+    route_scale: float
+    rope_theta: float = 10000.0
+    eps: float = 1e-5
+    attn: str = "auto"
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, h):
+        b, t, e = h.shape
+        norm = lambda name: RMSNorm(self.eps, self.dtype, name=name)
+        window = self.window if self.layer_type == "sliding_attention" \
+            else None
+        a = AfmoeAttention(
+            self.num_heads, self.num_kv_heads, self.head_dim, window,
+            self.rope_theta, self.eps, self.attn, self.dtype,
+            name="attn")(norm("norm_in")(h))
+        h = h + norm("norm_post_attn")(a)
+        if self.dense_width:
+            y = SwiGLU(self.dense_width, self.dtype,
+                       name="mlp")(norm("norm_pre_mlp")(h))
+            return h + norm("norm_post_mlp")(y)
+        # the router reads the float32 norm, the experts its rounding
+        m32 = RMSNorm(self.eps, jnp.float32, name="norm_pre_mlp")(h)
+        with jax.named_scope(spans.MOE_SHARED):
+            y = SwiGLU(self.expert_width * self.shared_experts, self.dtype,
+                       name="shared")(m32.astype(self.dtype))
+        y = y + RoutedExperts(
+            self.expert_width, self.experts_total, self.experts_held,
+            self.expert_offset, self.experts_per_token, self.route_scale,
+            self.dtype, name="experts")(m32.reshape(b * t, e)).reshape(b, t, e)
+        return h + norm("norm_post_mlp")(y)
+
+
+def _no_cache(cache_len, decode_cache):
+    if cache_len or decode_cache is not None:
+        raise NotImplementedError(
+            "afmoe has no KV-cache decode: window layers need a cache "
+            "that forgets (runtime/generate.py, ROADMAP.md M4)")
+
+
+def _run_layers(h, first: int, layer_types, dense_layers: int, remat: bool,
+                layer_kw):
+    """Layers ``[first, first + len(layer_types))`` of the model, named
+    ``layer<i>`` by their index in it (call inside a compact method);
+    each one's forward is recomputed in the backward pass when
+    ``remat``."""
+    cls = nn.remat(AfmoeLayer) if remat else AfmoeLayer
+    kw = dict(layer_kw)
+    for i, kind in enumerate(layer_types, start=first):
+        dense = kw["dense_width"] if i < dense_layers else 0
+        h = cls(**{**kw, "dense_width": dense, "layer_type": kind},
+                name=f"layer{i}")(h)
+    return h
+
+
+class AfmoeEmbedStage(nn.Module):
+    """Client bottom stage: ``[B, T] int -> [B, T, d_model]``: the
+    embedding (times ``sqrt(d_model)``, the family's muP rule; no
+    position table) and the first layers. ``layers`` is what
+    :func:`_run_layers` takes after ``h``."""
+
+    vocab: int
+    d_model: int
+    layers: tuple
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, tokens, *, cache_len: int = 0, decode_cache=None,
+                 pos=None):
+        _no_cache(cache_len, decode_cache)
+        emb = nn.Embed(self.vocab, self.d_model, dtype=self.dtype,
+                       embedding_init=_INIT, name="tok")(tokens)
+        h = emb * jnp.asarray(self.d_model ** 0.5, self.dtype)
+        return _run_layers(h, *self.layers)
+
+
+class AfmoeHeadStage(nn.Module):
+    """Final norm and the untied head over the vocabulary rows held;
+    products in the compute type, accumulated and returned in float32,
+    so the loss is a float32 softmax. The server's top stage ends in it;
+    alone it is the client's top stage of the U-shape."""
+
+    vocab: int
+    eps: float = 1e-5
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, h, *, cache_len: int = 0, decode_cache=None,
+                 pos=None):
+        _no_cache(cache_len, decode_cache)
+        x = RMSNorm(self.eps, self.dtype, name="norm_f")(h)
+        kernel = self.param("lm_head", _INIT, (h.shape[-1], self.vocab))
+        return jnp.dot(x, kernel.astype(self.dtype),
+                       preferred_element_type=jnp.float32)
+
+
+class AfmoeTrunkAndHead(nn.Module):
+    """Server top stage of the 2-party split: the rest of the layers,
+    then (``vocab`` > 0) the final norm and the head. With ``vocab`` 0
+    it is the U-shape's middle stage."""
+
+    layers: tuple
+    vocab: int = 0
+    eps: float = 1e-5
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, h, *, cache_len: int = 0, decode_cache=None,
+                 pos=None):
+        _no_cache(cache_len, decode_cache)
+        h = _run_layers(h, *self.layers)
+        if not self.vocab:
+            return h
+        return AfmoeHeadStage(self.vocab, self.eps, self.dtype,
+                              name="head")(h)
+
+
+def afmoe_plan(mode: str = "split", dtype: Any = jnp.float32, *,
+               vocab: int = 256, d_model: int = 64, num_heads: int = 4,
+               num_kv_heads: int = 2, head_dim: int = 16,
+               dense_width: int = 192, expert_width: int = 32,
+               experts_total: int = 8, experts_held: Optional[int] = None,
+               expert_offset: int = 0, experts_per_token: int = 2,
+               shared_experts: int = 1, route_scale: float = 1.0,
+               window: int = 8,
+               layer_types: Sequence[str] = ("sliding_attention",) * 4
+               + ("full_attention",),
+               dense_layers: int = 1, client_depth: int = 1,
+               rope_theta: float = 10000.0, rms_norm_eps: float = 1e-5,
+               attn: str = "auto", remat: bool = True) -> SplitPlan:
+    """Build the AFMoE :class:`SplitPlan` for ``mode``.
+
+    The arguments carry the published names' values for the layers kept:
+    ``layer_types`` one entry a layer, the first ``dense_layers`` of them
+    dense (SwiGLU of ``dense_width``), the rest routed (``experts_held``
+    of ``experts_total`` experts of ``expert_width`` from
+    ``expert_offset`` on, ``experts_per_token`` a token, beside
+    ``shared_experts`` shared ones). The client holds the embedding and
+    the first ``client_depth`` layers. ``remat`` recomputes each
+    layer's forward in the backward pass."""
+    if attn not in _ATTN_IMPLS:
+        raise ValueError(f"Unknown attn impl: {attn!r} (expected {_ATTN_IMPLS})")
+    layer_types = tuple(layer_types)
+    bad = sorted(set(layer_types) - set(_LAYER_TYPES))
+    if bad:
+        raise ValueError(f"Unknown layer types {bad} (expected {_LAYER_TYPES})")
+    held = experts_total if experts_held is None else experts_held
+    if not (0 <= expert_offset and expert_offset + held <= experts_total
+            and held >= 1):
+        raise ValueError(
+            f"experts [{expert_offset}, {expert_offset + held}) are not "
+            f"among the router's {experts_total}")
+    if not 0 <= client_depth <= len(layer_types):
+        raise ValueError(f"client_depth {client_depth} of "
+                         f"{len(layer_types)} layers")
+    if num_heads % num_kv_heads:
+        raise ValueError(f"{num_kv_heads} key/value heads do not divide "
+                         f"{num_heads} query heads")
+    layer_kw = tuple(dict(
+        num_heads=num_heads, num_kv_heads=num_kv_heads, head_dim=head_dim,
+        window=window, dense_width=dense_width, expert_width=expert_width,
+        experts_total=experts_total, experts_held=held,
+        expert_offset=expert_offset, experts_per_token=experts_per_token,
+        shared_experts=shared_experts, route_scale=float(route_scale),
+        rope_theta=float(rope_theta), eps=float(rms_norm_eps), attn=attn,
+        dtype=dtype).items())
+    span = lambda first, kinds: (first, kinds, dense_layers, remat, layer_kw)
+    eps = float(rms_norm_eps)
+    embed = from_flax("embed", AfmoeEmbedStage(
+        vocab, d_model, span(0, layer_types[:client_depth]), dtype))
+    rest = span(client_depth, layer_types[client_depth:])
+    if mode == "u_split":
+        return SplitPlan(
+            stages=(embed,
+                    from_flax("trunk", AfmoeTrunkAndHead(rest, 0, eps, dtype)),
+                    from_flax("head", AfmoeHeadStage(vocab, eps, dtype))),
+            owners=("client", "server", "client"))
+    return SplitPlan(
+        stages=(embed,
+                from_flax("trunk_head", AfmoeTrunkAndHead(
+                    rest, vocab, eps, dtype))),
+        owners=("client", "server"))

@@ -109,6 +109,15 @@ def _transformer_lm(mode: str, dtype: Any, **kw: Any) -> SplitPlan:
     return transformer_plan(mode=mode, dtype=dtype, lm=True, **kw)
 
 
+@register_model("afmoe")
+def _afmoe(mode: str, dtype: Any, **kw: Any) -> SplitPlan:
+    """Routed experts (this party's share of them) beside a shared one,
+    window beside full attention over grouped heads, RMSNorm, rotary
+    positions on the window layers (models/afmoe.py)."""
+    from split_learning_tpu.models.afmoe import afmoe_plan
+    return afmoe_plan(mode=mode, dtype=dtype, **kw)
+
+
 def get_plan(model: str = "split_cnn", mode: str = "split",
              dtype: Any = jnp.float32, **size_kw: Any) -> SplitPlan:
     """Build the SplitPlan for a model family under a learning mode.

@@ -214,6 +214,18 @@ SLO_BURN_SLOW = "slo_burn_rate_slow"
 REPLICAS_LIVE = "replicas_live"
 AUTOSCALE_DECISION = "autoscale_decision"
 
+# -- device scopes of the afmoe family (models/afmoe.py) --------------- #
+# ``jax.named_scope`` names, not host spans (so not in ALL_SPANS): they
+# reach a device trace in the operations' names, where the benchmark's
+# readers tell window attention from full and find the routed layer's
+# parts (the Pallas kernels inside keep the scope as their call's name).
+ATTN_WINDOW = "attn_window"
+ATTN_FULL = "attn_full"
+MOE_ROUTE = "moe_route"      # router, top-k, the pairs' sort and gathers
+MOE_EXPERTS = "moe_experts"  # the grouped products over the experts held
+MOE_SHARED = "moe_shared"
+DEVICE_SCOPES = (ATTN_WINDOW, ATTN_FULL, MOE_ROUTE, MOE_EXPERTS, MOE_SHARED)
+
 # the client-level phases that tile a step — the denominator of the
 # compute-vs-wire fraction (encode/wire are sub-phases of transport and
 # queue_wait/dispatch belong to the server party; counting either would

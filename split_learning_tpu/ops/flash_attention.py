@@ -45,6 +45,20 @@ Design (the canonical TPU flash schedule):
   accumulate — the grid stays static, ~2x fewer FLOPs at large T), and
   partially-masked diagonal blocks mask elementwise.
 
+- A ``window`` (query i sees keys j with ``0 <= i - j < window``; the
+  afmoe family's ``sliding_attention`` layers) shrinks the inner grid
+  extent to the band (:func:`_band_blocks`): step ``j`` of query block
+  ``i`` reads key block ``i - (n - 1) + j`` through the index map, so
+  key blocks wholly outside the band are neither fetched nor computed,
+  and the two edge blocks mask elementwise. The one-pass backward's
+  loop over query blocks stops at the band's end likewise.
+- Grouped key/value heads (``[B, T, H_kv, D]`` under ``H`` query
+  heads): K/V blocks are read from row ``b // (H // H_kv)`` of the
+  folded array, never repeated in HBM; the backward writes dK/dV per
+  query head in float32 and the wrapper sums each group's.
+  With ``window=None`` and equal head counts every kernel is traced
+  exactly as before these two existed (tests/test_afmoe.py).
+
 Like every op in this package there is a pure-jnp reference
 (:func:`split_learning_tpu.ops.ring_attention.full_attention`) and the
 kernels run under the Mosaic interpreter off-TPU
@@ -177,7 +191,8 @@ _DEFAULT_LIMIT_SAFE = 12 * 1024 * 1024
 _SPLIT_BLOCK_MAX = 512
 
 
-def _resolve_block(t: int, d: int, dtype, bh: int = 2) -> tuple[int, bool]:
+def _resolve_block(t: int, d: int, dtype, bh: int = 2,
+                   group: int = 1) -> tuple[int, bool]:
     """(block, onepass) for a public entry point: the swept default
     edge when the one-pass backward (which preflight-confirms itself)
     carries the gradient, capped to :data:`_SPLIT_BLOCK_MAX` when the
@@ -197,15 +212,16 @@ def _resolve_block(t: int, d: int, dtype, bh: int = 2) -> tuple[int, bool]:
     user-path compile error."""
     import os
     block = _pick_block(t)
-    onepass = _use_onepass(t, block, d, dtype, bh=bh)
+    onepass = _use_onepass(t, block, d, dtype, bh=bh, group=group)
     if (not onepass and block > _SPLIT_BLOCK_MAX
             and not os.environ.get("SLT_FLASH_BLOCK")):
         block = _SPLIT_BLOCK_MAX
-        onepass = _use_onepass(t, block, d, dtype, bh=bh)
+        onepass = _use_onepass(t, block, d, dtype, bh=bh, group=group)
     return block, onepass
 
 
-def _use_onepass(t: int, block: int, d: int, dtype, bh: int = 2) -> bool:
+def _use_onepass(t: int, block: int, d: int, dtype, bh: int = 2,
+                 group: int = 1) -> bool:
     """Backward-form selection: one-pass while its whole-sequence
     residency (see :func:`_onepass_resident_bytes`) fits 2/3 of the
     device's scoped-VMEM limit, leaving the rest for the
@@ -240,13 +256,14 @@ def _use_onepass(t: int, block: int, d: int, dtype, bh: int = 2) -> bool:
     if ((resident > _DEFAULT_LIMIT_SAFE or block > _SPLIT_BLOCK_MAX)
             and not use_interpret()):
         return _onepass_compile_ok(tp, round_up(d, LANE), block, dtype.name,
-                                   min(bh, 2))
+                                   min(bh, 2), group)
     return True
 
 
 @functools.lru_cache(maxsize=None)
 def _onepass_compile_ok(tp: int, dp: int, block: int,
-                        dtype_name: str, bh_probe: int = 2) -> bool:
+                        dtype_name: str, bh_probe: int = 2,
+                        group: int = 1) -> bool:
     """Preflight: does the one-pass backward *compile* on this device at
     the padded shape? ``vmem_limit_bytes`` is serialized into the Mosaic
     custom call as ``scoped_memory_configs`` (verified against the
@@ -263,14 +280,17 @@ def _onepass_compile_ok(tp: int, dp: int, block: int,
     genuine bh=1 program (no boundary at all) still probes exactly.
     Cached per process — one ~seconds compile per distinct (padded T,
     padded D, block, dtype, probe-bh). Mask flavor (causal/strict) is
-    irrelevant to scoped allocation, so the probe always uses
-    ``causal=False``."""
+    irrelevant to scoped allocation (a window too), so the probe always
+    uses ``causal=False``; grouped heads are not: their dK/dV blocks
+    leave in float32, so ``group`` is part of the probe."""
     call = _onepass_call(bh_probe, tp, tp, dp, block, 1.0, False, False,
-                         jnp.dtype(dtype_name))
+                         jnp.dtype(dtype_name), None, group)
     seq = jax.ShapeDtypeStruct((bh_probe, tp, dp), jnp.dtype(dtype_name))
+    kv = jax.ShapeDtypeStruct((max(1, bh_probe // group), tp, dp),
+                              jnp.dtype(dtype_name))
     row = jax.ShapeDtypeStruct((bh_probe, tp, _ROWW), jnp.float32)
     try:
-        jax.jit(call).lower(seq, seq, seq, seq, row, row).compile()
+        jax.jit(call).lower(kv, kv, seq, seq, row, row).compile()
         return True
     except Exception as e:
         # Broad on purpose: ANY compile failure means the two-kernel
@@ -340,6 +360,13 @@ def select_attention(b: int, t: int, h: int, itemsize: int,
     ``SLT_FLASH_AUTO_T`` overrides both: at or above that T, flash —
     the knob for re-pinning the crossover when the kernels change.
 
+    A ``window`` changes neither rule: the dense banded path
+    (``full_attention(..., window=)``) still builds and saves the whole
+    ``[B, H, T, T]`` scores and masks them, so its residency is counted
+    as above, over the query heads ``h`` (grouped key/value heads are
+    repeated to ``h`` there); only the flash kernels skip the blocks
+    outside the band.
+
     ``t_kv`` generalizes the rule to asymmetric query/key extents (the
     sharded parallel forms — ops/ring_attention.py — resolve their
     per-rank shapes through here so the crossover has one home)."""
@@ -377,13 +404,14 @@ def _device_hbm_bytes() -> int:
     return int(limit)
 
 
-def _scores(qb, kb, t, k0, q0, scale, causal, strict=False):
+def _scores(qb, kb, t, k0, q0, scale, causal, strict=False, window=None):
     """Masked scaled scores for one (q block, k block) pair. Operands
     stay in their storage dtype (bf16 runs the MXU at full rate) and
     accumulate in f32. Both padded key cols and padded query rows are
     masked, so fully-padded rows carry l == 0 / lse == _NEG_BIG.
     ``strict`` excludes the diagonal (row > col) — the mask a striped
-    ring hop from a future-rank shard needs (ops/ring_attention.py)."""
+    ring hop from a future-rank shard needs (ops/ring_attention.py).
+    ``window`` keeps only the band ``row - col < window``."""
     s = jax.lax.dot_general(
         qb, kb, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
@@ -392,21 +420,36 @@ def _scores(qb, kb, t, k0, q0, scale, causal, strict=False):
     ok = (rows < t) & (cols < t)
     if causal:
         ok &= (rows > cols) if strict else (rows >= cols)
+    if window is not None:
+        ok &= (rows - cols) < window
     return jnp.where(ok, s, _NEG_BIG), ok
 
 
+def _band_blocks(window: int, block: int, n_blk: int) -> int:
+    """How many key blocks a query block's band can touch (and query
+    blocks a key block's): rows ``[q0, q0 + block)`` see keys from
+    ``q0 - window + 1`` on, so ``ceil((window - 1) / block)`` blocks
+    before the diagonal one. The banded kernels make this their inner
+    grid extent, so blocks wholly outside the band are neither fetched
+    nor computed."""
+    return min(n_blk, -(-(window - 1) // block) + 1)
+
+
 def _fwd_kernel(blk: int, t: int, scale: float, causal: bool,
-                strict: bool, n_k: int,
+                strict: bool, n_k: int, window,
                 q_ref, k_ref, v_ref, o_ref, lse_ref,
                 acc_ref, m_ref, l_ref):
     """Grid (bh, q block, k block), k fastest. Scratch accumulators carry
-    the online softmax across the k dimension."""
+    the online softmax across the k dimension. With a ``window`` the
+    inner extent ``n_k`` is the band's (:func:`_band_blocks`) and step
+    ``j`` is key block ``qb_i - (n_k - 1) + j``, the diagonal one last."""
     qb_i = pl.program_id(1)
-    kb_i = pl.program_id(2)
+    step = pl.program_id(2)
+    kb_i = step if window is None else qb_i - (n_k - 1) + step
     q0 = qb_i * blk
     k0 = kb_i * blk
 
-    @pl.when(kb_i == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, _NEG_BIG)
@@ -419,7 +462,8 @@ def _fwd_kernel(blk: int, t: int, scale: float, causal: bool,
     def _accumulate():
         qb = q_ref[0]
         vb = v_ref[0]
-        s, ok = _scores(qb, k_ref[0], t, k0, q0, scale, causal, strict)
+        s, ok = _scores(qb, k_ref[0], t, k0, q0, scale, causal, strict,
+                        window)
         m = m_ref[:, 0]
         m_new = jnp.maximum(m, jnp.max(s, axis=1))
         # rebase then re-mask: exp(_NEG_BIG - _NEG_BIG) would be 1
@@ -432,12 +476,14 @@ def _fwd_kernel(blk: int, t: int, scale: float, causal: bool,
             preferred_element_type=jnp.float32)
         m_ref[:] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
 
-    if causal:
+    if window is not None:
+        pl.when(kb_i >= 0)(_accumulate)   # the band starts before key 0
+    elif causal:
         pl.when(kb_i <= qb_i)(_accumulate)
     else:
         _accumulate()
 
-    @pl.when(kb_i == n_k - 1)
+    @pl.when(step == n_k - 1)
     def _finish():
         l = l_ref[:, 0]
         # padded query rows are row-masked in _scores: l == 0 there
@@ -448,7 +494,7 @@ def _fwd_kernel(blk: int, t: int, scale: float, causal: bool,
 
 
 def _onepass_bwd_kernel(blk: int, t: int, scale: float, causal: bool,
-                        strict: bool, n_q: int,
+                        strict: bool, n_q: int, window,
                         k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
                         dk_ref, dv_ref, dq_ref):
     """Single-pass backward for mid-length T: grid ``(bh, k block)``
@@ -481,7 +527,7 @@ def _onepass_bwd_kernel(blk: int, t: int, scale: float, causal: bool,
         dob = do_ref[0, pl.ds(q0, blk), :]
         lse = lse_ref[0, pl.ds(q0, blk), :][:, :1]
         delta = delta_ref[0, pl.ds(q0, blk), :][:, :1]
-        s, ok = _scores(qb, kb, t, k0, q0, scale, causal, strict)
+        s, ok = _scores(qb, kb, t, k0, q0, scale, causal, strict, window)
         p = jnp.where(ok, jnp.exp(s - lse), 0.0)
         dv += jax.lax.dot_general(
             p.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
@@ -500,32 +546,36 @@ def _onepass_bwd_kernel(blk: int, t: int, scale: float, causal: bool,
         return dk, dv
 
     zeros = jnp.zeros(kb.shape[:1] + (dq_ref.shape[-1],), jnp.float32)
-    # causal: query blocks strictly before this key block are dead
+    # causal: query blocks strictly before this key block are dead;
+    # banded: so are those past the band (_band_blocks)
     start = kb_i if causal else 0
-    dk, dv = jax.lax.fori_loop(start, n_q, body, (zeros, zeros))
+    stop = n_q if window is None else jnp.minimum(
+        n_q, kb_i + _band_blocks(window, blk, n_q))
+    dk, dv = jax.lax.fori_loop(start, stop, body, (zeros, zeros))
     dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
 def _dq_kernel(blk: int, t: int, scale: float, causal: bool,
-               strict: bool, n_k: int,
+               strict: bool, n_k: int, window,
                q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                dq_ref, acc_ref):
     """Grid (bh, q block, k block): dQ = scale * sum_k dS_k @ K_k,
-    dS = P * (dO @ V^T - delta)."""
+    dS = P * (dO @ V^T - delta). Banded as :func:`_fwd_kernel` is."""
     qb_i = pl.program_id(1)
-    kb_i = pl.program_id(2)
+    step = pl.program_id(2)
+    kb_i = step if window is None else qb_i - (n_k - 1) + step
     q0 = qb_i * blk
     k0 = kb_i * blk
 
-    @pl.when(kb_i == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     def _accumulate():
         qb = q_ref[0]
         kb = k_ref[0]
-        s, ok = _scores(qb, kb, t, k0, q0, scale, causal, strict)
+        s, ok = _scores(qb, kb, t, k0, q0, scale, causal, strict, window)
         p = jnp.where(ok, jnp.exp(s - lse_ref[0][:, :1]), 0.0)
         dp = jax.lax.dot_general(
             do_ref[0], v_ref[0],
@@ -535,29 +585,34 @@ def _dq_kernel(blk: int, t: int, scale: float, causal: bool,
             ds.astype(kb.dtype), kb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    if causal:
+    if window is not None:
+        pl.when(kb_i >= 0)(_accumulate)
+    elif causal:
         # key blocks strictly in the future of this query block are dead
         pl.when(kb_i <= qb_i)(_accumulate)
     else:
         _accumulate()
 
-    @pl.when(kb_i == n_k - 1)
+    @pl.when(step == n_k - 1)
     def _finish():
         dq_ref[0] = (acc_ref[:] * scale).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(blk: int, t: int, scale: float, causal: bool,
-                strict: bool, n_q: int,
+                strict: bool, n_q: int, window, n_blk: int,
                 k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_acc, dv_acc):
     """Grid (bh, k block, q block): dV = sum_q P^T @ dO,
-    dK = scale * sum_q dS^T @ Q."""
+    dK = scale * sum_q dS^T @ Q. With a ``window`` the inner extent
+    ``n_q`` is the band's and step ``j`` is query block ``kb_i + j``
+    (of ``n_blk``), the diagonal one first."""
     kb_i = pl.program_id(1)
-    qb_i = pl.program_id(2)
+    step = pl.program_id(2)
+    qb_i = step if window is None else kb_i + step
     k0 = kb_i * blk
     q0 = qb_i * blk
 
-    @pl.when(qb_i == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -568,7 +623,7 @@ def _dkv_kernel(blk: int, t: int, scale: float, causal: bool,
         qb = q_ref[0]
         kb = k_ref[0]
         dob = do_ref[0]
-        s, ok = _scores(qb, kb, t, k0, q0, scale, causal, strict)
+        s, ok = _scores(qb, kb, t, k0, q0, scale, causal, strict, window)
         p = jnp.where(ok, jnp.exp(s - lse_ref[0][:, :1]), 0.0)
         dv_acc[:] += jax.lax.dot_general(
             p.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
@@ -581,44 +636,63 @@ def _dkv_kernel(blk: int, t: int, scale: float, causal: bool,
             ds.astype(qb.dtype), qb, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    if causal:
+    if window is not None:
+        pl.when(qb_i < n_blk)(_accumulate)   # the band ends past row T
+    elif causal:
         pl.when(qb_i >= kb_i)(_accumulate)
     else:
         _accumulate()
 
-    @pl.when(qb_i == n_q - 1)
+    @pl.when(step == n_q - 1)
     def _finish():
         dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
 # --------------------------------------------------------------------- #
+def _kv_index(group: int):
+    """Row of the folded ``[B * H_kv, T, D]`` key/value array that query
+    row ``b`` of ``[B * H, T, D]`` reads: query head ``n`` reads
+    key/value head ``n // group``, and ``B * H`` folds batch-major, so
+    the row is ``b // group``."""
+    return (lambda b: b) if group == 1 else (lambda b: b // group)
+
+
 def _onepass_call(bh: int, t: int, tp: int, dp: int, block: int,
-                  scale: float, causal: bool, strict: bool, in_dtype):
+                  scale: float, causal: bool, strict: bool, in_dtype,
+                  window=None, group: int = 1):
     """The one-pass backward's ``pallas_call``, shared verbatim between
     the real VJP (:func:`_make_flash`) and the preflight probe
     (:func:`_onepass_compile_ok`) so the probe compiles exactly what the
     user path would. Whole-sequence refs (index maps ignore the k grid
     dim; dq revisits its block consecutively across k) against the
-    raised ``_vmem_limit_bytes()``, not Mosaic's 16 MiB default."""
+    raised ``_vmem_limit_bytes()``, not Mosaic's 16 MiB default.
+
+    Grouped heads (``group`` query heads a key/value head): K/V blocks
+    are read from row ``b // group``, and dK/dV come out per *query*
+    head in float32 — the caller sums each group's."""
     n_blk = tp // block
+    kv = _kv_index(group)
     seq = pl.BlockSpec((1, tp, dp), lambda b, k: (b, 0, 0),
                        memory_space=pltpu.VMEM)
     seqrow = pl.BlockSpec((1, tp, _ROWW), lambda b, k: (b, 0, 0),
                           memory_space=pltpu.VMEM)
-    kblk = lambda: pl.BlockSpec((1, block, dp), lambda b, k: (b, k, 0),
+    kin = lambda: pl.BlockSpec((1, block, dp), lambda b, k: (kv(b), k, 0),
+                               memory_space=pltpu.VMEM)
+    kout = lambda: pl.BlockSpec((1, block, dp), lambda b, k: (b, k, 0),
                                 memory_space=pltpu.VMEM)
+    kv_dtype = in_dtype if group == 1 else jnp.float32
     return pl.pallas_call(
         functools.partial(_onepass_bwd_kernel, block, t, scale,
-                          causal, strict, n_blk),
+                          causal, strict, n_blk, window),
         out_shape=(
-            jax.ShapeDtypeStruct((bh, tp, dp), in_dtype),
-            jax.ShapeDtypeStruct((bh, tp, dp), in_dtype),
+            jax.ShapeDtypeStruct((bh, tp, dp), kv_dtype),
+            jax.ShapeDtypeStruct((bh, tp, dp), kv_dtype),
             jax.ShapeDtypeStruct((bh, tp, dp), jnp.float32),
         ),
         grid=(bh, n_blk),
-        in_specs=[kblk(), kblk(), seq, seq, seqrow, seqrow],
-        out_specs=(kblk(), kblk(), seq),
+        in_specs=[kin(), kin(), seq, seq, seqrow, seqrow],
+        out_specs=(kout(), kout(), seq),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_vmem_limit_bytes()),
         interpret=use_interpret(),
@@ -628,20 +702,26 @@ def _onepass_call(bh: int, t: int, tp: int, dp: int, block: int,
 @functools.lru_cache(maxsize=None)
 def _make_flash(bh: int, t: int, d: int, causal: bool, dtype_name: str,
                 block: int, with_lse: bool = False, strict: bool = False,
-                onepass: bool = False):
+                onepass: bool = False, window=None, group: int = 1):
     """Custom-VJP flash attention for one static ([BH, T, D], causal).
 
     ``with_lse=True`` additionally returns the per-row logsumexp as a
     differentiable output — the hook ring attention composes on
     (partial results merge exactly via (o, lse) pairs). The backward
     absorbs the lse cotangent into the ``delta`` row vector:
-    ``dS = P * (dP - (delta - g_lse))`` since ``d lse / d s = P``."""
+    ``dS = P * (dP - (delta - g_lse))`` since ``d lse / d s = P``.
+
+    ``window`` (with ``causal``) keeps keys ``0 <= i - j < window``; the
+    inner grid extent shrinks to the band (:func:`_band_blocks`).
+    ``group`` > 1: K/V are ``[BH // group, T, D]``."""
     in_dtype = jnp.dtype(dtype_name)
     scale = d ** -0.5
     tp = round_up(t, block)
     dp = round_up(d, LANE)
     n_blk = tp // block
-    grid = (bh, n_blk, n_blk)
+    n_in = n_blk if window is None else _band_blocks(window, block, n_blk)
+    grid = (bh, n_blk, n_in)
+    kv = _kv_index(group)
 
     def pad_qkv(x):
         return pad_axis(pad_axis(x, 1, tp), 2, dp)
@@ -651,6 +731,24 @@ def _make_flash(bh: int, t: int, d: int, causal: bool, dtype_name: str,
 
     def inner(b, i, k):   # block of the inner (grid dim 2) axis
         return (b, k, 0)
+
+    def kv_outer(b, i, k):   # key/value block of the outer axis
+        return (kv(b), i, 0)
+
+    if window is None:
+        def kv_inner(b, i, k):
+            return (kv(b), k, 0)
+
+        q_inner = inner
+    else:
+        # banded: step k of query block i is key block i - (n_in-1) + k
+        # (clamped: the kernel skips the steps before key 0), and step k
+        # of key block i is query block i + k (clamped likewise)
+        def kv_inner(b, i, k):
+            return (kv(b), jnp.maximum(i - (n_in - 1) + k, 0), 0)
+
+        def q_inner(b, i, k):
+            return (b, jnp.minimum(i + k, n_blk - 1), 0)
 
     blk = lambda idx: pl.BlockSpec((1, block, dp), idx,
                                    memory_space=pltpu.VMEM)
@@ -663,13 +761,13 @@ def _make_flash(bh: int, t: int, d: int, causal: bool, dtype_name: str,
         qp, kp, vp = pad_qkv(q), pad_qkv(k), pad_qkv(v)
         o, lse = pl.pallas_call(
             functools.partial(_fwd_kernel, block, t, scale, causal,
-                              strict, n_blk),
+                              strict, n_in, window),
             out_shape=(
                 jax.ShapeDtypeStruct((bh, tp, dp), in_dtype),
                 jax.ShapeDtypeStruct((bh, tp, _ROWW), jnp.float32),
             ),
             grid=grid,
-            in_specs=[blk(outer), blk(inner), blk(inner)],
+            in_specs=[blk(outer), blk(kv_inner), blk(kv_inner)],
             out_specs=(blk(outer), row(outer)),
             scratch_shapes=[acc_scratch, row_scratch, row_scratch],
             interpret=use_interpret(),
@@ -717,8 +815,8 @@ def _make_flash(bh: int, t: int, d: int, causal: bool, dtype_name: str,
             # mid-T fast path: one kernel, scores computed once per
             # block pair (shared builder — see _onepass_call)
             dk, dv, dq = _onepass_call(
-                bh, t, tp, dp, block, scale, causal, strict, in_dtype
-            )(kp, vp, qp, dop, lse, delta)
+                bh, t, tp, dp, block, scale, causal, strict, in_dtype,
+                window, group)(kp, vp, qp, dop, lse, delta)
             dq = dq.astype(in_dtype)
         else:
             # same per-generation allowance as the fwd call: the
@@ -729,13 +827,14 @@ def _make_flash(bh: int, t: int, d: int, causal: bool, dtype_name: str,
             # fallback exists to prevent
             split_params = pltpu.CompilerParams(
                 vmem_limit_bytes=_vmem_limit_bytes())
+            kv_dtype = in_dtype if group == 1 else jnp.float32
             dq = pl.pallas_call(
                 functools.partial(_dq_kernel, block, t, scale, causal,
-                                  strict, n_blk),
+                                  strict, n_in, window),
                 out_shape=jax.ShapeDtypeStruct((bh, tp, dp), in_dtype),
                 grid=grid,
-                in_specs=[blk(outer), blk(inner), blk(inner), blk(outer),
-                          row(outer), row(outer)],
+                in_specs=[blk(outer), blk(kv_inner), blk(kv_inner),
+                          blk(outer), row(outer), row(outer)],
                 out_specs=blk(outer),
                 scratch_shapes=[acc_scratch],
                 interpret=use_interpret(),
@@ -743,19 +842,25 @@ def _make_flash(bh: int, t: int, d: int, causal: bool, dtype_name: str,
             )(qp, kp, vp, dop, lse, delta)
             dk, dv = pl.pallas_call(
                 functools.partial(_dkv_kernel, block, t, scale, causal,
-                                  strict, n_blk),
+                                  strict, n_in, window, n_blk),
                 out_shape=(
-                    jax.ShapeDtypeStruct((bh, tp, dp), in_dtype),
-                    jax.ShapeDtypeStruct((bh, tp, dp), in_dtype),
+                    jax.ShapeDtypeStruct((bh, tp, dp), kv_dtype),
+                    jax.ShapeDtypeStruct((bh, tp, dp), kv_dtype),
                 ),
                 grid=grid,
-                in_specs=[blk(outer), blk(outer), blk(inner), blk(inner),
-                          row(inner), row(inner)],
+                in_specs=[blk(kv_outer), blk(kv_outer), blk(q_inner),
+                          blk(q_inner), row(q_inner), row(q_inner)],
                 out_specs=(blk(outer), blk(outer)),
                 scratch_shapes=[acc_scratch, acc_scratch],
                 interpret=use_interpret(),
                 compiler_params=split_params,
             )(kp, vp, qp, dop, lse, delta)
+        if group > 1:
+            # per query head in float32: each key/value head's gradient
+            # is the sum over the query heads that read it
+            fold = lambda x: x.reshape(
+                bh // group, group, tp, dp).sum(1).astype(in_dtype)
+            dk, dv = fold(dk), fold(dv)
         trim = lambda x: x[:, :t, :d]
         return trim(dq), trim(dk), trim(dv)
 
@@ -763,29 +868,53 @@ def _make_flash(bh: int, t: int, d: int, causal: bool, dtype_name: str,
     return attn
 
 
+def _folded(q, k, v, causal: bool, window, with_lse: bool, strict: bool):
+    """Shared entry: check the mask and the head counts, resolve the
+    block, fold ``[B, T, H, D]`` to ``[B * H, T, D]`` and call."""
+    b, t, h, d = q.shape
+    h_kv = k.shape[2]
+    if k.shape != v.shape or h % h_kv or k.shape[:2] != (b, t):
+        raise ValueError(f"q {q.shape} against k {k.shape}, v {v.shape}: "
+                         "key/value heads must divide the query heads")
+    if window is not None and (not causal or strict or window < 1):
+        raise ValueError("window is the causal band 0 <= i - j < window: "
+                         "it needs causal=True, strict=False, window >= 1")
+    if window is not None and window >= t:
+        window = None   # the band covers every causal key
+    block, onepass = _resolve_block(t, d, q.dtype, bh=b * h,
+                                    group=h // h_kv)
+    fn = _make_flash(b * h, t, d, causal, str(q.dtype), block,
+                     with_lse=with_lse, strict=strict, onepass=onepass,
+                     window=window, group=h // h_kv)
+
+    def fold(x):  # [B, T, H, D] -> [B*H, T, D]
+        return jnp.transpose(x, (0, 2, 1, 3)).reshape(-1, t, d)
+
+    return fn(fold(q), fold(k), fold(v))
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                    causal: bool = False) -> jax.Array:
+                    causal: bool = False, window: int | None = None
+                    ) -> jax.Array:
     """Blockwise-streamed attention, ``[B, T, H, D] -> [B, T, H, D]``.
 
     Drop-in for
     :func:`split_learning_tpu.ops.ring_attention.full_attention` with a
     Pallas kernel forward/backward (compiled on TPU, interpreted
-    elsewhere).
+    elsewhere). ``k``/``v`` may hold fewer heads than ``q``
+    (``[B, T, H_kv, D]``, ``H % H_kv == 0``): query head ``n`` reads
+    key/value head ``n // (H // H_kv)``. ``window`` (needs ``causal``)
+    lets query ``i`` see keys ``j`` with ``0 <= i - j < window``; key
+    blocks wholly outside the band are skipped, not masked.
     """
     b, t, h, d = q.shape
-    block, onepass = _resolve_block(t, d, q.dtype, bh=b * h)
-    fn = _make_flash(b * h, t, d, causal, str(q.dtype), block,
-                     onepass=onepass)
-
-    def fold(x):  # [B, T, H, D] -> [B*H, T, D]
-        return jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, t, d)
-
-    o = fn(fold(q), fold(k), fold(v))
+    o = _folded(q, k, v, causal, window, False, False)
     return jnp.transpose(o.reshape(b, h, t, d), (0, 2, 1, 3))
 
 
 def flash_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array,
-                             causal: bool = False, strict: bool = False
+                             causal: bool = False, strict: bool = False,
+                             window: int | None = None
                              ) -> tuple[jax.Array, jax.Array]:
     """:func:`flash_attention` that also returns the per-row logsumexp.
 
@@ -799,19 +928,12 @@ def flash_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array,
     ring hop from a future-rank shard needs; a fully-masked first row
     comes back as ``o = 0, lse = NEG_BIG``, the identity of the
     log-space merge. ``strict`` refines the causal mask, so it requires
-    ``causal=True``."""
+    ``causal=True``. ``window`` and grouped key/value heads as in
+    :func:`flash_attention`."""
     if strict and not causal:
         raise ValueError("strict=True refines the causal mask and "
                          "requires causal=True")
     b, t, h, d = q.shape
-    block, onepass = _resolve_block(t, d, q.dtype, bh=b * h)
-    fn = _make_flash(b * h, t, d, causal, str(q.dtype), block,
-                     with_lse=True, strict=strict,
-                     onepass=onepass)
-
-    def fold(x):
-        return jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, t, d)
-
-    o, lse = fn(fold(q), fold(k), fold(v))
+    o, lse = _folded(q, k, v, causal, window, True, strict)
     o = jnp.transpose(o.reshape(b, h, t, d), (0, 2, 1, 3))
     return o, jnp.transpose(lse.reshape(b, h, t), (0, 2, 1))

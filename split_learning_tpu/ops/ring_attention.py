@@ -47,18 +47,32 @@ from split_learning_tpu.parallel.mesh import DATA_AXIS, SEQ_AXIS
 
 
 def full_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                   causal: bool = False) -> jax.Array:
+                   causal: bool = False,
+                   window: Optional[int] = None) -> jax.Array:
     """Plain dense softmax attention, ``[B, T, H, D] -> [B, T, H, D]``.
 
     The single-device reference semantics both parallel forms must
     reproduce; also the path the transformer uses with no ``seq`` mesh
-    axis.
+    axis. ``k``/``v`` may hold fewer heads (``[B, T, H_kv, D]``): query
+    head ``n`` reads key/value head ``n // (H // H_kv)``. ``window``
+    (with ``causal``) keeps keys ``0 <= i - j < window``: the dense
+    banded path that serves the CPU and that the windowed flash
+    kernels are tested against.
     """
+    if window is not None and not causal:
+        raise ValueError("window is a causal band: it needs causal=True")
+    h, h_kv = q.shape[2], k.shape[2]
+    if h != h_kv:
+        k = jnp.repeat(k, h // h_kv, axis=2)
+        v = jnp.repeat(v, h // h_kv, axis=2)
     scale = q.shape[-1] ** -0.5
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     if causal:
         tq, tk = q.shape[1], k.shape[1]
-        mask = jnp.arange(tq)[:, None] >= jnp.arange(tk)[None, :]
+        behind = jnp.arange(tq)[:, None] - jnp.arange(tk)[None, :]
+        mask = behind >= 0
+        if window is not None:
+            mask &= behind < window
         s = jnp.where(mask[None, None], s, _NEG_BIG)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)

@@ -1,0 +1,290 @@
+"""The afmoe family (models/afmoe.py) against the benchmark's plain
+reference (benchmarks/reference/afmoe.py), its routed layer's shares
+against the whole, the grouped products against a loop, and the windowed
+grouped-head flash kernels (interpret mode) against the dense banded
+path. CPU, small sizes."""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from split_learning_tpu.core.losses import cross_entropy
+from split_learning_tpu.models import get_plan
+from split_learning_tpu.ops.flash_attention import (
+    flash_attention, flash_attention_with_lse)
+from split_learning_tpu.ops.grouped_matmul import (
+    grouped_matmul, grouped_matmul_reference)
+from split_learning_tpu.ops.ring_attention import full_attention
+from split_learning_tpu.runtime import ServerRuntime, SplitClientTrainer
+from split_learning_tpu.runtime.fused import FusedSplitTrainer
+from split_learning_tpu.transport import LocalTransport
+from split_learning_tpu.utils import Config
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmarks")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+from reference import afmoe as reference      # noqa: E402
+from reference import common as ref_common    # noqa: E402
+
+# the rehearsal's sizes: 1 dense + 4 routed layers (3 window, 1 full),
+# 8 experts of which 4 held from the third on, 2 a token, window 8 of T 16
+KW = dict(vocab=300, d_model=64, num_heads=4, num_kv_heads=2, head_dim=16,
+          dense_width=192, expert_width=32, experts_total=8, experts_held=4,
+          expert_offset=2, experts_per_token=2, shared_experts=1,
+          route_scale=2.826, window=8,
+          layer_types=["sliding_attention"] * 4 + ["full_attention"],
+          dense_layers=1, client_depth=1, attn="auto", remat=True)
+B, T, LR = 2, 16, 1e-3
+
+
+def batches(n, seed=0):
+    rs = np.random.RandomState(seed)
+    ids = rs.randint(0, KW["vocab"], (n, B, T + 1)).astype(np.int32)
+    return [(a[:, :-1], a[:, 1:]) for a in ids]
+
+
+def seeded(plan, x, seed=1):
+    """``plan.init``'s weights moved off their constants (norm scales
+    around 1, the selection bias around 0), in float32."""
+    params = plan.init(jax.random.PRNGKey(seed), x)
+    leaves, tree = jax.tree_util.tree_flatten(params)
+    keys = jax.random.split(jax.random.PRNGKey(seed + 100), len(leaves))
+    return jax.tree_util.tree_unflatten(tree, [
+        leaf + 0.02 * jax.random.normal(k, leaf.shape, leaf.dtype)
+        for leaf, k in zip(leaves, keys)])
+
+
+def flat(tree):
+    return {jax.tree_util.keystr(k): np.asarray(v, np.float32)
+            for k, v in jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+
+# float32 on the CPU: both sides are the same arithmetic in another order
+# (sorted pairs and grouped products against a masked loop over experts;
+# one-pass softmax against blocks), so a leaf's gradient agrees to 2e-4 of
+# its largest entry. bfloat16 compute against the float32 reference: 8
+# mantissa bits through five layers, and the odd token whose 2nd and 3rd
+# router scores lie within that noise picks another expert: the loss
+# within 0.05, a leaf's gradient norm within 8 % of the reference's or of
+# the median leaf's.
+@pytest.mark.parametrize("dtype,loss_tol,grad_tol", [
+    ("float32", 2e-5, 2e-4), ("bfloat16", 0.05, 0.08)])
+def test_loss_and_every_gradient_match_the_reference(dtype, loss_tol, grad_tol):
+    plan = get_plan("afmoe", "split", jnp.dtype(dtype), **KW)
+    (x, y), = batches(1)
+    params = seeded(plan, x)
+    want, want_g = jax.value_and_grad(
+        reference.loss_fn({"plan": {"kwargs": KW}}, "f32"), argnums=(0, 1))(
+            params[0], params[1], x, y)
+    got, got_g = jax.jit(jax.value_and_grad(
+        lambda p: cross_entropy(plan.apply(p, x), y)))(params)
+    assert abs(float(got) - float(want)) <= loss_tol
+    ref, prog = flat(want_g), flat(got_g)
+    assert ref.keys() == prog.keys()
+    if dtype == "float32":
+        for name, g in ref.items():
+            np.testing.assert_allclose(
+                prog[name], g, rtol=0, atol=grad_tol * max(np.abs(g).max(), 1e-6),
+                err_msg=name)
+    else:
+        norms = {k: np.linalg.norm(g) for k, g in ref.items()}
+        median = np.median(list(norms.values()))
+        for name, g in prog.items():
+            gap = abs(np.linalg.norm(g) - norms[name]) / max(norms[name], median)
+            assert gap <= grad_tol, (name, gap)
+    # the selection bias takes no gradient on either side
+    for name, g in prog.items():
+        if name.endswith("['expert_bias']"):
+            assert not g.any() and not ref[name].any()
+
+
+def trained(make, steps):
+    trainer = make()
+    losses = [trainer.train_step(x, y) for x, y in steps]
+    return trainer, losses
+
+
+def test_three_adamw_steps_match_the_reference():
+    """FusedSplitTrainer's first three steps against the reference's
+    training loop from the same weights: each loss, and every leaf's
+    change (float32: 1e-4 and 2 % of the change's norm; Adam's first steps
+    are lr-sized whatever the gradient, so a changed leaf moves by
+    about lr * sqrt(size))."""
+    plan = get_plan("afmoe", "split", jnp.float32, **KW)
+    steps = batches(3)
+    cfg = Config(mode="split", model="afmoe", optimizer="adamw", lr=LR,
+                 batch_size=B)
+    start = seeded(plan, steps[0][0])
+
+    class Seeded(type(plan)):
+        def init(self, rng, sample):
+            return jax.tree_util.tree_map(jnp.copy, start)
+
+    plan = Seeded(stages=plan.stages, owners=plan.owners)
+    trainer, losses = trained(lambda: FusedSplitTrainer(
+        plan, cfg, jax.random.PRNGKey(0), steps[0][0]), steps)
+    want = ref_common.train(
+        reference.loss_fn({"plan": {"kwargs": KW}}, "f32"),
+        lambda: ([jax.tree_util.tree_map(jnp.copy, start[0])],
+                 jax.tree_util.tree_map(jnp.copy, start[1])),
+        [[xy] for xy in steps], LR, B)
+    np.testing.assert_allclose(losses, [l[0] for l in want["losses"]], atol=1e-4)
+    got = {"client0": ref_common.named(ref_common.leaf_delta_norms(
+        trainer.state.params[0], start[0])),
+        "server": ref_common.named(ref_common.leaf_delta_norms(
+            trainer.state.params[1], start[1]))}
+    for party, leaves in want["delta_norms"].items():
+        for name, norm in leaves.items():
+            if name.endswith("expert_bias"):
+                assert norm == 0 and got[party][name] == 0
+            else:
+                assert got[party][name] == pytest.approx(norm, rel=0.02), name
+
+
+def test_fused_step_equals_the_two_party_step():
+    """One program for the whole split step against a SplitClientTrainer
+    and a ServerRuntime of the same plan over the local wire."""
+    plan = get_plan("afmoe", "split", jnp.float32, **KW)
+    cfg = Config(mode="split", model="afmoe", optimizer="adamw", lr=LR,
+                 batch_size=B)
+    steps = batches(3)
+    _, fused = trained(lambda: FusedSplitTrainer(
+        plan, cfg, jax.random.PRNGKey(3), steps[0][0]), steps)
+    server = ServerRuntime(plan, cfg, jax.random.PRNGKey(3), steps[0][0])
+    client = SplitClientTrainer(plan, cfg, jax.random.PRNGKey(3),
+                                LocalTransport(server))
+    party = [client.train_step(x, y, i) for i, (x, y) in enumerate(steps)]
+    np.testing.assert_allclose(fused, party, rtol=1e-5, atol=1e-6)
+
+
+def test_u_split_and_no_decode_cache():
+    plan = get_plan("afmoe", "u_split", jnp.float32, **KW)
+    assert plan.owners == ("client", "server", "client")
+    (x, _), = batches(1)
+    params = plan.init(jax.random.PRNGKey(0), x)
+    assert plan.apply(params, x).shape == (B, T, KW["vocab"])
+    with pytest.raises(NotImplementedError, match="KV-cache"):
+        plan.stages[0].apply(params[0], x, cache_len=T)
+    with pytest.raises(ValueError, match="experts"):
+        get_plan("afmoe", "split", **{**KW, "expert_offset": 6})
+
+
+def test_the_shares_add_up():
+    """8 experts in 4 shares of 2: the routed parts that all the shares
+    give, with the shared expert counted once, are the uncut layer."""
+    from split_learning_tpu.models.afmoe import AfmoeLayer
+    kw = dict(num_heads=4, num_kv_heads=2, head_dim=16,
+              layer_type="full_attention", window=8, dense_width=0,
+              expert_width=32, experts_total=8, experts_per_token=2,
+              shared_experts=1, route_scale=2.826)
+    h = jax.random.normal(jax.random.PRNGKey(0), (B, T, 64), jnp.float32)
+    whole = AfmoeLayer(**kw, experts_held=8, expert_offset=0)
+    p = whole.init(jax.random.PRNGKey(1), h)["params"]
+    p["experts"]["expert_bias"] = 0.1 * jax.random.normal(
+        jax.random.PRNGKey(2), (8,))
+    # norm_post_mlp is not linear: compare what goes into it
+    p["norm_post_mlp"]["scale"] = jnp.ones((64,))
+    inner = lambda layer, params: layer.apply(
+        {"params": params}, h, capture_intermediates=lambda m, _: m.name in (
+            "shared", "experts"))[1]["intermediates"]
+    got = inner(whole, p)
+    shared, routed = got["shared"]["__call__"][0], got["experts"]["__call__"][0]
+    parts = 0.0
+    for share in range(4):
+        cut = {**p, "experts": {**p["experts"], **{
+            n: p["experts"][n][2 * share:2 * share + 2]
+            for n in ("gate", "up", "down")}}}
+        part = inner(AfmoeLayer(**kw, experts_held=2, expert_offset=2 * share),
+                     cut)
+        np.testing.assert_array_equal(part["shared"]["__call__"][0], shared)
+        parts = parts + part["experts"]["__call__"][0]
+    np.testing.assert_allclose(parts, routed, atol=1e-5)
+    # and the uncut reference gives the same layer
+    kwr = dict(KW, experts_held=8, expert_offset=0, rms_norm_eps=1e-5,
+               rope_theta=10000.0)
+    mm = ref_common.matmul("f32")
+    want = jax.vmap(lambda one: reference.layer(p, one, "full_attention",
+                                                kwr, mm))(h)
+    np.testing.assert_allclose(whole.apply({"params": p}, h), want, atol=2e-5)
+
+
+@pytest.mark.parametrize("sizes", [
+    [10, 0, 20, 5],      # an empty expert, a partly filled buffer
+    [0, 35, 0, 0],       # every pair on one expert
+    [12, 12, 12, 12],    # the buffer full
+    [0, 0, 0, 0],        # nothing routed here
+], ids=["empty-expert", "all-on-one", "buffer-full", "nothing"])
+def test_grouped_products_match_a_loop(sizes):
+    m, k, n = 48, 24, 40
+    x = jax.random.normal(jax.random.PRNGKey(0), (m, k))
+    w = jax.random.normal(jax.random.PRNGKey(1), (len(sizes), k, n))
+    c = jax.random.normal(jax.random.PRNGKey(2), (m, n))
+    gs = jnp.array(sizes, jnp.int32)
+    f = lambda fn: (lambda x, w: jnp.sum(fn(x, w, gs) * c))
+    want = jax.value_and_grad(f(grouped_matmul_reference), argnums=(0, 1))(x, w)
+    got = jax.jit(jax.value_and_grad(f(grouped_matmul), argnums=(0, 1)))(x, w)
+    out = grouped_matmul(x, w, gs)
+    assert not np.asarray(out)[sum(sizes):].any()       # unfilled rows: zeros
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5, atol=1e-5)
+    for a, b in zip(got[1], want[1]):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+
+def qkv(t, h, h_kv, b=2, d=16):
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    return (jax.random.normal(ks[0], (b, t, h, d)),
+            jax.random.normal(ks[1], (b, t, h_kv, d)),
+            jax.random.normal(ks[2], (b, t, h_kv, d)),
+            jax.random.normal(ks[3], (b, t, h, d)))
+
+
+# T 300 pads to three 128 blocks and is no multiple of the window; 640 is
+# five blocks with a band of three; window 129 is one past a block edge
+@pytest.mark.parametrize("onepass", ["", "0"], ids=["onepass", "split"])
+@pytest.mark.parametrize("t,h,h_kv,window", [
+    (72, 4, 2, 8), (300, 4, 1, 100), (640, 2, 2, 200), (384, 2, 1, 129),
+    (640, 4, 2, None)])
+def test_window_and_grouped_heads_match_the_dense_band(
+        monkeypatch, onepass, t, h, h_kv, window):
+    """Forward and gradients of the interpreted kernels, both backward
+    forms, against ``full_attention``'s dense banded path."""
+    monkeypatch.setenv("SLT_FLASH_ONEPASS_T", onepass)
+    q, k, v, w = qkv(t, h, h_kv)
+    f = lambda fn: (lambda a, b, c: jnp.sum(
+        fn(a, b, c, causal=True, window=window) * w))
+    want = jax.value_and_grad(f(full_attention), argnums=(0, 1, 2))(q, k, v)
+    got = jax.jit(jax.value_and_grad(f(flash_attention),
+                                     argnums=(0, 1, 2)))(q, k, v)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5, atol=1e-4)
+    for a, b in zip(got[1], want[1]):
+        np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5)
+
+
+def test_no_window_is_the_kernel_it_was():
+    """``window=None`` with equal head counts traces the kernels the
+    other families run: the same jaxpr as the call without the argument
+    (PR 26 compared it, and its gradient's, with the parent commit's:
+    equal text), bit-equal outputs, and a window that covers the
+    sequence is no window."""
+    q, k, v, _ = qkv(200, 2, 2)
+    plain = jax.make_jaxpr(lambda a, b, c: flash_attention(a, b, c, True))
+    named = jax.make_jaxpr(
+        lambda a, b, c: flash_attention(a, b, c, causal=True, window=None))
+    assert str(plain(q, k, v)) == str(named(q, k, v))
+    base = flash_attention(q, k, v, causal=True)
+    np.testing.assert_array_equal(
+        base, flash_attention(q, k, v, causal=True, window=200))
+    o, lse = flash_attention_with_lse(q, k, v, causal=True, window=None)
+    np.testing.assert_array_equal(o, base)
+    assert lse.shape == (2, 200, 2)
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, k, v, causal=False, window=8)
+    with pytest.raises(ValueError, match="divide"):
+        three = jnp.zeros((2, 200, 3, 16))   # 3 heads under 2
+        flash_attention(q, three, three)
