@@ -1,0 +1,104 @@
+"""What the fused afmoe step keeps on the device, by XLA's own reckoning.
+
+    JAX_PLATFORMS=cpu python scripts/afmoe_memory.py [--workload trinity-mini-fused-t8192]
+
+Compiles the cell's fused step (``runtime/fused.py``'s ``step_fn``: loss,
+gradient, optimizer update, the state donated) at the cell's real sizes
+for a *described* TPU v5e, with no chip attached, and prints the compiled
+program's memory analysis as one JSON line: arguments, outputs, aliased,
+temporaries and their peak (arguments + outputs - aliased + temporaries),
+in bytes and GB.  Nothing runs, so it says what fits, never how fast.  It
+counts this one program, not what else a process keeps on the device
+(PERF.md section 4: two loaded executables once cost the reference its
+room).  ``--remat 0`` compiles the step without the family's ``remat``,
+to see what the routed part's rows cost when kept.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+# T 8192 takes the one-pass flash backward on the chip, after a preflight
+# compile that cannot run here: name the form instead
+os.environ.setdefault("SLT_FLASH_ONEPASS_T", "8192")
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
+
+
+def main() -> int:
+    parser = argparse.ArgumentParser()
+    parser.add_argument("--workload", default="trinity-mini-fused-t8192")
+    parser.add_argument("--remat", type=int, choices=(0, 1), default=None,
+                        help="override the configuration's plan.kwargs.remat")
+    args = parser.parse_args()
+
+    import run
+    _, cell, config = run.load_cell(args.workload)
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    import traffic
+    import weights
+    from split_learning_tpu.core.losses import cross_entropy
+    from split_learning_tpu.models.factory import get_plan
+    from split_learning_tpu.ops import common
+    from split_learning_tpu.runtime.state import (
+        apply_grads, make_state, make_tx)
+
+    # the kernels as the chip compiles them, not their interpreter
+    common._default_backend_platform = lambda: "tpu"
+    jax.config.update("jax_enable_compilation_cache", False)
+
+    job = traffic.load(cell["traffic"])
+    spec = config["plan"]
+    kw = dict(spec["kwargs"])
+    if args.remat is not None:
+        kw["remat"] = bool(args.remat)
+    plan = get_plan(spec["model"], spec["mode"], jnp.dtype(spec["dtype"]), **kw)
+    tx = make_tx(run.program_config(config, job))
+
+    rows = job["clients"] * job["rows_per_client"]
+    tokens = jax.ShapeDtypeStruct((rows, job["tokens_per_row"]), jnp.int32)
+    params = tuple(weights.stage_shapes(plan, tokens))
+    state = jax.eval_shape(lambda p: make_state(p, tx), params)
+
+    def step(state, x, y):
+        loss, grads = jax.value_and_grad(
+            lambda p: cross_entropy(plan.apply(p, x), y))(state.params)
+        return apply_grads(tx, state, grads), loss
+
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    chip = SingleDeviceSharding(topo.devices[0])
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip), tree)
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        on_chip(state), on_chip(tokens), on_chip(tokens)).compile()
+    m = compiled.memory_analysis()
+    sizes = {"arguments": m.argument_size_in_bytes,
+             "outputs": m.output_size_in_bytes,
+             "aliased": m.alias_size_in_bytes,
+             "temporaries": m.temp_size_in_bytes,
+             "code": m.generated_code_size_in_bytes}
+    sizes["peak"] = (sizes["arguments"] + sizes["outputs"] - sizes["aliased"]
+                     + sizes["temporaries"])
+    text = compiled.as_text()
+    print(json.dumps({
+        "workload": args.workload, "remat": kw.get("remat"),
+        "device": topo.devices[0].device_kind,
+        "bytes": sizes,
+        "gb": {k: round(v / 1e9, 3) for k, v in sizes.items()},
+        "tpu_custom_calls": text.count("tpu_custom_call"),
+    }))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

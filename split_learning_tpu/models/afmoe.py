@@ -35,6 +35,20 @@ token is dropped, shapes are static, one program serves every routing.
 What the absent experts would add is left out; no code stands in for
 absent chips. ``experts_held == experts_total`` is the whole layer.
 
+**What ``remat`` recomputes.** With ``remat`` the backward pass makes
+the routed part of every expert layer again (:class:`RoutedExperts`
+whole: router product, scores, top-k, the pairs' sorts, the gathers out
+and back, the three grouped products; the routing is deterministic, so
+the selection is the forward's) and keeps none of its tokens x experts
+per token rows. Everything else the forward made is kept for the
+backward: every dense product's output (q, k, v, output gate and output
+projection, the shared expert's and the dense layer's gate / up / down),
+what the flash kernels' backward reads (padded q, k, v, the output and
+the row logsumexp) and the elementwise work between them, so no kernel
+and no dense product of a layer runs a second time. ``Config.remat``
+(``core/stage.remat_plan``: the whole stage under ``jax.checkpoint``)
+nests over this for a user short of memory.
+
 The selection bias ``expert_bias`` is a float32 leaf under
 ``stop_gradient``: its published update (from per-expert token counts)
 would have to leave the step beside the activations, which a pure
@@ -247,6 +261,7 @@ class AfmoeLayer(nn.Module):
     eps: float = 1e-5
     attn: str = "auto"
     dtype: Any = jnp.float32
+    remat: bool = False       # recompute the routed part in the backward
 
     @nn.compact
     def __call__(self, h):
@@ -268,7 +283,10 @@ class AfmoeLayer(nn.Module):
         with jax.named_scope(spans.MOE_SHARED):
             y = SwiGLU(self.expert_width * self.shared_experts, self.dtype,
                        name="shared")(m32.astype(self.dtype))
-        y = y + RoutedExperts(
+        # only the routed part, with its tokens x experts_per_token rows,
+        # is too large to keep for the backward (the module header)
+        routed = nn.remat(RoutedExperts) if self.remat else RoutedExperts
+        y = y + routed(
             self.expert_width, self.experts_total, self.experts_held,
             self.expert_offset, self.experts_per_token, self.route_scale,
             self.dtype, name="experts")(m32.reshape(b * t, e)).reshape(b, t, e)
@@ -282,18 +300,17 @@ def _no_cache(cache_len, decode_cache):
             "that forgets (runtime/generate.py, ROADMAP.md M4)")
 
 
-def _run_layers(h, first: int, layer_types, dense_layers: int, remat: bool,
-                layer_kw):
+def _run_layers(h, first: int, layer_types, dense_layers: int, layer_kw):
     """Layers ``[first, first + len(layer_types))`` of the model, named
-    ``layer<i>`` by their index in it (call inside a compact method);
-    each one's forward is recomputed in the backward pass when
-    ``remat``."""
-    cls = nn.remat(AfmoeLayer) if remat else AfmoeLayer
+    ``layer<i>`` by their index in it (call inside a compact method).
+    ``layer_kw`` are :class:`AfmoeLayer`'s fields as items; with its
+    ``remat`` each expert layer's routed part is recomputed in the
+    backward pass and nothing else is (the module header)."""
     kw = dict(layer_kw)
     for i, kind in enumerate(layer_types, start=first):
         dense = kw["dense_width"] if i < dense_layers else 0
-        h = cls(**{**kw, "dense_width": dense, "layer_type": kind},
-                name=f"layer{i}")(h)
+        h = AfmoeLayer(**{**kw, "dense_width": dense, "layer_type": kind},
+                       name=f"layer{i}")(h)
     return h
 
 
@@ -380,8 +397,10 @@ def afmoe_plan(mode: str = "split", dtype: Any = jnp.float32, *,
     of ``experts_total`` experts of ``expert_width`` from
     ``expert_offset`` on, ``experts_per_token`` a token, beside
     ``shared_experts`` shared ones). The client holds the embedding and
-    the first ``client_depth`` layers. ``remat`` recomputes each
-    layer's forward in the backward pass."""
+    the first ``client_depth`` layers. ``remat`` recomputes each expert
+    layer's routed part in the backward pass, so that its tokens x
+    ``experts_per_token`` rows are never kept, and keeps everything else
+    of a layer's forward (the module header)."""
     if attn not in _ATTN_IMPLS:
         raise ValueError(f"Unknown attn impl: {attn!r} (expected {_ATTN_IMPLS})")
     layer_types = tuple(layer_types)
@@ -407,8 +426,8 @@ def afmoe_plan(mode: str = "split", dtype: Any = jnp.float32, *,
         expert_offset=expert_offset, experts_per_token=experts_per_token,
         shared_experts=shared_experts, route_scale=float(route_scale),
         rope_theta=float(rope_theta), eps=float(rms_norm_eps), attn=attn,
-        dtype=dtype).items())
-    span = lambda first, kinds: (first, kinds, dense_layers, remat, layer_kw)
+        dtype=dtype, remat=bool(remat)).items())
+    span = lambda first, kinds: (first, kinds, dense_layers, layer_kw)
     eps = float(rms_norm_eps)
     embed = from_flax("embed", AfmoeEmbedStage(
         vocab, d_model, span(0, layer_types[:client_depth]), dtype))

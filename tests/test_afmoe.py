@@ -14,6 +14,8 @@ import pytest
 
 from split_learning_tpu.core.losses import cross_entropy
 from split_learning_tpu.models import get_plan
+from split_learning_tpu.models.afmoe import AfmoeLayer
+from split_learning_tpu.obs import spans
 from split_learning_tpu.ops.flash_attention import (
     flash_attention, flash_attention_with_lse)
 from split_learning_tpu.ops.grouped_matmul import (
@@ -172,6 +174,69 @@ def test_u_split_and_no_decode_cache():
         plan.stages[0].apply(params[0], x, cache_len=T)
     with pytest.raises(ValueError, match="experts"):
         get_plan("afmoe", "split", **{**KW, "expert_offset": 6})
+
+
+@pytest.mark.parametrize("mode", ["split", "u_split"])
+def test_remat_changes_no_number(mode):
+    """``remat`` decides what the backward keeps and what it makes again,
+    never a value: float32 loss and every gradient leaf as without it."""
+    (x, y), = batches(1)
+    out = {}
+    for remat in (True, False):
+        plan = get_plan("afmoe", mode, jnp.float32, **{**KW, "remat": remat})
+        params = seeded(plan, x)
+        out[remat] = jax.jit(jax.value_and_grad(
+            lambda p: cross_entropy(plan.apply(p, x), y)))(params)
+    (loss, grads), (want, want_g) = out[True], out[False]
+    assert abs(float(loss) - float(want)) <= 1e-6
+    got, ref = flat(grads), flat(want_g)
+    assert got.keys() == ref.keys() and len(got) > 40
+    for name, g in ref.items():
+        np.testing.assert_allclose(got[name], g, rtol=0, atol=1e-6,
+                                   err_msg=name)
+
+
+def _pallas_calls(jaxpr, found):
+    """Every ``pallas_call`` equation of a jaxpr and of those inside it."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _pallas_calls(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("remat", [True, False], ids=["remat", "kept"])
+def test_remat_recomputes_the_routed_part_and_nothing_else(remat):
+    """One expert layer with the flash kernel (interpreted): under
+    ``remat`` the backward is handed no array with tokens x experts per
+    token rows (without it, the pair buffers are there: the test sees
+    them), and either way the gradient holds the attention's forward
+    kernel once (3 operands: q, k, v) beside its one-pass backward (6),
+    and the grouped products' 3 forward calls a second time only under
+    ``remat`` (3 + 6 backward, + 3 recomputed)."""
+    n, k = 24, KW["experts_per_token"]
+    fields = {f: KW[f] for f in (
+        "num_heads", "num_kv_heads", "head_dim", "window", "expert_width",
+        "experts_total", "experts_held", "expert_offset",
+        "experts_per_token", "shared_experts", "route_scale")}
+    layer = AfmoeLayer(**fields, layer_type="sliding_attention",
+                       dense_width=0, attn="flash", remat=remat)
+    h = jax.random.normal(jax.random.PRNGKey(0), (1, n, KW["d_model"]))
+    params = layer.init(jax.random.PRNGKey(1), h)
+    f = lambda p, x: jnp.sum(layer.apply(p, x) ** 2)
+    _, vjp = jax.vjp(f, params, h)
+    pair_rows = [a.shape for a in jax.tree_util.tree_leaves(vjp)
+                 if a.ndim and a.shape[0] == n * k]
+    assert (pair_rows == []) == remat, pair_rows
+    calls = _pallas_calls(jax.make_jaxpr(jax.grad(f))(params, h).jaxpr, [])
+    attn = [len(e.invars) for e in calls
+            if spans.ATTN_WINDOW in str(e.source_info.name_stack)]
+    assert sorted(attn) == [3, 6]
+    assert len(calls) - len(attn) == (12 if remat else 9)
 
 
 def test_the_shares_add_up():
