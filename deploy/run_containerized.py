@@ -8,7 +8,7 @@ THIS environment can actually execute versus what it cannot:
 
 executed here (real, not simulated):
 - the Dockerfile runtime-stage layout is assembled as a rootfs: COPY
-  semantics for ``/app/split_learning_tpu`` + ``/app/bench.py``, the
+  semantics for ``/app/split_learning_tpu``, the
   builder-stage native-codec precompile into ``/app/native-cache``,
   the Dockerfile's ENV block, ``USER appuser`` (uid 1000, non-root),
   ``WORKDIR /app``, a writable ``/ckpt`` standing in for the PVC
@@ -107,11 +107,9 @@ def build_rootfs() -> None:
     for d in (["app", "proc", "tmp", "home/appuser", "ckpt/server",
                "ckpt/client", "data"] + HOST_BINDS):
         os.makedirs(os.path.join(ROOTFS, d), exist_ok=True)
-    # COPY split_learning_tpu/ + bench.py
+    # COPY split_learning_tpu/
     shutil.copytree(os.path.join(REPO, "split_learning_tpu"),
                     os.path.join(ROOTFS, "app", "split_learning_tpu"))
-    shutil.copy(os.path.join(REPO, "bench.py"),
-                os.path.join(ROOTFS, "app"))
     # builder stage: pre-compile the native codec into the image cache
     out = subprocess.run(
         [sys.executable, "-c",

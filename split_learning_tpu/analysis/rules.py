@@ -34,10 +34,10 @@ SLT015    flight-recorder event names at ``flight.record(...)`` call
 
 Rules are deliberately project-shaped: scopes are path suffixes inside
 this repo, receivers are matched by the names the runtime actually
-uses, and the known-good exceptions (the ``overlap=False`` legacy
-branch; the ``_GroupD2H`` materialization latch, whose whole purpose is
-to hold its private lock across the D2H) are encoded here rather than
-waived at every site. Everything else goes through the
+uses, and the one known-good exception (the ``_GroupD2H``
+materialization latch, whose whole purpose is to hold its private lock
+across the D2H) is encoded here rather than waived at every site.
+Everything else goes through the
 ``# slt-lint: disable=SLT00N (reason)`` waiver syntax in engine.py.
 """
 
@@ -122,18 +122,6 @@ def _call_root(func: ast.expr) -> Optional[str]:
     return func.id if isinstance(func, ast.Name) else None
 
 
-def _is_overlap_gate(test: ast.expr) -> Optional[bool]:
-    """``if not self.overlap:`` -> True (body is the legacy branch);
-    ``if self.overlap:`` -> False (the *else* is legacy)."""
-    if (isinstance(test, ast.UnaryOp) and isinstance(test.op, ast.Not)
-            and isinstance(test.operand, ast.Attribute)
-            and test.operand.attr == "overlap"):
-        return True
-    if isinstance(test, ast.Attribute) and test.attr == "overlap":
-        return False
-    return None
-
-
 def _slt001_blocking(node: ast.Call, held_lock: str) -> Optional[str]:
     """Why this call must not run under the lock, or None."""
     f = node.func
@@ -155,8 +143,6 @@ def _slt001_blocking(node: ast.Call, held_lock: str) -> Optional[str]:
         return ".block_until_ready() blocks on device completion"
     if f.attr == "sleep" and root == "time":
         return "time.sleep under the lock serializes every other caller"
-    if f.attr == "_sleep_d2h":
-        return "synthetic D2H delay under the lock"
     if f.attr in ("result", "join"):
         return f".{f.attr}() blocks under the lock"
     if f.attr in ("wait", "wait_for") and recv != held_lock:
@@ -173,7 +159,6 @@ class _Slt001Visitor(ast.NodeVisitor):
         self.findings: List[Finding] = []
         self._class: List[str] = []
         self._held: List[str] = []
-        self._legacy = 0  # depth of explicitly-gated overlap-off branches
 
     def visit_ClassDef(self, node: ast.ClassDef) -> None:
         self._class.append(node.name)
@@ -194,32 +179,18 @@ class _Slt001Visitor(ast.NodeVisitor):
     visit_With = _visit_with
     visit_AsyncWith = _visit_with
 
-    def visit_If(self, node: ast.If) -> None:
-        gate = _is_overlap_gate(node.test)
-        for field, stmts in (("body", node.body), ("orelse", node.orelse)):
-            legacy = (gate is True and field == "body") or (
-                gate is False and field == "orelse")
-            if legacy:
-                self._legacy += 1
-            for s in stmts:
-                self.visit(s)
-            if legacy:
-                self._legacy -= 1
-        self.visit(node.test)
-
     def _skip_nested_def(self, node: Any) -> None:
         # a def under a with-lock doesn't run there; analyze it lock-free
         held, self._held = self._held, []
-        legacy, self._legacy = self._legacy, 0
         self.generic_visit(node)
-        self._held, self._legacy = held, legacy
+        self._held = held
 
     visit_FunctionDef = _skip_nested_def
     visit_AsyncFunctionDef = _skip_nested_def
     visit_Lambda = _skip_nested_def
 
     def visit_Call(self, node: ast.Call) -> None:
-        if self._held and not self._legacy:
+        if self._held:
             why = _slt001_blocking(node, self._held[-1])
             if why is not None:
                 self.findings.append(Finding(

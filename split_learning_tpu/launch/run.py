@@ -1141,8 +1141,6 @@ def cmd_train(args) -> int:
                 return ServerRuntime(plan, cfg,
                                      jax.random.PRNGKey(cfg.seed),
                                      sample, strict_steps=depth <= 1,
-                                     overlap=not getattr(
-                                         args, "no_overlap", False),
                                      decouple_bwd=getattr(
                                          args, "decouple_bwd", False),
                                      apply_lag=getattr(
@@ -1532,7 +1530,6 @@ def cmd_serve(args) -> int:
                     strict_steps=not args.allow_out_of_order,
                     coalesce_max=args.coalesce_max,
                     coalesce_window_ms=args.coalesce_window_ms,
-                    overlap=not args.no_overlap,
                     batching=args.batching,
                     tenants=args.tenants,
                     quota=args.quota,
@@ -2202,11 +2199,6 @@ def main(argv: Optional[list] = None) -> int:
                     help="split mode: stage the next N batches on device "
                          "while the current step is in flight (background "
                          "H2D transfer; 0 = off, 2 is a good start)")
-    pt.add_argument("--no-overlap", dest="no_overlap", action="store_true",
-                    help="local transport only: make the in-process server "
-                         "materialize results while holding its device "
-                         "lock (pre-async-dispatch behavior; escape hatch "
-                         "— see README 'Async dispatch & prefetch')")
     pt.add_argument("--decouple-bwd", dest="decouple_bwd",
                     action="store_true",
                     help="split mode, local transport: 2BP reply-first "
@@ -2338,12 +2330,6 @@ def main(argv: Optional[list] = None) -> int:
                          "admitted requests are stamped now+slo-ms and "
                          "the continuous batcher picks groups earliest-"
                          "deadline-first")
-    ps.add_argument("--no-overlap", dest="no_overlap", action="store_true",
-                    help="materialize step results while holding the "
-                         "device lock instead of off-lock (disables the "
-                         "async-dispatch overlap of step t's host copy "
-                         "with step t+1's compute; escape hatch — see "
-                         "README 'Async dispatch & prefetch')")
     ps.add_argument("--decouple-bwd", dest="decouple_bwd",
                     action="store_true",
                     help="split mode: 2BP reply-first step — reply with "

@@ -232,8 +232,7 @@ DEVICE_SCOPES = (ATTN_WINDOW, ATTN_FULL, MOE_ROUTE, MOE_EXPERTS, MOE_SHARED)
 # double-book)
 CLIENT_PHASES = (CLIENT_FWD, TRANSPORT, CLIENT_BWD, OPT_APPLY)
 
-# server-party span names, for reporting tools; D2H appears only when
-# the server runs with overlap on (async dispatch)
+# server-party span names, for reporting tools
 SERVER_PHASES = (QUEUE_WAIT, DISPATCH, D2H)
 
 # the transport decomposition trace_report.py tabulates

@@ -227,15 +227,6 @@ class FusedSplitTrainer:
         with obs_trace.span(spans.STEP_TOTAL):
             return self._dispatch_step(x, y)
 
-    def step_flops(self, x, y) -> float:
-        """MXU-relevant FLOPs of one optimizer step (fwd + bwd + update),
-        counted from the jaxpr of the *actual* jitted step — including the
-        transposed convs/dots autodiff emits (utils/flops.py). Feeds the
-        MFU line in bench.py."""
-        from split_learning_tpu.utils.flops import jaxpr_matmul_flops
-        return jaxpr_matmul_flops(
-            self._step, self.state, jnp.asarray(x), jnp.asarray(y))
-
     @property
     def params(self) -> Tuple[Any, ...]:
         return self.state.params

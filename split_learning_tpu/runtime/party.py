@@ -171,10 +171,6 @@ class PartyRuntime:
         # reject a sidecar that does not belong to the Orbax step it
         # actually restored
         self._ckpt_lineage = 0
-        # synthetic D2H cost model defaults (bench-only; the server
-        # overrides from its knobs — see ServerRuntime.__init__)
-        self._d2h_delay_s = 0.0
-        self._d2h_single = False
         # build attribution for /health, /metrics and trace_metadata():
         # uptime measured from runtime construction
         self._t_start = time.monotonic()
@@ -258,26 +254,6 @@ class PartyRuntime:
                 fl.record(spans.FL_GATHER, party=self.party,
                           nbytes=int(out.nbytes))
         return out
-
-    def _sleep_d2h(self) -> None:
-        # synthetic transfer cost (bench-only; see ServerRuntime.__init__)
-        if self._d2h_delay_s <= 0.0:
-            return
-        if not self._d2h_single:
-            time.sleep(self._d2h_delay_s)
-            return
-        # single-channel model: reserve the next free window, then
-        # sleep out the reservation off-lock. monotonic so a wall-clock
-        # step can never hand out a negative wait.
-        with self._d2h_chan_lock:
-            start = max(time.monotonic(), self._d2h_chan_free_at)
-            end = start + self._d2h_delay_s
-            self._d2h_chan_free_at = end
-        while True:
-            remaining = end - time.monotonic()
-            if remaining <= 0.0:
-                return
-            time.sleep(remaining)
 
     # -- traced-only MFU accounting ------------------------------------- #
     def _note_flops(self, name: str, fn: Any, args: Tuple[Any, ...],

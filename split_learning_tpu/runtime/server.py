@@ -61,9 +61,6 @@ class ServerRuntime(PartyRuntime):
                  coalesce_max: int = 1,
                  coalesce_window_ms: float = 2.0,
                  replay_window: int = 8,
-                 overlap: bool = True,
-                 d2h_delay_s: float = 0.0,
-                 d2h_single_channel: bool = False,
                  batching: str = "window",
                  tenants: int = 1,
                  quota: Optional[Any] = None,
@@ -100,29 +97,11 @@ class ServerRuntime(PartyRuntime):
         original reply instead of 409-ing (runtime/replay.py). 0
         disables the cache and restores at-most-once semantics.
 
-        ``overlap`` (default on) takes host materialization off the
-        lock: the lock covers only step admission + the jitted dispatch
-        (which returns device futures immediately, chaining on the
-        donated state), and the D2H transfer (``np.asarray``/``float``)
-        runs after release — step t's transfer overlaps step t+1's
-        device compute. Placement of the transfer cannot change
-        numerics, and the application order under the lock is unchanged,
-        so the loss sequence is bit-identical either way; ``False``
-        (`serve --no-overlap`) restores the fully serial hot path.
-
-        ``d2h_delay_s`` adds a synthetic pause to every host
-        materialization — bench-only (CPU JAX has no real transfer cost
-        to overlap), honestly labeled wherever it is used.
-        ``d2h_single_channel`` picks the contention model for that
-        synthetic pause: ``False`` (default) lets concurrent
-        materializations overlap their sleeps — the regime the
-        async-dispatch (overlap) benches claim, where a transfer runs
-        on the waiter's thread while other steps proceed; ``True``
-        queues them FIFO on one simulated host DMA channel, so N
-        dispatches always cost N transfer windows of wall clock — the
-        regime the coalescing-amortization benches claim, which would
-        otherwise measure thread phasing (whether two groups' sleeps
-        happen to overlap) instead of dispatch-count amortization.
+        Host materialization runs off the lock: the lock covers only
+        step admission + the jitted dispatch (which returns device
+        futures immediately, chaining on the donated state), and the D2H
+        transfer (``np.asarray``/``float``) runs after release — step
+        t's transfer overlaps step t+1's device compute.
 
         ``decouple_bwd`` (2BP, arXiv:2405.18047) splits the split-mode
         server step into two dispatches: a *reply* program (forward +
@@ -161,15 +140,6 @@ class ServerRuntime(PartyRuntime):
         self.plan = plan
         self.mode = cfg.mode
         self.strict_steps = strict_steps
-        self.overlap = bool(overlap)
-        self._d2h_delay_s = float(d2h_delay_s)
-        # single-channel contention model (see __init__ docstring):
-        # reservations bookkeep under this leaf lock (never wraps
-        # another acquire); the wait itself runs unlocked
-        self._d2h_single = bool(d2h_single_channel)
-        self._d2h_chan_lock = obs_locks.make_lock(
-            "ServerRuntime._d2h_chan", reentrant=False)
-        self._d2h_chan_free_at = 0.0
         # optional hook fired (under the lock) after every completed op
         # with the acknowledged client step — the serve CLI hangs periodic
         # checkpointing off it
@@ -508,14 +478,6 @@ class ServerRuntime(PartyRuntime):
                                 "split_step", self._split_step,
                                 (self.state, acts_dev, labels_dev),
                                 disp.elapsed_s())
-                    if not self.overlap:
-                        # legacy placement: the transfer rides inside the
-                        # lock (and inside the dispatch span — the old span
-                        # taxonomy, where dispatch = jit + materialization)
-                        self._sleep_d2h()
-                        with obs_dispatch.expected_d2h(self._dd):
-                            g_host = self._host_gather(g_acts)
-                            loss_f = float(loss)
                     # max(): with strict_steps off (pipelined clients) steps
                     # can arrive out of order, and the acknowledged step —
                     # what /health reports and checkpoints are labeled with —
@@ -530,17 +492,14 @@ class ServerRuntime(PartyRuntime):
                           client_id=client_id, party="server",
                           program=("reply_grad" if self._deferred
                                    is not None else "split_step"))
-            d2h = None
-            if self.overlap:
-                # off the lock: the jitted call above returned device
-                # futures (async dispatch), so forcing the transfer here
-                # lets step t's D2H overlap step t+1's device compute
-                with obs_trace.span(spans.D2H, bytes=obs_trace.nbytes(g_acts, loss),
-                                    **who) as d2h:
-                    self._sleep_d2h()
-                    with obs_dispatch.expected_d2h(self._dd):
-                        g_host = self._host_gather(g_acts)
-                        loss_f = float(loss)
+            # off the lock: the jitted call above returned device
+            # futures (async dispatch), so forcing the transfer here
+            # lets step t's D2H overlap step t+1's device compute
+            with obs_trace.span(spans.D2H, bytes=obs_trace.nbytes(g_acts, loss),
+                                **who) as d2h, \
+                    obs_dispatch.expected_d2h(self._dd):
+                g_host = self._host_gather(g_acts)
+                loss_f = float(loss)
             if disp.recording:
                 self._publish_server_spans(wait, disp, d2h, who)
             res = (g_host, loss_f)
@@ -575,18 +534,15 @@ class ServerRuntime(PartyRuntime):
         ``lock_hold`` histogram (fed from ``dispatch``, the lock-held
         window — as a span it would double-cover it), the step counter,
         on a decoupled server the ``reply_grad`` window (reply dispatch
-        -> cut-layer gradient on host, what the 2BP bench leg compares
+        -> cut-layer gradient on host, what a trace compares
         against the coupled dispatch + d2h), and ``CTX.server_spans``,
         so the transport can hand the server's seconds back to the
-        client (wire accounting). ``d2h`` is None with overlap off:
-        ``dispatch`` then contains the materialization."""
+        client (wire accounting)."""
         srv_spans = {spans.QUEUE_WAIT: wait.duration_s,
-                     spans.DISPATCH: disp.duration_s}
-        if d2h is not None:
-            srv_spans[spans.D2H] = d2h.duration_s
+                     spans.DISPATCH: disp.duration_s,
+                     spans.D2H: d2h.duration_s}
         if self._deferred is not None:
-            obs_trace.span_at(spans.REPLY_GRAD, disp.t0,
-                              (d2h if d2h is not None else disp).t1, **who)
+            obs_trace.span_at(spans.REPLY_GRAD, disp.t0, d2h.t1, **who)
         self._metrics.observe(spans.LOCK_HOLD, disp.duration_s)
         self._metrics.incr("split_steps_total")
         obs_trace.CTX.server_spans = srv_spans
@@ -745,16 +701,6 @@ class ServerRuntime(PartyRuntime):
                             "coalesced_step", self._coalesced_step,
                             (self.state, acts_dev, labels_dev, w_dev),
                             disp.elapsed_s())
-                if not self.overlap:
-                    # legacy placement: the whole group's transfer inside
-                    # the lock (dispatch span = jit + materialization).
-                    # ``rows=total`` gathers only the real rows — the padded
-                    # tail (zero-weight, possibly on other devices) never
-                    # crosses D2H, and the segment loop below never reads it.
-                    self._sleep_d2h()
-                    with obs_dispatch.expected_d2h(self._dd):
-                        g_acts = self._host_gather(g_acts, rows=total)
-                        per_ex = self._host_gather(per_ex, rows=total)
                 # what a waiter is told of the group's one dispatch: the
                 # lock-held window up to the jitted call's return
                 dw = disp.elapsed_s()
@@ -773,20 +719,14 @@ class ServerRuntime(PartyRuntime):
                                        is not None else "coalesced_step"),
                               size=len(admitted), rows=total, padded=padded,
                               reason=reason)
-                pg = (_GroupD2H(self, g_acts, per_ex, rows=total)
-                      if self.overlap else None)
+                pg = _GroupD2H(self, g_acts, per_ex, rows=total)
                 off = 0
                 for r, b in zip(admitted, sizes):
-                    if self.overlap:
-                        # deferred: the flusher thread hands each waiter a
-                        # thunk instead of a value, so it is free to collect
-                        # group t+1 while group t's waiters share one D2H
-                        # (the first to arrive materializes; see _GroupD2H)
-                        r.result = pg.segment(r, off, b, total)
-                    else:
-                        seg = (g_acts[off:off + b] * (total / b)).astype(
-                            g_acts.dtype, copy=False)
-                        r.result = (seg, float(per_ex[off:off + b].mean()))
+                    # deferred: the flusher thread hands each waiter a
+                    # thunk instead of a value, so it is free to collect
+                    # group t+1 while group t's waiters share one D2H
+                    # (the first to arrive materializes; see _GroupD2H)
+                    r.result = pg.segment(r, off, b, total)
                     off += b
                     for f in followers.get((r.client_id, r.step), ()):
                         f.result = r.result
@@ -889,15 +829,9 @@ class ServerRuntime(PartyRuntime):
                 if overflow > 0:
                     for key in list(self._u_residual)[:overflow]:
                         del self._u_residual[key]
-                if not self.overlap:
-                    self._sleep_d2h()
-                    with obs_dispatch.expected_d2h(self._dd):
-                        feats_host = self._host_gather(feats)
-            if self.overlap:
-                # off the lock: async dispatch returned device futures
-                self._sleep_d2h()
-                with obs_dispatch.expected_d2h(self._dd):
-                    feats_host = self._host_gather(feats)
+            # off the lock: async dispatch returned device futures
+            with obs_dispatch.expected_d2h(self._dd):
+                feats_host = self._host_gather(feats)
             if entry is not None:
                 self.replay.resolve(entry, feats_host)
             return feats_host
@@ -933,10 +867,6 @@ class ServerRuntime(PartyRuntime):
                                         str(feat_grads.dtype))):
                     self.state, g_acts = self._u_bwd(
                         self.state, acts, self._to_dev(feat_grads))
-                if not self.overlap:
-                    self._sleep_d2h()
-                    with obs_dispatch.expected_d2h(self._dd):
-                        g_host = self._host_gather(g_acts)
                 # max(): with strict_steps off (pipelined clients) steps
                 # can arrive out of order, and the acknowledged step —
                 # what /health reports and checkpoints are labeled with —
@@ -945,11 +875,9 @@ class ServerRuntime(PartyRuntime):
                 self._last_step[client_id] = acked
                 if self.on_step is not None:
                     self.on_step(acked)
-            if self.overlap:
-                # off the lock: async dispatch returned device futures
-                self._sleep_d2h()
-                with obs_dispatch.expected_d2h(self._dd):
-                    g_host = self._host_gather(g_acts)
+            # off the lock: async dispatch returned device futures
+            with obs_dispatch.expected_d2h(self._dd):
+                g_host = self._host_gather(g_acts)
             if entry is not None:
                 self.replay.resolve(entry, g_host)
             return g_host
@@ -1072,8 +1000,8 @@ class ServerRuntime(PartyRuntime):
 class _GroupD2H:
     """Deferred host materialization for one coalesced group.
 
-    With overlap on, ``_dispatch_group`` resolves each request with a
-    thunk instead of a value: the flusher thread never blocks on the
+    ``_dispatch_group`` resolves each request with a thunk instead of a
+    value: the flusher thread never blocks on the
     transfer (it is already collecting group t+1), and the first waiter
     thread to redeem its thunk pays the group's single D2H — everyone
     else reads the cached host arrays. The device references are dropped
@@ -1103,7 +1031,6 @@ class _GroupD2H:
                         spans.D2H, party="server", tid=req.client_id,
                         step=req.step, trace_id=req.trace_id,
                         registry=self._runtime._metrics) as d2h:
-                    self._runtime._sleep_d2h()
                     with obs_dispatch.expected_d2h(self._runtime._dd):
                         g = self._runtime._host_gather(
                             self._g_dev, rows=self._rows)

@@ -27,7 +27,7 @@ COMPILE_CACHE_DIR = os.path.join(_REPO_ROOT, ".jax_cache")
 def configure_compile_cache() -> str:
     """Place the persistent XLA compile cache; return its directory.
 
-    Called first thing by every entry point (the CLI, each bench role,
+    Called first thing by every entry point (the CLI, the benchmark,
     chip_smoke.py, __graft_entry__.py). With ``JAX_COMPILATION_CACHE_DIR``
     set this sets nothing — JAX already reads it — so the cache can be
     placed from outside; otherwise it points JAX at

@@ -14,6 +14,8 @@ if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
         _flags + " --xla_force_host_platform_device_count=8").strip()
 
+import threading  # noqa: E402
+
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import pytest  # noqa: E402
@@ -81,3 +83,58 @@ def mnist_batch(rng):
     x = jax.random.normal(kx, (64, 28, 28, 1), jnp.float32)
     y = jax.random.randint(ky, (64,), 0, 10)
     return x, y
+
+
+class HoldHostGather:
+    """Hold a party's replies in their materialization window.
+    ``PartyRuntime._host_gather`` is the one call every reply's
+    device-to-host copy goes through; inside the ``with`` block every
+    call of it on ``runtime`` sets ``entered`` and waits for ``release``
+    before it copies. The wrap lives on the instance; leaving the block
+    lets go of whatever still waits and takes the wrap off. ``spawn``
+    and ``join`` run the calls that are held: every wait is bounded by
+    ``WAIT_S``, and none is expected to run out."""
+
+    WAIT_S = 60.0
+
+    @staticmethod
+    def spawn(*fns):
+        """One started thread a function."""
+        threads = [threading.Thread(target=fn) for fn in fns]
+        for t in threads:
+            t.start()
+        return threads
+
+    @classmethod
+    def join(cls, threads) -> None:
+        for t in threads:
+            t.join(cls.WAIT_S)
+        assert not any(t.is_alive() for t in threads)
+
+    def __init__(self, runtime) -> None:
+        self.runtime = runtime
+        self.entered = threading.Event()
+        self.release = threading.Event()
+        self.calls = 0
+
+    def __enter__(self) -> "HoldHostGather":
+        inner = self.runtime._host_gather
+
+        def held(x, rows=None):
+            self.calls += 1
+            self.entered.set()
+            assert self.release.wait(self.WAIT_S), "the copy was never let go"
+            return inner(x, rows=rows)
+
+        self.runtime._host_gather = held
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.release.set()
+        del self.runtime._host_gather  # back to the class's method
+
+
+@pytest.fixture()
+def hold_host_gather():
+    """``with hold_host_gather(runtime) as hold:`` (see HoldHostGather)."""
+    return HoldHostGather

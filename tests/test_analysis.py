@@ -64,8 +64,6 @@ def test_slt001_negative_shapes(tmp_path):
             def step(self):
                 with self._lock:
                     acts = jnp.asarray(self.host)   # H2D: allowed
-                    if not self.overlap:
-                        g = np.asarray(self.dev)    # gated legacy branch
                 g = np.asarray(self.dev)            # off-lock
                 return g
             def wait_ok(self):
@@ -78,6 +76,25 @@ def test_slt001_negative_shapes(tmp_path):
                     self.g = np.asarray(self._g_dev)
     """)
     assert findings == []
+
+
+def test_slt001_flags_d2h_in_a_gated_branch(tmp_path):
+    """No attribute test exempts a branch: a host copy under the lock is
+    a finding whatever ``if`` it sits in, on either arm."""
+    findings = _lint(tmp_path, "runtime/server.py", """
+        import numpy as np
+        class ServerRuntime:
+            def step(self):
+                with self._lock:
+                    if not self.overlap:
+                        g = np.asarray(self.dev)
+                    if self.overlap:
+                        pass
+                    else:
+                        g = np.asarray(self.dev)
+                return g
+    """)
+    assert _rules(findings) == ["SLT001", "SLT001"]
 
 
 def test_slt001_out_of_scope_dir(tmp_path):

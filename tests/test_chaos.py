@@ -5,7 +5,6 @@ state machine, and backoff schedules. All fast — no real sleeps, tiny
 models — so CI can run this file as the fault-tolerance smoke."""
 
 import threading
-import time
 
 import jax
 import numpy as np
@@ -166,6 +165,39 @@ def test_trainer_retry_survives_server_chaos_without_losing_batches():
         server.stop()
 
 
+def test_chaotic_wire_with_retry_reproduces_the_clean_loss_series():
+    """Exactly-once means a fault schedule changes the wire, never the
+    math: the same seeded stream trained over a clean wire and over one
+    that loses replies, duplicates deliveries and 5xx-es, with the
+    client on the bounded-retry policy, gives the same loss at every
+    step, with no batch dropped, faults really injected and the replay
+    cache really engaged (the old chaos_soak leg's gates)."""
+    rs = np.random.RandomState(8)
+    data = [(rs.randn(BATCH, 28, 28, 1).astype(np.float32),
+             rs.randint(0, 10, BATCH).astype(np.int64)) for _ in range(30)]
+    series = {}
+    for run in ("clean", "chaos"):
+        cfg, plan, runtime = _runtime()
+        transport = LocalTransport(runtime)
+        if run == "chaos":
+            policy = ChaosPolicy("drop_resp=0.10,dup=0.05,http500=0.05",
+                                 seed=1234)
+            transport = ChaosTransport(transport, policy)
+        client = SplitClientTrainer(plan, cfg, jax.random.PRNGKey(0),
+                                    transport,
+                                    failure_policy=FailurePolicy.RETRY,
+                                    max_retries=3, retry_backoff=0.0)
+        try:
+            series[run] = [client.train_step(x, y, i)
+                           for i, (x, y) in enumerate(data)]
+            assert client.dropped_batches == 0
+        finally:
+            runtime.close()
+    assert sum(policy.injected.values()) > 0
+    assert runtime.replay.counters()["replay_hits"] > 0
+    assert series["chaos"] == series["clean"]
+
+
 def test_client_side_dup_served_from_replay_cache():
     """ChaosTransport dup delivers twice; the duplicate must come back
     from the server's replay cache bit-equal, with one apply."""
@@ -301,36 +333,73 @@ def test_ef_rollback_then_repack_is_bit_identical():
 # async dispatch (PR 5): exactly-once across the off-lock window
 # ---------------------------------------------------------------------- #
 
-def test_duplicate_during_materialization_blocks_on_inflight_future():
+@pytest.mark.parametrize("kind", ["split_step", "coalesced_group",
+                                  "2bp_reply"])
+def test_duplicate_during_materialization_blocks_on_inflight_future(
+        kind, hold_host_gather):
     """Async dispatch opens a window the old cache could not cover: the
     step is applied but its reply is still materializing off the lock.
     A duplicate landing there must block on the in-flight future and be
     served the ONE materialized reply — not 409 (the step is not a
-    stale replay) and not a second apply."""
+    stale replay), not a second apply and not a second copy. The same
+    holds behind a coalesced group's ``_GroupD2H`` latch, which a second
+    member waits on meanwhile, and for a 2BP reply, whose weight update
+    must enter the deferred queue once."""
+    kw = {"split_step": {},
+          "coalesced_group": {"coalesce_max": 2, "coalesce_window_ms": 500.0},
+          "2bp_reply": {"decouple_bwd": True, "apply_lag": 2}}[kind]
+    clients = (0, 1) if kind == "coalesced_group" else (0,)
     cfg = Config(mode="split", batch_size=BATCH)
     plan = get_plan(mode="split")
     sample = np.zeros((BATCH, 28, 28, 1), np.float32)
-    runtime = ServerRuntime(plan, cfg, jax.random.PRNGKey(2), sample,
-                            overlap=True, d2h_delay_s=0.4)
+    runtime = ServerRuntime(plan, cfg, jax.random.PRNGKey(2), sample, **kw)
     rs = np.random.RandomState(0)
     x = rs.randn(BATCH, 26, 26, 32).astype(np.float32)  # cut-layer acts
     y = rs.randint(0, 10, BATCH).astype(np.int64)
-    runtime.split_step(x, y, 0)  # compile + one padded materialization
-
     results = {}
-    ta = threading.Thread(
-        target=lambda: results.update(a=runtime.split_step(x, y, 1)))
-    ta.start()
-    time.sleep(0.15)  # the original is now materializing, off the lock
-    t0 = time.perf_counter()
-    res_b = runtime.split_step(x, y, 1)  # duplicate delivery
-    waited = time.perf_counter() - t0
-    ta.join()
-    res_a = results["a"]
+    spawn, join, wait_s = (hold_host_gather.spawn, hold_host_gather.join,
+                           hold_host_gather.WAIT_S)
 
-    assert waited > 0.05  # it really blocked on the in-flight future
-    np.testing.assert_array_equal(res_b[0], res_a[0])  # identical reply
-    assert res_b[1] == res_a[1]
+    def step(tag, s, c):
+        return lambda: results.__setitem__(
+            tag, runtime.split_step(x, y, s, client_id=c))
+
+    try:
+        join(spawn(*[step(("warm", c), 0, c) for c in clients]))  # compile
+
+        # the duplicate says when it has lost the claim and is about to
+        # block on the original's entry, and whether that was pending
+        dup_waiting = threading.Event()
+        pending = []
+        replay_wait = runtime.replay.wait
+
+        def wait(entry, *a, **k):
+            pending.append(not entry.done)
+            dup_waiting.set()
+            return replay_wait(entry, *a, **k)
+
+        runtime.replay.wait = wait
+        with hold_host_gather(runtime) as hold:
+            originals = spawn(*[step(("orig", c), 1, c) for c in clients])
+            assert hold.entered.wait(wait_s)  # applied; the copy is held
+            dup = spawn(step("dup", 1, 0))    # duplicate delivery
+            assert dup_waiting.wait(wait_s)
+            assert dup[0].is_alive() and "dup" not in results
+            hold.release.set()
+            join(originals + dup)
+            copies = hold.calls
+    finally:
+        runtime.close()
+
+    assert pending == [True]  # it waited on the in-flight future
+    np.testing.assert_array_equal(results["dup"][0], results["orig", 0][0])
+    assert results["dup"][1] == results["orig", 0][1]  # identical reply
     assert runtime.replay.hits == 1       # served from the future, once
+    # one copy of the gradient a serialized reply, two a group (gradient
+    # and per-example loss, once for both members): never one more
+    assert copies == (2 if kind == "coalesced_group" else 1)
     assert runtime.health()["step"] == 1
+    if kind == "2bp_reply":
+        # close() drained the queue: warmup + ONE update entered it
+        assert runtime._deferred.counters()["deferred_enqueued"] == 2
     assert int(runtime.state.step) == 2   # warmup + ONE apply, not two

@@ -1,8 +1,9 @@
 """chip_smoke.run_legs at tiny sizes on CPU: the same driver, checks and
 CLI entry point the chip run uses — fused (with the flash kernels,
 interpreted here), two-party local, and the device chain — so the smoke
-itself cannot rot between chip runs. ``chip_smoke.main`` is not driven
-here: it refuses to start off-chip (tests/test_backend_hermetic.py)."""
+itself cannot rot between chip runs. ``chip_smoke.main`` refuses to
+start off-chip (tests/test_backend_hermetic.py runs the script), and on
+a chip where the kernels would be interpreted (below)."""
 
 import os
 import sys
@@ -53,3 +54,27 @@ def test_a_failing_leg_raises():
                           "synthetic", "--transport", "fused"], steps=1)
     with pytest.raises(RuntimeError, match="leg X: CLI returned 2"):
         chip_smoke.run_legs([bad])
+
+
+def test_main_refuses_a_tpu_whose_kernels_would_be_interpreted(
+        monkeypatch, capsys):
+    """The second silent fallback ``main`` refuses: a TPU backend with
+    ``SLT_PALLAS_INTERPRET=1`` left in the environment would run every
+    Pallas kernel through the interpreter and still report a TPU. It
+    returns 1 before any leg and prints no result line."""
+    import jax
+
+    class _Tpu:
+        platform, device_kind = "tpu", "TPU v5 lite"
+
+    def no_leg(*a, **k):
+        raise AssertionError("a leg ran after the refusal")
+
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [_Tpu()])
+    monkeypatch.setenv("SLT_PALLAS_INTERPRET", "1")
+    monkeypatch.setattr(chip_smoke, "check_flash_kernel", no_leg)
+    monkeypatch.setattr(chip_smoke, "run_legs", no_leg)
+    assert chip_smoke.main() == 1
+    out, err = capsys.readouterr()
+    assert not out.strip(), out
+    assert "interpreted" in err

@@ -69,6 +69,40 @@ def test_lag0_bit_identical_to_legacy():
     assert dec["deferred_apply_depth"] == 0
 
 
+def test_lag2_ends_within_the_nats_budget_of_coupled():
+    """Staleness perturbs a trajectory that is going somewhere, it does
+    not derail it. The regime 2BP is for: an LM head over a vocabulary
+    much wider than the model, whose weight gradient and optimizer step
+    dominate the server step. On four fixed batches cycled (the loss
+    descends; fresh noise every step would random-walk the comparison)
+    a lag-2 server's last cycle ends within 0.1 nats of the coupled
+    server's (the old reply_latency_2bp leg allowed 0.35; 0.001 here)."""
+    vocab, steps = 2048, 16
+    plan = get_plan(model="transformer", mode="split", vocab=vocab,
+                    d_model=64, num_heads=4, client_depth=1,
+                    server_depth=1, lm=True)
+    cfg = Config(mode="split", model="transformer", batch_size=BATCH)
+    rs = np.random.RandomState(0)
+    x = rs.randint(0, vocab, (4, BATCH, 16)).astype(np.int32)
+    y = rs.randint(0, vocab, (4, BATCH, 16)).astype(np.int32)
+
+    def series(**kw):
+        server = ServerRuntime(plan, cfg, jax.random.PRNGKey(0), x[0], **kw)
+        client = SplitClientTrainer(plan, cfg, jax.random.PRNGKey(1),
+                                    LocalTransport(server))
+        try:
+            return [client.train_step(x[i % 4], y[i % 4], i)
+                    for i in range(steps)]
+        finally:
+            server.close()
+
+    coupled = series()
+    lag2 = series(decouple_bwd=True, apply_lag=2)
+    assert np.mean(coupled[-4:]) < np.mean(coupled[:4]) - 0.1  # descends
+    assert lag2 != coupled  # the lag really moved the weights a step saw
+    assert abs(np.mean(lag2[-4:]) - np.mean(coupled[-4:])) <= 0.1
+
+
 def test_default_off_is_the_untouched_legacy_path():
     """--decouple-bwd off must leave the PR 9 tree bit-for-bit alone:
     no decoupled programs compiled, no deferred queue, no reply_grad /

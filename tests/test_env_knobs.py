@@ -1,7 +1,9 @@
 """README "Environment knobs" coverage: every ``SLT_*`` variable the
-package, bench.py, or scripts/ read must appear in the README table.
+package or scripts/ read must appear in the README table.
 The table is hand-written prose; this grep is what keeps it honest —
-add a knob without documenting it and this fails with the name."""
+add a knob without documenting it and this fails with the name. The
+same grep keeps the README's file names honest: a script it tells the
+reader to run must be in the tree."""
 
 import os
 import re
@@ -18,7 +20,6 @@ def _source_files():
             for fn in filenames:
                 if fn.endswith(".py"):
                     yield os.path.join(dirpath, fn)
-    yield os.path.join(REPO, "bench.py")
 
 
 def test_every_slt_knob_is_documented_in_readme():
@@ -55,3 +56,22 @@ def test_readme_documents_no_phantom_knobs():
                     tree.update(KNOB.findall(f.read()))
     phantom = sorted(documented - tree)
     assert not phantom, f"README documents knobs nothing reads: {phantom}"
+
+
+# ``python <path>.py`` commands and every ``scripts/<name>.py``, wherever
+# the README names them
+_RUN_PY = re.compile(r"python3? +(?:-[A-Za-z]\S* +)*([A-Za-z0-9_./-]+\.py)\b")
+_SCRIPT_PY = re.compile(r"\bscripts/[A-Za-z0-9_]+\.py\b")
+
+
+def test_readme_names_no_file_that_is_gone():
+    """Deleting a script leaves its README sentences behind unless
+    something looks: every Python file the README tells the reader to
+    run, and every ``scripts/`` file it names, exists."""
+    with open(os.path.join(REPO, "README.md"), encoding="utf-8") as f:
+        readme = f.read()
+    named = set(_RUN_PY.findall(readme)) | set(_SCRIPT_PY.findall(readme))
+    assert len(named) >= 5, sorted(named)  # the regexes still match
+    gone = sorted(p for p in named
+                  if not os.path.exists(os.path.join(REPO, p)))
+    assert not gone, f"README.md names files that do not exist: {gone}"

@@ -1,6 +1,6 @@
 """The committed long-context TPU artifact
-(``artifacts/bench_tpu_transformer_*.json``, produced by
-``scripts/measure_long_context.py``): dense (XLA) vs Pallas-flash
+(``artifacts/bench_tpu_transformer_*.json``; the script that wrote it
+was deleted with bench.py by PR 28): dense (XLA) vs Pallas-flash
 attention across context lengths on one v5e chip.
 
 The two claims the docs make from it, pinned here so the artifact and the
@@ -24,8 +24,7 @@ _PAT = os.path.join(os.path.dirname(os.path.dirname(
 @pytest.fixture(scope="module")
 def artifact():
     paths = sorted(glob.glob(_PAT))
-    assert paths, (f"missing {_PAT}; run scripts/measure_long_context.py "
-                   "on a TPU-attached host")
+    assert paths, f"missing {_PAT}: a committed file, nothing makes it anew"
     with open(paths[-1]) as f:
         return json.load(f)
 

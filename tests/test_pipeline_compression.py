@@ -118,7 +118,7 @@ def test_compress_off_is_bitwise_legacy():
 
 def test_topk8_chain_parity_and_accounting():
     """A topk8 chain at density 0.3 converges with the dense twin
-    (loose absolute-nats budget — the bench leg owns the tight gate)
+    (loose absolute-nats budget)
     and every accounting surface lights up: the transports' raw/wire
     compression counters, each stage's wire_compression_ratio gauge,
     and the runner's per-stage report rows."""
@@ -148,6 +148,25 @@ def test_topk8_chain_parity_and_accounting():
             assert row["compress_wire_bytes"] > 0
     finally:
         _close(runner_c, stages_c)
+
+
+@pytest.mark.parametrize("mode", ["topk8", "clapping"])
+def test_compressed_hops_are_an_order_of_magnitude_lighter(mode):
+    """At density 0.25, the knee the chain is run at, a quarter of the
+    values as int8 plus a bit a value for the mask is 4 / (0.25 + 0.125)
+    = 10.7 times fewer bytes than float32: every hop's framed bytes,
+    request and reply together as the transport counts them, stay 10
+    times under what it would have sent raw, in both sparse modes (the
+    old mpmd_compressed leg's byte gate)."""
+    runner, stages, transports = _chain(4, 1, compress=mode, ef_mode=mode)
+    try:
+        _run(runner, 3, [_batch(i) for i in range(3)])
+        for t in transports:
+            raw = t.stats.summary()["compress_raw_bytes"]
+            framed = t.stats.bytes_sent + t.stats.bytes_received
+            assert 0 < framed * 10 <= raw, (mode, raw, framed)
+    finally:
+        _close(runner, stages)
 
 
 def test_clapping_is_topk8_arithmetic_without_the_ledger():

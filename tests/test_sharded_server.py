@@ -181,30 +181,26 @@ def test_predict_pads_and_trims_odd_batches():
         server2.close()
 
 
-def test_d2h_single_channel_serializes_concurrent_transfers():
-    """With d2h_single_channel=True, N concurrent synthetic transfers
-    reserve back-to-back windows on the one simulated DMA channel, so
-    wall clock is bounded below by N*delay — the property that makes
-    the sharded_server bench's dispatch-count amortization deterministic
-    instead of a thread-phasing race. (Default False keeps the overlap
-    benches' model: sleeps may overlap; no upper bound is asserted here
-    because parallel-sleep timing is scheduler noise.)"""
-    import threading
-    import time as _time
-
-    delay = 0.05
-    _, _, server = _server(d2h_delay_s=delay, d2h_single_channel=True)
+@pytest.mark.parametrize("kw", [{}, {"coalesce_max": 4,
+                                      "coalesce_window_ms": 5.0}],
+                         ids=["serialized", "coalesced"])
+def test_data2_hot_loops_are_steady_state_recompile_free(kw):
+    """A sharded program whose in/out shardings or committed-ness
+    drifted between steps would retrace every step and still train: the
+    dispatch watchdog counts none after the second step, serialized and
+    coalesced (the old sharded_server leg's compile gate)."""
+    from split_learning_tpu.obs import dispatch_debug
+    dd = dispatch_debug.tracker()
+    g0 = dd.gauges()
+    dispatch_debug.force(True)
     try:
-        threads = [threading.Thread(target=server._sleep_d2h)
-                   for _ in range(3)]
-        t0 = _time.monotonic()
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        assert _time.monotonic() - t0 >= 3 * delay
+        _series(steps=5, mesh=make_host_mesh(data=2), **kw)
     finally:
-        server.close()
+        dispatch_debug.force(False)
+    g1 = dd.gauges()
+    assert g1["compile_count"] > g0["compile_count"]  # it was watching
+    assert (g1["steady_state_recompiles"]
+            - g0["steady_state_recompiles"]) == 0
 
 
 # ---------------------------------------------------------------------- #
