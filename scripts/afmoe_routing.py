@@ -5,9 +5,11 @@
 For the benchmark cell's check batch at the cell's sizes (on the CPU with
 ``JAX_PLATFORMS=cpu``: the rehearsal's), with the weights the benchmark makes
 from the seed: one forward pass of the cell's plan, and for every routed layer the
-pairs per held expert (mean, max, empty experts) and their share of the
-worst-case buffer (tokens x experts per token rows) that the grouped
-products run over.  The jitted step cannot hand these counts out beside the
+pairs per held expert (mean, max, empty experts), their share of the
+worst-case buffer (tokens x experts per token rows), and the rung of the
+layer's ladder of row counts (``models/afmoe.py:pair_rungs``, ``rung_of``:
+the functions the model calls) that those pairs select on the device.  The
+jitted step cannot hand these counts out beside the
 activations (a ``Stage`` is ``(params, x) -> y``), so they are printed once,
 here, for PERF.md; ``flops/afmoe.py`` counts the expected pairs under even
 routing.  One JSON line a layer, then a summary.
@@ -76,6 +78,7 @@ def main() -> int:
                                     p["params"][name]["experts"])
     rows = x.size * kw["experts_per_token"]
     expected = rows * kw["experts_held"] / kw["experts_total"]
+    rungs = afmoe.pair_rungs(rows, kw["experts_held"], kw["experts_total"])
     fills = []
     for name in sorted(seen, key=lambda n: int(n[5:])):
         sizes = [int(s) for s in seen[name]]
@@ -84,10 +87,13 @@ def main() -> int:
                           "mean_per_expert": sum(sizes) / len(sizes),
                           "max_per_expert": max(sizes), "min_per_expert": min(sizes),
                           "empty_experts": sizes.count(0),
-                          "buffer_rows": rows, "buffer_share": sum(sizes) / rows}))
+                          "buffer_rows": rows, "buffer_share": sum(sizes) / rows,
+                          "rung": rungs[afmoe.rung_of(sum(sizes), rungs)]}))
     print(json.dumps({"workload": args.workload, "seed": args.seed, "tokens": int(x.size),
                       "expected_pairs_even_routing": expected,
-                      "pairs_over_expected": [f / expected for f in fills]}))
+                      "pairs_over_expected": [f / expected for f in fills],
+                      "ladder": list(rungs),
+                      "rungs_taken": [rungs[afmoe.rung_of(f, rungs)] for f in fills]}))
     return 0
 
 

@@ -27,27 +27,57 @@ One layer (RMSNorm(x) = x / sqrt(mean(x^2) + eps) * scale, no biases):
 **The routed layer is told its share.** It holds experts
 ``[expert_offset, expert_offset + experts_held)`` of ``experts_total``
 as three stacked leaves, routes over all of them and computes the part
-its own give: the (token, expert) pairs routed here are sorted by
-expert into a buffer sized for the worst case (tokens x experts per
-token rows), the grouped products (ops/grouped_matmul.py) run over the
-rows that are filled, and the result is gathered back per pair. No
+its own give: the (token, expert) pairs are sorted by the held expert
+they chose, pairs of absent experts last (:func:`held_pairs`), the first
+``R`` sorted rows are gathered, the grouped products
+(ops/grouped_matmul.py) run over the rows that are filled, and every
+token sums its pairs' rows, a row of zeros for each pair not held. No
 token is dropped, shapes are static, one program serves every routing.
 What the absent experts would add is left out; no code stands in for
 absent chips. ``experts_held == experts_total`` is the whole layer.
 
-**What ``remat`` recomputes.** With ``remat`` the backward pass makes
-the routed part of every expert layer again (:class:`RoutedExperts`
-whole: router product, scores, top-k, the pairs' sorts, the gathers out
-and back, the three grouped products; the routing is deterministic, so
-the selection is the forward's) and keeps none of its tokens x experts
-per token rows. Everything else the forward made is kept for the
-backward: every dense product's output (q, k, v, output gate and output
-projection, the shared expert's and the dense layer's gate / up / down),
-what the flash kernels' backward reads (padded q, k, v, the output and
-the row logsumexp) and the elementwise work between them, so no kernel
-and no dense product of a layer runs a second time. ``Config.remat``
-(``core/stage.remat_plan``: the whole stage under ``jax.checkpoint``)
-nests over this for a user short of memory.
+**``R`` follows the rows the routing fills.** The worst case is every
+pair held, ``n * k`` rows (tokens x experts per token); a share of the
+experts fills ``experts_held / experts_total`` of that under even
+routing. :func:`pair_rungs` gives two static row counts, twice the even
+share in whole row tiles (8192 for 8 of 128 experts at 8192 tokens x 8)
+and ``n * k``, each a body of one ``lax.switch`` whose index is read on
+the device from the sort's ``group_sizes`` (:func:`rung_of`: the
+smallest rung that holds their sum). The top rung is the worst case
+itself, so no routing overflows; sorted rows at or past ``R`` are pairs
+of absent experts and add exactly zero, as rows past the groups' sum do
+in any rung; the rows that are filled are the same rows in the same
+order and tiles whatever the rung. What stays ``n * k`` long is indexed
+by pair: the two sorts, and the gather of each token's rows (forward,
+and the gradient of the gather out), which reads an ``[R + 1, d]``
+table. A share of half the experts or more has one rung and no
+conditional. XLA reserves temporaries for the larger branch: a step's
+memory is the top rung's. Two rungs and not a ladder of halvings
+between them: every rung is one more body (twelve kernels, forward and
+backward) to trace, lower and load in every process, about 4 s of
+set-up each on the chip's host, for routings between 2 and 16 times the
+even share.
+
+**What ``remat`` recomputes.** With ``remat`` the routed part is one
+``custom_vjp`` (:func:`_routed_recomputed`): its backward switches again
+on the same index and makes the rung's body again inside the branch
+(:func:`_routed_rows`: the gather out, the three grouped products, the
+sum back), so none of a rung's rows is kept and what a conditional
+returns is its result alone. One ``checkpoint`` around a ``switch``
+would instead return every rung's kept rows from every branch, zeros
+for the rung not taken. The router product, scores, top-k and the pairs'
+sorts run once and their small results are kept. Everything else the
+forward made is kept for the backward too: every dense product's output
+(q, k, v, output gate and output projection, the shared expert's and the
+dense layer's gate / up / down), what the flash kernels' backward reads
+(padded q, k, v, the output and the row logsumexp) and the elementwise
+work between them, so no kernel and no dense product of a layer runs a
+second time. Without ``remat`` the routed part runs on the top rung
+alone and autodiff keeps its rows: kept rows of two rungs would both be
+outputs of the ``switch`` (15.9 GB a step at the benchmark's sizes, no
+cell runs it). ``Config.remat`` (``core/stage.remat_plan``: the whole
+stage under ``jax.checkpoint``) nests over this for a user short of
+memory.
 
 The selection bias ``expert_bias`` is a float32 leaf under
 ``stop_gradient``: its published update (from per-expert token counts)
@@ -69,7 +99,9 @@ from split_learning_tpu.core.stage import SplitPlan, from_flax
 from split_learning_tpu.obs import spans
 from split_learning_tpu.ops.flash_attention import (
     flash_attention, select_attention)
-from split_learning_tpu.ops.grouped_matmul import grouped_matmul
+from split_learning_tpu.ops.common import round_up
+from split_learning_tpu.ops.grouped_matmul import (
+    _tiles as _row_tiles, grouped_matmul)
 from split_learning_tpu.ops.ring_attention import full_attention
 
 _ATTN_IMPLS = ("auto", "full", "flash")
@@ -158,25 +190,70 @@ class SwiGLU(nn.Module):
         return _linear(m.shape[-1], self.dtype, "down")(jax.nn.silu(g) * u)
 
 
+def _take(x, rows):
+    """``x[rows]`` for row indices known to lie inside ``x``: no pass
+    over the result to fill what an index out of range would leave."""
+    return x.at[rows].get(mode="promise_in_bounds")
+
+
+def _row_of_pair(x, inverse):
+    """``x``'s row for each pair: row ``inverse[p]`` of the sorted rows, a
+    row of zeros for a pair sorted at or past ``len(x)``."""
+    if x.shape[0] < inverse.shape[0]:
+        x = jnp.pad(x, ((0, 1),) + ((0, 0),) * (x.ndim - 1))
+        inverse = jnp.minimum(inverse, x.shape[0] - 1)
+    return _take(x, inverse)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _take_rows(x, idx, inv, fan: int):
-    """``x[idx // fan]`` for a permutation ``idx`` of the ``fan * len(x)``
-    pair slots with inverse ``inv``: the gradient is a gather through
-    ``inv`` and a sum over each row's ``fan`` slots, never a scatter.
-    With ``fan`` 1 it is the permutation itself."""
-    return jnp.take(x, idx // fan, axis=0)
+def _rows_out(x, order, inverse, fan: int):
+    """``x[order // fan]``: the token of the pair at each sorted row.
+    ``order`` holds the first ``R`` entries of a permutation of the
+    ``fan * len(x)`` pairs, ``inverse`` is that permutation's inverse. The
+    gradient is a gather through ``inverse`` and a sum over each token's
+    ``fan`` pairs, never a scatter."""
+    return _take(x, order // fan)
 
 
-def _take_rows_fwd(x, idx, inv, fan):
-    return _take_rows(x, idx, inv, fan), inv
+def _rows_out_fwd(x, order, inverse, fan):
+    return _rows_out(x, order, inverse, fan), inverse
 
 
-def _take_rows_bwd(fan, inv, g):
-    back = jnp.take(g, inv, axis=0).reshape(-1, fan, g.shape[-1])
+def _rows_out_bwd(fan, inverse, g):
+    back = _row_of_pair(g, inverse).reshape(-1, fan, g.shape[-1])
     return back.sum(1, dtype=jnp.float32).astype(g.dtype), None, None
 
 
-_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+_rows_out.defvjp(_rows_out_fwd, _rows_out_bwd)
+
+
+@jax.custom_vjp
+def _rows_back(x, order, inverse, weights):
+    """``[n, d]``: every token's sum over its ``k`` pairs of the pair's
+    row of ``x`` (sorted rows ``[R, d]``; ``order``, ``inverse`` as in
+    :func:`_rows_out`) times its ``weights [n, k]``, summed in float32.
+    The gradient stays in the sorted rows: nothing in it has a row a pair
+    but the weights' own ``[n, k]``."""
+    n, k = weights.shape
+    per_pair = _row_of_pair(x, inverse).reshape(n, k, -1)
+    return jnp.einsum("nkd,nk->nd", per_pair.astype(jnp.float32),
+                      weights).astype(x.dtype)
+
+
+def _rows_back_fwd(x, order, inverse, weights):
+    return _rows_back(x, order, inverse, weights), (x, order, inverse, weights)
+
+
+def _rows_back_bwd(res, g):
+    x, order, inverse, weights = res
+    g = _take(g, order // weights.shape[1]).astype(jnp.float32)
+    w = _take(weights.reshape(-1), order)
+    d_w = _row_of_pair(jnp.sum(g * x.astype(jnp.float32), -1), inverse)
+    return ((g * w[:, None]).astype(x.dtype), None, None,
+            d_w.reshape(weights.shape))
+
+
+_rows_back.defvjp(_rows_back_fwd, _rows_back_bwd)
 
 
 def route(m32, router_kernel, bias, per_token: int, route_scale: float):
@@ -204,6 +281,84 @@ def held_pairs(chosen, offset: int, held: int):
     return order, inverse, sizes
 
 
+def pair_rungs(pairs: int, held: int, total: int) -> tuple[int, ...]:
+    """The row counts the routed part is built for, smallest first (the
+    module header): twice what even routing sends to ``held`` of ``total``
+    experts, in whole row tiles of the grouped product, and the worst
+    case ``pairs``; the worst case alone where the first is no less."""
+    rows = -(-2 * pairs * held // total)
+    rows = round_up(rows, _row_tiles(rows, 1, 1)[0])
+    return (rows, pairs) if rows < pairs else (pairs,)
+
+
+def rung_of(filled, rungs: Sequence[int]):
+    """Index of the smallest of ``rungs`` (:func:`pair_rungs`) that holds
+    ``filled`` rows; ``filled`` may be a device value."""
+    return sum(filled > rows for rows in rungs[:-1])
+
+
+def _routed_rows(rows: int, m, order, inverse, sizes, weights, gate, up, down):
+    """The held experts' part of ``[n, d]`` tokens ``m``, computed over
+    the first ``rows`` sorted pairs (``sum(sizes) <= rows``)."""
+    with jax.named_scope(spans.MOE_ROUTE):
+        order = order[:rows]
+        x = _rows_out(m, order, inverse, weights.shape[1])
+    with jax.named_scope(spans.MOE_EXPERTS):
+        act = jax.nn.silu(grouped_matmul(x, gate, sizes)) * \
+            grouped_matmul(x, up, sizes)
+        out = grouped_matmul(act, down, sizes)
+    with jax.named_scope(spans.MOE_ROUTE):
+        # rows of absent experts are zero, so their weights count for
+        # the router's normalisation and for nothing here
+        return _rows_back(out, order, inverse, weights)
+
+
+@functools.lru_cache(maxsize=None)
+def _rung(rows: int):
+    """(forward, backward) of :func:`_routed_rows` at ``rows``, each under
+    its own ``jit``: the backward makes the forward again and keeps
+    nothing of it. One pair a rung, so a step traces and lowers a rung
+    once for all its layers."""
+    body = functools.partial(_routed_rows, rows)
+
+    def backward(operands, g):
+        m, order, inverse, sizes, *floats = operands
+        _, vjp = jax.vjp(lambda m, *floats: body(m, order, inverse, sizes,
+                                                 *floats), m, *floats)
+        return vjp(g)
+
+    return jax.jit(body), jax.jit(backward)
+
+
+def _switch(half: int, rungs, sizes, *args):
+    """Run the smallest rung that holds ``sum(sizes)`` rows: its forward
+    (``half`` 0) or backward (1); the index never leaves the device."""
+    bodies = [_rung(rows)[half] for rows in rungs]
+    if len(bodies) == 1:
+        return bodies[0](*args)
+    return jax.lax.switch(rung_of(sizes.sum(), rungs), bodies, *args)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _routed_recomputed(rungs, m, order, inverse, sizes, *floats):
+    """:func:`_routed_rows` over the rung of ``rungs`` that ``sizes``
+    select, recomputed in that rung by the backward pass."""
+    return _switch(0, rungs, sizes, m, order, inverse, sizes, *floats)
+
+
+def _routed_recomputed_fwd(rungs, *operands):
+    return _routed_recomputed(rungs, *operands), operands
+
+
+def _routed_recomputed_bwd(rungs, operands, g):
+    sizes = operands[3]
+    d_m, *d_floats = _switch(1, rungs, sizes, operands, g)
+    return (d_m, None, None, None, *d_floats)
+
+
+_routed_recomputed.defvjp(_routed_recomputed_fwd, _routed_recomputed_bwd)
+
+
 class RoutedExperts(nn.Module):
     """The part of a routed layer that the experts held here give."""
 
@@ -214,6 +369,7 @@ class RoutedExperts(nn.Module):
     per_token: int
     route_scale: float
     dtype: Any = jnp.float32
+    remat: bool = False       # AfmoeLayer's: recompute each rung's body
 
     @nn.compact
     def __call__(self, m32):
@@ -229,18 +385,13 @@ class RoutedExperts(nn.Module):
             chosen, weights = route(m32, router, bias, k, self.route_scale)
             order, inverse, sizes = held_pairs(chosen, self.expert_offset,
                                                held)
-            m = m32.astype(self.dtype)
-            rows = _take_rows(m, order, inverse, k)
-        with jax.named_scope(spans.MOE_EXPERTS):
-            act = jax.nn.silu(grouped_matmul(rows, gate, sizes)) * \
-                grouped_matmul(rows, up, sizes)
-            out = grouped_matmul(act, down, sizes)
-        with jax.named_scope(spans.MOE_ROUTE):
-            # rows of absent experts are zero, so their weights count
-            # for the normalisation above and for nothing here
-            per_pair = _take_rows(out, inverse, order, 1).reshape(n, k, d)
-            return jnp.einsum("nkd,nk->nd", per_pair.astype(jnp.float32),
-                              weights).astype(self.dtype)
+        operands = (m32.astype(self.dtype), order, inverse, sizes, weights,
+                    gate, up, down)
+        if not self.remat:
+            # kept rows of both rungs would be the switch's outputs
+            return _routed_rows(n * k, *operands)
+        rungs = pair_rungs(n * k, held, self.experts_total)
+        return _routed_recomputed(rungs, *operands)
 
 
 class AfmoeLayer(nn.Module):
@@ -283,13 +434,13 @@ class AfmoeLayer(nn.Module):
         with jax.named_scope(spans.MOE_SHARED):
             y = SwiGLU(self.expert_width * self.shared_experts, self.dtype,
                        name="shared")(m32.astype(self.dtype))
-        # only the routed part, with its tokens x experts_per_token rows,
-        # is too large to keep for the backward (the module header)
-        routed = nn.remat(RoutedExperts) if self.remat else RoutedExperts
-        y = y + routed(
+        # only the routed part's rows, one for every pair computed, are
+        # too many to keep for the backward (the module header)
+        y = y + RoutedExperts(
             self.expert_width, self.experts_total, self.experts_held,
             self.expert_offset, self.experts_per_token, self.route_scale,
-            self.dtype, name="experts")(m32.reshape(b * t, e)).reshape(b, t, e)
+            self.dtype, self.remat,
+            name="experts")(m32.reshape(b * t, e)).reshape(b, t, e)
         return h + norm("norm_post_mlp")(y)
 
 
@@ -398,9 +549,9 @@ def afmoe_plan(mode: str = "split", dtype: Any = jnp.float32, *,
     ``expert_offset`` on, ``experts_per_token`` a token, beside
     ``shared_experts`` shared ones). The client holds the embedding and
     the first ``client_depth`` layers. ``remat`` recomputes each expert
-    layer's routed part in the backward pass, so that its tokens x
-    ``experts_per_token`` rows are never kept, and keeps everything else
-    of a layer's forward (the module header)."""
+    layer's routed part in the backward pass, at the rows its routing
+    fills, so that none of its rows is kept, and keeps everything else of
+    a layer's forward (the module header)."""
     if attn not in _ATTN_IMPLS:
         raise ValueError(f"Unknown attn impl: {attn!r} (expected {_ATTN_IMPLS})")
     layer_types = tuple(layer_types)

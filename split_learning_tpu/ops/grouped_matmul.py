@@ -3,11 +3,12 @@
 ``grouped_matmul(lhs, rhs, group_sizes)`` multiplies consecutive row
 groups of ``lhs [m, k]`` by their own matrix of ``rhs [g, k, n]``: rows
 ``[sum(sizes[:i]), sum(sizes[:i+1]))`` by ``rhs[i]``. The groups need
-not fill ``lhs``: a routed layer sizes its buffer for the worst case
-(tokens x experts per token rows) and fills what the routing sends to
-the experts held here (models/afmoe.py). Rows past ``sum(group_sizes)``
-come back as zeros and take no gradient, and the work follows the rows
-that are filled, not ``m``.
+not fill ``lhs``: a routed layer picks, on the device, the smaller of
+two static buffer sizes if it holds what the routing sends to the
+experts held here, else the worst case of tokens x experts per token
+rows (models/afmoe.py). Rows past ``sum(group_sizes)`` come back as
+zeros and take no gradient, and the work follows the rows that are
+filled, not ``m``.
 
 The kernels are ``jax.experimental.pallas.ops.tpu.megablox``'s ``gmm``
 (forward, and the gradient of ``lhs`` with ``rhs`` transposed) and

@@ -4,6 +4,7 @@ against the whole, the grouped products against a loop, and the windowed
 grouped-head flash kernels (interpret mode) against the dense banded
 path. CPU, small sizes."""
 
+import functools
 import os
 import sys
 
@@ -14,7 +15,8 @@ import pytest
 
 from split_learning_tpu.core.losses import cross_entropy
 from split_learning_tpu.models import get_plan
-from split_learning_tpu.models.afmoe import AfmoeLayer
+from split_learning_tpu.models import afmoe
+from split_learning_tpu.models.afmoe import AfmoeLayer, RoutedExperts
 from split_learning_tpu.obs import spans
 from split_learning_tpu.ops.flash_attention import (
     flash_attention, flash_attention_with_lse)
@@ -212,9 +214,10 @@ def _pallas_calls(jaxpr, found):
 @pytest.mark.parametrize("remat", [True, False], ids=["remat", "kept"])
 def test_remat_recomputes_the_routed_part_and_nothing_else(remat):
     """One expert layer with the flash kernel (interpreted): under
-    ``remat`` the backward is handed no array with tokens x experts per
-    token rows (without it, the pair buffers are there: the test sees
-    them), and either way the gradient holds the attention's forward
+    ``remat`` the backward is handed no rows of tokens x experts per
+    token (without it, the pair buffers are there: the test sees them;
+    the sort's two index vectors of that length are kept either way, the
+    sort is not made again), and either way the gradient holds the attention's forward
     kernel once (3 operands: q, k, v) beside its one-pass backward (6),
     and the grouped products' 3 forward calls a second time only under
     ``remat`` (3 + 6 backward, + 3 recomputed)."""
@@ -230,7 +233,7 @@ def test_remat_recomputes_the_routed_part_and_nothing_else(remat):
     f = lambda p, x: jnp.sum(layer.apply(p, x) ** 2)
     _, vjp = jax.vjp(f, params, h)
     pair_rows = [a.shape for a in jax.tree_util.tree_leaves(vjp)
-                 if a.ndim and a.shape[0] == n * k]
+                 if a.ndim > 1 and a.shape[0] == n * k]
     assert (pair_rows == []) == remat, pair_rows
     calls = _pallas_calls(jax.make_jaxpr(jax.grad(f))(params, h).jaxpr, [])
     attn = [len(e.invars) for e in calls
@@ -239,10 +242,174 @@ def test_remat_recomputes_the_routed_part_and_nothing_else(remat):
     assert len(calls) - len(attn) == (12 if remat else 9)
 
 
+# 32 tokens, 2 of 16 experts a token, experts 5 and 6 held: 64 pairs, and
+# the rungs are 16 rows (twice even routing's 8) and the worst case's 64
+LADDER = dict(width=32, experts_total=16, experts_held=2, expert_offset=5,
+              per_token=2, route_scale=2.826)
+LADDER_N, LADDER_D = 32, 64
+
+
+def routed_to(filled, dtype):
+    """Parameters and tokens of a ``LADDER`` layer whose router sends
+    exactly ``filled`` pairs to the two held experts: its first 16 rows
+    are ten times the identity, and every token's first 16 features are
+    +1 at the two experts it shall pick and -1 elsewhere."""
+    n, d, total = LADDER_N, LADDER_D, LADDER["experts_total"]
+    rs = np.random.RandomState(filled)
+    absent = [e for e in range(total) if e not in (5, 6)]
+    both = max(filled - n, 0)     # tokens that pick both held experts
+    picks = []
+    for t in range(n):
+        if t < both:
+            picks.append((5, 6))
+        elif t < filled - both:    # one held: two of three go to expert 5
+            picks.append((5 if t % 3 else 6, absent[rs.randint(len(absent))]))
+        else:
+            picks.append(tuple(rs.choice(absent, 2, replace=False)))
+    x = 0.3 * rs.randn(n, d).astype(np.float32)
+    x[:, :total] = -1.0
+    for t, pair in enumerate(picks):
+        x[t, list(pair)] = 1.0 + 0.1 * rs.rand(2)
+    layer = RoutedExperts(**LADDER, dtype=dtype, remat=True)
+    x = jnp.asarray(x)
+    params = layer.init(jax.random.PRNGKey(filled), x)["params"]
+    leaves, tree = jax.tree_util.tree_flatten(params)
+    keys = jax.random.split(jax.random.PRNGKey(filled + 1), len(leaves))
+    params = jax.tree_util.tree_unflatten(tree, [
+        leaf + 0.05 * jax.random.normal(k, leaf.shape) for leaf, k in zip(leaves, keys)])
+    router = 0.01 * np.asarray(params["router"])
+    router[:total] += 10.0 * np.eye(total, dtype=np.float32)
+    return {**params, "router": jnp.asarray(router),
+            "expert_bias": jnp.zeros((total,))}, x
+
+
+def plain_routed(params, x, dtype):
+    """The layer with no sort taken away: every one of the ``n * k`` sorted
+    rows through ``grouped_matmul_reference``, every pair gathered back."""
+    k = LADDER["per_token"]
+    chosen, weights = afmoe.route(x, params["router"], params["expert_bias"],
+                                  k, LADDER["route_scale"])
+    order, inverse, sizes = afmoe.held_pairs(chosen, 5, 2)
+    rows = x.astype(dtype)[order // k]
+    mm = lambda a, w: grouped_matmul_reference(a, params[w], sizes)
+    out = mm(jax.nn.silu(mm(rows, "gate")) * mm(rows, "up"), "down")
+    per_pair = out[inverse].reshape(x.shape[0], k, -1).astype(jnp.float32)
+    return jnp.einsum("nkd,nk->nd", per_pair, weights).astype(dtype), sizes
+
+
+@functools.lru_cache(maxsize=None)
+def _ladder_step(dtype, remat):
+    """One compile a form and type for all the routings below."""
+    layer = RoutedExperts(**LADDER, dtype=dtype, remat=remat)
+    c = jax.random.normal(jax.random.PRNGKey(9), (LADDER_N, LADDER_D))
+    f = lambda p, x: (lambda y: (jnp.sum(y.astype(jnp.float32) * c), y))(
+        layer.apply({"params": p}, x))
+    return jax.jit(jax.value_and_grad(f, argnums=(0, 1), has_aux=True))
+
+
+# rungs 16 and 64: inside each, on each edge, one past the lower edge,
+# nothing routed here, and every pair held (what the top rung is for)
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-6), ("bfloat16", 1e-2)])
+@pytest.mark.parametrize("filled,rung", [
+    (0, 16), (5, 16), (15, 16), (16, 16), (17, 64), (24, 64), (33, 64),
+    (50, 64), (63, 64), (64, 64)])
+def test_every_rung_gives_the_top_rungs_numbers(filled, rung, dtype, tol):
+    """The rung that holds the pairs routed here against the kept form
+    (``remat=False``: the top rung alone) and against the plain layer:
+    output, loss and every gradient, with no pair's part missing."""
+    dtype = jnp.dtype(dtype)
+    rungs = afmoe.pair_rungs(LADDER_N * 2, 2, 16)
+    assert rungs == (16, 64)
+    params, x = routed_to(filled, dtype)
+    want_y, sizes = plain_routed(params, x, dtype)
+    assert int(sizes.sum()) == filled
+    assert rungs[afmoe.rung_of(filled, rungs)] == rung
+    assert rungs[int(afmoe.rung_of(sizes.sum(), rungs))] == rung
+    run = lambda remat: _ladder_step(dtype, remat)(params, x)
+    (loss, y), grads = run(True)
+    (top_loss, top_y), top_grads = run(False)
+    scale = lambda a: max(float(np.abs(np.asarray(a, np.float32)).max()), 1e-6)
+    np.testing.assert_allclose(np.asarray(y, np.float32),
+                               np.asarray(top_y, np.float32),
+                               rtol=0, atol=tol * scale(top_y))
+    # the plain layer multiplies in another order: the file's float32 room
+    np.testing.assert_allclose(
+        np.asarray(y, np.float32), np.asarray(want_y, np.float32), rtol=0,
+        atol=max(tol, 2e-5) * scale(want_y))
+    assert abs(float(loss) - float(top_loss)) <= tol * max(abs(float(top_loss)), 1.0)
+    got, ref = flat(grads), flat(top_grads)
+    assert got.keys() == ref.keys() and len(got) == 6
+    for name, g in ref.items():
+        np.testing.assert_allclose(got[name], g, rtol=0, atol=tol * scale(g),
+                                   err_msg=name)
+    if filled:   # the held experts' weights do take a gradient
+        assert np.abs(ref["[0]['gate']"]).max() > 0
+
+
+def _conds(jaxpr, found):
+    """Every ``cond`` equation of a jaxpr and of those inside it, a Pallas
+    kernel's own body apart (megablox branches inside its kernels)."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            continue
+        if eqn.primitive.name == "cond":
+            found.append(eqn)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _conds(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("held,conds", [(8, 0), (4, 0), (1, 2)],
+                         ids=["whole-layer", "half", "an-eighth"])
+def test_a_layer_whose_rows_are_all_filled_has_no_conditional(held, conds):
+    """``experts_held == experts_total`` (and any share of a half or more:
+    twice even routing is the worst case) is one rung, so the gradient
+    traces to no ``cond``; an eighth is two rungs and two ``cond``s, the
+    forward's and the backward's."""
+    layer = RoutedExperts(width=32, experts_total=8, experts_held=held,
+                          expert_offset=0, per_token=2, route_scale=2.826,
+                          remat=True)
+    x = jax.random.normal(jax.random.PRNGKey(0), (24, 64))
+    params = layer.init(jax.random.PRNGKey(1), x)
+    f = lambda p, x: jnp.sum(layer.apply(p, x) ** 2)
+    found = _conds(jax.make_jaxpr(jax.grad(f, argnums=(0, 1)))(params, x).jaxpr, [])
+    assert len(found) == conds
+    assert afmoe.pair_rungs(48, held, 8) == ((16, 48) if conds else (48,))
+
+
+@pytest.mark.parametrize("mode", ["split", "u_split"])
+def test_no_conditional_returns_rows_of_pairs(mode):
+    """The recomputation's form: each rung recomputes inside its own branch
+    of the backward's ``cond``, so what a ``cond`` of the gradient returns
+    is its result (the forward's) or the cotangents (the backward's), never
+    ``n * k`` rows of a rung's buffers. A ``switch`` under one
+    ``checkpoint`` would return the union of both rungs' kept rows."""
+    t = 24
+    kw = {**KW, "experts_held": 1, "remat": True}
+    plan = get_plan("afmoe", mode, jnp.float32, **kw)
+    rs = np.random.RandomState(0)
+    ids = rs.randint(0, KW["vocab"], (B, t + 1)).astype(np.int32)
+    x, y = ids[:, :-1], ids[:, 1:]
+    params = seeded(plan, x)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: cross_entropy(plan.apply(p, x), y)))(params).jaxpr
+    found = _conds(jaxpr, [])
+    pairs = B * t * KW["experts_per_token"]
+    assert afmoe.pair_rungs(pairs, 1, 8) == (32, pairs)
+    assert len(found) == 2 * 4      # four routed layers, forward and backward
+    for eqn in found:
+        assert len(eqn.params["branches"]) == 2
+        rows = [v.aval.shape for v in eqn.outvars
+                if v.aval.shape and v.aval.shape[0] == pairs]
+        assert rows == [], rows
+
+
 def test_the_shares_add_up():
     """8 experts in 4 shares of 2: the routed parts that all the shares
     give, with the shared expert counted once, are the uncut layer."""
-    from split_learning_tpu.models.afmoe import AfmoeLayer
     kw = dict(num_heads=4, num_kv_heads=2, head_dim=16,
               layer_type="full_attention", window=8, dense_width=0,
               expert_width=32, experts_total=8, experts_per_token=2,
