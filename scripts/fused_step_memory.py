@@ -1,6 +1,6 @@
-"""What the fused afmoe step keeps on the device, by XLA's own reckoning.
+"""What a cell's fused step keeps on the device, by XLA's own reckoning.
 
-    JAX_PLATFORMS=cpu python scripts/afmoe_memory.py [--workload trinity-mini-fused-t8192]
+    JAX_PLATFORMS=cpu python scripts/fused_step_memory.py --workload <cell>
 
 Compiles the cell's fused step (``runtime/fused.py``'s ``step_fn``: loss,
 gradient, optimizer update, the state donated) at the cell's real sizes
@@ -10,8 +10,10 @@ temporaries and their peak (arguments + outputs - aliased + temporaries),
 in bytes and GB.  Nothing runs, so it says what fits, never how fast.  It
 counts this one program, not what else a process keeps on the device
 (PERF.md section 4: two loaded executables once cost the reference its
-room).  ``--remat 0`` compiles the step without the family's ``remat``,
-to see what the routed part's rows cost when kept.
+room).  It is the check of the fit rules of ``trinity-mini-fused-t8192``
+(13.0 GB or under) and ``phi4flash-fused-t8192`` (14.5).  ``--remat 0``
+compiles the step without the family's ``remat``, to see what the values it
+recomputes cost when kept.
 """
 
 from __future__ import annotations
@@ -33,7 +35,8 @@ sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
 
 def main() -> int:
     parser = argparse.ArgumentParser()
-    parser.add_argument("--workload", default="trinity-mini-fused-t8192")
+    parser.add_argument("--workload", required=True,
+                        help="a cell of BENCHMARK.json on the fused path")
     parser.add_argument("--remat", type=int, choices=(0, 1), default=None,
                         help="override the configuration's plan.kwargs.remat")
     args = parser.parse_args()

@@ -118,6 +118,15 @@ def _afmoe(mode: str, dtype: Any, **kw: Any) -> SplitPlan:
     return afmoe_plan(mode=mode, dtype=dtype, **kw)
 
 
+@register_model("phi4flash")
+def _phi4flash(mode: str, dtype: Any, **kw: Any) -> SplitPlan:
+    """State-space layers beside differential attention, and a second
+    half that reads a memory and a key/value set the first half made
+    (models/phi4flash.py)."""
+    from split_learning_tpu.models.phi4flash import phi4flash_plan
+    return phi4flash_plan(mode=mode, dtype=dtype, **kw)
+
+
 def get_plan(model: str = "split_cnn", mode: str = "split",
              dtype: Any = jnp.float32, **size_kw: Any) -> SplitPlan:
     """Build the SplitPlan for a model family under a learning mode.

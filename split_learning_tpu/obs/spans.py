@@ -224,7 +224,14 @@ ATTN_FULL = "attn_full"
 MOE_ROUTE = "moe_route"      # router, top-k, the pairs' sort and gathers
 MOE_EXPERTS = "moe_experts"  # the grouped products over the experts held
 MOE_SHARED = "moe_shared"
-DEVICE_SCOPES = (ATTN_WINDOW, ATTN_FULL, MOE_ROUTE, MOE_EXPERTS, MOE_SHARED)
+# -- and of the phi4flash family (models/phi4flash.py), which shares the
+# two attention scopes above
+SSM_CONV = "ssm_conv"        # the causal depthwise convolution and its silu
+SSM_SCAN = "ssm_scan"        # the selective-scan kernel's calls
+GMU = "gmu"                  # a gated memory unit, products included
+ATTN_CROSS = "attn_cross"    # attention over another layer's keys and values
+DEVICE_SCOPES = (ATTN_WINDOW, ATTN_FULL, MOE_ROUTE, MOE_EXPERTS, MOE_SHARED,
+                 SSM_CONV, SSM_SCAN, GMU, ATTN_CROSS)
 
 # the client-level phases that tile a step — the denominator of the
 # compute-vs-wire fraction (encode/wire are sub-phases of transport and
