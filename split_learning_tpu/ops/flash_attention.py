@@ -42,16 +42,31 @@ Design (the canonical TPU flash schedule):
   mandatory (past the dense HBM wall); ``attn="auto"`` arbitrates.
 - Causal masking uses global block coordinates; block pairs with no
   causal overlap skip their matmuls entirely (``pl.when`` around the
-  accumulate — the grid stays static, ~2x fewer FLOPs at large T), and
-  partially-masked diagonal blocks mask elementwise.
+  accumulate — the grid stays static, ~2x fewer FLOPs at large T).
+- A block pair that the mask only cuts (the diagonal one; under a window
+  the band's last one or two) has a body of its own in all four kernels:
+  it works in sub-tiles of ``_TILE`` rows and columns, runs only those
+  that hold a live (row, column) — per row tile one contiguous range of
+  columns, a pure function of the mask and of the pair's static offset
+  (:func:`_live_cols`; :func:`live_tile_share` counts them) — and masks
+  elementwise inside them. A skipped tile is one whose every ``p`` is
+  exactly 0, so the result is the whole-pair body's up to float32
+  summation order. Blocks, grid and DMAs stay at the block edge; whole
+  pairs keep the single full-block body, and without a causal mask
+  every kernel is traced as before (tests/test_afmoe.py). Where a row
+  block has one key block in all (GPT-2 at T 1024) the forward needs no
+  running maximum: each row tile's softmax is final (``_fwd_kernel``).
+- Every kernel body is traced once and replayed at the other call sites
+  (:func:`_traced_once`): a model calls one attention at many sites, and
+  tracing a body is what a call site costs a process at start-up.
 
 - A ``window`` (query i sees keys j with ``0 <= i - j < window``; the
   afmoe family's ``sliding_attention`` layers) shrinks the inner grid
   extent to the band (:func:`_band_blocks`): step ``j`` of query block
   ``i`` reads key block ``i - (n - 1) + j`` through the index map, so
   key blocks wholly outside the band are neither fetched nor computed,
-  and the two edge blocks mask elementwise. The one-pass backward's
-  loop over query blocks stops at the band's end likewise.
+  and the edge blocks run their live sub-tiles (above). The one-pass
+  backward's loop over query blocks stops at the band's end likewise.
 - Grouped key/value heads (``[B, T, H_kv, D]`` under ``H`` query
   heads): K/V blocks are read from row ``b // (H // H_kv)`` of the
   folded array, never repeated in HBM; the backward writes dK/dV per
@@ -103,7 +118,13 @@ def _pick_block(t: int) -> int:
     keeps every matmul MXU-shaped ([1024,128]x[128,1024]); the f32
     scores block is 4 MiB and the kernels' working set stays inside
     Mosaic's 16 MiB default (compiled and measured on-chip at
-    T=1024..8192). SLT_FLASH_BLOCK overrides for tuning."""
+    T=1024..8192). SLT_FLASH_BLOCK overrides for tuning.
+
+    The sweep predates PR 1 and nothing since has repeated it. What a
+    1024-row block wastes where the mask cuts it (half of the diagonal
+    pair, most of a window's far edge) is no longer paid by a smaller
+    block but inside the kernel bodies: ``_TILE`` / :func:`_live_cols`
+    (PR 31)."""
     import os
     env = os.environ.get("SLT_FLASH_BLOCK")
     if env:
@@ -191,16 +212,17 @@ _DEFAULT_LIMIT_SAFE = 12 * 1024 * 1024
 _SPLIT_BLOCK_MAX = 512
 
 
-def _resolve_block(t: int, d: int, dtype, bh: int = 2,
-                   group: int = 1) -> tuple[int, bool]:
+def _resolve_block(t: int, d: int, dtype, bh: int = 2, group: int = 1,
+                   mask: tuple = (False, False, None)) -> tuple[int, bool]:
     """(block, onepass) for a public entry point: the swept default
     edge when the one-pass backward (which preflight-confirms itself)
     carries the gradient, capped to :data:`_SPLIT_BLOCK_MAX` when the
     two-kernel split must take over. An explicit ``SLT_FLASH_BLOCK``
     tuning override is honored verbatim — sweeps must measure the edge
     they asked for, cap included in what they signed up for. ``bh`` is
-    the program's batch*heads, forwarded so the preflight probes the
-    grid shape the user will actually compile (see
+    the program's batch*heads and ``mask`` its ``(causal, strict,
+    window)``, forwarded so the preflight probes the grid shape and the
+    kernel bodies the user will actually compile (see
     :func:`_onepass_compile_ok`).
 
     Cost note: resolving the backward form eagerly means even a
@@ -212,16 +234,16 @@ def _resolve_block(t: int, d: int, dtype, bh: int = 2,
     user-path compile error."""
     import os
     block = _pick_block(t)
-    onepass = _use_onepass(t, block, d, dtype, bh=bh, group=group)
+    onepass = _use_onepass(t, block, d, dtype, bh, group, mask)
     if (not onepass and block > _SPLIT_BLOCK_MAX
             and not os.environ.get("SLT_FLASH_BLOCK")):
         block = _SPLIT_BLOCK_MAX
-        onepass = _use_onepass(t, block, d, dtype, bh=bh, group=group)
+        onepass = _use_onepass(t, block, d, dtype, bh, group, mask)
     return block, onepass
 
 
 def _use_onepass(t: int, block: int, d: int, dtype, bh: int = 2,
-                 group: int = 1) -> bool:
+                 group: int = 1, mask: tuple = (False, False, None)) -> bool:
     """Backward-form selection: one-pass while its whole-sequence
     residency (see :func:`_onepass_resident_bytes`) fits 2/3 of the
     device's scoped-VMEM limit, leaving the rest for the
@@ -256,14 +278,15 @@ def _use_onepass(t: int, block: int, d: int, dtype, bh: int = 2,
     if ((resident > _DEFAULT_LIMIT_SAFE or block > _SPLIT_BLOCK_MAX)
             and not use_interpret()):
         return _onepass_compile_ok(tp, round_up(d, LANE), block, dtype.name,
-                                   min(bh, 2), group)
+                                   min(bh, 2), group, mask)
     return True
 
 
 @functools.lru_cache(maxsize=None)
 def _onepass_compile_ok(tp: int, dp: int, block: int,
                         dtype_name: str, bh_probe: int = 2,
-                        group: int = 1) -> bool:
+                        group: int = 1,
+                        mask: tuple = (False, False, None)) -> bool:
     """Preflight: does the one-pass backward *compile* on this device at
     the padded shape? ``vmem_limit_bytes`` is serialized into the Mosaic
     custom call as ``scoped_memory_configs`` (verified against the
@@ -279,12 +302,17 @@ def _onepass_compile_ok(tp: int, dp: int, block: int,
     boundary, residency does not grow further with bh beyond it, and a
     genuine bh=1 program (no boundary at all) still probes exactly.
     Cached per process — one ~seconds compile per distinct (padded T,
-    padded D, block, dtype, probe-bh). Mask flavor (causal/strict) is
-    irrelevant to scoped allocation (a window too), so the probe always
-    uses ``causal=False``; grouped heads are not: their dK/dV blocks
-    leave in float32, so ``group`` is part of the probe."""
-    call = _onepass_call(bh_probe, tp, tp, dp, block, 1.0, False, False,
-                         jnp.dtype(dtype_name), None, group)
+    padded D, block, dtype, probe-bh, group, mask). ``mask`` is the
+    call's ``(causal, strict, window)``: since PR 31 a pair the mask cuts
+    has a body of its own (sub-tiles, a ``cond`` at the band's far edge),
+    so the probe compiles the bodies the call will run — their float32
+    score temporaries are ``[rows, tile]`` where the whole pair's are
+    ``[block, block]``, so they ask for less, but that is the compiler's
+    to say. Grouped heads leave their dK/dV blocks in float32, so
+    ``group`` is part of the probe too."""
+    causal, strict, window = mask
+    call = _onepass_call(bh_probe, tp, tp, dp, block, 1.0, causal, strict,
+                         jnp.dtype(dtype_name), window, group)
     seq = jax.ShapeDtypeStruct((bh_probe, tp, dp), jnp.dtype(dtype_name))
     kv = jax.ShapeDtypeStruct((max(1, bh_probe // group), tp, dp),
                               jnp.dtype(dtype_name))
@@ -435,7 +463,150 @@ def _band_blocks(window: int, block: int, n_blk: int) -> int:
     return min(n_blk, -(-(window - 1) // block) + 1)
 
 
-def _fwd_kernel(blk: int, t: int, scale: float, causal: bool,
+# Inside a block pair that the mask cuts (the diagonal one, and under a
+# window the band's far edge) the kernels work in sub-tiles of this many
+# rows and columns and run only those that hold a live (row, column);
+# blocks, grid and DMAs stay at _pick_block's edge. One constant, no
+# knob: see _tile_edge.
+_TILE = 256
+
+
+def _tile_edge(blk: int) -> int:
+    """Sub-tile edge inside a cut pair of ``blk``-row blocks: ``_TILE``
+    where it divides a larger block, else the block itself (one tile,
+    which is the whole-pair body)."""
+    return _TILE if blk > _TILE and blk % _TILE == 0 else blk
+
+
+def _live_cols(offset: int, blk: int, tile: int, causal: bool,
+               strict: bool = False, window=None) -> tuple:
+    """Per row tile of a (query block, key block) pair, the column tiles
+    ``[lo, hi)`` that hold at least one live (row, column); ``(0, 0)``
+    where none does. ``offset`` is the pair's first row minus its first
+    column (``q0 - k0``), so local ``(i, j)`` sits ``i - j + offset`` past
+    the diagonal: causal keeps ``>= 0`` (``strict``: ``>= 1``), a window
+    ``< window``. The live columns of a row tile are one contiguous
+    range. Rows and columns past ``t`` are not looked at here: the
+    elementwise mask handles them."""
+    out = []
+    for r0 in range(0, blk, tile):
+        j_lo, j_hi = 0, blk - 1
+        if causal:       # the tile's last row sees furthest right
+            j_hi = min(j_hi, r0 + tile - 1 + offset - int(strict))
+        if window is not None:   # its first row sees furthest left
+            j_lo = max(j_lo, r0 + offset - window + 1)
+        out.append((j_lo // tile, j_hi // tile + 1) if j_lo <= j_hi
+                   else (0, 0))
+    return tuple(out)
+
+
+def _live_rows(cols: tuple) -> tuple:
+    """:func:`_live_cols` seen from the key side: per column tile the row
+    tiles ``[lo, hi)`` that reach it (contiguous likewise)."""
+    out = []
+    for c in range(len(cols)):
+        rows = [r for r, (lo, hi) in enumerate(cols) if lo <= c < hi]
+        out.append((rows[0], rows[-1] + 1) if rows else (0, 0))
+    return tuple(out)
+
+
+def _cut_pairs(n_off: int, blk: int, tile: int, causal: bool,
+               strict: bool, window) -> dict:
+    """The block pairs the mask cuts, among those a kernel visits: offset
+    index ``o`` (query block minus key block, ``0 <= o < n_off``) to its
+    :func:`_live_cols`. A causal mask cuts the diagonal pair (``o == 0``),
+    a window the band's last one or two; every other pair is whole and
+    keeps the single full-block body. Empty without a causal mask or
+    where the block is one tile."""
+    if not causal or tile == blk:
+        return {}
+    whole = ((0, blk // tile),) * (blk // tile)
+    cuts = {}
+    for o in range(n_off):
+        cols = _live_cols(o * blk, blk, tile, causal, strict, window)
+        if cols != whole:
+            cuts[o] = cols
+    return cuts
+
+
+def live_tile_share(t: int, block: int, causal: bool, window=None,
+                    strict: bool = False, tile: int | None = None) -> float:
+    """Share of the sub-tiles in the block pairs the kernels visit at
+    (padded) ``t`` that they still run: the static counter of how often
+    the cut-pair bodies engage. 1.0 without a causal mask."""
+    if not causal:
+        return 1.0
+    tile = _tile_edge(block) if tile is None else tile
+    n_blk = round_up(t, block) // block
+    n_off = n_blk if window is None else _band_blocks(window, block, n_blk)
+    per = [sum(hi - lo for lo, hi in
+               _live_cols(o * block, block, tile, causal, strict, window))
+           for o in range(n_off)]
+    pairs = [n_blk - o for o in range(n_off)]   # query blocks at offset o
+    return (sum(n * p for n, p in zip(pairs, per))
+            / (sum(pairs) * (block // tile) ** 2))
+
+
+def _row_tiles(ranges: tuple, blk: int) -> list:
+    """The live part of a cut pair by row tile: (rows, columns) as slices
+    of the block, a row tile's live columns being one range
+    (:func:`_live_cols`), so one narrower product."""
+    tile = blk // len(ranges)
+    return [(slice(r * tile, (r + 1) * tile), slice(lo * tile, hi * tile))
+            for r, (lo, hi) in enumerate(ranges) if lo < hi]
+
+
+def _key_tiles(ranges: tuple, blk: int) -> list:
+    """The same by key tile: a key tile's live rows are one range
+    (:func:`_live_rows`), so one shorter product."""
+    tile = blk // len(ranges)
+    return [(slice(lo * tile, hi * tile), slice(c * tile, (c + 1) * tile))
+            for c, (lo, hi) in enumerate(_live_rows(ranges)) if lo < hi]
+
+
+def _both(a, b):
+    """``a & b`` where ``a`` may be None (no condition)."""
+    return b if a is None else a & b
+
+
+def _by_kind(live, o, cuts: dict, n_off: int, body, tiles) -> None:
+    """Dispatch one grid step of a (query block, key block) kernel:
+    ``body(rows, cols)`` over ``tiles(ranges)`` where the pair's offset
+    index ``o()`` is one the mask cuts, ``body()`` on every other pair,
+    nothing where ``live`` (None: always) is false."""
+    def cut(ranges):
+        for rows, cols in tiles(ranges):
+            body(rows, cols)
+
+    o = o() if cuts else None
+    for oc, ranges in cuts.items():
+        pl.when(_both(live, o == oc))(functools.partial(cut, ranges))
+    if len(cuts) == n_off:
+        return
+    for oc in cuts:
+        live = _both(live, o != oc)
+    if live is None:
+        body()
+    else:
+        pl.when(live)(body)
+
+
+def _blk(ref, rows=None):
+    """Block 0 of a ``[1, rows, lanes]`` ref, or its rows ``rows``."""
+    return ref[0] if rows is None else ref[0, rows, :]
+
+
+def _ix(rows=None):
+    """Index of a scratch ref's rows ``rows`` (None: all of them)."""
+    return slice(None) if rows is None else rows
+
+
+def _past(x0, rows=None):
+    """Global index of the first row of ``rows`` in a block at ``x0``."""
+    return x0 if rows is None else x0 + rows.start
+
+
+def _fwd_kernel(blk: int, tile: int, t: int, scale: float, causal: bool,
                 strict: bool, n_k: int, window,
                 q_ref, k_ref, v_ref, o_ref, lse_ref,
                 acc_ref, m_ref, l_ref):
@@ -448,6 +619,36 @@ def _fwd_kernel(blk: int, t: int, scale: float, causal: bool,
     kb_i = step if window is None else qb_i - (n_k - 1) + step
     q0 = qb_i * blk
     k0 = kb_i * blk
+    cuts = _cut_pairs(n_k, blk, tile, causal, strict, window)
+
+    def _final(rows, cols):
+        """The whole softmax of the rows ``rows``, whose live keys are
+        ``cols`` of this block and no other's."""
+        vb = _blk(v_ref, cols)
+        s, ok = _scores(_blk(q_ref, rows), _blk(k_ref, cols), t,
+                        _past(k0, cols), _past(q0, rows), scale, causal,
+                        strict, window)
+        m = jnp.max(s, axis=1, keepdims=True)
+        p = jnp.where(ok, jnp.exp(s - m), 0.0)
+        l = jnp.sum(p, axis=1, keepdims=True)
+        # padded query rows, and a strict mask's first row, see no key
+        l_safe = jnp.where(l > 0.0, l, 1.0)
+        acc = jax.lax.dot_general(
+            p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        o_ref[0, rows, :] = (acc / l_safe).astype(o_ref.dtype)
+        lse = jnp.where(l > 0.0, m + jnp.log(l_safe), _NEG_BIG)
+        lse_ref[0, rows, :] = jnp.broadcast_to(lse, (s.shape[0], _ROWW))
+
+    if n_k == 1 and cuts:
+        # One key block (GPT-2 at T 1024): this pair is all a row sees,
+        # so there is no running maximum to rebase and nothing to
+        # rescale. Each row tile's softmax is final and goes straight to
+        # the outputs; the scratch accumulators are not touched. (On the
+        # diagonal pair every row tile has live columns.)
+        for rows, cols in _row_tiles(cuts[0], blk):
+            _final(rows, cols)
+        return
 
     @pl.when(step == 0)
     def _init():
@@ -458,30 +659,36 @@ def _fwd_kernel(blk: int, t: int, scale: float, causal: bool,
     # causal: a key block strictly in the future of the whole query
     # block contributes nothing — skip its matmuls entirely (the grid
     # stays static; only the compute is guarded). Blocks are square, so
-    # "any overlap" is kb_i <= qb_i.
-    def _accumulate():
-        qb = q_ref[0]
-        vb = v_ref[0]
-        s, ok = _scores(qb, k_ref[0], t, k0, q0, scale, causal, strict,
-                        window)
-        m = m_ref[:, 0]
+    # "any overlap" is kb_i <= qb_i. ``rows`` / ``cols``: the sub-tiles of
+    # a cut pair (slices of the block), None for the whole pair.
+    def _accumulate(rows=None, cols=None):
+        qb = _blk(q_ref, rows)
+        vb = _blk(v_ref, cols)
+        s, ok = _scores(qb, _blk(k_ref, cols), t, _past(k0, cols),
+                        _past(q0, rows), scale, causal, strict, window)
+        ix, roww = _ix(rows), (s.shape[0], _ROWW)
+        m = m_ref[ix, 0]
         m_new = jnp.maximum(m, jnp.max(s, axis=1))
         # rebase then re-mask: exp(_NEG_BIG - _NEG_BIG) would be 1
         p = jnp.where(ok, jnp.exp(s - m_new[:, None]), 0.0)
         corr = jnp.exp(m - m_new)
-        l_ref[:] = l_ref[:] * corr[:, None] + jnp.broadcast_to(
-            jnp.sum(p, axis=1)[:, None], l_ref.shape)
-        acc_ref[:] = acc_ref[:] * corr[:, None] + jax.lax.dot_general(
+        l_ref[ix] = l_ref[ix] * corr[:, None] + jnp.broadcast_to(
+            jnp.sum(p, axis=1)[:, None], roww)
+        acc_ref[ix] = acc_ref[ix] * corr[:, None] + jax.lax.dot_general(
             p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_ref[:] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
+        m_ref[ix] = jnp.broadcast_to(m_new[:, None], roww)
 
     if window is not None:
-        pl.when(kb_i >= 0)(_accumulate)   # the band starts before key 0
+        # the band starts before key 0; step j sits n_k - 1 - j blocks
+        # before the diagonal
+        live, o = kb_i >= 0, lambda: n_k - 1 - step
     elif causal:
-        pl.when(kb_i <= qb_i)(_accumulate)
+        live, o = kb_i <= qb_i, lambda: qb_i - kb_i
     else:
-        _accumulate()
+        live, o = None, None
+    _by_kind(live, o, cuts, n_k, _accumulate,
+             functools.partial(_row_tiles, blk=blk))
 
     @pl.when(step == n_k - 1)
     def _finish():
@@ -493,8 +700,8 @@ def _fwd_kernel(blk: int, t: int, scale: float, causal: bool,
         lse_ref[0] = jnp.broadcast_to(lse[:, None], lse_ref.shape[1:])
 
 
-def _onepass_bwd_kernel(blk: int, t: int, scale: float, causal: bool,
-                        strict: bool, n_q: int, window,
+def _onepass_bwd_kernel(blk: int, tile: int, t: int, scale: float,
+                        causal: bool, strict: bool, n_q: int, window,
                         k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
                         dk_ref, dv_ref, dq_ref):
     """Single-pass backward for mid-length T: grid ``(bh, k block)``
@@ -520,43 +727,83 @@ def _onepass_bwd_kernel(blk: int, t: int, scale: float, causal: bool,
     kb = k_ref[0]
     vb = v_ref[0]
 
-    def body(j, carry):
-        dk, dv = carry
-        q0 = j * blk
-        qb = q_ref[0, pl.ds(q0, blk), :]
-        dob = do_ref[0, pl.ds(q0, blk), :]
-        lse = lse_ref[0, pl.ds(q0, blk), :][:, :1]
-        delta = delta_ref[0, pl.ds(q0, blk), :][:, :1]
+    def pair(q0, n, kb, vb, k0, dk, dv):
+        """Rows ``[q0, q0 + n)`` of the queries against the keys ``kb`` at
+        ``k0``: dQ accumulates in place, (dK, dV) are returned added to
+        ``dk`` / ``dv`` (None: nothing to add to)."""
+        qb = q_ref[0, pl.ds(q0, n), :]
+        dob = do_ref[0, pl.ds(q0, n), :]
+        lse = lse_ref[0, pl.ds(q0, n), :][:, :1]
+        delta = delta_ref[0, pl.ds(q0, n), :][:, :1]
         s, ok = _scores(qb, kb, t, k0, q0, scale, causal, strict, window)
         p = jnp.where(ok, jnp.exp(s - lse), 0.0)
-        dv += jax.lax.dot_general(
+        dv_p = jax.lax.dot_general(
             p.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        dv = dv_p if dv is None else dv + dv_p
         dp = jax.lax.dot_general(
             dob, vb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         ds = p * (dp - delta)
-        dk += jax.lax.dot_general(
+        dk_p = jax.lax.dot_general(
             ds.astype(qb.dtype), qb, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dq_ref[0, pl.ds(q0, blk), :] += (jax.lax.dot_general(
+        dk = dk_p if dk is None else dk + dk_p
+        dq_ref[0, pl.ds(q0, n), :] += (jax.lax.dot_general(
             ds.astype(kb.dtype), kb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
         ).astype(dq_ref.dtype)
         return dk, dv
 
+    def body(j, carry):
+        return pair(j * blk, blk, kb, vb, k0, *carry)
+
+    def cut(j, ranges, carry):
+        """Query block ``j``, a pair the mask cuts: per key tile its live
+        rows are one range (:func:`_live_rows`), so each key tile takes
+        one shorter product and leaves its own rows of dK, dV."""
+        parts = []
+        for c, (lo, hi) in enumerate(_live_rows(ranges)):
+            if lo == hi:
+                z = jnp.zeros((tile, dq_ref.shape[-1]), jnp.float32)
+                parts.append((z, z))
+                continue
+            keys = slice(c * tile, (c + 1) * tile)
+            parts.append(pair(j * blk + lo * tile, (hi - lo) * tile,
+                              k_ref[0, keys, :], v_ref[0, keys, :],
+                              k0 + c * tile, None, None))
+        dk, dv = (jnp.concatenate(x, axis=0) for x in zip(*parts))
+        return carry[0] + dk, carry[1] + dv
+
     zeros = jnp.zeros(kb.shape[:1] + (dq_ref.shape[-1],), jnp.float32)
     # causal: query blocks strictly before this key block are dead;
-    # banded: so are those past the band (_band_blocks)
-    start = kb_i if causal else 0
-    stop = n_q if window is None else jnp.minimum(
-        n_q, kb_i + _band_blocks(window, blk, n_q))
-    dk, dv = jax.lax.fori_loop(start, stop, body, (zeros, zeros))
+    # banded: so are those past the band (_band_blocks). Query block
+    # kb_i + o sits o blocks past the diagonal: the cut ones run their
+    # live sub-tiles, the whole ones between them the loop.
+    n_off = n_q if window is None else _band_blocks(window, blk, n_q)
+    cuts = _cut_pairs(n_off, blk, tile, causal, strict, window)
+    carry = (zeros, zeros)
+    if 0 in cuts:
+        carry = cut(kb_i, cuts[0], carry)
+    whole = [o for o in range(n_off) if o not in cuts]   # one run
+    if whole:
+        start = 0
+        if causal:
+            start = kb_i + whole[0] if whole[0] else kb_i
+        stop = n_q if window is None else jnp.minimum(
+            n_q, kb_i + (whole[-1] + 1))
+        carry = jax.lax.fori_loop(start, stop, body, carry)
+    for o in cuts:
+        if o:   # the band's far edge, where it is inside T
+            carry = jax.lax.cond(
+                kb_i + o < n_q, functools.partial(cut, kb_i + o, cuts[o]),
+                lambda c: c, carry)
+    dk, dv = carry
     dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
-def _dq_kernel(blk: int, t: int, scale: float, causal: bool,
+def _dq_kernel(blk: int, tile: int, t: int, scale: float, causal: bool,
                strict: bool, n_k: int, window,
                q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                dq_ref, acc_ref):
@@ -572,33 +819,36 @@ def _dq_kernel(blk: int, t: int, scale: float, causal: bool,
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def _accumulate():
-        qb = q_ref[0]
-        kb = k_ref[0]
-        s, ok = _scores(qb, kb, t, k0, q0, scale, causal, strict, window)
-        p = jnp.where(ok, jnp.exp(s - lse_ref[0][:, :1]), 0.0)
+    def _accumulate(rows=None, cols=None):
+        qb = _blk(q_ref, rows)
+        kb = _blk(k_ref, cols)
+        s, ok = _scores(qb, kb, t, _past(k0, cols), _past(q0, rows), scale,
+                        causal, strict, window)
+        p = jnp.where(ok, jnp.exp(s - _blk(lse_ref, rows)[:, :1]), 0.0)
         dp = jax.lax.dot_general(
-            do_ref[0], v_ref[0],
+            _blk(do_ref, rows), _blk(v_ref, cols),
             (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, :1])
-        acc_ref[:] += jax.lax.dot_general(
+        ds = p * (dp - _blk(delta_ref, rows)[:, :1])
+        acc_ref[_ix(rows)] += jax.lax.dot_general(
             ds.astype(kb.dtype), kb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     if window is not None:
-        pl.when(kb_i >= 0)(_accumulate)
+        live, o = kb_i >= 0, lambda: n_k - 1 - step
     elif causal:
         # key blocks strictly in the future of this query block are dead
-        pl.when(kb_i <= qb_i)(_accumulate)
+        live, o = kb_i <= qb_i, lambda: qb_i - kb_i
     else:
-        _accumulate()
+        live, o = None, None
+    _by_kind(live, o, _cut_pairs(n_k, blk, tile, causal, strict, window),
+             n_k, _accumulate, functools.partial(_row_tiles, blk=blk))
 
     @pl.when(step == n_k - 1)
     def _finish():
         dq_ref[0] = (acc_ref[:] * scale).astype(dq_ref.dtype)
 
 
-def _dkv_kernel(blk: int, t: int, scale: float, causal: bool,
+def _dkv_kernel(blk: int, tile: int, t: int, scale: float, causal: bool,
                 strict: bool, n_q: int, window, n_blk: int,
                 k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_acc, dv_acc):
@@ -619,29 +869,33 @@ def _dkv_kernel(blk: int, t: int, scale: float, causal: bool,
 
     # causal: this key block only receives gradient from query blocks at
     # or after it (q0 >= k0 for some overlap) — skip strictly-past ones
-    def _accumulate():
-        qb = q_ref[0]
-        kb = k_ref[0]
-        dob = do_ref[0]
-        s, ok = _scores(qb, kb, t, k0, q0, scale, causal, strict, window)
-        p = jnp.where(ok, jnp.exp(s - lse_ref[0][:, :1]), 0.0)
-        dv_acc[:] += jax.lax.dot_general(
+    def _accumulate(rows=None, cols=None):
+        qb = _blk(q_ref, rows)
+        kb = _blk(k_ref, cols)
+        dob = _blk(do_ref, rows)
+        s, ok = _scores(qb, kb, t, _past(k0, cols), _past(q0, rows), scale,
+                        causal, strict, window)
+        p = jnp.where(ok, jnp.exp(s - _blk(lse_ref, rows)[:, :1]), 0.0)
+        dv_acc[_ix(cols)] += jax.lax.dot_general(
             p.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(
-            dob, v_ref[0], (((1,), (1,)), ((), ())),
+            dob, _blk(v_ref, cols), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, :1])
-        dk_acc[:] += jax.lax.dot_general(
+        ds = p * (dp - _blk(delta_ref, rows)[:, :1])
+        dk_acc[_ix(cols)] += jax.lax.dot_general(
             ds.astype(qb.dtype), qb, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     if window is not None:
-        pl.when(qb_i < n_blk)(_accumulate)   # the band ends past row T
+        # the band ends past row T; step j sits j blocks past the diagonal
+        live, o = qb_i < n_blk, lambda: step
     elif causal:
-        pl.when(qb_i >= kb_i)(_accumulate)
+        live, o = qb_i >= kb_i, lambda: qb_i - kb_i
     else:
-        _accumulate()
+        live, o = None, None
+    _by_kind(live, o, _cut_pairs(n_q, blk, tile, causal, strict, window),
+             n_q, _accumulate, functools.partial(_key_tiles, blk=blk))
 
     @pl.when(step == n_q - 1)
     def _finish():
@@ -650,6 +904,32 @@ def _dkv_kernel(blk: int, t: int, scale: float, causal: bool,
 
 
 # --------------------------------------------------------------------- #
+def _traced_once(kernel):
+    """``kernel`` as a function whose Python runs once. A kernel body is a
+    pure function of its refs' shapes, and a model calls one attention at
+    many sites (GPT-2's step 24 times, and twice more while the harness
+    asks for its shapes), each of which would trace the body anew: about
+    0.1 s a site on the chip's host for the whole-pair bodies, and several
+    times that for a kernel with cut pairs (PR 31). So the first site's
+    jaxpr is kept and later sites replay it, one bind an equation."""
+    kept = {}
+
+    def run(*refs):
+        key = tuple(jax.typeof(r) for r in refs)
+        if key not in kept:
+            kept[key] = jax.make_jaxpr(kernel)(*refs)
+        jax.core.eval_jaxpr(kept[key].jaxpr, kept[key].consts, *refs)
+
+    return run
+
+
+@functools.lru_cache(maxsize=None)
+def _onepass_kernel(*static):
+    """The one-pass backward's body for one set of static arguments, the
+    same object at every call site so that it is traced once."""
+    return _traced_once(functools.partial(_onepass_bwd_kernel, *static))
+
+
 def _kv_index(group: int):
     """Row of the folded ``[B * H_kv, T, D]`` key/value array that query
     row ``b`` of ``[B * H, T, D]`` reads: query head ``n`` reads
@@ -683,8 +963,8 @@ def _onepass_call(bh: int, t: int, tp: int, dp: int, block: int,
                                 memory_space=pltpu.VMEM)
     kv_dtype = in_dtype if group == 1 else jnp.float32
     return pl.pallas_call(
-        functools.partial(_onepass_bwd_kernel, block, t, scale,
-                          causal, strict, n_blk, window),
+        _onepass_kernel(block, _tile_edge(block), t, scale, causal, strict,
+                        n_blk, window),
         out_shape=(
             jax.ShapeDtypeStruct((bh, tp, dp), kv_dtype),
             jax.ShapeDtypeStruct((bh, tp, dp), kv_dtype),
@@ -756,12 +1036,16 @@ def _make_flash(bh: int, t: int, d: int, causal: bool, dtype_name: str,
                                    memory_space=pltpu.VMEM)
     acc_scratch = pltpu.VMEM((block, dp), jnp.float32)
     row_scratch = pltpu.VMEM((block, _ROWW), jnp.float32)
+    static = (block, _tile_edge(block), t, scale, causal, strict, n_in,
+              window)
+    fwd_kernel = _traced_once(functools.partial(_fwd_kernel, *static))
+    dq_kernel = _traced_once(functools.partial(_dq_kernel, *static))
+    dkv_kernel = _traced_once(functools.partial(_dkv_kernel, *static, n_blk))
 
     def fwd_call(q, k, v):
         qp, kp, vp = pad_qkv(q), pad_qkv(k), pad_qkv(v)
         o, lse = pl.pallas_call(
-            functools.partial(_fwd_kernel, block, t, scale, causal,
-                              strict, n_in, window),
+            fwd_kernel,
             out_shape=(
                 jax.ShapeDtypeStruct((bh, tp, dp), in_dtype),
                 jax.ShapeDtypeStruct((bh, tp, _ROWW), jnp.float32),
@@ -829,8 +1113,7 @@ def _make_flash(bh: int, t: int, d: int, causal: bool, dtype_name: str,
                 vmem_limit_bytes=_vmem_limit_bytes())
             kv_dtype = in_dtype if group == 1 else jnp.float32
             dq = pl.pallas_call(
-                functools.partial(_dq_kernel, block, t, scale, causal,
-                                  strict, n_in, window),
+                dq_kernel,
                 out_shape=jax.ShapeDtypeStruct((bh, tp, dp), in_dtype),
                 grid=grid,
                 in_specs=[blk(outer), blk(kv_inner), blk(kv_inner),
@@ -841,8 +1124,7 @@ def _make_flash(bh: int, t: int, d: int, causal: bool, dtype_name: str,
                 compiler_params=split_params,
             )(qp, kp, vp, dop, lse, delta)
             dk, dv = pl.pallas_call(
-                functools.partial(_dkv_kernel, block, t, scale, causal,
-                                  strict, n_in, window, n_blk),
+                dkv_kernel,
                 out_shape=(
                     jax.ShapeDtypeStruct((bh, tp, dp), kv_dtype),
                     jax.ShapeDtypeStruct((bh, tp, dp), kv_dtype),
@@ -882,7 +1164,8 @@ def _folded(q, k, v, causal: bool, window, with_lse: bool, strict: bool):
     if window is not None and window >= t:
         window = None   # the band covers every causal key
     block, onepass = _resolve_block(t, d, q.dtype, bh=b * h,
-                                    group=h // h_kv)
+                                    group=h // h_kv,
+                                    mask=(causal, strict, window))
     fn = _make_flash(b * h, t, d, causal, str(q.dtype), block,
                      with_lse=with_lse, strict=strict, onepass=onepass,
                      window=window, group=h // h_kv)

@@ -138,3 +138,34 @@ class HoldHostGather:
 def hold_host_gather():
     """``with hold_host_gather(runtime) as hold:`` (see HoldHostGather)."""
     return HoldHostGather
+
+
+@pytest.fixture()
+def flash_tiled(monkeypatch):
+    """``flash_tiled(q, k, v, block=, tile=, onepass=, ...)``: the flash
+    kernels of ops/flash_attention.py built at a small ``block`` whose
+    cut pairs work in sub-tiles of ``tile`` (the module's ``_TILE``, a
+    constant of 256 on the chip), interpreted. ``[B, T, H, D]`` in and
+    out; with ``with_lse`` also the ``[B, T, H]`` logsumexp."""
+    import importlib
+    fa = importlib.import_module("split_learning_tpu.ops.flash_attention")
+
+    def call(q, k, v, *, block, tile, onepass, causal=True, window=None,
+             strict=False, with_lse=False):
+        monkeypatch.setattr(fa, "_TILE", tile)
+        fa._make_flash.cache_clear()   # the edge is no part of its key
+        b, t, h, d = q.shape
+        fn = fa._make_flash(b * h, t, d, causal, str(q.dtype), block,
+                            with_lse=with_lse, strict=strict,
+                            onepass=onepass, window=window,
+                            group=h // k.shape[2])
+        fold = lambda x: jnp.transpose(x, (0, 2, 1, 3)).reshape(-1, t, d)
+        out = fn(fold(q), fold(k), fold(v))
+        o = out[0] if with_lse else out
+        o = jnp.transpose(o.reshape(b, h, t, d), (0, 2, 1, 3))
+        if with_lse:
+            return o, jnp.transpose(out[1].reshape(b, h, t), (0, 2, 1))
+        return o
+
+    yield call
+    fa._make_flash.cache_clear()

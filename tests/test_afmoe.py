@@ -498,6 +498,68 @@ def test_window_and_grouped_heads_match_the_dense_band(
         np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5)
 
 
+# blocks of 128 in sub-tiles of 32 or 64, four query heads a key/value
+# head: window 256 is Trinity's case (a multiple of the block: diagonal,
+# one whole pair, a far edge cut above its diagonal), 200 cuts the last
+# two pairs of the band, 512 at T 512 is clamped to the blocks there are
+@pytest.mark.parametrize("onepass", [True, False], ids=["onepass", "split"])
+@pytest.mark.parametrize("t,tile,window", [
+    (512, 32, 256), (512, 64, 200), (640, 32, 129), (512, 32, 500)])
+def test_cut_band_edges_match_the_dense_band(flash_tiled, onepass, t, tile,
+                                             window):
+    """Forward and all three gradients where the band's edge pairs run
+    their live sub-tiles alone, over grouped heads, both backward forms."""
+    q, k, v, w = qkv(t, 4, 1, b=1)
+    f = lambda fn: jax.value_and_grad(
+        lambda a, b, c: jnp.sum(fn(a, b, c) * w), argnums=(0, 1, 2))
+    want = f(lambda a, b, c: full_attention(
+        a, b, c, causal=True, window=window))(q, k, v)
+    got = f(lambda a, b, c: flash_tiled(
+        a, b, c, block=128, tile=tile, onepass=onepass, window=window))(
+            q, k, v)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5, atol=1e-4)
+    for a, b in zip(got[1], want[1]):
+        np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5)
+
+
+@pytest.mark.parametrize("onepass", ["", "0"], ids=["onepass", "split"])
+@pytest.mark.parametrize("kw", [
+    dict(causal=True), dict(causal=True, window=2048),
+    dict(causal=True, window=512), dict(causal=True, strict=True),
+    dict(causal=False)],
+    ids=["causal", "window-2048", "window-512", "strict", "not-causal"])
+def test_tiled_kernels_keep_their_calls_and_operands(monkeypatch, onepass,
+                                                     kw):
+    """What the trace readers tell the kernels apart by (``benchmarks/
+    trace_reduce.py:short_name``): at blocks of 1024 and 512, where the
+    cut pairs run in sub-tiles, an attention call's gradient holds the
+    ``pallas_call``s it held before PR 31 with the operands they had
+    (forward 3; one-pass backward 6; split backward 6 and 6), and a call
+    with no causal mask traces to the letter as with one tile a block."""
+    import importlib
+    fa = importlib.import_module("split_learning_tpu.ops.flash_attention")
+    monkeypatch.setenv("SLT_FLASH_ONEPASS_T", onepass)
+    q = jnp.zeros((1, 4096, 2, 16))
+    kv = jnp.zeros((1, 4096, 1, 16))
+
+    def traced(tile):
+        monkeypatch.setattr(fa, "_TILE", tile)
+        fa._make_flash.cache_clear()
+        return jax.make_jaxpr(jax.grad(
+            lambda a, b, c: jnp.sum(flash_attention_with_lse(
+                a, b, c, **kw)[0]), argnums=(0, 1, 2)))(q, kv, kv)
+
+    tiled, whole = traced(256), traced(4096)
+    fa._make_flash.cache_clear()
+    operands = lambda j: [len(e.invars) for e in _pallas_calls(j.jaxpr, [])]
+    assert operands(tiled) == operands(whole) == (
+        [3, 6, 6] if onepass else [3, 6])
+    if kw["causal"]:
+        assert len(str(tiled)) > len(str(whole))   # the cut pairs' bodies
+    else:
+        assert str(tiled) == str(whole)
+
+
 def test_no_window_is_the_kernel_it_was():
     """``window=None`` with equal head counts traces the kernels the
     other families run: the same jaxpr as the call without the argument

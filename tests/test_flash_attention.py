@@ -45,6 +45,140 @@ def test_gradients_match_dense(causal):
                                    atol=5e-5, rtol=5e-5)
 
 
+def _mask(tp, causal, strict, window):
+    """The position mask over a padded sequence, as a boolean matrix."""
+    behind = np.arange(tp)[:, None] - np.arange(tp)[None, :]
+    ok = np.ones((tp, tp), bool)
+    if causal:
+        ok &= behind > 0 if strict else behind >= 0
+    if window is not None:
+        ok &= behind < window
+    return ok
+
+
+# blocks of 128: window under / equal to / one over / between / twice the
+# block; T 300 is ragged (three blocks, 84 rows of padding)
+@pytest.mark.parametrize("tile", [32, 64])
+@pytest.mark.parametrize("t,causal,strict,window", [
+    (128, True, False, None), (640, True, False, None),
+    (384, True, True, None), (384, True, False, 64),
+    (640, True, False, 128), (640, True, False, 129),
+    (640, True, False, 200), (640, True, False, 256),
+    (300, True, False, None), (300, True, False, 100),
+    (384, False, False, None)],
+    ids=["one-block", "causal", "strict", "window-under", "window-equal",
+         "window-one-over", "window-between", "window-twice", "ragged",
+         "ragged-window", "not-causal"])
+def test_live_tiles_are_the_masks_own(t, causal, strict, window, tile):
+    """``_live_cols`` / ``_live_rows`` / ``live_tile_share`` against a
+    count over the boolean mask: in every block pair the kernels visit, a
+    sub-tile is run if and only if the position mask leaves one of its
+    entries live (rows and columns past a ragged T are the elementwise
+    mask's: every tile they leave live is among those run)."""
+    import importlib
+    fa = importlib.import_module("split_learning_tpu.ops.flash_attention")
+    block, n = 128, 128 // tile
+    n_blk = -(-t // block)
+    ok = _mask(n_blk * block, causal, strict, window)
+    within = np.arange(n_blk * block) < t
+    real = ok & within[:, None] & within[None, :]
+    n_off = n_blk if window is None else fa._band_blocks(window, block, n_blk)
+    ran = visited = 0
+    for qb in range(n_blk):
+        for kb in range(n_blk):
+            if causal and not 0 <= qb - kb < n_off:
+                continue   # a pair the grid skips whole
+            pair = ok[qb * block:, kb * block:][:block, :block]
+            tiles = pair.reshape(n, tile, n, tile).any(axis=(1, 3))
+            cols = fa._live_cols((qb - kb) * block, block, tile, causal,
+                                 strict, window)
+            got = np.zeros((n, n), bool)
+            for r, (lo, hi) in enumerate(cols):
+                got[r, lo:hi] = True
+            np.testing.assert_array_equal(got, tiles, err_msg=f"{qb},{kb}")
+            back = np.zeros((n, n), bool)
+            for c, (lo, hi) in enumerate(fa._live_rows(cols)):
+                back[lo:hi, c] = True
+            np.testing.assert_array_equal(back, tiles)
+            seen = real[qb * block:, kb * block:][:block, :block]
+            assert not (seen.reshape(n, tile, n, tile).any(axis=(1, 3))
+                        & ~got).any()
+            ran += got.sum()
+            visited += n * n
+    assert fa.live_tile_share(t, block, causal, window, strict,
+                              tile) == pytest.approx(ran / visited)
+    if not causal:
+        assert ran == visited and fa._cut_pairs(
+            n_off, block, tile, causal, strict, window) == {}
+
+
+@pytest.mark.parametrize("t,window,at_512,at_256", [
+    (1024, None, 0.750, 0.625), (8192, None, 0.944, 0.917),
+    (8192, 2048, 0.833, 0.750), (8192, 512, 0.517, 0.388)],
+    ids=["gpt2", "full-t8192", "trinity-window", "phi4flash-window"])
+def test_live_tile_share_at_the_cells_shapes(t, window, at_512, at_256):
+    """ISSUE 31's table: the share of today's sub-tiles that stay live at
+    blocks of 1024, by the mask of each benchmark cell's calls."""
+    from split_learning_tpu.ops.flash_attention import live_tile_share
+    assert live_tile_share(t, 1024, True, window, tile=512) == pytest.approx(
+        at_512, abs=1e-3)
+    assert live_tile_share(t, 1024, True, window, tile=256) == pytest.approx(
+        at_256, abs=1e-3)
+    assert live_tile_share(t, 1024, True, window) == pytest.approx(
+        at_256, abs=1e-3)   # the module's constant
+    assert live_tile_share(t, 1024, False) == 1.0
+
+
+def _dense(q, k, v, strict, window):
+    """Causal attention with its logsumexp, the plain way; a row that
+    sees no key (the first, under ``strict``) gives zeros and NEG_BIG."""
+    from split_learning_tpu.ops.common import NEG_BIG
+    t, g = q.shape[1], q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
+    ok = jnp.asarray(_mask(t, True, strict, window))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    s = jnp.where(ok, s, NEG_BIG)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.where(ok, jnp.exp(s - m), 0.0)
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    seen = l > 0
+    o = jnp.einsum("bhqk,bkhd->bqhd", p / jnp.where(seen, l, 1.0), v)
+    lse = jnp.where(seen, m + jnp.log(jnp.where(seen, l, 1.0)), NEG_BIG)
+    return o, jnp.transpose(lse[..., 0], (0, 2, 1))
+
+
+# blocks of 128 in sub-tiles of 32 or 64: one block is GPT-2's case (the
+# diagonal pair and nothing else), 384 adds whole pairs below it, strict
+# is a ring hop's mask, 300 pads 84 rows and columns
+@pytest.mark.parametrize("onepass", [True, False], ids=["onepass", "split"])
+@pytest.mark.parametrize("t,tile,strict", [
+    (128, 32, False), (384, 32, False), (256, 64, True), (300, 32, False)],
+    ids=["one-block", "many-blocks", "strict", "ragged"])
+def test_cut_pairs_match_dense(flash_tiled, onepass, t, tile, strict):
+    """Forward, logsumexp and all three gradients of the kernels whose
+    diagonal pair runs its live sub-tiles alone, both backward forms."""
+    q, k, v = qkv(t=t, b=1, h=2)
+    ks = jax.random.split(jax.random.PRNGKey(9), 2)
+    w = jax.random.normal(ks[0], q.shape)
+    w_lse = jax.random.normal(ks[1], q.shape[:3])
+
+    def loss(fn):
+        def f(a, b, c):
+            o, lse = fn(a, b, c)
+            lse = jnp.where(lse < -1e20, 0.0, lse)   # rows that see no key
+            return jnp.sum(o * w) + jnp.sum(lse * w_lse)
+        return jax.value_and_grad(f, argnums=(0, 1, 2))
+
+    got = loss(lambda a, b, c: flash_tiled(
+        a, b, c, block=128, tile=tile, onepass=onepass, strict=strict,
+        with_lse=True))(q, k, v)
+    want = loss(lambda a, b, c: _dense(a, b, c, strict, None))(q, k, v)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5, atol=1e-3)
+    for g, wg in zip(got[1], want[1]):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(wg),
+                                   atol=1e-4, rtol=1e-4)
+
+
 @pytest.mark.parametrize("t,block", [(640, 128), (2048, 1024)])
 def test_multi_block_gradients(t, block):
     """Multi-block grids under the adaptive block picker: T=640 tiles as
@@ -189,6 +323,34 @@ def test_large_block_always_preflights(monkeypatch):
     probed.clear()
     assert fa._use_onepass(1024, 512, 128, jnp.bfloat16)
     assert not probed
+
+
+def test_preflight_probes_the_mask_the_call_uses(monkeypatch):
+    """A pair the mask cuts has a body of its own, so the one-pass
+    preflight compiles the call's own (causal, strict, window): the mask
+    reaches the probe, and a verdict is per mask."""
+    import importlib
+    fa = importlib.import_module(
+        "split_learning_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "_vmem_limit_bytes", lambda: 96 * 1024 * 1024)
+    monkeypatch.setattr(fa, "use_interpret", lambda: False)
+    probed = []
+    monkeypatch.setattr(fa, "_onepass_compile_ok",
+                        lambda *a: probed.append(a) or a[-1][2] != 512)
+    q = jnp.zeros((1, 8192, 4, 128), jnp.bfloat16)
+    kv = jnp.zeros((1, 8192, 2, 128), jnp.bfloat16)
+    made = []
+    monkeypatch.setattr(fa, "_make_flash", lambda *a, **kw: made.append(
+        (a[5], kw["onepass"])) or (lambda q, k, v: (
+            (q, q[..., 0]) if kw["with_lse"] else q)))
+    fa.flash_attention(q, kv, kv, causal=True, window=512)
+    fa.flash_attention(q, kv, kv, causal=True)
+    fa.flash_attention_with_lse(q, kv, kv, causal=True, strict=True)
+    assert [p[-1] for p in probed] == [
+        (True, False, 512), (True, False, 512),   # refused at 1024 and 512
+        (True, False, None), (True, True, None)]
+    assert {p[:6] for p in probed[2:]} == {(8192, 128, 1024, "bfloat16", 2, 2)}
+    assert made == [(512, False), (1024, True), (1024, True)]
 
 
 def test_resolve_block_caps_split_form(monkeypatch):

@@ -344,6 +344,34 @@ def test_differential_attention_against_a_dense_two_softmax_form(kind, impl):
         np.testing.assert_allclose(full[:5], want[:5])
 
 
+# blocks of 128 in sub-tiles of 32: the window is under the block, as the
+# cell's 512 is under 1024, so every row block is a diagonal pair cut on
+# both sides and a far pair of which a corner is live; two query heads a
+# key/value head, queries and keys zero-padded to twice their width and
+# the queries scaled by sqrt 2, as ``DiffAttention`` hands them over
+@pytest.mark.parametrize("onepass", [True, False], ids=["onepass", "split"])
+@pytest.mark.parametrize("t,window", [(384, 64), (300, 100)],
+                         ids=["window-under-block", "ragged"])
+def test_flash_kernels_cut_a_window_under_the_block(flash_tiled, onepass, t,
+                                                    window):
+    from split_learning_tpu.ops.ring_attention import full_attention
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    pad = lambda x: jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, 8)))
+    q = pad(jax.random.normal(ks[0], (1, t, 4, 8))) * math.sqrt(2)
+    k = pad(jax.random.normal(ks[1], (1, t, 2, 8)))
+    v = jax.random.normal(ks[2], (1, t, 2, 16))
+    w = jax.random.normal(ks[3], (1, t, 4, 16))
+    f = lambda fn: jax.value_and_grad(
+        lambda a, b, c: jnp.sum(fn(a, b, c) * w), argnums=(0, 1, 2))
+    want = f(lambda a, b, c: full_attention(
+        a, b, c, causal=True, window=window))(q, k, v)
+    got = f(lambda a, b, c: flash_tiled(
+        a, b, c, block=128, tile=32, onepass=onepass, window=window))(q, k, v)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5, atol=1e-4)
+    for a, b in zip(got[1], want[1]):
+        np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5)
+
+
 def test_scopes_name_the_new_parts():
     assert {spans.SSM_CONV, spans.SSM_SCAN, spans.GMU, spans.ATTN_CROSS} <= set(
         spans.DEVICE_SCOPES)
