@@ -2,9 +2,14 @@
 
     python scripts/afmoe_routing.py [--workload trinity-mini-fused-t8192] [--seed N]
 
+(or ``--workload joyai-flash-fused-t8192``: any family whose routed layer is
+``models/afmoe.py``'s.)
+
 For the benchmark cell's check batch at the cell's sizes (on the CPU with
 ``JAX_PLATFORMS=cpu``: the rehearsal's), with the weights the benchmark makes
-from the seed: one forward pass of the cell's plan, and for every routed layer the
+from the seed: one forward pass of the cell's plan (through the final stage's
+own objective where it has one, so that a prediction module's block is
+among them), and for every routed layer the
 pairs per held expert (mean, max, empty experts), their share of the
 worst-case buffer (tokens x experts per token rows), and the rung of the
 layer's ladder of row counts (``models/afmoe.py:pair_rungs``, ``rung_of``:
@@ -51,7 +56,7 @@ def main() -> int:
     spec = config["plan"]
     kw = spec["kwargs"]
     key = weights.seed_key(args.seed)
-    (x, _), = traffic.batches(job, config["data"], args.seed)[0]
+    (x, y), = traffic.batches(job, config["data"], args.seed)[0]
     # the cell's own plan and the weights of the cell's run of this seed
     plan = get_plan(spec["model"], spec["mode"], jnp.dtype(spec["dtype"]), **kw)
     shapes = weights.stage_shapes(plan, x)
@@ -63,24 +68,34 @@ def main() -> int:
         return afmoe.held_pairs(chosen, kw["expert_offset"], kw["experts_held"])[2]
 
     seen = {}
+    norms = ("norm_pre_mlp", "norm_mlp")   # the routed part's input, by family
+    watch = dict(capture_intermediates=lambda module, _: module.name in norms,
+                 mutable=["intermediates"])
 
-    def capture(module, _):
-        return module.name == "norm_pre_mlp"
+    def routed(weights, captured, path=""):
+        """Every captured layer that holds experts, however deep."""
+        for name, sub in captured.items():
+            if "experts" in weights.get(name, {}):
+                m32 = next(sub[n] for n in norms if n in sub)["__call__"][0]
+                seen[path + name] = counts(m32.reshape(-1, m32.shape[-1]),
+                                           weights[name]["experts"])
+            elif isinstance(sub, dict):
+                routed(weights.get(name, {}), sub, path + name + "/")
+
+    def forward(stage, p, h):
+        if stage.objective is not None:
+            return stage.objective(p, h, jnp.asarray(y), **watch)
+        return stage.apply(p, h, **watch)
 
     h = jnp.asarray(x)
     for stage, p in zip(plan.stages, params):
-        h, state = jax.jit(lambda p, h, stage=stage: stage.apply(
-            p, h, capture_intermediates=capture, mutable=["intermediates"]))(p, h)
-        for name, layer in state["intermediates"].items():
-            if "experts" in p["params"].get(name, {}):
-                m32 = layer["norm_pre_mlp"]["__call__"][0]
-                seen[name] = counts(m32.reshape(-1, m32.shape[-1]),
-                                    p["params"][name]["experts"])
+        h, state = jax.jit(forward, static_argnums=0)(stage, p, h)
+        routed(p["params"], state["intermediates"])
     rows = x.size * kw["experts_per_token"]
     expected = rows * kw["experts_held"] / kw["experts_total"]
     rungs = afmoe.pair_rungs(rows, kw["experts_held"], kw["experts_total"])
     fills = []
-    for name in sorted(seen, key=lambda n: int(n[5:])):
+    for name in sorted(seen):
         sizes = [int(s) for s in seen[name]]
         fills.append(sum(sizes))
         print(json.dumps({"layer": name, "pairs_here": sum(sizes),

@@ -11,7 +11,8 @@ in bytes and GB.  Nothing runs, so it says what fits, never how fast.  It
 counts this one program, not what else a process keeps on the device
 (PERF.md section 4: two loaded executables once cost the reference its
 room).  It is the check of the fit rules of ``trinity-mini-fused-t8192``
-(13.0 GB or under) and ``phi4flash-fused-t8192`` (14.5).  ``--remat 0``
+(13.0 GB or under), ``phi4flash-fused-t8192`` and
+``joyai-flash-fused-t8192`` (14.5).  ``--remat 0``
 compiles the step without the family's ``remat``, to see what the values it
 recomputes cost when kept.
 """
@@ -50,7 +51,7 @@ def main() -> int:
 
     import traffic
     import weights
-    from split_learning_tpu.core.losses import cross_entropy
+    from split_learning_tpu.core.losses import plan_loss
     from split_learning_tpu.models.factory import get_plan
     from split_learning_tpu.ops import common
     from split_learning_tpu.runtime.state import (
@@ -75,7 +76,7 @@ def main() -> int:
 
     def step(state, x, y):
         loss, grads = jax.value_and_grad(
-            lambda p: cross_entropy(plan.apply(p, x), y))(state.params)
+            lambda p: plan_loss(plan, p, x, y))(state.params)
         return apply_grads(tx, state, grads), loss
 
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")

@@ -25,5 +25,44 @@ def per_example_cross_entropy(logits: jax.Array,
     return optax.softmax_cross_entropy_with_integer_labels(logits, labels)
 
 
+def final_loss(stage, params, x: jax.Array, labels: jax.Array,
+               loss_op=cross_entropy, logits=None) -> jax.Array:
+    """The loss of a plan's final ``stage`` on its input ``x``: the one
+    function every final-stage loss goes through. ``loss_op`` on the
+    stage's logits, as it always was; or, where the stage carries its
+    own objective (``core/stage.Stage.objective``: its loss reads the
+    labels as an input), that objective's losses, returned as they are
+    for :func:`per_example_cross_entropy` and as their mean for any
+    other ``loss_op``. ``logits``: the stage's output where the caller
+    has made it already (an evaluation that also counts hits)."""
+    if stage.objective is None:
+        if logits is None:
+            logits = stage.apply(params, x)
+        return loss_op(logits, labels)
+    losses = stage.objective(params, x, labels)
+    return losses if loss_op is per_example_cross_entropy else losses.mean()
+
+
+def plan_loss(plan, params, x: jax.Array, labels: jax.Array,
+              loss_op=cross_entropy) -> jax.Array:
+    """:func:`final_loss` of the whole ``plan``: every stage but the last
+    applied to ``x``, then the last stage's loss."""
+    last = plan.num_stages - 1
+    return final_loss(plan.stages[last], params[last],
+                      plan.apply_range(params, x, 0, last), labels, loss_op)
+
+
+def refuse_objective(plan, where: str) -> None:
+    """Raise for a plan whose final stage carries its own objective, on
+    a path that computes its loss from logits alone: such a path would
+    train on the first loss and drop the rest without a word."""
+    stage = plan.stages[-1]
+    if stage.objective is not None:
+        raise ValueError(
+            f"stage {stage.name!r} carries its own objective (its loss "
+            f"reads the labels as an input), which {where} cannot run: it "
+            "takes a loss from logits alone")
+
+
 def accuracy(logits: jax.Array, labels: jax.Array) -> jax.Array:
     return jnp.mean((jnp.argmax(logits, axis=-1) == labels).astype(jnp.float32))

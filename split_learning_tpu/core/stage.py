@@ -32,11 +32,22 @@ class Stage:
     ``apply(params, x) -> y`` must be jit-traceable (static shapes, no
     Python side effects) so that a stage can live inside a pjit'd pipeline
     or be jitted standalone on the client/server.
+
+    ``objective(params, x, labels) -> losses`` is a final stage's own
+    training objective, one loss a token (or a row), for a stage whose
+    loss reads the labels as an input and not only as targets (a module
+    that embeds the next token to predict the one after it,
+    models/joyai_llm_flash.py). ``None``, as every other stage has it:
+    the loss is an operator on ``apply``'s logits. Every final-stage
+    loss goes through ``core/losses.final_loss``, which takes the one or
+    the other; a path that cannot carry an objective refuses the stage
+    (``core/losses.refuse_objective``).
     """
 
     name: str
     init: Callable[[jax.Array, Array], Params]  # (rng, sample_input) -> params
     apply: Callable[[Params, Array], Array]     # (params, x) -> y
+    objective: Optional[Callable[[Params, Array, Array], Array]] = None
 
     def out_spec(self, params: Params, x_spec: jax.ShapeDtypeStruct) -> jax.ShapeDtypeStruct:
         """Shape-infer this stage's output without running it."""
@@ -73,23 +84,36 @@ def remat_plan(plan: "SplitPlan") -> "SplitPlan":
     the fused/pipelined single-program paths (``Config.remat``).
     """
     stages = tuple(
-        dataclasses.replace(s, apply=jax.checkpoint(s.apply))
+        dataclasses.replace(
+            s, apply=jax.checkpoint(s.apply),
+            objective=s.objective and jax.checkpoint(s.objective))
         for s in plan.stages)
     return dataclasses.replace(plan, stages=stages)
 
 
-def from_flax(name: str, module: Any) -> Stage:
+def from_flax(name: str, module: Any,
+              objective: Optional[str] = None) -> Stage:
     """Wrap a flax.linen Module as a Stage.
 
     Extra keyword arguments pass through to ``module.apply`` — the
     transformer stages use this for their KV-cache decode modes
     (``cache_len=``/``decode_cache=``/``pos=``, models/transformer.py);
-    plain ``apply(params, x)`` is unchanged for every other caller."""
+    plain ``apply(params, x)`` is unchanged for every other caller.
+    ``objective`` names the module's method ``(x, labels) -> losses``
+    that is the stage's own objective (:class:`Stage`); ``init`` then
+    goes through it, with one label for each index of all but ``x``'s
+    last axis, so that the weights only the objective reads are made."""
+    apply = lambda params, x, **kw: module.apply(params, x, **kw)
+    if objective is None:
+        return Stage(name=name, apply=apply,
+                     init=lambda rng, sample: module.init(rng, sample))
     return Stage(
-        name=name,
-        init=lambda rng, sample: module.init(rng, sample),
-        apply=lambda params, x, **kw: module.apply(params, x, **kw),
-    )
+        name=name, apply=apply,
+        init=lambda rng, sample: module.init(
+            rng, sample, jnp.zeros(jnp.shape(sample)[:-1], jnp.int32),
+            method=objective),
+        objective=lambda params, x, labels, **kw: module.apply(
+            params, x, labels, method=objective, **kw))
 
 
 @dataclasses.dataclass(frozen=True)

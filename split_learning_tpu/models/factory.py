@@ -127,6 +127,17 @@ def _phi4flash(mode: str, dtype: Any, **kw: Any) -> SplitPlan:
     return phi4flash_plan(mode=mode, dtype=dtype, **kw)
 
 
+@register_model("joyai_llm_flash")
+def _joyai_llm_flash(mode: str, dtype: Any, **kw: Any) -> SplitPlan:
+    """Latent attention (keys wider than values), this party's share of
+    the routed experts beside a shared one, and a multi-token-prediction
+    module whose loss the final stage carries as its own objective
+    (models/joyai_llm_flash.py)."""
+    from split_learning_tpu.models.joyai_llm_flash import (
+        joyai_llm_flash_plan)
+    return joyai_llm_flash_plan(mode=mode, dtype=dtype, **kw)
+
+
 def get_plan(model: str = "split_cnn", mode: str = "split",
              dtype: Any = jnp.float32, **size_kw: Any) -> SplitPlan:
     """Build the SplitPlan for a model family under a learning mode.

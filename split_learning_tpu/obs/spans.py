@@ -230,8 +230,14 @@ SSM_CONV = "ssm_conv"        # the causal depthwise convolution and its silu
 SSM_SCAN = "ssm_scan"        # the selective-scan kernel's calls
 GMU = "gmu"                  # a gated memory unit, products included
 ATTN_CROSS = "attn_cross"    # attention over another layer's keys and values
+# -- and of the joyai_llm_flash family (models/joyai_llm_flash.py), which
+# shares the three routed-layer scopes above
+ATTN_LATENT = "attn_latent"  # the flash calls of a latent-attention layer
+MLA_PROJ = "mla_proj"        # its down- and up-projections, norms, rotary
+MTP = "mtp"                  # the multi-token-prediction module, whole
 DEVICE_SCOPES = (ATTN_WINDOW, ATTN_FULL, MOE_ROUTE, MOE_EXPERTS, MOE_SHARED,
-                 SSM_CONV, SSM_SCAN, GMU, ATTN_CROSS)
+                 SSM_CONV, SSM_SCAN, GMU, ATTN_CROSS, ATTN_LATENT, MLA_PROJ,
+                 MTP)
 
 # the client-level phases that tile a step — the denominator of the
 # compute-vs-wire fraction (encode/wire are sub-phases of transport and

@@ -73,6 +73,12 @@ Design (the canonical TPU flash schedule):
   query head in float32 and the wrapper sums each group's.
   With ``window=None`` and equal head counts every kernel is traced
   exactly as before these two existed (tests/test_afmoe.py).
+- Values of another width than the keys (``[B, T, H_kv, D_v]``; latent
+  attention: keys of 192 under values of 128): every operand pads to
+  its own lane tiles, so QK^T runs at the keys' padded width and PV,
+  the output and dV at the values'; the scale is the keys'. A call
+  whose widths are equal is traced as before
+  (tests/test_joyai_llm_flash.py).
 
 Like every op in this package there is a pure-jnp reference
 (:func:`split_learning_tpu.ops.ring_attention.full_attention`) and the
@@ -173,16 +179,19 @@ def _vmem_limit_bytes() -> int:
         "figure for this generation (ops/flash_attention.py)")
 
 
-def _onepass_resident_bytes(tp: int, d: int, itemsize: int) -> int:
+def _onepass_resident_bytes(tp: int, d: int, itemsize: int,
+                            d_v: int | None = None) -> int:
     """True VMEM footprint of the one-pass backward's whole-sequence
-    refs. Per padded row: Q + dO in the storage dtype, the f32 dQ
-    output, and the LSE/delta rows — which cost a full 128-lane tile
+    refs. Per padded row: Q (at the keys' width ``d``) + dO (at the
+    values' ``d_v``, ``d`` where not given) in the storage dtype, the f32
+    dQ output, and the LSE/delta rows — which cost a full 128-lane tile
     each despite _ROWW=8, because VMEM pads the minor dimension to the
     lane width. Pallas double-buffers every ref (constant index maps
     included — the 16.5 MiB scoped-allocation failure at T=4096 bf16
     was exactly 2x the naive sum), hence the factor 2."""
     dp = round_up(d, LANE)
-    per_row = dp * (2 * itemsize + 4) + 2 * LANE * 4
+    dvp = dp if d_v is None else round_up(d_v, LANE)
+    per_row = dp * (itemsize + 4) + dvp * itemsize + 2 * LANE * 4
     return 2 * tp * per_row
 
 
@@ -213,7 +222,8 @@ _SPLIT_BLOCK_MAX = 512
 
 
 def _resolve_block(t: int, d: int, dtype, bh: int = 2, group: int = 1,
-                   mask: tuple = (False, False, None)) -> tuple[int, bool]:
+                   mask: tuple = (False, False, None),
+                   d_v: int | None = None) -> tuple[int, bool]:
     """(block, onepass) for a public entry point: the swept default
     edge when the one-pass backward (which preflight-confirms itself)
     carries the gradient, capped to :data:`_SPLIT_BLOCK_MAX` when the
@@ -223,7 +233,8 @@ def _resolve_block(t: int, d: int, dtype, bh: int = 2, group: int = 1,
     the program's batch*heads and ``mask`` its ``(causal, strict,
     window)``, forwarded so the preflight probes the grid shape and the
     kernel bodies the user will actually compile (see
-    :func:`_onepass_compile_ok`).
+    :func:`_onepass_compile_ok`). ``d_v`` is the values' width where it
+    is not the keys' ``d``.
 
     Cost note: resolving the backward form eagerly means even a
     forward-only call at a >512 edge pays the one-pass preflight
@@ -234,16 +245,17 @@ def _resolve_block(t: int, d: int, dtype, bh: int = 2, group: int = 1,
     user-path compile error."""
     import os
     block = _pick_block(t)
-    onepass = _use_onepass(t, block, d, dtype, bh, group, mask)
+    onepass = _use_onepass(t, block, d, dtype, bh, group, mask, d_v)
     if (not onepass and block > _SPLIT_BLOCK_MAX
             and not os.environ.get("SLT_FLASH_BLOCK")):
         block = _SPLIT_BLOCK_MAX
-        onepass = _use_onepass(t, block, d, dtype, bh, group, mask)
+        onepass = _use_onepass(t, block, d, dtype, bh, group, mask, d_v)
     return block, onepass
 
 
 def _use_onepass(t: int, block: int, d: int, dtype, bh: int = 2,
-                 group: int = 1, mask: tuple = (False, False, None)) -> bool:
+                 group: int = 1, mask: tuple = (False, False, None),
+                 d_v: int | None = None) -> bool:
     """Backward-form selection: one-pass while its whole-sequence
     residency (see :func:`_onepass_resident_bytes`) fits 2/3 of the
     device's scoped-VMEM limit, leaving the rest for the
@@ -267,7 +279,7 @@ def _use_onepass(t: int, block: int, d: int, dtype, bh: int = 2,
     env = os.environ.get("SLT_FLASH_ONEPASS_T")
     if env:   # empty string = unset, like SLT_FLASH_AUTO_T
         return tp <= int(env)
-    resident = _onepass_resident_bytes(tp, d, dtype.itemsize)
+    resident = _onepass_resident_bytes(tp, d, dtype.itemsize, d_v)
     if resident > _vmem_limit_bytes() * 2 // 3:
         return False
     # Skip the preflight only inside the margin it was derived for:
@@ -277,8 +289,9 @@ def _use_onepass(t: int, block: int, d: int, dtype, bh: int = 2,
     # alone can blow the default limit even at tiny T.
     if ((resident > _DEFAULT_LIMIT_SAFE or block > _SPLIT_BLOCK_MAX)
             and not use_interpret()):
+        widths = () if d_v is None else (round_up(d_v, LANE),)
         return _onepass_compile_ok(tp, round_up(d, LANE), block, dtype.name,
-                                   min(bh, 2), group, mask)
+                                   min(bh, 2), group, mask, *widths)
     return True
 
 
@@ -286,7 +299,8 @@ def _use_onepass(t: int, block: int, d: int, dtype, bh: int = 2,
 def _onepass_compile_ok(tp: int, dp: int, block: int,
                         dtype_name: str, bh_probe: int = 2,
                         group: int = 1,
-                        mask: tuple = (False, False, None)) -> bool:
+                        mask: tuple = (False, False, None),
+                        dvp: int | None = None) -> bool:
     """Preflight: does the one-pass backward *compile* on this device at
     the padded shape? ``vmem_limit_bytes`` is serialized into the Mosaic
     custom call as ``scoped_memory_configs`` (verified against the
@@ -309,16 +323,20 @@ def _onepass_compile_ok(tp: int, dp: int, block: int,
     score temporaries are ``[rows, tile]`` where the whole pair's are
     ``[block, block]``, so they ask for less, but that is the compiler's
     to say. Grouped heads leave their dK/dV blocks in float32, so
-    ``group`` is part of the probe too."""
+    ``group`` is part of the probe too, and so is ``dvp``, the values'
+    padded width where it is not the keys' ``dp``."""
     causal, strict, window = mask
+    dvp = dp if dvp is None else dvp
     call = _onepass_call(bh_probe, tp, tp, dp, block, 1.0, causal, strict,
-                         jnp.dtype(dtype_name), window, group)
-    seq = jax.ShapeDtypeStruct((bh_probe, tp, dp), jnp.dtype(dtype_name))
-    kv = jax.ShapeDtypeStruct((max(1, bh_probe // group), tp, dp),
-                              jnp.dtype(dtype_name))
+                         jnp.dtype(dtype_name), window, group, dvp)
+    of = lambda rows, lanes: jax.ShapeDtypeStruct(
+        (rows, tp, lanes), jnp.dtype(dtype_name))
+    kv_rows = max(1, bh_probe // group)
     row = jax.ShapeDtypeStruct((bh_probe, tp, _ROWW), jnp.float32)
     try:
-        jax.jit(call).lower(kv, kv, seq, seq, row, row).compile()
+        jax.jit(call).lower(of(kv_rows, dp), of(kv_rows, dvp),
+                            of(bh_probe, dp), of(bh_probe, dvp),
+                            row, row).compile()
         return True
     except Exception as e:
         # Broad on purpose: ANY compile failure means the two-kernel
@@ -727,6 +745,14 @@ def _onepass_bwd_kernel(blk: int, tile: int, t: int, scale: float,
     kb = k_ref[0]
     vb = v_ref[0]
 
+    def zero_pair(rows):
+        """(dK, dV) of ``rows`` keys that no query reaches: one array for
+        both where the values are as wide as the keys."""
+        dk = jnp.zeros((rows, dq_ref.shape[-1]), jnp.float32)
+        if dv_ref.shape[-1] == dq_ref.shape[-1]:
+            return dk, dk
+        return dk, jnp.zeros((rows, dv_ref.shape[-1]), jnp.float32)
+
     def pair(q0, n, kb, vb, k0, dk, dv):
         """Rows ``[q0, q0 + n)`` of the queries against the keys ``kb`` at
         ``k0``: dQ accumulates in place, (dK, dV) are returned added to
@@ -765,8 +791,7 @@ def _onepass_bwd_kernel(blk: int, tile: int, t: int, scale: float,
         parts = []
         for c, (lo, hi) in enumerate(_live_rows(ranges)):
             if lo == hi:
-                z = jnp.zeros((tile, dq_ref.shape[-1]), jnp.float32)
-                parts.append((z, z))
+                parts.append(zero_pair(tile))
                 continue
             keys = slice(c * tile, (c + 1) * tile)
             parts.append(pair(j * blk + lo * tile, (hi - lo) * tile,
@@ -775,14 +800,13 @@ def _onepass_bwd_kernel(blk: int, tile: int, t: int, scale: float,
         dk, dv = (jnp.concatenate(x, axis=0) for x in zip(*parts))
         return carry[0] + dk, carry[1] + dv
 
-    zeros = jnp.zeros(kb.shape[:1] + (dq_ref.shape[-1],), jnp.float32)
     # causal: query blocks strictly before this key block are dead;
     # banded: so are those past the band (_band_blocks). Query block
     # kb_i + o sits o blocks past the diagonal: the cut ones run their
     # live sub-tiles, the whole ones between them the loop.
     n_off = n_q if window is None else _band_blocks(window, blk, n_q)
     cuts = _cut_pairs(n_off, blk, tile, causal, strict, window)
-    carry = (zeros, zeros)
+    carry = zero_pair(kb.shape[0])
     if 0 in cuts:
         carry = cut(kb_i, cuts[0], carry)
     whole = [o for o in range(n_off) if o not in cuts]   # one run
@@ -940,7 +964,7 @@ def _kv_index(group: int):
 
 def _onepass_call(bh: int, t: int, tp: int, dp: int, block: int,
                   scale: float, causal: bool, strict: bool, in_dtype,
-                  window=None, group: int = 1):
+                  window=None, group: int = 1, dvp: int | None = None):
     """The one-pass backward's ``pallas_call``, shared verbatim between
     the real VJP (:func:`_make_flash`) and the preflight probe
     (:func:`_onepass_compile_ok`) so the probe compiles exactly what the
@@ -950,29 +974,32 @@ def _onepass_call(bh: int, t: int, tp: int, dp: int, block: int,
 
     Grouped heads (``group`` query heads a key/value head): K/V blocks
     are read from row ``b // group``, and dK/dV come out per *query*
-    head in float32 — the caller sums each group's."""
+    head in float32 — the caller sums each group's. ``dvp`` is the
+    values' padded width (V, dO and dV) where it is not ``dp``, the
+    queries' and keys' (Q, K, dQ and dK)."""
     n_blk = tp // block
+    dvp = dp if dvp is None else dvp
     kv = _kv_index(group)
-    seq = pl.BlockSpec((1, tp, dp), lambda b, k: (b, 0, 0),
-                       memory_space=pltpu.VMEM)
-    seqrow = pl.BlockSpec((1, tp, _ROWW), lambda b, k: (b, 0, 0),
-                          memory_space=pltpu.VMEM)
-    kin = lambda: pl.BlockSpec((1, block, dp), lambda b, k: (kv(b), k, 0),
-                               memory_space=pltpu.VMEM)
-    kout = lambda: pl.BlockSpec((1, block, dp), lambda b, k: (b, k, 0),
-                                memory_space=pltpu.VMEM)
+    seq = lambda lanes: pl.BlockSpec((1, tp, lanes), lambda b, k: (b, 0, 0),
+                                     memory_space=pltpu.VMEM)
+    seqrow = seq(_ROWW)
+    kin = lambda lanes: pl.BlockSpec(
+        (1, block, lanes), lambda b, k: (kv(b), k, 0),
+        memory_space=pltpu.VMEM)
+    kout = lambda lanes: pl.BlockSpec(
+        (1, block, lanes), lambda b, k: (b, k, 0), memory_space=pltpu.VMEM)
     kv_dtype = in_dtype if group == 1 else jnp.float32
     return pl.pallas_call(
         _onepass_kernel(block, _tile_edge(block), t, scale, causal, strict,
                         n_blk, window),
         out_shape=(
             jax.ShapeDtypeStruct((bh, tp, dp), kv_dtype),
-            jax.ShapeDtypeStruct((bh, tp, dp), kv_dtype),
+            jax.ShapeDtypeStruct((bh, tp, dvp), kv_dtype),
             jax.ShapeDtypeStruct((bh, tp, dp), jnp.float32),
         ),
         grid=(bh, n_blk),
-        in_specs=[kin(), kin(), seq, seq, seqrow, seqrow],
-        out_specs=(kout(), kout(), seq),
+        in_specs=[kin(dp), kin(dvp), seq(dp), seq(dvp), seqrow, seqrow],
+        out_specs=(kout(dp), kout(dvp), seq(dp)),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_vmem_limit_bytes()),
         interpret=use_interpret(),
@@ -982,8 +1009,15 @@ def _onepass_call(bh: int, t: int, tp: int, dp: int, block: int,
 @functools.lru_cache(maxsize=None)
 def _make_flash(bh: int, t: int, d: int, causal: bool, dtype_name: str,
                 block: int, with_lse: bool = False, strict: bool = False,
-                onepass: bool = False, window=None, group: int = 1):
+                onepass: bool = False, window=None, group: int = 1,
+                d_v: int | None = None):
     """Custom-VJP flash attention for one static ([BH, T, D], causal).
+
+    ``d`` is the width of a query and of a key, and sets the scale
+    ``d ** -0.5``; ``d_v`` the width of a value and of the output where
+    it differs (latent attention: keys of 192 under values of 128). Each
+    pads to its own lane tiles, so the second product runs at the
+    values' width whatever the keys'.
 
     ``with_lse=True`` additionally returns the per-row logsumexp as a
     differentiable output — the hook ring attention composes on
@@ -998,13 +1032,15 @@ def _make_flash(bh: int, t: int, d: int, causal: bool, dtype_name: str,
     scale = d ** -0.5
     tp = round_up(t, block)
     dp = round_up(d, LANE)
+    d_v = d if d_v is None else d_v
+    dvp = round_up(d_v, LANE)
     n_blk = tp // block
     n_in = n_blk if window is None else _band_blocks(window, block, n_blk)
     grid = (bh, n_blk, n_in)
     kv = _kv_index(group)
 
-    def pad_qkv(x):
-        return pad_axis(pad_axis(x, 1, tp), 2, dp)
+    def pad_qkv(x, lanes=dp):
+        return pad_axis(pad_axis(x, 1, tp), 2, lanes)
 
     def outer(b, i, k):   # block of the outer (grid dim 1) axis
         return (b, i, 0)
@@ -1030,11 +1066,12 @@ def _make_flash(bh: int, t: int, d: int, causal: bool, dtype_name: str,
         def q_inner(b, i, k):
             return (b, jnp.minimum(i + k, n_blk - 1), 0)
 
-    blk = lambda idx: pl.BlockSpec((1, block, dp), idx,
-                                   memory_space=pltpu.VMEM)
-    row = lambda idx: pl.BlockSpec((1, block, _ROWW), idx,
-                                   memory_space=pltpu.VMEM)
+    blk = lambda idx, lanes=dp: pl.BlockSpec((1, block, lanes), idx,
+                                             memory_space=pltpu.VMEM)
+    vblk = lambda idx: blk(idx, dvp)     # a block of V, O, dO or dV
+    row = lambda idx: blk(idx, _ROWW)
     acc_scratch = pltpu.VMEM((block, dp), jnp.float32)
+    v_scratch = pltpu.VMEM((block, dvp), jnp.float32)
     row_scratch = pltpu.VMEM((block, _ROWW), jnp.float32)
     static = (block, _tile_edge(block), t, scale, causal, strict, n_in,
               window)
@@ -1043,17 +1080,17 @@ def _make_flash(bh: int, t: int, d: int, causal: bool, dtype_name: str,
     dkv_kernel = _traced_once(functools.partial(_dkv_kernel, *static, n_blk))
 
     def fwd_call(q, k, v):
-        qp, kp, vp = pad_qkv(q), pad_qkv(k), pad_qkv(v)
+        qp, kp, vp = pad_qkv(q), pad_qkv(k), pad_qkv(v, dvp)
         o, lse = pl.pallas_call(
             fwd_kernel,
             out_shape=(
-                jax.ShapeDtypeStruct((bh, tp, dp), in_dtype),
+                jax.ShapeDtypeStruct((bh, tp, dvp), in_dtype),
                 jax.ShapeDtypeStruct((bh, tp, _ROWW), jnp.float32),
             ),
             grid=grid,
-            in_specs=[blk(outer), blk(kv_inner), blk(kv_inner)],
-            out_specs=(blk(outer), row(outer)),
-            scratch_shapes=[acc_scratch, row_scratch, row_scratch],
+            in_specs=[blk(outer), blk(kv_inner), vblk(kv_inner)],
+            out_specs=(vblk(outer), row(outer)),
+            scratch_shapes=[v_scratch, row_scratch, row_scratch],
             interpret=use_interpret(),
             # same per-generation allowance the one-pass backward gets
             # (a limit, not a reservation): at the default <=1024 edges
@@ -1067,8 +1104,8 @@ def _make_flash(bh: int, t: int, d: int, causal: bool, dtype_name: str,
 
     def out_of(o, lse):
         if with_lse:
-            return o[:, :t, :d], lse[:, :t, 0]
-        return o[:, :t, :d]
+            return o[:, :t, :d_v], lse[:, :t, 0]
+        return o[:, :t, :d_v]
 
     @jax.custom_vjp
     def attn(q, k, v):
@@ -1086,7 +1123,7 @@ def _make_flash(bh: int, t: int, d: int, causal: bool, dtype_name: str,
             g, g_lse = g
         # dO stays in the storage dtype so the backward matmuls run the
         # MXU at native rate; delta accumulates in f32
-        dop = pad_axis(pad_axis(g.astype(in_dtype), 1, tp), 2, dp)
+        dop = pad_qkv(g.astype(in_dtype), dvp)
         delta = jnp.sum(dop.astype(jnp.float32) * o.astype(jnp.float32),
                         axis=2, keepdims=True)
         if g_lse is not None:
@@ -1100,7 +1137,7 @@ def _make_flash(bh: int, t: int, d: int, causal: bool, dtype_name: str,
             # block pair (shared builder — see _onepass_call)
             dk, dv, dq = _onepass_call(
                 bh, t, tp, dp, block, scale, causal, strict, in_dtype,
-                window, group)(kp, vp, qp, dop, lse, delta)
+                window, group, dvp)(kp, vp, qp, dop, lse, delta)
             dq = dq.astype(in_dtype)
         else:
             # same per-generation allowance as the fwd call: the
@@ -1116,8 +1153,8 @@ def _make_flash(bh: int, t: int, d: int, causal: bool, dtype_name: str,
                 dq_kernel,
                 out_shape=jax.ShapeDtypeStruct((bh, tp, dp), in_dtype),
                 grid=grid,
-                in_specs=[blk(outer), blk(kv_inner), blk(kv_inner),
-                          blk(outer), row(outer), row(outer)],
+                in_specs=[blk(outer), blk(kv_inner), vblk(kv_inner),
+                          vblk(outer), row(outer), row(outer)],
                 out_specs=blk(outer),
                 scratch_shapes=[acc_scratch],
                 interpret=use_interpret(),
@@ -1127,13 +1164,13 @@ def _make_flash(bh: int, t: int, d: int, causal: bool, dtype_name: str,
                 dkv_kernel,
                 out_shape=(
                     jax.ShapeDtypeStruct((bh, tp, dp), kv_dtype),
-                    jax.ShapeDtypeStruct((bh, tp, dp), kv_dtype),
+                    jax.ShapeDtypeStruct((bh, tp, dvp), kv_dtype),
                 ),
                 grid=grid,
-                in_specs=[blk(kv_outer), blk(kv_outer), blk(q_inner),
-                          blk(q_inner), row(q_inner), row(q_inner)],
-                out_specs=(blk(outer), blk(outer)),
-                scratch_shapes=[acc_scratch, acc_scratch],
+                in_specs=[blk(kv_outer), vblk(kv_outer), blk(q_inner),
+                          vblk(q_inner), row(q_inner), row(q_inner)],
+                out_specs=(blk(outer), vblk(outer)),
+                scratch_shapes=[acc_scratch, v_scratch],
                 interpret=use_interpret(),
                 compiler_params=split_params,
             )(kp, vp, qp, dop, lse, delta)
@@ -1141,10 +1178,10 @@ def _make_flash(bh: int, t: int, d: int, causal: bool, dtype_name: str,
             # per query head in float32: each key/value head's gradient
             # is the sum over the query heads that read it
             fold = lambda x: x.reshape(
-                bh // group, group, tp, dp).sum(1).astype(in_dtype)
+                bh // group, group, tp, x.shape[-1]).sum(1).astype(in_dtype)
             dk, dv = fold(dk), fold(dv)
-        trim = lambda x: x[:, :t, :d]
-        return trim(dq), trim(dk), trim(dv)
+        trim = lambda x, lanes=d: x[:, :t, :lanes]
+        return trim(dq), trim(dk), trim(dv, d_v)
 
     attn.defvjp(vjp_fwd, vjp_bwd)
     return attn
@@ -1154,24 +1191,28 @@ def _folded(q, k, v, causal: bool, window, with_lse: bool, strict: bool):
     """Shared entry: check the mask and the head counts, resolve the
     block, fold ``[B, T, H, D]`` to ``[B * H, T, D]`` and call."""
     b, t, h, d = q.shape
-    h_kv = k.shape[2]
-    if k.shape != v.shape or h % h_kv or k.shape[:2] != (b, t):
+    h_kv, d_v = k.shape[2], v.shape[3]
+    if (k.shape[:3] != v.shape[:3] or k.shape[3] != d or h % h_kv
+            or k.shape[:2] != (b, t)):
         raise ValueError(f"q {q.shape} against k {k.shape}, v {v.shape}: "
-                         "key/value heads must divide the query heads")
+                         "key/value heads must divide the query heads, "
+                         "and a key is as wide as a query")
     if window is not None and (not causal or strict or window < 1):
         raise ValueError("window is the causal band 0 <= i - j < window: "
                          "it needs causal=True, strict=False, window >= 1")
     if window is not None and window >= t:
         window = None   # the band covers every causal key
+    # equal widths are the call every other family makes: no argument
+    widths = {} if d_v == d else {"d_v": d_v}
     block, onepass = _resolve_block(t, d, q.dtype, bh=b * h,
                                     group=h // h_kv,
-                                    mask=(causal, strict, window))
+                                    mask=(causal, strict, window), **widths)
     fn = _make_flash(b * h, t, d, causal, str(q.dtype), block,
                      with_lse=with_lse, strict=strict, onepass=onepass,
-                     window=window, group=h // h_kv)
+                     window=window, group=h // h_kv, **widths)
 
     def fold(x):  # [B, T, H, D] -> [B*H, T, D]
-        return jnp.transpose(x, (0, 2, 1, 3)).reshape(-1, t, d)
+        return jnp.transpose(x, (0, 2, 1, 3)).reshape(-1, t, x.shape[-1])
 
     return fn(fold(q), fold(k), fold(v))
 
@@ -1186,13 +1227,15 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     Pallas kernel forward/backward (compiled on TPU, interpreted
     elsewhere). ``k``/``v`` may hold fewer heads than ``q``
     (``[B, T, H_kv, D]``, ``H % H_kv == 0``): query head ``n`` reads
-    key/value head ``n // (H // H_kv)``. ``window`` (needs ``causal``)
+    key/value head ``n // (H // H_kv)``. ``v`` may be of another width
+    than ``q`` and ``k`` (``[B, T, H_kv, D_v]``); the output is ``[B, T,
+    H, D_v]``. ``window`` (needs ``causal``)
     lets query ``i`` see keys ``j`` with ``0 <= i - j < window``; key
     blocks wholly outside the band are skipped, not masked.
     """
-    b, t, h, d = q.shape
+    b, t, h, _ = q.shape
     o = _folded(q, k, v, causal, window, False, False)
-    return jnp.transpose(o.reshape(b, h, t, d), (0, 2, 1, 3))
+    return jnp.transpose(o.reshape(b, h, t, v.shape[-1]), (0, 2, 1, 3))
 
 
 def flash_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -1216,7 +1259,7 @@ def flash_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array,
     if strict and not causal:
         raise ValueError("strict=True refines the causal mask and "
                          "requires causal=True")
-    b, t, h, d = q.shape
+    b, t, h, _ = q.shape
     o, lse = _folded(q, k, v, causal, window, True, strict)
-    o = jnp.transpose(o.reshape(b, h, t, d), (0, 2, 1, 3))
+    o = jnp.transpose(o.reshape(b, h, t, v.shape[-1]), (0, 2, 1, 3))
     return o, jnp.transpose(lse.reshape(b, h, t), (0, 2, 1))

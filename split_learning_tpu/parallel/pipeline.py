@@ -51,7 +51,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from split_learning_tpu.core.losses import cross_entropy
+from split_learning_tpu.core.losses import cross_entropy, refuse_objective
 from split_learning_tpu.core.stage import SplitPlan, remat_plan
 from split_learning_tpu.obs import dispatch_debug as obs_dispatch
 from split_learning_tpu.parallel.mesh import DATA_AXIS, PIPE_AXIS
@@ -90,6 +90,8 @@ class PipelinedTrainer:
                  microbatches: Optional[int] = None) -> None:
         if PIPE_AXIS not in mesh.axis_names:
             raise ValueError(f"mesh must have a {PIPE_AXIS!r} axis")
+        # the last rank's logits are gathered over microbatches first
+        refuse_objective(plan, "the SPMD pipeline (PipelinedTrainer)")
         n_pipe = mesh.shape[PIPE_AXIS]
         if n_pipe != plan.num_stages:
             raise ValueError(

@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from split_learning_tpu.core.losses import cross_entropy
+from split_learning_tpu.core.losses import final_loss, plan_loss
 from split_learning_tpu.core.stage import SplitPlan, stage_backward
 from split_learning_tpu.obs import dispatch_debug as obs_dispatch
 from split_learning_tpu.obs import spans
@@ -300,7 +300,7 @@ class USplitClientTrainer:
 
         def head_step(params_c, feats, labels):
             def loss_fn(p, f):
-                return cross_entropy(stage_c.apply(p, f), labels)
+                return final_loss(stage_c, p, f, labels)
             loss, (g_c, g_feats) = jax.value_and_grad(
                 loss_fn, argnums=(0, 1))(params_c, feats)
             return loss, g_c, g_feats
@@ -387,8 +387,7 @@ class FederatedClientTrainer:
 
         def step_fn(state: TrainState, x, y):
             def loss_fn(params):
-                logits = plan.apply(params, x)
-                return cross_entropy(logits, y)
+                return plan_loss(plan, params, x, y)
             loss, grads = jax.value_and_grad(loss_fn)(state.params)
             return apply_grads(self._tx, state, grads), loss
 

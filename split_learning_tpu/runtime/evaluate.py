@@ -16,7 +16,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from split_learning_tpu.core.losses import cross_entropy
+from split_learning_tpu.core.losses import (
+    cross_entropy, final_loss, refuse_objective)
 from split_learning_tpu.core.stage import SplitPlan
 from split_learning_tpu.data.datasets import Split, batches
 
@@ -57,11 +58,14 @@ def evaluate(plan: SplitPlan, params: Sequence[Any], split: Split,
     orbax restore yields lists, which ``plan.apply`` accepts as-is).
     """
     params = jax.tree_util.tree_map(jnp.asarray, list(params))
+    last = plan.num_stages - 1
 
     @jax.jit
     def fwd(params, x, y):
-        logits = plan.apply(params, x)
-        loss = cross_entropy(logits, y)
+        feats = plan.apply_range(params, x, 0, last)
+        logits = plan.stages[last].apply(params[last], feats)
+        loss = final_loss(plan.stages[last], params[last], feats, y,
+                          logits=logits)
         correct = jnp.sum(jnp.argmax(logits, axis=-1) == y)
         return loss, correct
 
@@ -112,12 +116,20 @@ def evaluate_remote(plan: SplitPlan, client_params: Sequence[Any],
             x = st.apply(p, x)
         return x
 
+    if not post_stages:
+        refuse_objective(plan, "split-party evaluation (the server's "
+                               "predict returns logits)")
+
     @jax.jit
     def post_and_score(params, feats, y):
-        logits = feats
-        for st, p in zip(post_stages, params):
-            logits = st.apply(p, logits)
-        loss = cross_entropy(logits, y)
+        for st, p in zip(post_stages[:-1], params):
+            feats = st.apply(p, feats)
+        if post_stages:
+            logits = post_stages[-1].apply(params[-1], feats)
+            loss = final_loss(post_stages[-1], params[-1], feats, y,
+                              logits=logits)
+        else:
+            logits, loss = feats, cross_entropy(feats, y)
         correct = jnp.sum(jnp.argmax(logits, axis=-1) == y)
         return loss, correct
 

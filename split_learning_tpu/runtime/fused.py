@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from split_learning_tpu.core.losses import cross_entropy
+from split_learning_tpu.core.losses import cross_entropy, plan_loss
 from split_learning_tpu.core.stage import SplitPlan, remat_plan
 from split_learning_tpu.obs import dispatch_debug as obs_dispatch
 from split_learning_tpu.obs import spans
@@ -108,8 +108,7 @@ class FusedSplitTrainer:
             loss_op = cross_entropy
 
         def loss_fn(params, x, y):
-            logits = plan.apply(params, x)
-            return loss_op(logits, y)
+            return plan_loss(plan, params, x, y, loss_op)
 
         def update(state: TrainState, grads) -> TrainState:
             if not use_pallas_opt:

@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from split_learning_tpu.core.losses import (
-    cross_entropy, per_example_cross_entropy)
+    final_loss, per_example_cross_entropy)
 from split_learning_tpu.core.stage import SplitPlan
 from split_learning_tpu.obs import dispatch_debug as obs_dispatch
 from split_learning_tpu.obs import flight as obs_flight
@@ -241,8 +241,7 @@ class ServerRuntime(PartyRuntime):
             # src/server_part.py:45-52) and returns d(loss)/d(acts).
             def step_fn(state: TrainState, acts, labels):
                 def loss_fn(params, acts):
-                    logits = stage.apply(params, acts)
-                    return cross_entropy(logits, labels)
+                    return final_loss(stage, params, acts, labels)
                 loss, (g_params, g_acts) = jax.value_and_grad(
                     loss_fn, argnums=(0, 1))(state.params, acts)
                 new_state = apply_grads(tx, state, g_params)
@@ -260,8 +259,8 @@ class ServerRuntime(PartyRuntime):
             # can hand each client its own segment-mean loss.
             def group_step_fn(state: TrainState, acts, labels, weights):
                 def loss_fn(params, acts):
-                    logits = stage.apply(params, acts)
-                    per_ex = per_example_cross_entropy(logits, labels)
+                    per_ex = final_loss(stage, params, acts, labels,
+                                        per_example_cross_entropy)
                     return jnp.sum(per_ex * weights), per_ex
                 (_, per_ex), (g_params, g_acts) = jax.value_and_grad(
                     loss_fn, argnums=(0, 1), has_aux=True)(
@@ -283,8 +282,7 @@ class ServerRuntime(PartyRuntime):
                 # the on-device residual snapshot.
                 def reply_fn(params, acts, labels):
                     def fwd(acts):
-                        logits = stage.apply(params, acts)
-                        return cross_entropy(logits, labels)
+                        return final_loss(stage, params, acts, labels)
                     loss, g_acts = jax.value_and_grad(fwd)(acts)
                     return g_acts, loss
 
@@ -302,8 +300,7 @@ class ServerRuntime(PartyRuntime):
                 def deferred_apply_fn(state: TrainState, fwd_params,
                                       acts, labels):
                     def loss_fn(params, acts):
-                        logits = stage.apply(params, acts)
-                        return cross_entropy(logits, labels)
+                        return final_loss(stage, params, acts, labels)
                     g_params = jax.grad(loss_fn)(fwd_params, acts)
                     return apply_grads(tx, state, g_params)
 
@@ -316,8 +313,8 @@ class ServerRuntime(PartyRuntime):
                 # fused group step, so compile counts stay bounded)
                 def group_reply_fn(params, acts, labels, weights):
                     def fwd(acts):
-                        logits = stage.apply(params, acts)
-                        per_ex = per_example_cross_entropy(logits, labels)
+                        per_ex = final_loss(stage, params, acts, labels,
+                                            per_example_cross_entropy)
                         return jnp.sum(per_ex * weights), per_ex
                     (_, per_ex), g_acts = jax.value_and_grad(
                         fwd, has_aux=True)(acts)
@@ -330,8 +327,8 @@ class ServerRuntime(PartyRuntime):
                 def group_apply_fn(state: TrainState, fwd_params,
                                    acts, labels, weights):
                     def loss_fn(params, acts):
-                        logits = stage.apply(params, acts)
-                        per_ex = per_example_cross_entropy(logits, labels)
+                        per_ex = final_loss(stage, params, acts, labels,
+                                            per_example_cross_entropy)
                         return jnp.sum(per_ex * weights)
                     g_params = jax.grad(loss_fn)(fwd_params, acts)
                     return apply_grads(tx, state, g_params)

@@ -61,7 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from split_learning_tpu.core.losses import cross_entropy
+from split_learning_tpu.core.losses import final_loss
 from split_learning_tpu.core.stage import SplitPlan
 from split_learning_tpu.obs import dispatch_debug as obs_dispatch
 from split_learning_tpu.obs import flight as obs_flight
@@ -204,7 +204,7 @@ class StageRuntime(PartyRuntime):
         if self.is_last:
             def loss_reply_fn(params, x, labels):
                 def fwd(x):
-                    return cross_entropy(stage.apply(params, x), labels)
+                    return final_loss(stage, params, x, labels)
                 loss, g_x = jax.value_and_grad(fwd)(x)
                 if M > 1:
                     g_x = g_x * inv_m
@@ -217,7 +217,7 @@ class StageRuntime(PartyRuntime):
                 g_sum = None
                 for x, y in zip(xs, ys):
                     def loss_fn(p, x=x, y=y):
-                        ce = cross_entropy(stage.apply(p, x), y)
+                        ce = final_loss(stage, p, x, y)
                         return ce * inv_m if M > 1 else ce
                     gp = jax.grad(loss_fn)(fwd_params)
                     g_sum = gp if g_sum is None else jax.tree_util.tree_map(
