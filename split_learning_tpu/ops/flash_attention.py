@@ -43,6 +43,17 @@ Design (the canonical TPU flash schedule):
 - Causal masking uses global block coordinates; block pairs with no
   causal overlap skip their matmuls entirely (``pl.when`` around the
   accumulate — the grid stays static, ~2x fewer FLOPs at large T).
+- The kind of a block pair — dead, whole or cut: a pure function of the
+  mask, the pair's offset and the static shapes — decides what its grid
+  step fetches, masks and stores. A dead pair fetches nothing: its step
+  names the block of the nearest live step (:func:`_inner_maps`), and
+  Pallas copies nothing for an index that did not move. A whole pair
+  (:func:`_whole_pairs`) masks nothing: the forward and the one-pass
+  backward take its scaled scores as they are, no iota, compare or
+  select. A cut pair runs its live sub-tiles, masked (next item). The
+  forward's running maximum and sum live replicated over a lane tile
+  (:func:`_lanes`), so no step broadcasts them across lanes.
+  :func:`fetched_pair_share` and :func:`unmasked_pair_share` count both.
 - A block pair that the mask only cuts (the diagonal one; under a window
   the band's last one or two) has a body of its own in all four kernels:
   it works in sub-tiles of ``_TILE`` rows and columns, runs only those
@@ -53,7 +64,7 @@ Design (the canonical TPU flash schedule):
   exactly 0, so the result is the whole-pair body's up to float32
   summation order. Blocks, grid and DMAs stay at the block edge; whole
   pairs keep the single full-block body, and without a causal mask
-  every kernel is traced as before (tests/test_afmoe.py). Where a row
+  no kernel has a sub-tile (tests/test_afmoe.py). Where a row
   block has one key block in all (GPT-2 at T 1024) the forward needs no
   running maximum: each row tile's softmax is final (``_fwd_kernel``).
 - Every kernel body is traced once and replayed at the other call sites
@@ -97,6 +108,7 @@ merges normalized ``(o, lse)`` partials in log space.
 from __future__ import annotations
 
 import functools
+import operator
 
 import jax
 import jax.numpy as jnp
@@ -450,17 +462,22 @@ def _device_hbm_bytes() -> int:
     return int(limit)
 
 
-def _scores(qb, kb, t, k0, q0, scale, causal, strict=False, window=None):
+def _scores(qb, kb, t, k0, q0, scale, causal, strict=False, window=None,
+            masked=True):
     """Masked scaled scores for one (q block, k block) pair. Operands
     stay in their storage dtype (bf16 runs the MXU at full rate) and
     accumulate in f32. Both padded key cols and padded query rows are
     masked, so fully-padded rows carry l == 0 / lse == _NEG_BIG.
     ``strict`` excludes the diagonal (row > col) — the mask a striped
     ring hop from a future-rank shard needs (ops/ring_attention.py).
-    ``window`` keeps only the band ``row - col < window``."""
+    ``window`` keeps only the band ``row - col < window``. Not
+    ``masked`` (a pair of :func:`_whole_pairs`): the scores as they are
+    and no mask, for :func:`_kept` to pass through."""
     s = jax.lax.dot_general(
         qb, kb, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
+    if not masked:
+        return s, None
     rows = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
     cols = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     ok = (rows < t) & (cols < t)
@@ -469,6 +486,12 @@ def _scores(qb, kb, t, k0, q0, scale, causal, strict=False, window=None):
     if window is not None:
         ok &= (rows - cols) < window
     return jnp.where(ok, s, _NEG_BIG), ok
+
+
+def _kept(ok, p):
+    """``p`` where the mask ``ok`` of :func:`_scores` holds, 0 elsewhere;
+    ``p`` itself where there is no mask."""
+    return p if ok is None else jnp.where(ok, p, 0.0)
 
 
 def _band_blocks(window: int, block: int, n_blk: int) -> int:
@@ -547,6 +570,32 @@ def _cut_pairs(n_off: int, blk: int, tile: int, causal: bool,
     return cuts
 
 
+def _whole_pairs(n_off: int, t: int, blk: int, causal: bool, strict: bool,
+                 window) -> frozenset:
+    """The block pairs in which nothing is masked, among those a kernel
+    visits, by offset index as in :func:`_cut_pairs`: local ``(i, j)`` of
+    offset ``o`` sits between ``(o - 1) * blk + 1`` and ``(o + 1) * blk -
+    1`` past the diagonal, and the mask keeps both ends. Their bodies take
+    the scores as they are. None where ``t`` is ragged: the last block's
+    padded rows and columns are masked in every pair that holds them, so
+    every pair keeps its mask."""
+    if t % blk:
+        return frozenset()
+    if not causal:
+        return frozenset(range(n_off))
+    return frozenset(
+        o for o in range(n_off) if (o - 1) * blk + 1 >= int(strict)
+        and (window is None or (o + 1) * blk - 1 < window))
+
+
+def _extents(t: int, block: int, window) -> tuple:
+    """(blocks a sequence, block pairs a kernel visits a block): the grid
+    of ``t`` in blocks of ``block``, banded under a ``window``."""
+    n_blk = round_up(t, block) // block
+    return n_blk, (n_blk if window is None
+                   else _band_blocks(window, block, n_blk))
+
+
 def live_tile_share(t: int, block: int, causal: bool, window=None,
                     strict: bool = False, tile: int | None = None) -> float:
     """Share of the sub-tiles in the block pairs the kernels visit at
@@ -555,14 +604,42 @@ def live_tile_share(t: int, block: int, causal: bool, window=None,
     if not causal:
         return 1.0
     tile = _tile_edge(block) if tile is None else tile
-    n_blk = round_up(t, block) // block
-    n_off = n_blk if window is None else _band_blocks(window, block, n_blk)
+    n_blk, n_off = _extents(t, block, window)
     per = [sum(hi - lo for lo, hi in
                _live_cols(o * block, block, tile, causal, strict, window))
            for o in range(n_off)]
     pairs = [n_blk - o for o in range(n_off)]   # query blocks at offset o
     return (sum(n * p for n, p in zip(pairs, per))
             / (sum(pairs) * (block // tile) ** 2))
+
+
+def unmasked_pair_share(t: int, block: int, causal: bool, window=None,
+                        strict: bool = False) -> float:
+    """Share of the live block pairs at ``t`` whose bodies mask nothing
+    (:func:`_whole_pairs`): 28 of 36 for a causal T 8192 in blocks of
+    1024, none where a row block has one key block or ``t`` is ragged."""
+    n_blk, n_off = _extents(t, block, window)
+    whole = _whole_pairs(n_off, t, block, causal, strict, window)
+    if not causal:   # every pair is visited, at no offset
+        return float(bool(whole))
+    return (sum(n_blk - o for o in whole)
+            / sum(n_blk - o for o in range(n_off)))
+
+
+def fetched_pair_share(t: int, block: int, causal: bool,
+                       window=None) -> float:
+    """Share of the forward's grid steps a head that fetch a key block: a
+    step whose key block index (:func:`_inner_maps`) is the step before's
+    copies nothing, so the dead pairs past the diagonal (and before key 0
+    under a window) cost a grid step and no traffic. 36 of 64 for a
+    causal T 8192 in blocks of 1024, where every step fetched before."""
+    n_blk, n_in = _extents(t, block, window)
+    kv_inner, _ = _inner_maps(n_blk, n_in, causal, window, _kv_index(1))
+    named = [[int(kv_inner(0, i, k)[1]) for k in range(n_in)]
+             for i in range(n_blk)]
+    fetched = sum(1 + sum(a != b for a, b in zip(row, row[1:]))
+                  for row in named)
+    return fetched / (n_blk * n_in)
 
 
 def _row_tiles(ranges: tuple, blk: int) -> list:
@@ -587,26 +664,45 @@ def _both(a, b):
     return b if a is None else a & b
 
 
-def _by_kind(live, o, cuts: dict, n_off: int, body, tiles) -> None:
-    """Dispatch one grid step of a (query block, key block) kernel:
-    ``body(rows, cols)`` over ``tiles(ranges)`` where the pair's offset
-    index ``o()`` is one the mask cuts, ``body()`` on every other pair,
-    nothing where ``live`` (None: always) is false."""
+def _by_kind(live, o, cuts: dict, n_off: int, body, tiles,
+             whole=frozenset()) -> None:
+    """Dispatch one grid step of a (query block, key block) kernel by the
+    kind of its pair, whose offset index is ``o()``: ``body(rows, cols)``
+    over ``tiles(ranges)`` where the mask cuts it into sub-tiles
+    (``cuts``), ``body(masked=False)`` where it masks nothing (``whole``),
+    ``body()`` on every other pair, nothing where ``live`` (None: always)
+    is false."""
     def cut(ranges):
         for rows, cols in tiles(ranges):
             body(rows, cols)
 
-    o = o() if cuts else None
-    for oc, ranges in cuts.items():
-        pl.when(_both(live, o == oc))(functools.partial(cut, ranges))
-    if len(cuts) == n_off:
-        return
-    for oc in cuts:
-        live = _both(live, o != oc)
-    if live is None:
-        body()
-    else:
-        pl.when(live)(body)
+    rest = [x for x in range(n_off) if x not in cuts]
+    kinds = [(functools.partial(cut, ranges), [oc])
+             for oc, ranges in cuts.items()]
+    kinds += [(functools.partial(body, masked=False),
+               [x for x in rest if x in whole]),
+              (body, [x for x in rest if x not in whole])]
+    kinds = [(fn, at) for fn, at in kinds if at]
+    o = o() if len(kinds) > 1 else None
+    for fn, at in kinds:
+        here = live
+        if len(at) < n_off:
+            here = _both(live, functools.reduce(
+                operator.or_, [o == x for x in at]))
+        if here is None:
+            fn()
+        else:
+            pl.when(here)(fn)
+
+
+def _lanes(x, n: int):
+    """A statistic held replicated over one lane tile, ``[rows, LANE]``,
+    over ``n`` lanes: the same vregs again where ``n`` is whole tiles (so
+    that nothing is broadcast across lanes), column 0 broadcast where the
+    blocks are smaller than a tile."""
+    if n % LANE:
+        return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+    return x if n == LANE else pltpu.repeat(x, n // LANE, axis=1)
 
 
 def _blk(ref, rows=None):
@@ -676,26 +772,30 @@ def _fwd_kernel(blk: int, tile: int, t: int, scale: float, causal: bool,
 
     # causal: a key block strictly in the future of the whole query
     # block contributes nothing — skip its matmuls entirely (the grid
-    # stays static; only the compute is guarded). Blocks are square, so
-    # "any overlap" is kb_i <= qb_i. ``rows`` / ``cols``: the sub-tiles of
-    # a cut pair (slices of the block), None for the whole pair.
-    def _accumulate(rows=None, cols=None):
+    # stays static; only the compute is guarded, and the index map names
+    # the diagonal's block again, so nothing is fetched: _inner_maps).
+    # Blocks are square, so "any overlap" is kb_i <= qb_i. ``rows`` /
+    # ``cols``: the sub-tiles of a cut pair (slices of the block), None
+    # for the whole pair. m and l are held replicated over a lane tile
+    # (_lanes): read, rescaled and stored as such, no lane broadcast.
+    def _accumulate(rows=None, cols=None, masked=True):
         qb = _blk(q_ref, rows)
         vb = _blk(v_ref, cols)
         s, ok = _scores(qb, _blk(k_ref, cols), t, _past(k0, cols),
-                        _past(q0, rows), scale, causal, strict, window)
-        ix, roww = _ix(rows), (s.shape[0], _ROWW)
-        m = m_ref[ix, 0]
-        m_new = jnp.maximum(m, jnp.max(s, axis=1))
+                        _past(q0, rows), scale, causal, strict, window,
+                        masked)
+        ix = _ix(rows)
+        m = m_ref[ix]
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         # rebase then re-mask: exp(_NEG_BIG - _NEG_BIG) would be 1
-        p = jnp.where(ok, jnp.exp(s - m_new[:, None]), 0.0)
+        p = _kept(ok, jnp.exp(s - _lanes(m_new, s.shape[1])))
         corr = jnp.exp(m - m_new)
-        l_ref[ix] = l_ref[ix] * corr[:, None] + jnp.broadcast_to(
-            jnp.sum(p, axis=1)[:, None], roww)
-        acc_ref[ix] = acc_ref[ix] * corr[:, None] + jax.lax.dot_general(
-            p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[ix] = jnp.broadcast_to(m_new[:, None], roww)
+        l_ref[ix] = l_ref[ix] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[ix] = (acc_ref[ix] * _lanes(corr, acc_ref.shape[1])
+                       + jax.lax.dot_general(
+                           p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
+                           preferred_element_type=jnp.float32))
+        m_ref[ix] = m_new
 
     if window is not None:
         # the band starts before key 0; step j sits n_k - 1 - j blocks
@@ -706,16 +806,18 @@ def _fwd_kernel(blk: int, tile: int, t: int, scale: float, causal: bool,
     else:
         live, o = None, None
     _by_kind(live, o, cuts, n_k, _accumulate,
-             functools.partial(_row_tiles, blk=blk))
+             functools.partial(_row_tiles, blk=blk),
+             _whole_pairs(n_k, t, blk, causal, strict, window))
 
     @pl.when(step == n_k - 1)
     def _finish():
-        l = l_ref[:, 0]
+        l = l_ref[:]
         # padded query rows are row-masked in _scores: l == 0 there
         l_safe = jnp.where(l > 0.0, l, 1.0)
-        o_ref[0] = (acc_ref[:] / l_safe[:, None]).astype(o_ref.dtype)
-        lse = jnp.where(l > 0.0, m_ref[:, 0] + jnp.log(l_safe), _NEG_BIG)
-        lse_ref[0] = jnp.broadcast_to(lse[:, None], lse_ref.shape[1:])
+        o_ref[0] = (acc_ref[:] / _lanes(l_safe, acc_ref.shape[1])
+                    ).astype(o_ref.dtype)
+        lse = jnp.where(l > 0.0, m_ref[:] + jnp.log(l_safe), _NEG_BIG)
+        lse_ref[0] = lse[:, :_ROWW]
 
 
 def _onepass_bwd_kernel(blk: int, tile: int, t: int, scale: float,
@@ -753,7 +855,7 @@ def _onepass_bwd_kernel(blk: int, tile: int, t: int, scale: float,
             return dk, dk
         return dk, jnp.zeros((rows, dv_ref.shape[-1]), jnp.float32)
 
-    def pair(q0, n, kb, vb, k0, dk, dv):
+    def pair(q0, n, kb, vb, k0, dk, dv, masked=True):
         """Rows ``[q0, q0 + n)`` of the queries against the keys ``kb`` at
         ``k0``: dQ accumulates in place, (dK, dV) are returned added to
         ``dk`` / ``dv`` (None: nothing to add to)."""
@@ -761,8 +863,9 @@ def _onepass_bwd_kernel(blk: int, tile: int, t: int, scale: float,
         dob = do_ref[0, pl.ds(q0, n), :]
         lse = lse_ref[0, pl.ds(q0, n), :][:, :1]
         delta = delta_ref[0, pl.ds(q0, n), :][:, :1]
-        s, ok = _scores(qb, kb, t, k0, q0, scale, causal, strict, window)
-        p = jnp.where(ok, jnp.exp(s - lse), 0.0)
+        s, ok = _scores(qb, kb, t, k0, q0, scale, causal, strict, window,
+                        masked)
+        p = _kept(ok, jnp.exp(s - lse))
         dv_p = jax.lax.dot_general(
             p.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -780,9 +883,6 @@ def _onepass_bwd_kernel(blk: int, tile: int, t: int, scale: float,
             preferred_element_type=jnp.float32) * scale
         ).astype(dq_ref.dtype)
         return dk, dv
-
-    def body(j, carry):
-        return pair(j * blk, blk, kb, vb, k0, *carry)
 
     def cut(j, ranges, carry):
         """Query block ``j``, a pair the mask cuts: per key tile its live
@@ -811,12 +911,18 @@ def _onepass_bwd_kernel(blk: int, tile: int, t: int, scale: float,
         carry = cut(kb_i, cuts[0], carry)
     whole = [o for o in range(n_off) if o not in cuts]   # one run
     if whole:
+        # its body takes the scores as they are if the mask leaves every
+        # entry of every pair of the run
+        masked = not set(whole) <= _whole_pairs(n_off, t, blk, causal,
+                                                strict, window)
         start = 0
         if causal:
             start = kb_i + whole[0] if whole[0] else kb_i
         stop = n_q if window is None else jnp.minimum(
             n_q, kb_i + (whole[-1] + 1))
-        carry = jax.lax.fori_loop(start, stop, body, carry)
+        carry = jax.lax.fori_loop(
+            start, stop, lambda j, carry: pair(
+                j * blk, blk, kb, vb, k0, *carry, masked=masked), carry)
     for o in cuts:
         if o:   # the band's far edge, where it is inside T
             carry = jax.lax.cond(
@@ -962,6 +1068,40 @@ def _kv_index(group: int):
     return (lambda b: b) if group == 1 else (lambda b: b // group)
 
 
+def _inner_maps(n_blk: int, n_in: int, causal: bool, window, kv):
+    """Index maps of the blocks that move with the inner grid axis, in a
+    grid ``(bh, outer block i, inner step k)``: ``kv_inner``, the key and
+    value block of query block ``i`` (forward, dQ), and ``q_inner``, the
+    query-side block of key block ``i`` (dK/dV). A step whose pair is dead
+    names the block of the nearest live step, and Pallas copies nothing
+    for a step whose index is the step before's, so a dead pair costs a
+    grid step and no fetch. ``kv`` is :func:`_kv_index`'s row map."""
+    if window is not None:
+        # banded: step k of query block i is key block i - (n_in-1) + k
+        # (clamped: the kernel skips the steps before key 0), and step k
+        # of key block i is query block i + k (clamped likewise)
+        def kv_inner(b, i, k):
+            return (kv(b), jnp.maximum(i - (n_in - 1) + k, 0), 0)
+
+        def q_inner(b, i, k):
+            return (b, jnp.minimum(i + k, n_blk - 1), 0)
+    elif causal and n_blk > 1:
+        # step k is block k: the key blocks past the diagonal are dead,
+        # and so are the query blocks before it (one block has neither)
+        def kv_inner(b, i, k):
+            return (kv(b), jnp.minimum(k, i), 0)
+
+        def q_inner(b, i, k):
+            return (b, jnp.maximum(k, i), 0)
+    else:
+        def kv_inner(b, i, k):
+            return (kv(b), k, 0)
+
+        def q_inner(b, i, k):
+            return (b, k, 0)
+    return kv_inner, q_inner
+
+
 def _onepass_call(bh: int, t: int, tp: int, dp: int, block: int,
                   scale: float, causal: bool, strict: bool, in_dtype,
                   window=None, group: int = 1, dvp: int | None = None):
@@ -1034,8 +1174,7 @@ def _make_flash(bh: int, t: int, d: int, causal: bool, dtype_name: str,
     dp = round_up(d, LANE)
     d_v = d if d_v is None else d_v
     dvp = round_up(d_v, LANE)
-    n_blk = tp // block
-    n_in = n_blk if window is None else _band_blocks(window, block, n_blk)
+    n_blk, n_in = _extents(t, block, window)
     grid = (bh, n_blk, n_in)
     kv = _kv_index(group)
 
@@ -1045,36 +1184,25 @@ def _make_flash(bh: int, t: int, d: int, causal: bool, dtype_name: str,
     def outer(b, i, k):   # block of the outer (grid dim 1) axis
         return (b, i, 0)
 
-    def inner(b, i, k):   # block of the inner (grid dim 2) axis
-        return (b, k, 0)
-
     def kv_outer(b, i, k):   # key/value block of the outer axis
         return (kv(b), i, 0)
 
-    if window is None:
-        def kv_inner(b, i, k):
-            return (kv(b), k, 0)
-
-        q_inner = inner
-    else:
-        # banded: step k of query block i is key block i - (n_in-1) + k
-        # (clamped: the kernel skips the steps before key 0), and step k
-        # of key block i is query block i + k (clamped likewise)
-        def kv_inner(b, i, k):
-            return (kv(b), jnp.maximum(i - (n_in - 1) + k, 0), 0)
-
-        def q_inner(b, i, k):
-            return (b, jnp.minimum(i + k, n_blk - 1), 0)
-
+    kv_inner, q_inner = _inner_maps(n_blk, n_in, causal, window, kv)
     blk = lambda idx, lanes=dp: pl.BlockSpec((1, block, lanes), idx,
                                              memory_space=pltpu.VMEM)
     vblk = lambda idx: blk(idx, dvp)     # a block of V, O, dO or dV
     row = lambda idx: blk(idx, _ROWW)
     acc_scratch = pltpu.VMEM((block, dp), jnp.float32)
     v_scratch = pltpu.VMEM((block, dvp), jnp.float32)
-    row_scratch = pltpu.VMEM((block, _ROWW), jnp.float32)
     static = (block, _tile_edge(block), t, scale, causal, strict, n_in,
               window)
+    # the forward's running maximum and sum, replicated over a lane tile
+    # (_lanes); where every row tile's softmax is final (_fwd_kernel:
+    # one key block, cut) they are not touched and stay as narrow as
+    # they were
+    final = n_in == 1 and _cut_pairs(n_in, block, _tile_edge(block), causal,
+                                     strict, window)
+    stat_scratch = pltpu.VMEM((block, _ROWW if final else LANE), jnp.float32)
     fwd_kernel = _traced_once(functools.partial(_fwd_kernel, *static))
     dq_kernel = _traced_once(functools.partial(_dq_kernel, *static))
     dkv_kernel = _traced_once(functools.partial(_dkv_kernel, *static, n_blk))
@@ -1090,7 +1218,7 @@ def _make_flash(bh: int, t: int, d: int, causal: bool, dtype_name: str,
             grid=grid,
             in_specs=[blk(outer), blk(kv_inner), vblk(kv_inner)],
             out_specs=(vblk(outer), row(outer)),
-            scratch_shapes=[v_scratch, row_scratch, row_scratch],
+            scratch_shapes=[v_scratch, stat_scratch, stat_scratch],
             interpret=use_interpret(),
             # same per-generation allowance the one-pass backward gets
             # (a limit, not a reservation): at the default <=1024 edges

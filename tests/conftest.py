@@ -146,7 +146,8 @@ def flash_tiled(monkeypatch):
     kernels of ops/flash_attention.py built at a small ``block`` whose
     cut pairs work in sub-tiles of ``tile`` (the module's ``_TILE``, a
     constant of 256 on the chip), interpreted. ``[B, T, H, D]`` in and
-    out; with ``with_lse`` also the ``[B, T, H]`` logsumexp."""
+    out (``v`` and the output may be of another width); with ``with_lse``
+    also the ``[B, T, H]`` logsumexp."""
     import importlib
     fa = importlib.import_module("split_learning_tpu.ops.flash_attention")
 
@@ -155,14 +156,16 @@ def flash_tiled(monkeypatch):
         monkeypatch.setattr(fa, "_TILE", tile)
         fa._make_flash.cache_clear()   # the edge is no part of its key
         b, t, h, d = q.shape
+        d_v = v.shape[-1]
         fn = fa._make_flash(b * h, t, d, causal, str(q.dtype), block,
                             with_lse=with_lse, strict=strict,
                             onepass=onepass, window=window,
-                            group=h // k.shape[2])
-        fold = lambda x: jnp.transpose(x, (0, 2, 1, 3)).reshape(-1, t, d)
+                            group=h // k.shape[2], d_v=d_v)
+        fold = lambda x: jnp.transpose(x, (0, 2, 1, 3)).reshape(
+            -1, t, x.shape[-1])
         out = fn(fold(q), fold(k), fold(v))
         o = out[0] if with_lse else out
-        o = jnp.transpose(o.reshape(b, h, t, d), (0, 2, 1, 3))
+        o = jnp.transpose(o.reshape(b, h, t, d_v), (0, 2, 1, 3))
         if with_lse:
             return o, jnp.transpose(out[1].reshape(b, h, t), (0, 2, 1))
         return o

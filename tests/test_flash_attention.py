@@ -83,12 +83,18 @@ def test_live_tiles_are_the_masks_own(t, causal, strict, window, tile):
     within = np.arange(n_blk * block) < t
     real = ok & within[:, None] & within[None, :]
     n_off = n_blk if window is None else fa._band_blocks(window, block, n_blk)
-    ran = visited = 0
+    whole = fa._whole_pairs(n_off, t, block, causal, strict, window)
+    ran = visited = pairs = unmasked = 0
     for qb in range(n_blk):
         for kb in range(n_blk):
             if causal and not 0 <= qb - kb < n_off:
                 continue   # a pair the grid skips whole
             pair = ok[qb * block:, kb * block:][:block, :block]
+            # a pair's body drops its mask only where nothing is masked
+            # (a ragged T keeps every pair's, the whole ones' too)
+            bare = (qb - kb) in whole if causal else bool(whole)
+            assert bare == bool(pair.all() and t % block == 0)
+            pairs, unmasked = pairs + 1, unmasked + bare
             tiles = pair.reshape(n, tile, n, tile).any(axis=(1, 3))
             cols = fa._live_cols((qb - kb) * block, block, tile, causal,
                                  strict, window)
@@ -107,6 +113,8 @@ def test_live_tiles_are_the_masks_own(t, causal, strict, window, tile):
             visited += n * n
     assert fa.live_tile_share(t, block, causal, window, strict,
                               tile) == pytest.approx(ran / visited)
+    assert fa.unmasked_pair_share(t, block, causal, window,
+                                  strict) == pytest.approx(unmasked / pairs)
     if not causal:
         assert ran == visited and fa._cut_pairs(
             n_off, block, tile, causal, strict, window) == {}
@@ -129,6 +137,55 @@ def test_live_tile_share_at_the_cells_shapes(t, window, at_512, at_256):
     assert live_tile_share(t, 1024, False) == 1.0
 
 
+@pytest.mark.parametrize("t,window,fetched,unmasked", [
+    (1024, None, 1.0, 0.0), (8192, None, 36 / 64, 28 / 36),
+    (8192, 2048, 21 / 24, 7 / 21), (8192, 512, 15 / 16, 0.0),
+    (8000, None, 36 / 64, 0.0)],
+    ids=["gpt2", "full-t8192", "trinity-window", "phi4flash-window",
+         "ragged"])
+def test_pair_shares_at_the_cells_shapes(t, window, fetched, unmasked):
+    """ISSUE 33's counters at blocks of 1024, by the mask of each cell's
+    calls: the share of the forward's grid steps that fetch a key block
+    (all of them before: the dead pairs' blocks were fetched too) and the
+    share of the live pairs whose body masks nothing."""
+    from split_learning_tpu.ops.flash_attention import (
+        fetched_pair_share, unmasked_pair_share)
+    assert fetched_pair_share(t, 1024, True, window) == pytest.approx(fetched)
+    assert unmasked_pair_share(t, 1024, True, window) == pytest.approx(
+        unmasked)
+    assert fetched_pair_share(t, 1024, False) == 1.0
+    assert unmasked_pair_share(t, 1024, False) == float(t % 1024 == 0)
+
+
+def test_index_maps_name_no_dead_block():
+    """The inner index maps as plain functions: in a causal grid of 8
+    blocks, step ``k`` of row block ``i`` names key block ``min(k, i)``
+    (36 fetches a head where the grid has 64 steps: a step that names the
+    block of the step before copies nothing) and, from the key side,
+    query block ``max(k, i)``; under a window the band's clamps; a grid
+    of one block, or no causal mask, names block ``k`` as it did."""
+    import importlib
+    fa = importlib.import_module("split_learning_tpu.ops.flash_attention")
+    kv_inner, q_inner = fa._inner_maps(8, 8, True, None, fa._kv_index(4))
+    named = [[int(kv_inner(5, i, k)[1]) for k in range(8)] for i in range(8)]
+    assert named == [[min(k, i) for k in range(8)] for i in range(8)]
+    assert sum(len(set(row)) for row in named) == 36
+    assert all(a <= b for row in named for a, b in zip(row, row[1:]))
+    assert kv_inner(5, 3, 7)[0] == 1 and q_inner(5, 3, 7)[0] == 5
+    assert [[int(q_inner(0, i, k)[1]) for k in range(8)]
+            for i in range(8)] == [[max(k, i) for k in range(8)]
+                                   for i in range(8)]
+    kv_inner, q_inner = fa._inner_maps(8, 3, True, 2048, fa._kv_index(1))
+    assert [int(kv_inner(0, i, k)[1]) for i in (0, 1, 7)
+            for k in range(3)] == [0, 0, 0, 0, 0, 1, 5, 6, 7]
+    assert [int(q_inner(0, i, k)[1]) for i in (0, 6, 7)
+            for k in range(3)] == [0, 1, 2, 6, 7, 7, 7, 7, 7]
+    for n_blk, causal in ((1, True), (8, False)):
+        kv_inner, q_inner = fa._inner_maps(n_blk, n_blk, causal, None,
+                                           fa._kv_index(1))
+        assert kv_inner(2, 0, 5) == (2, 5, 0) == q_inner(2, 0, 5)
+
+
 def _dense(q, k, v, strict, window):
     """Causal attention with its logsumexp, the plain way; a row that
     sees no key (the first, under ``strict``) gives zeros and NEG_BIG."""
@@ -148,18 +205,30 @@ def _dense(q, k, v, strict, window):
 
 
 # blocks of 128 in sub-tiles of 32 or 64: one block is GPT-2's case (the
-# diagonal pair and nothing else), 384 adds whole pairs below it, strict
-# is a ring hop's mask, 300 pads 84 rows and columns
+# diagonal pair and nothing else), 384 adds whole pairs below it (their
+# bodies mask nothing) and dead ones past it (their blocks are not
+# fetched), strict is a ring hop's mask, 300 pads 84 rows and columns
+# (every pair keeps its mask); window 256 has one whole pair between two
+# cut ones, 200 cuts the band's last two; then one key/value head under
+# two query heads, and keys of 24 over values of 16
 @pytest.mark.parametrize("onepass", [True, False], ids=["onepass", "split"])
-@pytest.mark.parametrize("t,tile,strict", [
-    (128, 32, False), (384, 32, False), (256, 64, True), (300, 32, False)],
-    ids=["one-block", "many-blocks", "strict", "ragged"])
-def test_cut_pairs_match_dense(flash_tiled, onepass, t, tile, strict):
+@pytest.mark.parametrize("t,tile,strict,window,kv_heads,d_k", [
+    (128, 32, False, None, 2, 16), (384, 32, False, None, 2, 16),
+    (256, 64, True, None, 2, 16), (300, 32, False, None, 2, 16),
+    (512, 32, False, 256, 2, 16), (512, 64, False, 200, 2, 16),
+    (384, 32, False, None, 1, 16), (384, 64, False, None, 2, 24)],
+    ids=["one-block", "many-blocks", "strict", "ragged", "window-blocks",
+         "window-between", "grouped", "keys-wider"])
+def test_cut_pairs_match_dense(flash_tiled, onepass, t, tile, strict, window,
+                               kv_heads, d_k):
     """Forward, logsumexp and all three gradients of the kernels whose
-    diagonal pair runs its live sub-tiles alone, both backward forms."""
+    block pairs each run the body of their kind, both backward forms."""
     q, k, v = qkv(t=t, b=1, h=2)
+    k, v = k[:, :, :kv_heads], v[:, :, :kv_heads]
+    if d_k != v.shape[-1]:   # queries and keys of d_k over values of 16
+        q, k = (jnp.tile(x, (1, 1, 1, 2))[..., :d_k] for x in (q, k))
     ks = jax.random.split(jax.random.PRNGKey(9), 2)
-    w = jax.random.normal(ks[0], q.shape)
+    w = jax.random.normal(ks[0], q.shape[:3] + v.shape[3:])
     w_lse = jax.random.normal(ks[1], q.shape[:3])
 
     def loss(fn):
@@ -171,8 +240,8 @@ def test_cut_pairs_match_dense(flash_tiled, onepass, t, tile, strict):
 
     got = loss(lambda a, b, c: flash_tiled(
         a, b, c, block=128, tile=tile, onepass=onepass, strict=strict,
-        with_lse=True))(q, k, v)
-    want = loss(lambda a, b, c: _dense(a, b, c, strict, None))(q, k, v)
+        window=window, with_lse=True))(q, k, v)
+    want = loss(lambda a, b, c: _dense(a, b, c, strict, window))(q, k, v)
     np.testing.assert_allclose(got[0], want[0], rtol=1e-5, atol=1e-3)
     for g, wg in zip(got[1], want[1]):
         np.testing.assert_allclose(np.asarray(g), np.asarray(wg),
