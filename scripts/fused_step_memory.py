@@ -3,7 +3,8 @@
     JAX_PLATFORMS=cpu python scripts/fused_step_memory.py --workload <cell>
 
 Compiles the cell's fused step (``runtime/fused.py``'s ``step_fn``: loss,
-gradient, optimizer update, the state donated) at the cell's real sizes
+gradient, optimizer update, the step's counters, the state donated) at the
+cell's real sizes
 for a *described* TPU v5e, with no chip attached, and prints the compiled
 program's memory analysis as one JSON line: arguments, outputs, aliased,
 temporaries and their peak (arguments + outputs - aliased + temporaries),
@@ -51,7 +52,7 @@ def main() -> int:
 
     import traffic
     import weights
-    from split_learning_tpu.core.losses import plan_loss
+    from split_learning_tpu.core.losses import plan_loss_with_counters
     from split_learning_tpu.models.factory import get_plan
     from split_learning_tpu.ops import common
     from split_learning_tpu.runtime.state import (
@@ -75,9 +76,10 @@ def main() -> int:
     state = jax.eval_shape(lambda p: make_state(p, tx), params)
 
     def step(state, x, y):
-        loss, grads = jax.value_and_grad(
-            lambda p: plan_loss(plan, p, x, y))(state.params)
-        return apply_grads(tx, state, grads), loss
+        (loss, counters), grads = jax.value_and_grad(
+            lambda p: plan_loss_with_counters(plan, p, x, y),
+            has_aux=True)(state.params)
+        return apply_grads(tx, state, grads), loss, counters
 
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])

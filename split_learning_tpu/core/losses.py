@@ -7,9 +7,13 @@ federated mode). Mean reduction over the batch, integer class labels.
 
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import optax
+
+from split_learning_tpu.core.stage import with_counters
 
 
 def cross_entropy(logits: jax.Array, labels: jax.Array) -> jax.Array:
@@ -50,6 +54,30 @@ def plan_loss(plan, params, x: jax.Array, labels: jax.Array,
     last = plan.num_stages - 1
     return final_loss(plan.stages[last], params[last],
                       plan.apply_range(params, x, 0, last), labels, loss_op)
+
+
+def plan_loss_with_counters(plan, params, x: jax.Array, labels: jax.Array,
+                            loss_op=cross_entropy) -> tuple:
+    """(:func:`plan_loss`, the counters the plan's stages sowed on the
+    way): the same loss by the same code, every stage's ``apply`` and
+    ``objective`` called through ``core/stage.with_counters``. For a
+    plan that sows nothing the counters are ``{}`` and the trace is
+    :func:`plan_loss`'s."""
+    counters: dict = {}
+
+    def counting(stage):
+        def through(fn):
+            def call(p, *args):
+                out, sown = with_counters(stage, fn, p, *args)
+                counters.update(sown)
+                return out
+            return fn and call
+        return dataclasses.replace(stage, apply=through(stage.apply),
+                                   objective=through(stage.objective))
+
+    counted = dataclasses.replace(
+        plan, stages=tuple(counting(s) for s in plan.stages))
+    return plan_loss(counted, params, x, labels, loss_op), counters
 
 
 def refuse_objective(plan, where: str) -> None:

@@ -16,10 +16,14 @@ removes that class of bug, SURVEY.md §5 "Race detection").
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from flax import traverse_util
+
+from split_learning_tpu.obs import spans
 
 Params = Any  # a pytree of arrays
 Array = jax.Array
@@ -42,12 +46,19 @@ class Stage:
     loss goes through ``core/losses.final_loss``, which takes the one or
     the other; a path that cannot carry an objective refuses the stage
     (``core/losses.refuse_objective``).
+
+    ``sows``: ``apply`` and ``objective`` take ``mutable=[collection]``
+    and then return ``(result, {collection: what the modules sowed})``,
+    as a flax module's ``apply`` does (:func:`from_flax` sets it; a
+    hand-written stage has nothing to sow). :func:`with_counters` is the
+    one caller.
     """
 
     name: str
     init: Callable[[jax.Array, Array], Params]  # (rng, sample_input) -> params
     apply: Callable[[Params, Array], Array]     # (params, x) -> y
     objective: Optional[Callable[[Params, Array, Array], Array]] = None
+    sows: bool = False
 
     def out_spec(self, params: Params, x_spec: jax.ShapeDtypeStruct) -> jax.ShapeDtypeStruct:
         """Shape-infer this stage's output without running it."""
@@ -73,6 +84,33 @@ def stage_backward(stage: "Stage", params: Params, x: Array,
     return g_params
 
 
+def with_counters(stage: "Stage", fn: Callable, params: Params, *args):
+    """``fn(params, *args)`` for ``fn`` the stage's ``apply`` or
+    ``objective``, and beside it the counters its modules sowed into
+    ``obs/spans.STEP_COUNTERS`` meanwhile: ``{"<stage>/<module path>":
+    {name: value}}``, values still on the device; ``{}`` for a stage that
+    sows nothing. This is how a value that only exists inside a jitted
+    step leaves it (the routed layer's pairs and rung, models/afmoe.py);
+    a caller that does not come through here never makes the collection
+    mutable, and its program has none of it."""
+    if not stage.sows:
+        return fn(params, *args), {}
+    out, sown = fn(params, *args, mutable=[spans.STEP_COUNTERS])
+    counters: dict = {}
+    flat = traverse_util.flatten_dict(sown.get(spans.STEP_COUNTERS, {}))
+    for (*path, name), value in flat.items():
+        counters.setdefault("/".join((stage.name, *path)), {})[name] = value
+    return out, counters
+
+
+def _checkpointed(fn: Callable) -> Callable:
+    """``jax.checkpoint(fn)``; keywords (``mutable=`` of
+    :func:`with_counters`) are closed over, not traced as arguments."""
+    plain = jax.checkpoint(fn)
+    return lambda *args, **kw: jax.checkpoint(
+        functools.partial(fn, **kw))(*args) if kw else plain(*args)
+
+
 def remat_plan(plan: "SplitPlan") -> "SplitPlan":
     """A plan whose stages rematerialize under reverse-mode AD.
 
@@ -85,8 +123,8 @@ def remat_plan(plan: "SplitPlan") -> "SplitPlan":
     """
     stages = tuple(
         dataclasses.replace(
-            s, apply=jax.checkpoint(s.apply),
-            objective=s.objective and jax.checkpoint(s.objective))
+            s, apply=_checkpointed(s.apply),
+            objective=s.objective and _checkpointed(s.objective))
         for s in plan.stages)
     return dataclasses.replace(plan, stages=stages)
 
@@ -105,10 +143,10 @@ def from_flax(name: str, module: Any,
     last axis, so that the weights only the objective reads are made."""
     apply = lambda params, x, **kw: module.apply(params, x, **kw)
     if objective is None:
-        return Stage(name=name, apply=apply,
+        return Stage(name=name, apply=apply, sows=True,
                      init=lambda rng, sample: module.init(rng, sample))
     return Stage(
-        name=name, apply=apply,
+        name=name, apply=apply, sows=True,
         init=lambda rng, sample: module.init(
             rng, sample, jnp.zeros(jnp.shape(sample)[:-1], jnp.int32),
             method=objective),

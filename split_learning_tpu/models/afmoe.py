@@ -79,11 +79,20 @@ cell runs it). ``Config.remat`` (``core/stage.remat_plan``: the whole
 stage under ``jax.checkpoint``) nests over this for a user short of
 memory.
 
+**What the step reports.** A caller that applies the layer with the
+collection ``obs/spans.STEP_COUNTERS`` mutable (``core/stage.with_counters``:
+the fused step) gets, for each routed layer, what the device alone knew:
+``pairs`` (``group_sizes``: the pairs each held expert got this step),
+``rows`` (the row count of the rung they select; ``n * k`` without
+``remat`` or where the ladder has one rung) and ``ladder`` (the static
+rungs). Nothing else is computed for them, and a caller that does not ask
+traces the program without them.
+
 The selection bias ``expert_bias`` is a float32 leaf under
-``stop_gradient``: its published update (from per-expert token counts)
-would have to leave the step beside the activations, which a pure
-``Stage`` cannot give (ROADMAP.md M4), so it stays where ``init`` put
-it. Decoding through a KV cache is not built for this family.
+``stop_gradient``: its published update follows the per-expert token
+counts of a step, which now leave the step as ``pairs`` (above); nothing
+feeds them back yet (ROADMAP.md M4a), so the bias stays where ``init``
+put it. Decoding through a KV cache is not built for this family.
 """
 
 from __future__ import annotations
@@ -387,11 +396,29 @@ class RoutedExperts(nn.Module):
                                                held)
         operands = (m32.astype(self.dtype), order, inverse, sizes, weights,
                     gate, up, down)
+        # without remat: kept rows of both rungs would be the switch's outputs
+        rungs = pair_rungs(n * k, held, self.experts_total) if self.remat \
+            else (n * k,)
+        # init makes every collection mutable: the weights stay alone
+        if self.is_mutable_collection(spans.STEP_COUNTERS) \
+                and not self.is_initializing():
+            self._count(sizes, rungs)
         if not self.remat:
-            # kept rows of both rungs would be the switch's outputs
             return _routed_rows(n * k, *operands)
-        rungs = pair_rungs(n * k, held, self.experts_total)
         return _routed_recomputed(rungs, *operands)
+
+    def _count(self, sizes, rungs) -> None:
+        """Sow what the step decides on the device (the module header):
+        the pairs each held expert got, the rows of the rung they select,
+        and the rungs themselves. Only a caller that made the collection
+        mutable reaches this, so no other program has these values."""
+        ladder = jnp.asarray(rungs, jnp.int32)
+        for name, value in ((spans.MOE_PAIRS, sizes),
+                            (spans.MOE_ROWS,
+                             ladder[rung_of(sizes.sum(), rungs)]),
+                            (spans.MOE_LADDER, ladder)):
+            self.sow(spans.STEP_COUNTERS, name, value,
+                     reduce_fn=lambda _, new: new, init_fn=lambda: None)
 
 
 class AfmoeLayer(nn.Module):

@@ -33,6 +33,10 @@ H2D = "h2d"
 ROUND = "round"
 # the fused step's blocking read of the loss (runtime/fused.py)
 LOSS_WAIT = "loss_wait"
+# the fused step's read of its counters (below), opened while recording
+# only; its attributes are the record: ``layers`` and one list a counter,
+# an entry a layer
+COUNTERS_READ = "counters_read"
 
 # -- server-party spans ------------------------------------------------ #
 QUEUE_WAIT = "queue_wait"
@@ -239,6 +243,17 @@ DEVICE_SCOPES = (ATTN_WINDOW, ATTN_FULL, MOE_ROUTE, MOE_EXPERTS, MOE_SHARED,
                  SSM_CONV, SSM_SCAN, GMU, ATTN_CROSS, ATTN_LATENT, MLA_PROJ,
                  MTP)
 
+# -- counters that leave a jitted step (core/stage.with_counters) ------- #
+# the flax collection a module sows its step's counters into; mutable
+# only where the caller asks for them (the fused step), so every other
+# caller traces the program without them. The routed layer
+# (models/afmoe.py RoutedExperts) sows three: the pairs each held expert
+# got, the row count of the rung it ran, and the static rungs it chose from.
+STEP_COUNTERS = "step_counters"
+MOE_PAIRS = "pairs"
+MOE_ROWS = "rows"
+MOE_LADDER = "ladder"
+
 # the client-level phases that tile a step — the denominator of the
 # compute-vs-wire fraction (encode/wire are sub-phases of transport and
 # queue_wait/dispatch belong to the server party; counting either would
@@ -253,4 +268,4 @@ TRANSPORT_SUB = (ENCODE, WIRE, QUEUE_WAIT, DISPATCH, D2H)
 
 ALL_SPANS = (CLIENT_FWD, ENCODE, WIRE, TRANSPORT, CLIENT_BWD, OPT_APPLY,
              STEP_TOTAL, QUEUE_WAIT, DISPATCH, D2H, REPLY_GRAD,
-             DEFERRED_APPLY, ROUND, H2D, LOSS_WAIT)
+             DEFERRED_APPLY, ROUND, H2D, LOSS_WAIT, COUNTERS_READ)

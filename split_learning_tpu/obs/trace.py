@@ -20,7 +20,7 @@ also appends one record to an in-memory ring: name, span id, parent id
 start and end in nanoseconds on the profiler's clock
 (``time.time_ns()``: the xplane's host events are stamped with the same
 wall clock), and its few attributes (``bytes``, ``rows``, ``group``,
-``reason``).
+``reason``; ``counters_read``'s are a step's counters).
 
 Recording is on after :func:`enable` (the CLI's ``--trace PATH`` and
 ``SLT_TRACE``) and for as long as a profiler session is active
@@ -43,7 +43,17 @@ The taxonomy (names in obs/spans.py, slt-lint SLT003):
   async-dispatch servers ``d2h``, the off-lock host materialization
   (once per group, on the waiter that redeems it). ``lock_hold`` is a
   metrics histogram fed from ``dispatch``, never a span.
-- fused: ``step_total`` > ``h2d``, ``dispatch``, ``loss_wait``.
+- fused: ``step_total`` > ``h2d``, ``dispatch``, ``loss_wait`` and,
+  where the plan's modules sow step counters (``spans.STEP_COUNTERS``:
+  the routed layer's pairs and rung, models/afmoe.py),
+  ``counters_read``: the one ``device_get`` that brings a step's
+  counters to the host. It is opened only while recording, so it is the
+  one span that is no annotation when off, and its attributes are the
+  record: ``layers`` (module paths) and one list a counter, an entry a
+  layer (``pairs``, ``rows``, ``ladder``). On the profiler's clock like
+  every record, it lines a step's rungs up with that step's
+  ``conditional`` events in the device trace, and it rides the Chrome
+  export's ``args``.
 
 TRACING ADDS NO SYNCHRONISATION. A span measures what its thread did,
 including the waits the program itself makes (``np.asarray(acts)``

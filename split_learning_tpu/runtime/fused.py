@@ -29,7 +29,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from split_learning_tpu.core.losses import cross_entropy, plan_loss
+from split_learning_tpu.core.losses import (
+    cross_entropy, plan_loss_with_counters)
 from split_learning_tpu.core.stage import SplitPlan, remat_plan
 from split_learning_tpu.obs import dispatch_debug as obs_dispatch
 from split_learning_tpu.obs import spans
@@ -108,7 +109,7 @@ class FusedSplitTrainer:
             loss_op = cross_entropy
 
         def loss_fn(params, x, y):
-            return plan_loss(plan, params, x, y, loss_op)
+            return plan_loss_with_counters(plan, params, x, y, loss_op)
 
         def update(state: TrainState, grads) -> TrainState:
             if not use_pallas_opt:
@@ -121,8 +122,17 @@ class FusedSplitTrainer:
                               step=state.step + 1)
 
         def step_fn(state: TrainState, x, y):
+            """``(state, loss, counters)``: what the plan's modules sowed
+            into ``spans.STEP_COUNTERS`` this step, by module path, still
+            on the device (``{}`` for a plan that sows nothing, whose
+            program is then the one without this output). Nothing reads
+            them but :meth:`train_step` while recording. A microbatch's
+            counters stay inside the scan and ``epoch_fn`` drops a
+            step's: XLA removes both."""
+            counters = {}
             if microbatches == 1:
-                loss, grads = jax.value_and_grad(loss_fn)(state.params, x, y)
+                (loss, counters), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(state.params, x, y)
             else:
                 # GPipe-style gradient accumulation: scan over microbatches.
                 mb = microbatches
@@ -132,7 +142,8 @@ class FusedSplitTrainer:
                 def micro(carry, xy):
                     g_acc, l_acc = carry
                     xmb, ymb = xy
-                    l, g = jax.value_and_grad(loss_fn)(state.params, xmb, ymb)
+                    (l, _), g = jax.value_and_grad(
+                        loss_fn, has_aux=True)(state.params, xmb, ymb)
                     g_acc = jax.tree_util.tree_map(jnp.add, g_acc, g)
                     return (g_acc, l_acc + l), None
 
@@ -142,7 +153,7 @@ class FusedSplitTrainer:
                 grads = jax.tree_util.tree_map(lambda g: g / mb, g_sum)
                 loss = l_sum / mb
             new_state = update(state, grads)
-            return new_state, loss
+            return new_state, loss, counters
 
         def epoch_fn(state: TrainState, xs, ys):
             """T steps in one XLA program: lax.scan over the step axis.
@@ -152,7 +163,7 @@ class FusedSplitTrainer:
             jit-once/scan-many idiom the reference's per-batch HTTP round
             trip structurally rules out."""
             return jax.lax.scan(
-                lambda s, xy: step_fn(s, xy[0], xy[1]), state, (xs, ys))
+                lambda s, xy: step_fn(s, xy[0], xy[1])[:2], state, (xs, ys))
 
         if mesh is not None:
             state_sh = self.state_sharding
@@ -163,7 +174,7 @@ class FusedSplitTrainer:
             self._step = jax.jit(
                 step_fn,
                 in_shardings=(state_sh, x_sh, y_sh),
-                out_shardings=(state_sh, replicated(mesh)),
+                out_shardings=(state_sh, replicated(mesh), replicated(mesh)),
                 donate_argnums=(0,),
             )
             self._epoch = jax.jit(
@@ -185,14 +196,31 @@ class FusedSplitTrainer:
         """One fused step on the global batch (sharded over clients).
         Spans (obs/trace.py): ``step_total`` > ``h2d`` (the inputs),
         ``dispatch`` (the call of the jitted step), ``loss_wait`` (the
-        blocking read of the loss, where the device's time shows)."""
+        blocking read of the loss, where the device's time shows) and,
+        while recording and where the plan sows any, ``counters_read``:
+        one ``device_get`` of the step's counters, whose attributes are
+        the record (``layers``: the module paths; one list a counter,
+        an entry a layer: the routed layer's ``pairs``, ``rows`` and
+        ``ladder``, models/afmoe.py). With recording off the counters
+        are never fetched."""
         with obs_trace.span(spans.STEP_TOTAL):
-            loss = self._dispatch_step(x, y)
+            loss, counters = self._dispatch_step(x, y)
             with obs_dispatch.expected_d2h(self._dd), \
                     obs_trace.span(spans.LOSS_WAIT):
-                return float(loss)
+                loss = float(loss)
+            if counters and obs_trace.recording():
+                with obs_dispatch.expected_d2h(self._dd), \
+                        obs_trace.span(spans.COUNTERS_READ) as read:
+                    got = jax.device_get(counters)
+                    layers = sorted(got)
+                    names = sorted({n for layer in got.values() for n in layer})
+                    # a layer that sows no counter of a name reads None
+                    read.set(layers=layers, **{
+                        n: [got[layer][n].tolist() if n in got[layer]
+                            else None for layer in layers] for n in names})
+            return loss
 
-    def _dispatch_step(self, x, y) -> jax.Array:
+    def _dispatch_step(self, x, y) -> Tuple[jax.Array, dict]:
         with obs_trace.span(spans.H2D, bytes=obs_trace.nbytes(x, y)):
             x = jnp.asarray(x)
             y = jnp.asarray(y)
@@ -202,8 +230,8 @@ class FusedSplitTrainer:
         with obs_trace.span(spans.DISPATCH), obs_dispatch.step_scope(
                 self._dd, (self._ddtok, "fused_step"),
                 sig_fn=lambda: (x.shape, str(x.dtype), y.shape)):
-            self.state, loss = self._step(self.state, x, y)
-        return loss
+            self.state, loss, counters = self._step(self.state, x, y)
+        return loss, counters
 
     def train_epoch(self, xs, ys) -> jax.Array:
         """Run ``xs.shape[0]`` steps in one device dispatch; returns the
@@ -222,9 +250,10 @@ class FusedSplitTrainer:
 
     def train_step_async(self, x, y) -> jax.Array:
         """Like train_step but does not block on the loss transfer —
-        use in throughput benchmarks to keep the device queue full."""
+        use in throughput benchmarks to keep the device queue full. The
+        step's counters are not read."""
         with obs_trace.span(spans.STEP_TOTAL):
-            return self._dispatch_step(x, y)
+            return self._dispatch_step(x, y)[0]
 
     @property
     def params(self) -> Tuple[Any, ...]:

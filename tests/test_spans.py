@@ -214,10 +214,14 @@ def test_a_span_open_when_the_session_ends_is_not_kept(tmp_path):
 
 
 def test_span_names_are_registered_and_not_the_benchmarks():
-    assert {spans.ROUND, spans.H2D, spans.LOSS_WAIT} <= set(spans.ALL_SPANS)
+    children = {spans.ROUND, spans.H2D, spans.LOSS_WAIT, spans.COUNTERS_READ}
+    assert children <= set(spans.ALL_SPANS)
     for phases in (spans.CLIENT_PHASES, spans.SERVER_PHASES,
                    spans.TRANSPORT_SUB):
-        assert not {spans.ROUND, spans.H2D, spans.LOSS_WAIT} & set(phases)
+        assert not children & set(phases)
+    # a collection's and its counters' names are no spans
+    assert not {spans.STEP_COUNTERS, spans.MOE_PAIRS, spans.MOE_ROWS,
+                spans.MOE_LADDER} & set(spans.ALL_SPANS)
     assert len(set(spans.ALL_SPANS)) == len(spans.ALL_SPANS)
     assert not any("." in name for name in spans.ALL_SPANS)
 
