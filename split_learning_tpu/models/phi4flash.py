@@ -51,12 +51,16 @@ Every layer (LN = LayerNorm with scale and bias)::
 Stages as the other families have them: split = client(embedding + the
 first ``client_depth`` kept layers) -> server(the rest + norm + head);
 u_split moves norm + head back to the client; federated is the
-composition. ``remat`` recomputes each layer's MLP in the backward pass
-and nothing else: its LayerNorm, the ``2 * mlp_width`` wide product and
-the gate are the widest values a layer makes, while every mixer's
-values are kept, so no kernel's forward runs twice (XLA's analysis of
-the step with nothing, the MLPs or whole layers recomputed is in
-benchmarks/configs). Weights
+composition. ``remat`` recomputes, in the backward pass, the two passes
+around each layer's widest product and nothing else: an MLP keeps the
+output of its ``2 * mlp_width`` wide product ``LN_2(h) W_gu`` and makes
+its LayerNorm and ``silu(g) * u`` again. What costs a pass over memory
+is made twice, what costs a product is not, every mixer's values are
+kept, and so no product and no kernel's forward runs a second time. XLA's
+analysis of the fused step at the benchmark's sizes (T 8192, five
+layers; scripts/fused_step_memory.py, the fit rule is 14.5 GB): nothing
+recomputed 14.916 GB, this form 14.496 GB, the MLPs whole (the product
+too: five more products a step) 12.822 GB, whole layers 10.282 GB. Weights
 are float32, products run in ``dtype``. Decoding is not built: it needs
 a recurrent state beside a key/value cache (runtime/generate.py).
 """
@@ -70,6 +74,7 @@ from typing import Any, Sequence
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from split_learning_tpu.core.stage import SplitPlan, from_flax
 from split_learning_tpu.models.afmoe import RMSNorm
@@ -82,6 +87,7 @@ from split_learning_tpu.ops.selective_scan import selective_scan
 _ATTN_IMPLS = ("auto", "full", "flash")
 _INIT = nn.initializers.normal(0.02)
 _F32 = jnp.float32
+_KEPT = "mlp_gate_up"    # the one value a layer's ``remat`` keeps
 
 
 def layer_kind(index: int, num_layers: int, mb_per_layer: int) -> str:
@@ -266,7 +272,9 @@ class DiffAttention(nn.Module):
 
 
 class SwiGLU(nn.Module):
-    """``(silu(g) * u) W_down`` with ``[g; u] = LN(h) W_gu``."""
+    """``(silu(g) * u) W_down`` with ``[g; u] = LN(h) W_gu``. The product
+    ``[g; u]`` carries the name :data:`_KEPT`: what a ``remat`` of this
+    module keeps."""
 
     sizes: Sizes
 
@@ -274,8 +282,9 @@ class SwiGLU(nn.Module):
     def __call__(self, h):
         z_ = self.sizes
         x = nn.LayerNorm(epsilon=z_.eps, dtype=z_.dtype, name="ln")(h)
-        g, up = jnp.split(_dense(2 * z_.mlp_width, z_.dtype, "gate_up")(x),
-                          2, axis=-1)
+        gu = checkpoint_name(
+            _dense(2 * z_.mlp_width, z_.dtype, "gate_up")(x), _KEPT)
+        g, up = jnp.split(gu, 2, axis=-1)
         return _dense(h.shape[-1], z_.dtype, "down")(jax.nn.silu(g) * up)
 
 
@@ -300,7 +309,9 @@ class Phi4FlashLayer(nn.Module):
             out, shared = DiffAttention(z_, kind, self.index,
                                         name="attn")(u, kv)
         h = h + out
-        mlp = nn.remat(SwiGLU) if z_.remat else SwiGLU
+        # the product is kept, the LayerNorm and the gate made again
+        keep = jax.checkpoint_policies.save_only_these_names(_KEPT)
+        mlp = nn.remat(SwiGLU, policy=keep) if z_.remat else SwiGLU
         return h + mlp(z_, name="mlp")(h), shared
 
 
@@ -410,8 +421,11 @@ def phi4flash_plan(mode: str = "split", dtype: Any = jnp.float32, *,
     the published indices of the layers built, in order (each keeps the
     kind and the ``lambda_init`` of its published place), of which the
     client holds the first ``client_depth`` beside the embedding.
-    ``d_inner`` is ``expand * d_model``. ``remat`` recomputes each layer's
-    MLP in the backward pass (the module header)."""
+    ``d_inner`` is ``expand * d_model``. ``remat`` makes each MLP's
+    LayerNorm and gate again in the backward pass and keeps its ``2 *
+    mlp_width`` wide product (the module header, with XLA's four figures:
+    14.916 GB with nothing recomputed, 14.496 so, 12.822 with the product
+    recomputed too, 10.282 with whole layers)."""
     if attn not in _ATTN_IMPLS:
         raise ValueError(f"Unknown attn impl: {attn!r} (expected {_ATTN_IMPLS})")
     kept = tuple(int(i) for i in layers_kept)

@@ -385,8 +385,10 @@ def test_scopes_name_the_new_parts():
 
 
 def test_remat_changes_no_number():
-    """Each layer's MLP under ``nn.remat`` or not: the same loss and the
-    same gradients (float32: the recomputed forward is the forward)."""
+    """Each layer's MLP under ``nn.remat`` that keeps its wide product, or
+    under none: the same loss and the same gradients (float32: the kept
+    product is the forward's own, and the recomputed passes around it
+    are the forward's)."""
     (x, y), = batches(1)
     out = []
     for remat in (True, False):
@@ -402,3 +404,50 @@ def test_remat_changes_no_number():
         np.testing.assert_allclose(a, b, rtol=0,
                                    atol=1e-5 * max(np.abs(b).max(), 1e-3),
                                    err_msg=name)
+
+
+def _equations(jaxpr, inside=()):
+    """``(names of the equations it lies inside, equation)`` for every
+    equation of a jaxpr and of those inside it."""
+    for eqn in jaxpr.eqns:
+        yield inside, eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub, inside + (eqn.primitive.name,))
+
+
+@pytest.mark.parametrize("named,again", [(True, 0), (False, 5)],
+                         ids=["product-kept", "name-lost"])
+def test_remat_keeps_the_wide_product_and_recomputes_around_it(
+        monkeypatch, named, again):
+    """In the gradient's jaxpr the ``[B, T, 2 * mlp_width]`` product is
+    there once a layer, in the forward, and the five recomputed bodies
+    (one ``checkpoint`` equation a layer: LayerNorm and the gate) hold
+    none. Without the product's name the policy keeps nothing and every
+    body makes it again, as the whole-MLP wrap did: the count sees it."""
+    if not named:
+        monkeypatch.setattr(phi4flash, "checkpoint_name", lambda x, name: x)
+    # 2 * 96 = 192: no other product of these sizes is that wide (the
+    # Mamba's in_proj is 2 * 128)
+    width = 96
+    plan = get_plan("phi4flash", "split", jnp.float32,
+                    **{**KW, "mlp_width": width})
+    (x, y), = batches(1)
+    shapes = jax.eval_shape(plan.init, jax.random.PRNGKey(0), x)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: cross_entropy(plan.apply(p, x), y)))(shapes).jaxpr
+    bodies = [e for _, e in _equations(jaxpr)
+              if e.primitive.name == "remat2"]    # jax.checkpoint's
+    assert len(bodies) == len(KW["layers_kept"])
+    wide = Counter(
+        bool(inside) for inside, e in _equations(jaxpr)
+        if e.primitive.name == "dot_general"
+        and e.outvars[0].aval.shape == (B, T, 2 * width))
+    assert wide[False] == len(KW["layers_kept"]) and wide[True] == again
+    # what a body does make again: the LayerNorm's statistics and the gate
+    for body in bodies:
+        made = Counter(e.primitive.name
+                       for _, e in _equations(body.params["jaxpr"]))
+        assert made["rsqrt"] == 1 and made["logistic"] >= 1
