@@ -98,7 +98,7 @@ put it. Decoding through a KV cache is not built for this family.
 from __future__ import annotations
 
 import functools
-from typing import Any, Optional, Sequence
+from typing import Any, Callable, Optional, Sequence
 
 import flax.linen as nn
 import jax
@@ -474,8 +474,9 @@ class AfmoeLayer(nn.Module):
 def _no_cache(cache_len, decode_cache):
     if cache_len or decode_cache is not None:
         raise NotImplementedError(
-            "afmoe has no KV-cache decode: window layers need a cache "
-            "that forgets (runtime/generate.py, ROADMAP.md M4)")
+            "no KV-cache decode is built for these layers: a window needs "
+            "a cache that forgets (ROADMAP.md M4), a short convolution its "
+            "last tokens beside the keys and values (M7; runtime/generate.py)")
 
 
 def _run_layers(h, first: int, layer_types, dense_layers: int, layer_kw):
@@ -494,23 +495,28 @@ def _run_layers(h, first: int, layer_types, dense_layers: int, layer_kw):
 
 class AfmoeEmbedStage(nn.Module):
     """Client bottom stage: ``[B, T] int -> [B, T, d_model]``: the
-    embedding (times ``sqrt(d_model)``, the family's muP rule; no
-    position table) and the first layers. ``layers`` is what
-    :func:`_run_layers` takes after ``h``."""
+    embedding (times ``sqrt(d_model)``, the family's muP rule, unless
+    ``mup`` is off; no position table) and the first layers. ``layers``
+    is what ``run`` takes after ``h``: :func:`_run_layers`, or another
+    family's (models/lfm2_moe.py), whose layers these three stages then
+    hold."""
 
     vocab: int
     d_model: int
     layers: tuple
     dtype: Any = jnp.float32
+    run: Optional[Callable] = None
+    mup: bool = True
 
     @nn.compact
     def __call__(self, tokens, *, cache_len: int = 0, decode_cache=None,
                  pos=None):
         _no_cache(cache_len, decode_cache)
-        emb = nn.Embed(self.vocab, self.d_model, dtype=self.dtype,
-                       embedding_init=_INIT, name="tok")(tokens)
-        h = emb * jnp.asarray(self.d_model ** 0.5, self.dtype)
-        return _run_layers(h, *self.layers)
+        h = nn.Embed(self.vocab, self.d_model, dtype=self.dtype,
+                     embedding_init=_INIT, name="tok")(tokens)
+        if self.mup:
+            h = h * jnp.asarray(self.d_model ** 0.5, self.dtype)
+        return (self.run or _run_layers)(h, *self.layers)
 
 
 class AfmoeHeadStage(nn.Module):
@@ -542,12 +548,13 @@ class AfmoeTrunkAndHead(nn.Module):
     vocab: int = 0
     eps: float = 1e-5
     dtype: Any = jnp.float32
+    run: Optional[Callable] = None    # as AfmoeEmbedStage's
 
     @nn.compact
     def __call__(self, h, *, cache_len: int = 0, decode_cache=None,
                  pos=None):
         _no_cache(cache_len, decode_cache)
-        h = _run_layers(h, *self.layers)
+        h = (self.run or _run_layers)(h, *self.layers)
         if not self.vocab:
             return h
         return AfmoeHeadStage(self.vocab, self.eps, self.dtype,

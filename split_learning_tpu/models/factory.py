@@ -138,6 +138,15 @@ def _joyai_llm_flash(mode: str, dtype: Any, **kw: Any) -> SplitPlan:
     return joyai_llm_flash_plan(mode=mode, dtype=dtype, **kw)
 
 
+@register_model("lfm2_moe")
+def _lfm2_moe(mode: str, dtype: Any, **kw: Any) -> SplitPlan:
+    """Gated short convolutions in most layers, grouped-head attention
+    with normed queries and keys in the others, and this party's share of
+    the routed experts with no shared one (models/lfm2_moe.py)."""
+    from split_learning_tpu.models.lfm2_moe import lfm2_moe_plan
+    return lfm2_moe_plan(mode=mode, dtype=dtype, **kw)
+
+
 def get_plan(model: str = "split_cnn", mode: str = "split",
              dtype: Any = jnp.float32, **size_kw: Any) -> SplitPlan:
     """Build the SplitPlan for a model family under a learning mode.

@@ -79,6 +79,7 @@ from jax.ad_checkpoint import checkpoint_name
 from split_learning_tpu.core.stage import SplitPlan, from_flax
 from split_learning_tpu.models.afmoe import RMSNorm
 from split_learning_tpu.obs import spans
+from split_learning_tpu.ops.common import causal_depthwise_conv
 from split_learning_tpu.ops.flash_attention import (
     flash_attention, select_attention)
 from split_learning_tpu.ops.ring_attention import full_attention
@@ -165,12 +166,10 @@ class Mamba(nn.Module):
         # the state out (a 16-wide last axis pads eightfold on the chip)
         a_log = self.param("A_log", nn.initializers.zeros, (n, inner))
         d_skip = self.param("D", nn.initializers.ones, (inner,))
-        t = x.shape[1]
         with jax.named_scope(spans.SSM_CONV):
             # x'_t from x_{t - d_conv + 1 .. t}: tap k weighs x_{t-(K-1)+k}
-            past = jnp.pad(x.astype(_F32), ((0, 0), (z_.d_conv - 1, 0), (0, 0)))
-            x = jax.nn.silu(conv_b + sum(
-                conv_w[k] * past[:, k:k + t] for k in range(z_.d_conv)))
+            x = jax.nn.silu(conv_b + causal_depthwise_conv(
+                x.astype(_F32), conv_w))
         r, b, c = jnp.split(_product(x, x_proj, dtype),
                             [z_.dt_rank, z_.dt_rank + n], axis=-1)
         delta = jax.nn.softplus(_product(r, dt_proj, dtype) + dt_bias)

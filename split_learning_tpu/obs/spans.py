@@ -239,9 +239,12 @@ ATTN_CROSS = "attn_cross"    # attention over another layer's keys and values
 ATTN_LATENT = "attn_latent"  # the flash calls of a latent-attention layer
 MLA_PROJ = "mla_proj"        # its down- and up-projections, norms, rotary
 MTP = "mtp"                  # the multi-token-prediction module, whole
+# -- and of the lfm2_moe family (models/lfm2_moe.py), which shares
+# ``attn_full`` and the routed layer's ``moe_route`` / ``moe_experts``
+SHORT_CONV = "short_conv"    # a gated short convolution's gates and taps
 DEVICE_SCOPES = (ATTN_WINDOW, ATTN_FULL, MOE_ROUTE, MOE_EXPERTS, MOE_SHARED,
                  SSM_CONV, SSM_SCAN, GMU, ATTN_CROSS, ATTN_LATENT, MLA_PROJ,
-                 MTP)
+                 MTP, SHORT_CONV)
 
 # -- counters that leave a jitted step (core/stage.with_counters) ------- #
 # the flax collection a module sows its step's counters into; mutable

@@ -57,3 +57,14 @@ def as_rows_of_lanes(flat: jax.Array, rows: int) -> jax.Array:
     elementwise kernels over arbitrarily-shaped leaves."""
     padded = pad_axis(flat, 0, rows * LANE)
     return padded.reshape(rows, LANE)
+
+
+def causal_depthwise_conv(x: jax.Array, taps: jax.Array) -> jax.Array:
+    """``y_t = sum_k taps[k] * x_{t - (K - 1) + k}`` per channel: ``x [B,
+    T, C]``, ``taps [K, C]``, zeros before the sequence's start, so the
+    last tap weighs the current token. A shifted sum of slices, which
+    XLA fuses into one pass (models/phi4flash.py's Mamba at four taps,
+    models/lfm2_moe.py's gated convolution at three)."""
+    t, k = x.shape[1], taps.shape[0]
+    past = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    return sum(taps[i] * past[:, i:i + t] for i in range(k))
