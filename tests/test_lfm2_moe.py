@@ -429,8 +429,10 @@ DIGESTS = {
         "fused_step": "3349073ae300bef8", "outputs": 333, "equations": 4632,
         "state_and_loss_alone": "bbad2f4f7c235af3", "equations_alone": 4545},
     "lfm2-moe-fused-t8192": {
-        "fused_step": "95477362e3eccc89", "outputs": 177, "equations": 2215,
-        "state_and_loss_alone": "139934ec994c1266", "equations_alone": 2154},
+        # PR 38: the grouped products take the 1536-wide experts whole
+        # (95477362e3eccc89 / 139934ec994c1266 before, the same equations)
+        "fused_step": "e2cfe85da3c9f053", "outputs": 177, "equations": 2215,
+        "state_and_loss_alone": "589fbae6853e320e", "equations_alone": 2154},
 }
 
 
