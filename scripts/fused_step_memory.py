@@ -13,7 +13,8 @@ counts this one program, not what else a process keeps on the device
 (PERF.md section 4: two loaded executables once cost the reference its
 room).  It is the check of the fit rules of ``trinity-mini-fused-t8192``
 (13.0 GB or under), ``phi4flash-fused-t8192``,
-``joyai-flash-fused-t8192`` and ``lfm2-moe-fused-t8192`` (14.5).  ``--remat 0``
+``joyai-flash-fused-t8192``, ``lfm2-moe-fused-t8192`` and
+``nemotronh-moe-fused-t8192`` (14.5).  ``--remat 0``
 compiles the step without the family's ``remat``, to see what the values it
 recomputes cost when kept.
 """

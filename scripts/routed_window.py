@@ -4,6 +4,9 @@ its step counters say.
     python scripts/routed_window.py --workload trinity-mini-fused-t8192 --seed N
         [--seconds 20] [--table chiprun_out/<name>.json]
 
+(any cell whose plan holds ``models/afmoe.py``'s ``RoutedExperts``: the
+``joyai-flash``, ``lfm2-moe`` and ``nemotronh-moe`` cells too)
+
 Runs ``benchmarks/run.py``'s own ``main`` (the cell's weights, batches, check
 steps and untraced window) under ``obs.enable()``: no profiler session, the
 recorder alone, so the step is the untraced one plus one ``counters_read`` a

@@ -26,7 +26,8 @@ One layer (RMSNorm(x) = x / sqrt(mean(x^2) + eps) * scale, no biases):
 
 **The routed layer is told its share.** It holds experts
 ``[expert_offset, expert_offset + experts_held)`` of ``experts_total``
-as three stacked leaves, routes over all of them and computes the part
+as three stacked leaves (two where the expert has no gate:
+:class:`RoutedExperts`), routes over all of them and computes the part
 its own give: the (token, expert) pairs are sorted by the held expert
 they chose, pairs of absent experts last (:func:`held_pairs`), the first
 ``R`` sorted rows are gathered, the grouped products
@@ -61,7 +62,7 @@ even share.
 **What ``remat`` recomputes.** With ``remat`` the routed part is one
 ``custom_vjp`` (:func:`_routed_recomputed`): its backward switches again
 on the same index and makes the rung's body again inside the branch
-(:func:`_routed_rows`: the gather out, the three grouped products, the
+(:func:`_routed_rows`: the gather out, the grouped products, the
 sum back), so none of a rung's rows is kept and what a conditional
 returns is its result alone. One ``checkpoint`` around a ``switch``
 would instead return every rung's kept rows from every branch, zeros
@@ -153,6 +154,11 @@ def rope(x: jax.Array, theta: float) -> jax.Array:
 
 
 class AfmoeAttention(nn.Module):
+    """Grouped-head attention. ``plain`` (models/nemotron_h.py's) is the
+    same layer without the output gate and without the norms of q and k:
+    with ``window`` None it has no positions either, four products and
+    the kernel."""
+
     num_heads: int
     num_kv_heads: int
     head_dim: int
@@ -161,6 +167,7 @@ class AfmoeAttention(nn.Module):
     eps: float = 1e-5
     attn: str = "auto"
     dtype: Any = jnp.float32
+    plain: bool = False
 
     @nn.compact
     def __call__(self, u):
@@ -169,9 +176,10 @@ class AfmoeAttention(nn.Module):
         q = _linear(h * d, self.dtype, "q")(u).reshape(b, t, h, d)
         k = _linear(hk * d, self.dtype, "k")(u).reshape(b, t, hk, d)
         v = _linear(hk * d, self.dtype, "v")(u).reshape(b, t, hk, d)
-        gate = _linear(h * d, self.dtype, "gate")(u)
-        q = RMSNorm(self.eps, self.dtype, name="q_norm")(q)
-        k = RMSNorm(self.eps, self.dtype, name="k_norm")(k)
+        if not self.plain:
+            gate = _linear(h * d, self.dtype, "gate")(u)
+            q = RMSNorm(self.eps, self.dtype, name="q_norm")(q)
+            k = RMSNorm(self.eps, self.dtype, name="k_norm")(k)
         if self.window is not None:
             q, k = rope(q, self.rope_theta), rope(k, self.rope_theta)
         impl = self.attn
@@ -184,7 +192,9 @@ class AfmoeAttention(nn.Module):
         scope = spans.ATTN_FULL if self.window is None else spans.ATTN_WINDOW
         with jax.named_scope(scope):
             o = fn(q, k, v, causal=True, window=self.window)
-        a = o.reshape(b, t, h * d) * jax.nn.sigmoid(gate)
+        a = o.reshape(b, t, h * d)
+        if not self.plain:
+            a = a * jax.nn.sigmoid(gate)
         return _linear(e, self.dtype, "out")(a)
 
 
@@ -306,15 +316,23 @@ def rung_of(filled, rungs: Sequence[int]):
     return sum(filled > rows for rows in rungs[:-1])
 
 
-def _routed_rows(rows: int, m, order, inverse, sizes, weights, gate, up, down):
+def _routed_rows(rows: int, gated: bool, m, order, inverse, sizes, weights,
+                 *mats):
     """The held experts' part of ``[n, d]`` tokens ``m``, computed over
-    the first ``rows`` sorted pairs (``sum(sizes) <= rows``)."""
+    the first ``rows`` sorted pairs (``sum(sizes) <= rows``). ``mats`` are
+    the experts' stacked leaves: gate, up and down of a ``gated`` expert
+    (``silu(x W_gate) * (x W_up)``), else up and down (``relu(x W_up)^2``)."""
     with jax.named_scope(spans.MOE_ROUTE):
         order = order[:rows]
         x = _rows_out(m, order, inverse, weights.shape[1])
     with jax.named_scope(spans.MOE_EXPERTS):
-        act = jax.nn.silu(grouped_matmul(x, gate, sizes)) * \
-            grouped_matmul(x, up, sizes)
+        if gated:
+            gate, up, down = mats
+            act = jax.nn.silu(grouped_matmul(x, gate, sizes)) * \
+                grouped_matmul(x, up, sizes)
+        else:
+            up, down = mats
+            act = jnp.square(jax.nn.relu(grouped_matmul(x, up, sizes)))
         out = grouped_matmul(act, down, sizes)
     with jax.named_scope(spans.MOE_ROUTE):
         # rows of absent experts are zero, so their weights count for
@@ -323,12 +341,12 @@ def _routed_rows(rows: int, m, order, inverse, sizes, weights, gate, up, down):
 
 
 @functools.lru_cache(maxsize=None)
-def _rung(rows: int):
+def _rung(rows: int, gated: bool):
     """(forward, backward) of :func:`_routed_rows` at ``rows``, each under
     its own ``jit``: the backward makes the forward again and keeps
-    nothing of it. One pair a rung, so a step traces and lowers a rung
-    once for all its layers."""
-    body = functools.partial(_routed_rows, rows)
+    nothing of it. One pair a rung and expert form, so a step traces and
+    lowers a rung once for all its layers."""
+    body = functools.partial(_routed_rows, rows, gated)
 
     def backward(operands, g):
         m, order, inverse, sizes, *floats = operands
@@ -339,29 +357,31 @@ def _rung(rows: int):
     return jax.jit(body), jax.jit(backward)
 
 
-def _switch(half: int, rungs, sizes, *args):
+def _switch(half: int, form, sizes, *args):
     """Run the smallest rung that holds ``sum(sizes)`` rows: its forward
-    (``half`` 0) or backward (1); the index never leaves the device."""
-    bodies = [_rung(rows)[half] for rows in rungs]
+    (``half`` 0) or backward (1); the index never leaves the device.
+    ``form`` is ``(rungs, gated)``."""
+    rungs, gated = form
+    bodies = [_rung(rows, gated)[half] for rows in rungs]
     if len(bodies) == 1:
         return bodies[0](*args)
     return jax.lax.switch(rung_of(sizes.sum(), rungs), bodies, *args)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _routed_recomputed(rungs, m, order, inverse, sizes, *floats):
-    """:func:`_routed_rows` over the rung of ``rungs`` that ``sizes``
+def _routed_recomputed(form, m, order, inverse, sizes, *floats):
+    """:func:`_routed_rows` over the rung of ``form``'s that ``sizes``
     select, recomputed in that rung by the backward pass."""
-    return _switch(0, rungs, sizes, m, order, inverse, sizes, *floats)
+    return _switch(0, form, sizes, m, order, inverse, sizes, *floats)
 
 
-def _routed_recomputed_fwd(rungs, *operands):
-    return _routed_recomputed(rungs, *operands), operands
+def _routed_recomputed_fwd(form, *operands):
+    return _routed_recomputed(form, *operands), operands
 
 
-def _routed_recomputed_bwd(rungs, operands, g):
+def _routed_recomputed_bwd(form, operands, g):
     sizes = operands[3]
-    d_m, *d_floats = _switch(1, rungs, sizes, operands, g)
+    d_m, *d_floats = _switch(1, form, sizes, operands, g)
     return (d_m, None, None, None, *d_floats)
 
 
@@ -369,7 +389,11 @@ _routed_recomputed.defvjp(_routed_recomputed_fwd, _routed_recomputed_bwd)
 
 
 class RoutedExperts(nn.Module):
-    """The part of a routed layer that the experts held here give."""
+    """The part of a routed layer that the experts held here give. An
+    expert is ``W_down (silu(W_gate x) * (W_up x))``, three stacked
+    leaves, or with ``gated`` off ``W_down relu(W_up x)^2``, two
+    (models/nemotron_h.py's): two grouped products a pass where the gated
+    form runs three."""
 
     width: int
     experts_total: int
@@ -379,6 +403,7 @@ class RoutedExperts(nn.Module):
     route_scale: float
     dtype: Any = jnp.float32
     remat: bool = False       # AfmoeLayer's: recompute each rung's body
+    gated: bool = True
 
     @nn.compact
     def __call__(self, m32):
@@ -387,15 +412,15 @@ class RoutedExperts(nn.Module):
         router = self.param("router", _INIT, (d, self.experts_total))
         bias = self.param("expert_bias", nn.initializers.zeros,
                           (self.experts_total,))
-        gate = self.param("gate", _INIT, (held, d, self.width))
-        up = self.param("up", _INIT, (held, d, self.width))
-        down = self.param("down", _INIT, (held, self.width, d))
+        wide = lambda name: self.param(name, _INIT, (held, d, self.width))
+        mats = ((wide("gate"),) if self.gated else ()) + (
+            wide("up"), self.param("down", _INIT, (held, self.width, d)))
         with jax.named_scope(spans.MOE_ROUTE):
             chosen, weights = route(m32, router, bias, k, self.route_scale)
             order, inverse, sizes = held_pairs(chosen, self.expert_offset,
                                                held)
         operands = (m32.astype(self.dtype), order, inverse, sizes, weights,
-                    gate, up, down)
+                    *mats)
         # without remat: kept rows of both rungs would be the switch's outputs
         rungs = pair_rungs(n * k, held, self.experts_total) if self.remat \
             else (n * k,)
@@ -404,8 +429,8 @@ class RoutedExperts(nn.Module):
                 and not self.is_initializing():
             self._count(sizes, rungs)
         if not self.remat:
-            return _routed_rows(n * k, *operands)
-        return _routed_recomputed(rungs, *operands)
+            return _routed_rows(n * k, self.gated, *operands)
+        return _routed_recomputed((rungs, self.gated), *operands)
 
     def _count(self, sizes, rungs) -> None:
         """Sow what the step decides on the device (the module header):
@@ -476,7 +501,8 @@ def _no_cache(cache_len, decode_cache):
         raise NotImplementedError(
             "no KV-cache decode is built for these layers: a window needs "
             "a cache that forgets (ROADMAP.md M4), a short convolution its "
-            "last tokens beside the keys and values (M7; runtime/generate.py)")
+            "last tokens and a state-space layer its state beside the keys "
+            "and values (M7; runtime/generate.py)")
 
 
 def _run_layers(h, first: int, layer_types, dense_layers: int, layer_kw):

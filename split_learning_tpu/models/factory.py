@@ -147,6 +147,15 @@ def _lfm2_moe(mode: str, dtype: Any, **kw: Any) -> SplitPlan:
     return lfm2_moe_plan(mode=mode, dtype=dtype, **kw)
 
 
+@register_model("nemotron_h")
+def _nemotron_h(mode: str, dtype: Any, **kw: Any) -> SplitPlan:
+    """One mixer a layer: Mamba-2 in its chunked form, this party's share
+    of ungated relu^2 experts beside a shared one, grouped-head attention
+    without positions (models/nemotron_h.py)."""
+    from split_learning_tpu.models.nemotron_h import nemotron_h_plan
+    return nemotron_h_plan(mode=mode, dtype=dtype, **kw)
+
+
 def get_plan(model: str = "split_cnn", mode: str = "split",
              dtype: Any = jnp.float32, **size_kw: Any) -> SplitPlan:
     """Build the SplitPlan for a model family under a learning mode.

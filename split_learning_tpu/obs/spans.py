@@ -242,9 +242,12 @@ MTP = "mtp"                  # the multi-token-prediction module, whole
 # -- and of the lfm2_moe family (models/lfm2_moe.py), which shares
 # ``attn_full`` and the routed layer's ``moe_route`` / ``moe_experts``
 SHORT_CONV = "short_conv"    # a gated short convolution's gates and taps
+# -- and of the nemotron_h family (models/nemotron_h.py), which shares
+# ``attn_full``, ``ssm_conv`` and the routed layer's three scopes
+SSM_SSD = "ssm_ssd"          # a Mamba-2 recurrence's chunked form (ops/ssd.py)
 DEVICE_SCOPES = (ATTN_WINDOW, ATTN_FULL, MOE_ROUTE, MOE_EXPERTS, MOE_SHARED,
                  SSM_CONV, SSM_SCAN, GMU, ATTN_CROSS, ATTN_LATENT, MLA_PROJ,
-                 MTP, SHORT_CONV)
+                 MTP, SHORT_CONV, SSM_SSD)
 
 # -- counters that leave a jitted step (core/stage.with_counters) ------- #
 # the flax collection a module sows its step's counters into; mutable

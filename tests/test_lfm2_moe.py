@@ -433,6 +433,12 @@ DIGESTS = {
         # (95477362e3eccc89 / 139934ec994c1266 before, the same equations)
         "fused_step": "e2cfe85da3c9f053", "outputs": 177, "equations": 2215,
         "state_and_loss_alone": "589fbae6853e320e", "equations_alone": 2154},
+    "nemotronh-moe-fused-t8192": {
+        # PR 39 brought the cell; the eight above read what they read at its
+        # parent, though models/afmoe.py's attention and routed layer took a
+        # second form each for it
+        "fused_step": "8e836b4a0a66af89", "outputs": 180, "equations": 2493,
+        "state_and_loss_alone": "4ea174b3155afc5d", "equations_alone": 2431},
 }
 
 
