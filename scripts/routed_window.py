@@ -12,13 +12,15 @@ steps and untraced window) under ``obs.enable()``: no profiler session, the
 recorder alone, so the step is the untraced one plus one ``counters_read`` a
 step (``runtime/fused.py``). The benchmark's result line comes first (its
 ``tokens_per_s`` is the rate with recording on); then one JSON line from the
-window's records: for each routed layer the window step at which it first ran
-a rung above its lowest and the share of steps it spent there, its pairs over
+window's records: for each routed layer its steps by the rung it ran (one
+count a rung of its ladder, lowest first, whatever the ladder's length), the
+window step at which it first ran each rung above its lowest, its pairs over
 the even share at the first and the last step and its fullest expert's share
-of them; the median step time by the number of layers above the lowest rung;
-what a rung of twice the lowest would have held; what reading the counters
-cost. ``--table`` keeps the per-step rows. On the CPU (``JAX_PLATFORMS=cpu``)
-it is the rehearsal's sizes, where the ladder has one rung.
+of them; the (step, layer) samples by rung over all layers; the median step
+time by how many layers ran each rung (``"3/1/0"``: three on the lowest, one
+on the next, none on the top); what reading the counters cost. ``--table``
+keeps the per-step rows. On the CPU (``JAX_PLATFORMS=cpu``) it is the
+rehearsal's sizes, where the ladder has one rung.
 """
 
 from __future__ import annotations
@@ -37,7 +39,9 @@ sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
 
 def reduce(records: list, before: int, even: float) -> tuple:
     """(summary, per-step rows) of the window: the records' steps but the
-    first ``before`` (the check steps and the warm-up step)."""
+    first ``before`` (the check steps and the warm-up step). A row's
+    ``rung`` is, layer by layer, the index in the layer's ladder of the
+    rows it ran."""
     steps = [r for r in records if r["name"] == "step_total"][before:]
     reads = {r["parent_id"]: r for r in records if r["name"] == "counters_read"}
     rows, layers = [], []
@@ -48,34 +52,41 @@ def reduce(records: list, before: int, even: float) -> tuple:
         rows.append({"step": k, "ms": 1e3 * step["duration"],
                      "read_ms": 1e3 * read["duration"], "pairs": a["pairs"],
                      "rows": a["rows"],
-                     "up": [r > min(l) for r, l in zip(a["rows"], a["ladder"])]})
+                     "rung": [l.index(r)
+                              for r, l in zip(a["rows"], a["ladder"])]})
     if not rows:
         return {"steps": len(steps), "layers": []}, rows
-    low = [min(l) for l in a["ladder"]]
+    ladders = a["ladder"]       # pair_rungs': smallest first
     by_layer = {}
     for i, layer in enumerate(layers):
-        up = [r["up"][i] for r in rows]
+        ran = [r["rung"][i] for r in rows]
         held = [sum(r["pairs"][i]) for r in rows]
         by_layer[layer] = {
-            "first_step_up": up.index(True) if any(up) else None,
-            "share_of_steps_up": sum(up) / len(up),
+            "ladder": ladders[i],
+            "steps_by_rung": [ran.count(n) for n in range(len(ladders[i]))],
+            "first_step_on_rung": [
+                next((k for k, n in enumerate(ran) if n >= rung), None)
+                for rung in range(1, len(ladders[i]))],
             "pairs_x_even_first": held[0] / even,
             "pairs_x_even_last": held[-1] / even,
             "pairs_x_even_max": max(held) / even,
             "fullest_expert_share_first": max(rows[0]["pairs"][i]) / max(held[0], 1),
             "fullest_expert_share_last": max(rows[-1]["pairs"][i]) / max(held[-1], 1)}
-    by_up = {}
+    longest = max(len(l) for l in ladders)
+    by_rungs = {}
     for r in rows:
-        by_up.setdefault(sum(r["up"]), []).append(r["ms"])
-    samples = [(sum(p), lo) for r in rows for p, lo in zip(r["pairs"], low)]
-    over = [(p, lo) for p, lo in samples if p > lo]
+        key = "/".join(str(r["rung"].count(n)) for n in range(longest))
+        by_rungs.setdefault(key, []).append(r["ms"])
+    sampled = [n for r in rows for n in r["rung"]]
     return {
-        "steps": len(rows), "layers": by_layer, "ladder": a["ladder"][0],
+        "steps": len(rows), "layers": by_layer, "ladder": ladders[0],
         "even_pairs": even,
-        "step_ms_by_layers_up": {str(n): {"steps": len(ms), "median_ms": statistics.median(ms)}
-                                 for n, ms in sorted(by_up.items())},
-        "samples": len(samples), "samples_over_lowest": len(over),
-        "of_them_within_twice_lowest": sum(p <= 2 * lo for p, lo in over),
+        "step_ms_by_layers_on_each_rung": {
+            key: {"steps": len(ms), "median_ms": statistics.median(ms)}
+            for key, ms in sorted(by_rungs.items(), reverse=True)},
+        "step_ms_median": statistics.median(r["ms"] for r in rows),
+        "samples": len(sampled),
+        "samples_by_rung": [sampled.count(n) for n in range(longest)],
         "counters_read_ms_p50": statistics.median(r["read_ms"] for r in rows),
         "counters_read_ms_max": max(r["read_ms"] for r in rows)}, rows
 

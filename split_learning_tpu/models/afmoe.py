@@ -40,8 +40,9 @@ absent chips. ``experts_held == experts_total`` is the whole layer.
 **``R`` follows the rows the routing fills.** The worst case is every
 pair held, ``n * k`` rows (tokens x experts per token); a share of the
 experts fills ``experts_held / experts_total`` of that under even
-routing. :func:`pair_rungs` gives two static row counts, twice the even
-share in whole row tiles (8192 for 8 of 128 experts at 8192 tokens x 8)
+routing. :func:`pair_rungs` gives up to three static row counts: twice
+the even share in whole row tiles (8192 for 8 of 128 experts at 8192
+tokens x 8), twice that (16384) where it is still under the worst case,
 and ``n * k``, each a body of one ``lax.switch`` whose index is read on
 the device from the sort's ``group_sizes`` (:func:`rung_of`: the
 smallest rung that holds their sum). The top rung is the worst case
@@ -51,13 +52,30 @@ in any rung; the rows that are filled are the same rows in the same
 order and tiles whatever the rung. What stays ``n * k`` long is indexed
 by pair: the two sorts, and the gather of each token's rows (forward,
 and the gradient of the gather out), which reads an ``[R + 1, d]``
-table. A share of half the experts or more has one rung and no
-conditional. XLA reserves temporaries for the larger branch: a step's
-memory is the top rung's. Two rungs and not a ladder of halvings
-between them: every rung is one more body (twelve kernels, forward and
-backward) to trace, lower and load in every process, about 4 s of
-set-up each on the chip's host, for routings between 2 and 16 times the
-even share.
+table. A share of a quarter to a half of the experts has two rungs
+(twice the lower one is the worst case or more), a share of half or
+more has one rung and no conditional. XLA sizes a step's temporaries for
+the largest branch, the top rung's; how it packs them moves a little
+with the number of branches (PERF.md, Findings PR 41). The grouped
+products follow the rows that are filled whatever the rung; everything
+XLA runs around them (the gather out, the zeroing of unfilled rows after
+every product, the activation, the float32 cotangent rows) is a pass
+over the rung's static rows, so a layer whose routing sends it 2.1 times
+the even share would, with two rungs, run 4 to 16 times the rows it ran
+a step before. A router without a balancing loss drifts exactly there:
+of the (step, layer) samples above the lower rung in the four routed
+cells' 20 s windows, most are under twice it (PERF.md, Findings PR 35-41).
+Three rungs and not four or a ladder of halvings up to the worst case:
+every rung is one more body (twelve kernel call sites, forward and
+backward; eight without a gate) to trace, lower and load in every
+process. PR 29 read ``setup_s`` +17.8 % for two more rungs against a
+bound of 10 % and was refused; one more costs 4 to 8 %, and a fourth
+between the middle and the top would save under 2 ms a layer where the
+pairs themselves, at four times the even share, are the products' own
+work. A rung is its own XLA program of the same bfloat16 mathematics:
+where a rounding falls may follow its shapes, so two rungs agree to the
+comparison's limits on the chip and not to the last bit (on the CPU, in
+float32, a routing gives the same output from any rung that holds it).
 
 **What ``remat`` recomputes.** With ``remat`` the routed part is one
 ``custom_vjp`` (:func:`_routed_recomputed`): its backward switches again
@@ -74,11 +92,11 @@ dense layer's gate / up / down), what the flash kernels' backward reads
 (padded q, k, v, the output and the row logsumexp) and the elementwise
 work between them, so no kernel and no dense product of a layer runs a
 second time. Without ``remat`` the routed part runs on the top rung
-alone and autodiff keeps its rows: kept rows of two rungs would both be
-outputs of the ``switch`` (15.9 GB a step at the benchmark's sizes, no
-cell runs it). ``Config.remat`` (``core/stage.remat_plan``: the whole
-stage under ``jax.checkpoint``) nests over this for a user short of
-memory.
+alone and autodiff keeps its rows: kept rows of every rung would all be
+outputs of the ``switch`` (15.9 GB a step at the benchmark's sizes with
+two rungs, no cell runs it). ``Config.remat`` (``core/stage.remat_plan``:
+the whole stage under ``jax.checkpoint``) nests over this for a user
+short of memory.
 
 **What the step reports.** A caller that applies the layer with the
 collection ``obs/spans.STEP_COUNTERS`` mutable (``core/stage.with_counters``:
@@ -303,11 +321,12 @@ def held_pairs(chosen, offset: int, held: int):
 def pair_rungs(pairs: int, held: int, total: int) -> tuple[int, ...]:
     """The row counts the routed part is built for, smallest first (the
     module header): twice what even routing sends to ``held`` of ``total``
-    experts, in whole row tiles of the grouped product, and the worst
-    case ``pairs``; the worst case alone where the first is no less."""
+    experts, in whole row tiles of the grouped product, twice that, and
+    the worst case ``pairs``; each of the first two only where it is
+    under the worst case."""
     rows = -(-2 * pairs * held // total)
     rows = round_up(rows, _row_tiles(rows, 1, 1)[0])
-    return (rows, pairs) if rows < pairs else (pairs,)
+    return tuple(r for r in (rows, 2 * rows) if r < pairs) + (pairs,)
 
 
 def rung_of(filled, rungs: Sequence[int]):
@@ -421,7 +440,7 @@ class RoutedExperts(nn.Module):
                                                held)
         operands = (m32.astype(self.dtype), order, inverse, sizes, weights,
                     *mats)
-        # without remat: kept rows of both rungs would be the switch's outputs
+        # without remat: kept rows of every rung would be the switch's outputs
         rungs = pair_rungs(n * k, held, self.experts_total) if self.remat \
             else (n * k,)
         # init makes every collection mutable: the weights stay alone

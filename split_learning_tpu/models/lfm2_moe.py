@@ -42,7 +42,7 @@ kept layers) -> server(the rest + final norm + untied head); u_split
 moves norm and head back to the client; federated is the composition.
 
 **What ``remat`` recomputes**, in the backward pass: the routed part of
-each routed layer (models/afmoe.py's header: it is what gives the two
+each routed layer (models/afmoe.py's header: it is what gives the three
 rungs of rows) and nothing else. Every dense product's output, both
 gates' inputs, what the flash kernels' backward reads and the dense
 layer's SwiGLU are kept: no product, no flash forward and no

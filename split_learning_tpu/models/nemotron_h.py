@@ -67,7 +67,7 @@ kept layers) -> server(the rest + final norm + untied head); u_split
 moves norm and head back to the client; federated is the composition.
 
 **What ``remat`` recomputes**, in the backward pass: the routed part of
-each ``E`` layer (models/afmoe.py's header: it is what gives the two rungs
+each ``E`` layer (models/afmoe.py's header: it is what gives the three rungs
 of rows), and the two elementwise passes of each ``M`` layer, which are
 bound by bytes and not by arithmetic: ``silu(conv(xBC) + b_conv)`` is made
 again from the first product's output, the gate with the grouped norm

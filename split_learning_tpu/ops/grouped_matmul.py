@@ -3,10 +3,11 @@
 ``grouped_matmul(lhs, rhs, group_sizes)`` multiplies consecutive row
 groups of ``lhs [m, k]`` by their own matrix of ``rhs [g, k, n]``: rows
 ``[sum(sizes[:i]), sum(sizes[:i+1]))`` by ``rhs[i]``. The groups need
-not fill ``lhs``: a routed layer picks, on the device, the smaller of
-two static buffer sizes if it holds what the routing sends to the
-experts held here, else the worst case of tokens x experts per token
-rows (models/afmoe.py). Rows past ``sum(group_sizes)`` come back as
+not fill ``lhs``: a routed layer picks, on the device, the smallest of
+up to three static buffer sizes that holds what the routing sends to
+the experts held here (twice the even share, twice that, and the worst
+case of tokens x experts per token rows: models/afmoe.pair_rungs, whose
+header says why three). Rows past ``sum(group_sizes)`` come back as
 zeros and take no gradient, and the work follows the rows that are
 filled, not ``m``.
 

@@ -249,7 +249,7 @@ LADDER = dict(width=32, experts_total=16, experts_held=2, expert_offset=5,
 LADDER_N, LADDER_D = 32, 64
 
 
-def routed_to(filled, dtype):
+def routed_to(filled, dtype, gated=True):
     """Parameters and tokens of a ``LADDER`` layer whose router sends
     exactly ``filled`` pairs to the two held experts: its first 16 rows
     are ten times the identity, and every token's first 16 features are
@@ -270,7 +270,7 @@ def routed_to(filled, dtype):
     x[:, :total] = -1.0
     for t, pair in enumerate(picks):
         x[t, list(pair)] = 1.0 + 0.1 * rs.rand(2)
-    layer = RoutedExperts(**LADDER, dtype=dtype, remat=True)
+    layer = RoutedExperts(**LADDER, dtype=dtype, remat=True, gated=gated)
     x = jnp.asarray(x)
     params = layer.init(jax.random.PRNGKey(filled), x)["params"]
     leaves, tree = jax.tree_util.tree_flatten(params)
@@ -292,40 +292,47 @@ def plain_routed(params, x, dtype):
     order, inverse, sizes = afmoe.held_pairs(chosen, 5, 2)
     rows = x.astype(dtype)[order // k]
     mm = lambda a, w: grouped_matmul_reference(a, params[w], sizes)
-    out = mm(jax.nn.silu(mm(rows, "gate")) * mm(rows, "up"), "down")
+    act = jax.nn.silu(mm(rows, "gate")) * mm(rows, "up") if "gate" in params \
+        else jnp.square(jax.nn.relu(mm(rows, "up")))
+    out = mm(act, "down")
     per_pair = out[inverse].reshape(x.shape[0], k, -1).astype(jnp.float32)
     return jnp.einsum("nkd,nk->nd", per_pair, weights).astype(dtype), sizes
 
 
 @functools.lru_cache(maxsize=None)
-def _ladder_step(dtype, remat):
+def _ladder_step(dtype, remat, gated=True):
     """One compile a form and type for all the routings below."""
-    layer = RoutedExperts(**LADDER, dtype=dtype, remat=remat)
+    layer = RoutedExperts(**LADDER, dtype=dtype, remat=remat, gated=gated)
     c = jax.random.normal(jax.random.PRNGKey(9), (LADDER_N, LADDER_D))
     f = lambda p, x: (lambda y: (jnp.sum(y.astype(jnp.float32) * c), y))(
         layer.apply({"params": p}, x))
     return jax.jit(jax.value_and_grad(f, argnums=(0, 1), has_aux=True))
 
 
-# rungs 16 and 64: inside each, on each edge, one past the lower edge,
-# nothing routed here, and every pair held (what the top rung is for)
+# rungs 16, 32 and 64: inside each, on each edge, one past each lower edge,
+# nothing routed here, and every pair held (what the top rung is for); the
+# form without a gate on each edge and one past it
+FILLS = [(0, 16), (5, 16), (15, 16), (16, 16), (17, 32), (24, 32), (31, 32),
+         (32, 32), (33, 64), (50, 64), (63, 64), (64, 64)]
+UNGATED = [(16, 16), (17, 32), (24, 32), (32, 32), (33, 64)]
+
+
 @pytest.mark.parametrize("dtype,tol", [("float32", 1e-6), ("bfloat16", 1e-2)])
-@pytest.mark.parametrize("filled,rung", [
-    (0, 16), (5, 16), (15, 16), (16, 16), (17, 64), (24, 64), (33, 64),
-    (50, 64), (63, 64), (64, 64)])
-def test_every_rung_gives_the_top_rungs_numbers(filled, rung, dtype, tol):
+@pytest.mark.parametrize("filled,rung,gated", [
+    *((f, r, True) for f, r in FILLS), *((f, r, False) for f, r in UNGATED)])
+def test_every_rung_gives_the_top_rungs_numbers(filled, rung, gated, dtype, tol):
     """The rung that holds the pairs routed here against the kept form
     (``remat=False``: the top rung alone) and against the plain layer:
     output, loss and every gradient, with no pair's part missing."""
     dtype = jnp.dtype(dtype)
     rungs = afmoe.pair_rungs(LADDER_N * 2, 2, 16)
-    assert rungs == (16, 64)
-    params, x = routed_to(filled, dtype)
+    assert rungs == (16, 32, 64)
+    params, x = routed_to(filled, dtype, gated)
     want_y, sizes = plain_routed(params, x, dtype)
     assert int(sizes.sum()) == filled
     assert rungs[afmoe.rung_of(filled, rungs)] == rung
     assert rungs[int(afmoe.rung_of(sizes.sum(), rungs))] == rung
-    run = lambda remat: _ladder_step(dtype, remat)(params, x)
+    run = lambda remat: _ladder_step(dtype, remat, gated)(params, x)
     (loss, y), grads = run(True)
     (top_loss, top_y), top_grads = run(False)
     scale = lambda a: max(float(np.abs(np.asarray(a, np.float32)).max()), 1e-6)
@@ -338,12 +345,12 @@ def test_every_rung_gives_the_top_rungs_numbers(filled, rung, dtype, tol):
         atol=max(tol, 2e-5) * scale(want_y))
     assert abs(float(loss) - float(top_loss)) <= tol * max(abs(float(top_loss)), 1.0)
     got, ref = flat(grads), flat(top_grads)
-    assert got.keys() == ref.keys() and len(got) == 6
+    assert got.keys() == ref.keys() and len(got) == 5 + gated
     for name, g in ref.items():
         np.testing.assert_allclose(got[name], g, rtol=0, atol=tol * scale(g),
                                    err_msg=name)
     if filled:   # the held experts' weights do take a gradient
-        assert np.abs(ref["[0]['gate']"]).max() > 0
+        assert np.abs(ref["[0]['up']"]).max() > 0
 
 
 def _conds(jaxpr, found):
@@ -362,22 +369,70 @@ def _conds(jaxpr, found):
     return found
 
 
-@pytest.mark.parametrize("held,conds", [(8, 0), (4, 0), (1, 2)],
-                         ids=["whole-layer", "half", "an-eighth"])
-def test_a_layer_whose_rows_are_all_filled_has_no_conditional(held, conds):
+@pytest.mark.parametrize("held,remat,ladder", [
+    (8, True, (48,)), (4, True, (48,)), (2, True, (32, 48)),
+    (1, True, (16, 32, 48)), (1, False, (48,))],
+    ids=["whole-layer", "half", "a-quarter", "an-eighth", "an-eighth-kept"])
+def test_a_layer_whose_rows_are_all_filled_has_no_conditional(held, remat, ladder):
     """``experts_held == experts_total`` (and any share of a half or more:
     twice even routing is the worst case) is one rung, so the gradient
-    traces to no ``cond``; an eighth is two rungs and two ``cond``s, the
-    forward's and the backward's."""
+    traces to no ``cond``; a quarter is two rungs, an eighth three, and
+    either two ``cond``s, the forward's and the backward's, of a branch a
+    rung. Without ``remat`` the layer runs ``n * k`` rows whatever its
+    share, and no ``cond``."""
     layer = RoutedExperts(width=32, experts_total=8, experts_held=held,
                           expert_offset=0, per_token=2, route_scale=2.826,
-                          remat=True)
+                          remat=remat)
     x = jax.random.normal(jax.random.PRNGKey(0), (24, 64))
     params = layer.init(jax.random.PRNGKey(1), x)
     f = lambda p, x: jnp.sum(layer.apply(p, x) ** 2)
     found = _conds(jax.make_jaxpr(jax.grad(f, argnums=(0, 1)))(params, x).jaxpr, [])
-    assert len(found) == conds
-    assert afmoe.pair_rungs(48, held, 8) == ((16, 48) if conds else (48,))
+    if remat:
+        assert afmoe.pair_rungs(48, held, 8) == ladder
+    assert [len(e.params["branches"]) for e in found] == \
+        [len(ladder)] * (2 if len(ladder) > 1 else 0)
+    _, sown = layer.apply(params, x, mutable=[spans.STEP_COUNTERS])
+    assert sown[spans.STEP_COUNTERS][spans.MOE_LADDER].tolist() == list(ladder)
+
+
+# (pairs a step, experts held, experts in all) of the four routed cells
+CELL_SHAPES = {"trinity-mini": (65536, 8, 128), "joyai-flash": (65536, 8, 256),
+               "lfm2-moe": (32768, 8, 64), "nemotronh-moe": (49152, 8, 128)}
+CELL_LADDERS = {"trinity-mini": (8192, 16384, 65536),
+                "joyai-flash": (4096, 8192, 65536),
+                "lfm2-moe": (8192, 16384, 32768),
+                "nemotronh-moe": (6144, 12288, 49152)}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+@pytest.mark.parametrize("divisor,count", [(None, 3), (4, 2), (2, 1), (1, 1)],
+                         ids=["cell", "quarter", "half", "all"])
+def test_the_ladder_is_twice_even_twice_that_and_the_worst_case(cell, divisor, count):
+    """One rule from the shapes the layer is given: three rungs at the
+    cells' shares, two where a quarter of the experts is held (twice the
+    lower rung is the worst case itself), one from a half on."""
+    pairs, held, total = CELL_SHAPES[cell]
+    held = total // divisor if divisor else held
+    even = pairs * held // total
+    rungs = afmoe.pair_rungs(pairs, held, total)
+    assert rungs == tuple(sorted({min(2 * even, pairs), min(4 * even, pairs), pairs}))
+    assert len(rungs) == count and all(rows % 512 == 0 for rows in rungs)
+    if divisor is None:
+        assert rungs == CELL_LADDERS[cell]
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+@pytest.mark.parametrize("at,rung", [
+    ("nothing", 0), ("low", 0), ("low+1", 1), ("middle", 1), ("middle+1", 2),
+    ("top", 2)])
+def test_rung_of_turns_at_each_threshold_and_one_past_it(cell, at, rung):
+    rungs = CELL_LADDERS[cell]
+    filled = {"nothing": 0, "low": rungs[0], "low+1": rungs[0] + 1,
+              "middle": rungs[1], "middle+1": rungs[1] + 1,
+              "top": rungs[2]}[at]
+    assert afmoe.rung_of(filled, rungs) == rung
+    assert int(afmoe.rung_of(jnp.int32(filled), rungs)) == rung   # on the device
+    assert rungs[rung] >= filled and (rung == 0 or rungs[rung - 1] < filled)
 
 
 @pytest.mark.parametrize("mode", ["split", "u_split"])
@@ -386,7 +441,7 @@ def test_no_conditional_returns_rows_of_pairs(mode):
     of the backward's ``cond``, so what a ``cond`` of the gradient returns
     is its result (the forward's) or the cotangents (the backward's), never
     ``n * k`` rows of a rung's buffers. A ``switch`` under one
-    ``checkpoint`` would return the union of both rungs' kept rows."""
+    ``checkpoint`` would return the union of every rung's kept rows."""
     t = 24
     kw = {**KW, "experts_held": 1, "remat": True}
     plan = get_plan("afmoe", mode, jnp.float32, **kw)
@@ -398,10 +453,10 @@ def test_no_conditional_returns_rows_of_pairs(mode):
         lambda p: cross_entropy(plan.apply(p, x), y)))(params).jaxpr
     found = _conds(jaxpr, [])
     pairs = B * t * KW["experts_per_token"]
-    assert afmoe.pair_rungs(pairs, 1, 8) == (32, pairs)
+    assert afmoe.pair_rungs(pairs, 1, 8) == (32, 64, pairs)
     assert len(found) == 2 * 4      # four routed layers, forward and backward
     for eqn in found:
-        assert len(eqn.params["branches"]) == 2
+        assert len(eqn.params["branches"]) == 3
         rows = [v.aval.shape for v in eqn.outvars
                 if v.aval.shape and v.aval.shape[0] == pairs]
         assert rows == [], rows

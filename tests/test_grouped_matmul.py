@@ -128,8 +128,9 @@ def test_tile_fill_is_useful_over_run_work(k, n, old, new):
 
 
 @pytest.mark.parametrize("config,rungs", [
-    ("trinity-mini", (8192, 65536)), ("joyai-llm-flash", (4096, 65536)),
-    ("lfm2-24b-a2b", (8192, 32768))])
+    ("trinity-mini", (8192, 16384, 65536)),
+    ("joyai-llm-flash", (4096, 8192, 65536)),
+    ("lfm2-24b-a2b", (8192, 16384, 32768))])
 def test_the_row_tile_and_the_rungs_did_not_move(config, rungs):
     assert pair_rungs(*CELLS[config][1]) == rungs
     assert all(gm._tiles(rows, 1, 1)[0] == 512 for rows in rungs)

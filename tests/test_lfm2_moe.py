@@ -372,7 +372,7 @@ def test_the_scopes_name_the_new_parts_and_the_step_counts_four_layers():
     assert read["ladder"] == [[64]] * 4 and read["rows"] == [64] * 4
     assert all(len(p) == 4 and 0 < sum(p) <= 64 for p in read["pairs"])
     # at the published sizes: 8192 tokens x 4 a token, 8 of 64 held
-    assert pair_rungs(32768, 8, 64) == (8192, 32768)
+    assert pair_rungs(32768, 8, 64) == (8192, 16384, 32768)
 
 
 @pytest.mark.parametrize("attn", ["full", "flash"])
@@ -419,26 +419,28 @@ DIGESTS = {
         "server_cross_entropy": "233e0d12f30900d2",
         "server_per_example_cross_entropy": "6e0f47fe2ae29a1a",
         "client_fwd": "c070cde753ba777a", "client_bwd": "709953683d33d6be"},
+    # PR 41: the four routed cells' ladders have a third rung (a third branch
+    # of every routed layer's two conditionals); the other five are unedited
     "trinity-mini-fused-t8192": {
-        "fused_step": "979c2daa30438931", "outputs": 294, "equations": 3833,
-        "state_and_loss_alone": "a41fad0a79813b26", "equations_alone": 3772},
+        "fused_step": "492dddd8ecbad3f4", "outputs": 294, "equations": 3857,
+        "state_and_loss_alone": "2a6e85528d47f27b", "equations_alone": 3784},
     "phi4flash-fused-t8192": {
         "fused_step": "09060d61752870bf", "outputs": 231, "equations": 2767,
         "state_and_loss_alone": "cafee9375516a342", "equations_alone": 2750},
     "joyai-flash-fused-t8192": {
-        "fused_step": "3349073ae300bef8", "outputs": 333, "equations": 4632,
-        "state_and_loss_alone": "bbad2f4f7c235af3", "equations_alone": 4545},
+        "fused_step": "4ed6ed722c498ecd", "outputs": 333, "equations": 4662,
+        "state_and_loss_alone": "da37b672441bcd21", "equations_alone": 4560},
     "lfm2-moe-fused-t8192": {
         # PR 38: the grouped products take the 1536-wide experts whole
-        # (95477362e3eccc89 / 139934ec994c1266 before, the same equations)
-        "fused_step": "e2cfe85da3c9f053", "outputs": 177, "equations": 2215,
-        "state_and_loss_alone": "589fbae6853e320e", "equations_alone": 2154},
+        # (e2cfe85da3c9f053 / 589fbae6853e320e with two rungs, 2215 / 2154)
+        "fused_step": "b4d854ba89c9d50a", "outputs": 177, "equations": 2239,
+        "state_and_loss_alone": "1c1de4ea8f557a4e", "equations_alone": 2166},
     "nemotronh-moe-fused-t8192": {
         # PR 39 brought the cell; the eight above read what they read at its
         # parent, though models/afmoe.py's attention and routed layer took a
         # second form each for it
-        "fused_step": "8e836b4a0a66af89", "outputs": 180, "equations": 2493,
-        "state_and_loss_alone": "4ea174b3155afc5d", "equations_alone": 2431},
+        "fused_step": "9e01ca06e572914f", "outputs": 180, "equations": 2511,
+        "state_and_loss_alone": "0611112aef509c8a", "equations_alone": 2440},
 }
 
 

@@ -584,8 +584,10 @@ def test_the_tiles_of_the_published_expert():
     assert gm._tiles(6144, 1856, 2688) == (512, 1024, 896)
     assert gm.tile_fill(6144, 2688, 1856) == pytest.approx(1856 / 2048)
     assert round(gm.tile_fill(49152, 2688, 1856), 3) == 0.906
-    # 8192 tokens x 6 a token, 8 of 128 held: twice the even 3072, and all
-    assert pair_rungs(49152, 8, 128) == (6144, 49152)
+    # 8192 tokens x 6 a token, 8 of 128 held: twice the even 3072, twice
+    # that, and all
+    assert pair_rungs(49152, 8, 128) == (6144, 12288, 49152)
+    assert gm._tiles(12288, 2688, 1856) == gm._tiles(6144, 2688, 1856)
 
 
 @pytest.mark.parametrize("mode,stages", [("split", 2), ("u_split", 3),

@@ -5,7 +5,9 @@ host reads it only while recording, and a program that does not ask for
 it is the program without it. CPU, small sizes."""
 
 import dataclasses
+import importlib.util
 import json
+import os
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +35,7 @@ B, T, VOCAB = 2, 16, 300
 # the benchmark rehearsals' sizes: four routed layers behind a dense one
 # (and in joyai the prediction module's block: five), 2 of 8 experts held,
 # so the ladder has two rungs: 32 rows, twice the even share, and all 64
+# (twice 32 is the worst case itself: no middle rung at a quarter held)
 ROUTED = dict(vocab=VOCAB, d_model=64, dense_width=192, expert_width=32,
               experts_total=8, experts_held=2, expert_offset=2,
               experts_per_token=2, dense_layers=1, client_depth=1)
@@ -146,21 +149,24 @@ def test_sown_counters_equal_the_layers_own_functions(model):
     assert len({int(c[spans.MOE_PAIRS].sum()) for c in counters.values()}) > 1
 
 
-@pytest.mark.parametrize("remat,favoured,rows,ladder", [
-    (True, 0.0, 32, (32, 128)),      # an eighth of the pairs: the low rung
-    (True, 9.0, 128, (32, 128)),     # every pair held: past it
-    (False, 0.0, 128, (128,)),       # kept rows: the top rung alone
-], ids=["low-rung", "overflow", "no-remat"])
-def test_the_rung_reported_is_the_rung_run(remat, favoured, rows, ladder):
-    """A bias that sends every token to the two held experts overflows
-    the lower rung, and the layer says so."""
+@pytest.mark.parametrize("remat,favoured,held,rows,ladder", [
+    (True, (0.0, 0.0), None, 32, (32, 64, 128)),   # about an eighth: the low rung
+    (True, (9.0, -9.0), 64, 64, (32, 64, 128)),    # one pair a token: the middle
+    (True, (9.0, 9.0), 128, 128, (32, 64, 128)),   # every pair held: the top
+    (False, (0.0, 0.0), None, 128, (128,)),        # kept rows: the top rung alone
+    (False, (9.0, -9.0), 64, 128, (128,)),
+], ids=["low-rung", "middle-rung", "overflow", "no-remat", "no-remat-middle"])
+def test_the_rung_reported_is_the_rung_run(remat, favoured, held, rows, ladder):
+    """A bias that sends every token to one of the two held experts, or to
+    both, overflows the lower rung, or the middle one, and the layer says
+    so: ``rows`` is the smallest rung that holds the pairs."""
     layer = afmoe.RoutedExperts(width=8, experts_total=16, experts_held=2,
                                 expert_offset=3, per_token=2,
                                 route_scale=1.0, remat=remat)
     m = jax.random.normal(jax.random.PRNGKey(0), (64, 16))
     params = layer.init(jax.random.PRNGKey(1), m)
     assert sorted(params) == ["params"]          # init sows nothing
-    bias = jnp.zeros(16).at[3:5].set(favoured)
+    bias = jnp.zeros(16).at[3:5].set(jnp.asarray(favoured))
     params = {"params": {**params["params"], "expert_bias": bias}}
     plain = layer.apply(params, m)
     out, sown = layer.apply(params, m, mutable=[spans.STEP_COUNTERS])
@@ -169,7 +175,8 @@ def test_the_rung_reported_is_the_rung_run(remat, favoured, rows, ladder):
     assert tuple(got[spans.MOE_LADDER].tolist()) == ladder
     assert int(got[spans.MOE_ROWS]) == rows
     pairs = int(got[spans.MOE_PAIRS].sum())
-    assert pairs <= rows and (pairs == 128) == bool(favoured)
+    assert max([r for r in ladder if r < rows], default=-1) < pairs <= rows
+    assert held in (None, pairs)
 
 
 def _device_gets(monkeypatch):
@@ -228,6 +235,67 @@ def test_the_host_reads_the_counters_only_while_recording(monkeypatch):
     events = json.loads(json.dumps(tr.chrome_events()))
     args = [e["args"] for e in events if e["name"] == spans.COUNTERS_READ]
     assert len(args) == 2 and args[0]["pairs"] == first["pairs"]
+
+
+@pytest.mark.parametrize("favoured,pairs,rows", [(-9.0, 0, 16), (9.0, 32, 32)],
+                         ids=["low-rung", "middle-rung"])
+def test_counters_read_names_the_middle_rung(favoured, pairs, rows):
+    """One of eight experts held: 64 pairs, a ladder of 16, 32 and all 64.
+    A bias that sends every token's first pair to the held expert fills 32
+    rows, and the fused step's ``counters_read`` says the middle rung ran;
+    one that keeps every token off it, the lowest."""
+    kw = dict(SMALL, experts_held=1)
+    plan = get_plan("afmoe", "split", jnp.float32, **kw)
+    x, y = batch()
+    trainer = FusedSplitTrainer(plan, config("afmoe"),
+                                jax.random.PRNGKey(0), x)
+    held = kw["expert_offset"]
+    trainer.state = trainer.state._replace(params=jax.tree_util.tree_map_with_path(
+        lambda path, leaf: leaf.at[held].set(favoured)
+        if "expert_bias" in jax.tree_util.keystr(path) else leaf,
+        trainer.state.params))
+    tr = obs.enable()
+    try:
+        assert np.isfinite(trainer.train_step(x, y))
+    finally:
+        obs.disable()
+    read, = [r["attrs"] for r in tr.spans() if r["name"] == spans.COUNTERS_READ]
+    assert read["layers"] == SMALL_LAYERS
+    assert read["ladder"] == [[16, 32, 64]] and read["rows"] == [rows]
+    assert read["pairs"] == [[pairs]]
+
+
+@pytest.mark.parametrize("ladder,ran,by_rung,first,key", [
+    ([64], [64, 64, 64], [3], [], "2"),
+    ([32, 64], [32, 64, 32], [2, 1], [1], "1/1"),
+    ([16, 32, 64], [16, 32, 64], [1, 1, 1], [1, 2], "1/1/0"),
+    ([16, 32, 64], [16, 64, 64], [1, 0, 2], [1, 1], "1/0/1"),
+], ids=["one-rung", "two", "three", "skips-the-middle"])
+def test_routed_window_tabulates_a_ladder_of_any_length(ladder, ran, by_rung, first, key):
+    """``scripts/routed_window.py:reduce`` over three recorded steps of two
+    layers, the second always on its lowest rung: samples by rung, the step
+    each higher rung was first reached, and the step time keyed by how many
+    layers sat on each rung."""
+    spec = importlib.util.spec_from_file_location("routed_window", os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "scripts", "routed_window.py"))
+    routed_window = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(routed_window)
+    records = []
+    for i, rows in enumerate(ran):
+        records += [
+            {"name": spans.STEP_TOTAL, "span_id": i, "duration": 0.1},
+            {"name": spans.COUNTERS_READ, "parent_id": i, "duration": 0.001,
+             "attrs": {"layers": ["a", "b"], "pairs": [[rows - 1, 1], [2, 2]],
+                       "rows": [rows, ladder[0]], "ladder": [ladder, ladder]}}]
+    summary, table = routed_window.reduce(records, 0, 4.0)
+    assert summary["layers"]["a"]["steps_by_rung"] == by_rung
+    assert summary["layers"]["a"]["first_step_on_rung"] == first
+    assert summary["layers"]["b"]["steps_by_rung"] == [3] + [0] * (len(ladder) - 1)
+    assert summary["samples_by_rung"] == [n + m for n, m in zip(
+        by_rung, summary["layers"]["b"]["steps_by_rung"])]
+    assert key in summary["step_ms_by_layers_on_each_rung"]
+    assert [r["rung"][0] for r in table] == [ladder.index(r) for r in ran]
 
 
 @pytest.mark.parametrize("over,reads", [
