@@ -3,7 +3,7 @@
     python scripts/scope_time.py --workload lfm2-moe-fused-t8192 --seed N
         [--scopes short_conv,attn_full,moe_route,moe_experts]
     python scripts/scope_time.py --workload nemotronh-moe-fused-t8192 --seed N
-        [--scopes ssm_ssd,ssm_conv,moe_shared]
+        [--scopes ssm_ssd,ssm_conv,moe_shared] [--top 8]
 
 Runs ``benchmarks/run.py``'s own ``main`` with ``--trace 1`` and, before the
 run deletes its trace, reads the xplane once more: the benchmark's reduction
@@ -17,7 +17,8 @@ from the cache), by the instruction's name. The benchmark's result
 line comes first; then one JSON line: for each scope (``obs/spans.py``
 ``DEVICE_SCOPES`` by default) the events whose ``op_name`` holds it, their
 device milliseconds a step of the window, and their share of the device's
-busy time. A fusion counts under the scope of its root operation, and a
+busy time (with ``--top N`` also the scope's N longest operations). A fusion
+counts under the scope of its root operation, and a
 ``conditional`` covers its branch's operations, so the routed layer's scopes
 count twice what ran inside a rung: read those from the grouped products'
 own metrics. Only a chip run has device events; on the CPU
@@ -46,10 +47,12 @@ def op_names(hlo_text: str) -> dict:
     return dict(_INSTRUCTION.findall(hlo_text))
 
 
-def scope_seconds(path: str, scopes: tuple, step_span: str, names: dict) -> dict:
+def scope_seconds(path: str, scopes: tuple, step_span: str, names: dict,
+                  top: int = 0) -> dict:
     """Per scope ``{"events", "ms_per_step", "share_of_busy_pct"}`` over the
-    ``bench.window`` of the xplane at ``path``; ``names`` is
-    :func:`op_names` of the step that ran."""
+    ``bench.window`` of the xplane at ``path``, and with ``top`` its longest
+    operations (``trace_reduce.short_name``, milliseconds a step);
+    ``names`` is :func:`op_names` of the step that ran."""
     from jax.profiler import ProfileData
 
     import trace_reduce
@@ -64,6 +67,7 @@ def scope_seconds(path: str, scopes: tuple, step_span: str, names: dict) -> dict
                         lo, hi = int(e.start_ns), int(e.start_ns + e.duration_ns)
                     steps += e.name == step_span
     found = {s: [0, 0] for s in scopes}
+    ops = {s: {} for s in scopes}
     busy, named = [], 0
     for plane in data.planes:
         if not plane.name.startswith(trace_reduce.DEVICE_PREFIX):
@@ -85,11 +89,20 @@ def scope_seconds(path: str, scopes: tuple, step_span: str, names: dict) -> dict
                     if scope in text:
                         found[scope][0] += 1
                         found[scope][1] += b - a
+                        short = trace_reduce.short_name(e.name)
+                        ops[scope][short] = ops[scope].get(short, 0) + b - a
     busy_ns = sum(b - a for a, b in trace_reduce.union(busy))
+
+    def longest(scope: str) -> dict:
+        ranked = sorted(ops[scope].items(), key=lambda kv: -kv[1])[:top]
+        return {"top": [[name, ns * 1e-6 / max(steps, 1)]
+                        for name, ns in ranked]} if top else {}
+
     return {"steps": steps, "events": len(busy), "events_with_an_op_name": named,
             "busy_ms_per_step": busy_ns * 1e-6 / max(steps, 1),
             "scopes": {s: {"events": n, "ms_per_step": ns * 1e-6 / max(steps, 1),
-                           "share_of_busy_pct": 100.0 * ns / max(busy_ns, 1)}
+                           "share_of_busy_pct": 100.0 * ns / max(busy_ns, 1),
+                           **longest(s)}
                        for s, (n, ns) in found.items()}}
 
 
@@ -99,6 +112,8 @@ def main() -> int:
     parser.add_argument("--workload", default="lfm2-moe-fused-t8192")
     parser.add_argument("--seed", type=int, default=1)
     parser.add_argument("--scopes", default=",".join(spans.DEVICE_SCOPES))
+    parser.add_argument("--top", type=int, default=0,
+                        help="a scope's longest operations to name")
     args = parser.parse_args()
     scopes = tuple(s for s in args.scopes.split(",") if s)
 
@@ -125,7 +140,7 @@ def main() -> int:
 
     def and_the_scopes(trace_dir, workload, chips):
         read.update(scope_seconds(trace_reduce.newest_xplane(trace_dir),
-                                  scopes, step_span, kept))
+                                  scopes, step_span, kept, args.top))
         return reduce_trace(trace_dir, workload, chips)
 
     paths.fused.Driver = Keeping

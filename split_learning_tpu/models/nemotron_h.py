@@ -19,8 +19,13 @@ statistics in float32, no bias in any product)::
   ``n`` reads group ``n // (heads / groups)``; ``dt = softplus(dt +
   dt_bias)``, no clamp; ``a_n = -exp(A_log_n)``, one scalar a head; the
   recurrence ``S_t = exp(dt_t a) S_{t-1} + dt_t x_t B_t^T``, ``y_t = S_t C_t
-  + D_n x_t`` in its chunked form (ops/ssd.py: four batched products a
-  chunk of ``chunk`` tokens and one short scan over the chunks' states);
+  + D_n x_t`` in its chunked form (ops/ssd.py: four products a chunk of
+  ``chunk`` tokens and the chunks' states carried from one to the next; at
+  the published sizes, chunks and states of 128 and eight heads of 64 a
+  group, two Pallas kernels that keep a chunk's scores, decays and their
+  product and a group's state in VMEM, write ``y`` and each chunk's first
+  state, and make the rest again in the backward; at any other size, the
+  tests' and a rehearsal's chunks of 8 among them, batched ``einsum``s);
   ``y = RMSNorm_groups(y * silu(z))``, **the gate before the norm**, the
   statistics over each group's ``d_inner / groups`` channels, one scale of
   ``d_inner``; ``y W_out``. The products run in ``dtype``; the
@@ -73,16 +78,22 @@ bound by bytes and not by arithmetic: ``silu(conv(xBC) + b_conv)`` is made
 again from the first product's output, the gate with the grouped norm
 from ``z`` and the recurrence's ``y``, so that of each the inputs are
 kept and none of the float32 values between (2.2 GB a step at the
-benchmark's sizes). Every product's output, the chunked form's decays,
-scores and states, the shared expert's hidden layer and what the flash
-kernels' backward reads are kept: no product, no flash forward and no
-grouped product outside the routed part runs a second time. XLA's
-analysis of the fused step at the benchmark's sizes (T 8192, seven
-layers; scripts/fused_step_memory.py, the fit rule is 14.5 GB): the
-routed part alone 14.897 GB, this form 13.812; with the chunked form's
-intra-chunk arrays made again from the chunks' states as well 12.288,
-and those in place of the two passes 14.477: each was run on the chip,
-and this form was the fastest of the three (PERF.md, Findings PR 39).
+benchmark's sizes). Every product's output, the shared expert's hidden
+layer and what the flash kernels' backward reads are kept: no product
+outside the recurrence, no flash forward and no grouped product outside
+the routed part runs a second time. Of the recurrence its operands, ``l``
+and the chunks' first states are kept (ops/ssd.py's ``custom_vjp``, at
+the kernels' sizes: 67 MB a layer in ``dtype``), and its backward kernel
+makes a chunk's scores, decays and masked product again in VMEM; the
+plain form keeps those three (402 MB a layer). XLA's analysis of the
+fused step at the benchmark's sizes (T 8192, seven layers;
+scripts/fused_step_memory.py, the fit rule is 14.5 GB): 12.094 GB with
+the kernels (PR 42), 13.933 with the plain form and three rungs (PR 41),
+of which PR 39 read with two rungs: the routed part alone 14.897 GB,
+this form 13.812; with the chunked form's intra-chunk arrays made again
+from the chunks' states as well 12.288, and those in place of the two
+passes 14.477: each was run on the chip, and this form was the fastest
+of the three (PERF.md, Findings PR 39).
 Decoding is not built: it needs a ``[heads, head_dim, state]`` state and
 the convolution's last ``conv_taps - 1`` tokens beside a key/value cache
 (runtime/generate.py, ROADMAP.md M7).

@@ -59,6 +59,26 @@ def as_rows_of_lanes(flat: jax.Array, rows: int) -> jax.Array:
     return padded.reshape(rows, LANE)
 
 
+def traced_once(kernel):
+    """``kernel`` as a function whose Python runs once. A kernel body is a
+    pure function of its refs' shapes, and a model calls one kernel at
+    many sites (GPT-2's step its attention 24 times, and twice more while
+    the harness asks for its shapes; a Mamba-2 hybrid its recurrence once a
+    layer), each of which would trace the body anew: about 0.1 s a site on
+    the chip's host for the flash kernels' whole-pair bodies, and several
+    times that for a kernel with cut pairs (PR 31). So the first site's
+    jaxpr is kept and later sites replay it, one bind an equation."""
+    kept = {}
+
+    def run(*refs):
+        key = tuple(jax.typeof(r) for r in refs)
+        if key not in kept:
+            kept[key] = jax.make_jaxpr(kernel)(*refs)
+        jax.core.eval_jaxpr(kept[key].jaxpr, kept[key].consts, *refs)
+
+    return run
+
+
 def causal_depthwise_conv(x: jax.Array, taps: jax.Array) -> jax.Array:
     """``y_t = sum_k taps[k] * x_{t - (K - 1) + k}`` per channel: ``x [B,
     T, C]``, ``taps [K, C]``, zeros before the sequence's start, so the

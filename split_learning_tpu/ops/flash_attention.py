@@ -68,8 +68,8 @@ Design (the canonical TPU flash schedule):
   block has one key block in all (GPT-2 at T 1024) the forward needs no
   running maximum: each row tile's softmax is final (``_fwd_kernel``).
 - Every kernel body is traced once and replayed at the other call sites
-  (:func:`_traced_once`): a model calls one attention at many sites, and
-  tracing a body is what a call site costs a process at start-up.
+  (ops/common.py's ``traced_once``): a model calls one attention at many
+  sites, and tracing a body is what a call site costs a process at start-up.
 
 - A ``window`` (query i sees keys j with ``0 <= i - j < window``; the
   afmoe family's ``sliding_attention`` layers) shrinks the inner grid
@@ -116,7 +116,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from split_learning_tpu.ops.common import (
-    LANE, NEG_BIG as _NEG_BIG, pad_axis, round_up, use_interpret)
+    LANE, NEG_BIG as _NEG_BIG, pad_axis, round_up, traced_once, use_interpret)
 
 _BLOCK = 128   # minimum block edge (the MXU tile); see _pick_block
 _ROWW = 8      # lane width of the LSE/delta row vectors (tile-masked)
@@ -1034,30 +1034,11 @@ def _dkv_kernel(blk: int, tile: int, t: int, scale: float, causal: bool,
 
 
 # --------------------------------------------------------------------- #
-def _traced_once(kernel):
-    """``kernel`` as a function whose Python runs once. A kernel body is a
-    pure function of its refs' shapes, and a model calls one attention at
-    many sites (GPT-2's step 24 times, and twice more while the harness
-    asks for its shapes), each of which would trace the body anew: about
-    0.1 s a site on the chip's host for the whole-pair bodies, and several
-    times that for a kernel with cut pairs (PR 31). So the first site's
-    jaxpr is kept and later sites replay it, one bind an equation."""
-    kept = {}
-
-    def run(*refs):
-        key = tuple(jax.typeof(r) for r in refs)
-        if key not in kept:
-            kept[key] = jax.make_jaxpr(kernel)(*refs)
-        jax.core.eval_jaxpr(kept[key].jaxpr, kept[key].consts, *refs)
-
-    return run
-
-
 @functools.lru_cache(maxsize=None)
 def _onepass_kernel(*static):
     """The one-pass backward's body for one set of static arguments, the
     same object at every call site so that it is traced once."""
-    return _traced_once(functools.partial(_onepass_bwd_kernel, *static))
+    return traced_once(functools.partial(_onepass_bwd_kernel, *static))
 
 
 def _kv_index(group: int):
@@ -1203,9 +1184,9 @@ def _make_flash(bh: int, t: int, d: int, causal: bool, dtype_name: str,
     final = n_in == 1 and _cut_pairs(n_in, block, _tile_edge(block), causal,
                                      strict, window)
     stat_scratch = pltpu.VMEM((block, _ROWW if final else LANE), jnp.float32)
-    fwd_kernel = _traced_once(functools.partial(_fwd_kernel, *static))
-    dq_kernel = _traced_once(functools.partial(_dq_kernel, *static))
-    dkv_kernel = _traced_once(functools.partial(_dkv_kernel, *static, n_blk))
+    fwd_kernel = traced_once(functools.partial(_fwd_kernel, *static))
+    dq_kernel = traced_once(functools.partial(_dq_kernel, *static))
+    dkv_kernel = traced_once(functools.partial(_dkv_kernel, *static, n_blk))
 
     def fwd_call(q, k, v):
         qp, kp, vp = pad_qkv(q), pad_qkv(k), pad_qkv(v, dvp)

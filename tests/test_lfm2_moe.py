@@ -436,11 +436,12 @@ DIGESTS = {
         "fused_step": "b4d854ba89c9d50a", "outputs": 177, "equations": 2239,
         "state_and_loss_alone": "1c1de4ea8f557a4e", "equations_alone": 2166},
     "nemotronh-moe-fused-t8192": {
-        # PR 39 brought the cell; the eight above read what they read at its
-        # parent, though models/afmoe.py's attention and routed layer took a
-        # second form each for it
-        "fused_step": "9e01ca06e572914f", "outputs": 180, "equations": 2511,
-        "state_and_loss_alone": "0611112aef509c8a", "equations_alone": 2440},
+        # PR 42: the three Mamba-2 layers' recurrence runs as ops/ssd.py's
+        # two kernels (9e01ca06e572914f / 0611112aef509c8a with the plain
+        # form, 2511 / 2440 equations); the eight above are unedited: none
+        # imports ops/ssd.py
+        "fused_step": "c56e1e2fedbc254a", "outputs": 180, "equations": 2160,
+        "state_and_loss_alone": "659599157f1a1886", "equations_alone": 2098},
 }
 
 

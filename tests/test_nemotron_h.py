@@ -661,6 +661,19 @@ def test_the_scopes_name_the_new_parts_and_the_step_counts_three_layers():
     # 2 x 20 tokens x 2 a token = 80 pairs; 4 of 8 experts held: one rung
     assert read["ladder"] == [[80]] * 3 and read["rows"] == [80] * 3
     assert all(len(p) == 4 and 0 < sum(p) <= 80 for p in read["pairs"])
+    # at sizes that fill the recurrence's tiles (ops/ssd.py: chunks and
+    # states of 128, 4 heads of 64 over 2 groups) the step holds its two
+    # kernels once a Mamba-2 layer, under their scope, and at the
+    # rehearsal's sizes above none
+    assert "ssd_fwd" not in text and "ssd_bwd" not in text
+    wide = get_plan("nemotron_h", "split", jnp.float32, **{
+        **KW, "mamba_heads": 4, "mamba_head_dim": 64, "ssm_state": 128,
+        "chunk": 128})
+    tokens = jnp.zeros((1, 300), jnp.int32)
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda p: plan_loss(wide, p, tokens, tokens)))(
+            jax.eval_shape(wide.init, jax.random.PRNGKey(0), tokens)))
+    assert text.count("name=ssd_fwd") == text.count("name=ssd_bwd") == 3
 
 
 @pytest.mark.parametrize("attn", ["full", "flash"])
