@@ -15,7 +15,7 @@ per example) and its client's forward and recomputed backward.  One JSON line
 a cell; ``diff`` two runs.  About 5 minutes for the nine cells.  A function's
 address in the text (a ``remat`` policy prints as ``<function ... at 0x...>``)
 is left out of what is hashed, so that two processes agree.
-``tests/test_lfm2_moe.py`` holds every cell's digest (``cell_digests``): a PR
+``tests/test_step_digest.py`` holds every cell's digest (``cell_digests``): a PR
 that means to change a cell's program replaces that cell's line there.
 """
 

@@ -1,12 +1,10 @@
 """Split AFMoE — routed experts beside a shared one, window beside full
 attention over grouped heads (the Trinity family's ``afmoe`` layer).
 
-Nothing of models/transformer.py's ``Block`` fits but the stage layout:
-
-- split:   client(embedding + N_c layers) -> server(the rest + norm + head)
-- u_split: client(embedding + N_c layers) -> server(the rest)
-           -> client(norm + head)
-- federated: the composition of the split plan.
+Nothing of models/transformer.py's ``Block`` fits; the stages are
+models/cut.py's (split, u_split, federated), given :func:`_run_layers`, the
+embedding times ``sqrt(d_model)`` (the family's muP rule) and
+:class:`RMSNorm` as the final norm.
 
 One layer (RMSNorm(x) = x / sqrt(mean(x^2) + eps) * scale, no biases):
 
@@ -117,13 +115,14 @@ put it. Decoding through a KV cache is not built for this family.
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable, Optional, Sequence
+from typing import Any, Optional, Sequence
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from split_learning_tpu.core.stage import SplitPlan, from_flax
+from split_learning_tpu.core.stage import SplitPlan
+from split_learning_tpu.models import cut
 from split_learning_tpu.obs import spans
 from split_learning_tpu.ops.flash_attention import (
     flash_attention, select_attention)
@@ -132,7 +131,6 @@ from split_learning_tpu.ops.grouped_matmul import (
     _tiles as _row_tiles, grouped_matmul)
 from split_learning_tpu.ops.ring_attention import full_attention
 
-_ATTN_IMPLS = ("auto", "full", "flash")
 _LAYER_TYPES = ("sliding_attention", "full_attention")
 _HI = jax.lax.Precision.HIGHEST
 _INIT = nn.initializers.normal(0.02)
@@ -515,15 +513,6 @@ class AfmoeLayer(nn.Module):
         return h + norm("norm_post_mlp")(y)
 
 
-def _no_cache(cache_len, decode_cache):
-    if cache_len or decode_cache is not None:
-        raise NotImplementedError(
-            "no KV-cache decode is built for these layers: a window needs "
-            "a cache that forgets (ROADMAP.md M4), a short convolution its "
-            "last tokens and a state-space layer its state beside the keys "
-            "and values (M7; runtime/generate.py)")
-
-
 def _run_layers(h, first: int, layer_types, dense_layers: int, layer_kw):
     """Layers ``[first, first + len(layer_types))`` of the model, named
     ``layer<i>`` by their index in it (call inside a compact method).
@@ -536,74 +525,6 @@ def _run_layers(h, first: int, layer_types, dense_layers: int, layer_kw):
         h = AfmoeLayer(**{**kw, "dense_width": dense, "layer_type": kind},
                        name=f"layer{i}")(h)
     return h
-
-
-class AfmoeEmbedStage(nn.Module):
-    """Client bottom stage: ``[B, T] int -> [B, T, d_model]``: the
-    embedding (times ``sqrt(d_model)``, the family's muP rule, unless
-    ``mup`` is off; no position table) and the first layers. ``layers``
-    is what ``run`` takes after ``h``: :func:`_run_layers`, or another
-    family's (models/lfm2_moe.py), whose layers these three stages then
-    hold."""
-
-    vocab: int
-    d_model: int
-    layers: tuple
-    dtype: Any = jnp.float32
-    run: Optional[Callable] = None
-    mup: bool = True
-
-    @nn.compact
-    def __call__(self, tokens, *, cache_len: int = 0, decode_cache=None,
-                 pos=None):
-        _no_cache(cache_len, decode_cache)
-        h = nn.Embed(self.vocab, self.d_model, dtype=self.dtype,
-                     embedding_init=_INIT, name="tok")(tokens)
-        if self.mup:
-            h = h * jnp.asarray(self.d_model ** 0.5, self.dtype)
-        return (self.run or _run_layers)(h, *self.layers)
-
-
-class AfmoeHeadStage(nn.Module):
-    """Final norm and the untied head over the vocabulary rows held;
-    products in the compute type, accumulated and returned in float32,
-    so the loss is a float32 softmax. The server's top stage ends in it;
-    alone it is the client's top stage of the U-shape."""
-
-    vocab: int
-    eps: float = 1e-5
-    dtype: Any = jnp.float32
-
-    @nn.compact
-    def __call__(self, h, *, cache_len: int = 0, decode_cache=None,
-                 pos=None):
-        _no_cache(cache_len, decode_cache)
-        x = RMSNorm(self.eps, self.dtype, name="norm_f")(h)
-        kernel = self.param("lm_head", _INIT, (h.shape[-1], self.vocab))
-        return jnp.dot(x, kernel.astype(self.dtype),
-                       preferred_element_type=jnp.float32)
-
-
-class AfmoeTrunkAndHead(nn.Module):
-    """Server top stage of the 2-party split: the rest of the layers,
-    then (``vocab`` > 0) the final norm and the head. With ``vocab`` 0
-    it is the U-shape's middle stage."""
-
-    layers: tuple
-    vocab: int = 0
-    eps: float = 1e-5
-    dtype: Any = jnp.float32
-    run: Optional[Callable] = None    # as AfmoeEmbedStage's
-
-    @nn.compact
-    def __call__(self, h, *, cache_len: int = 0, decode_cache=None,
-                 pos=None):
-        _no_cache(cache_len, decode_cache)
-        h = (self.run or _run_layers)(h, *self.layers)
-        if not self.vocab:
-            return h
-        return AfmoeHeadStage(self.vocab, self.eps, self.dtype,
-                              name="head")(h)
 
 
 def afmoe_plan(mode: str = "split", dtype: Any = jnp.float32, *,
@@ -631,45 +552,28 @@ def afmoe_plan(mode: str = "split", dtype: Any = jnp.float32, *,
     layer's routed part in the backward pass, at the rows its routing
     fills, so that none of its rows is kept, and keeps everything else of
     a layer's forward (the module header)."""
-    if attn not in _ATTN_IMPLS:
-        raise ValueError(f"Unknown attn impl: {attn!r} (expected {_ATTN_IMPLS})")
+    cut.check_attn(attn)
     layer_types = tuple(layer_types)
     bad = sorted(set(layer_types) - set(_LAYER_TYPES))
     if bad:
         raise ValueError(f"Unknown layer types {bad} (expected {_LAYER_TYPES})")
-    held = experts_total if experts_held is None else experts_held
-    if not (0 <= expert_offset and expert_offset + held <= experts_total
-            and held >= 1):
-        raise ValueError(
-            f"experts [{expert_offset}, {expert_offset + held}) are not "
-            f"among the router's {experts_total}")
-    if not 0 <= client_depth <= len(layer_types):
-        raise ValueError(f"client_depth {client_depth} of "
-                         f"{len(layer_types)} layers")
-    if num_heads % num_kv_heads:
-        raise ValueError(f"{num_kv_heads} key/value heads do not divide "
-                         f"{num_heads} query heads")
+    held = cut.held_experts(experts_total, experts_held, expert_offset)
+    cut.check_client_depth(client_depth, len(layer_types))
+    cut.check_heads(num_heads, num_kv_heads)
+    eps = float(rms_norm_eps)
     layer_kw = tuple(dict(
         num_heads=num_heads, num_kv_heads=num_kv_heads, head_dim=head_dim,
         window=window, dense_width=dense_width, expert_width=expert_width,
         experts_total=experts_total, experts_held=held,
         expert_offset=expert_offset, experts_per_token=experts_per_token,
         shared_experts=shared_experts, route_scale=float(route_scale),
-        rope_theta=float(rope_theta), eps=float(rms_norm_eps), attn=attn,
+        rope_theta=float(rope_theta), eps=eps, attn=attn,
         dtype=dtype, remat=bool(remat)).items())
     span = lambda first, kinds: (first, kinds, dense_layers, layer_kw)
-    eps = float(rms_norm_eps)
-    embed = from_flax("embed", AfmoeEmbedStage(
-        vocab, d_model, span(0, layer_types[:client_depth]), dtype))
-    rest = span(client_depth, layer_types[client_depth:])
-    if mode == "u_split":
-        return SplitPlan(
-            stages=(embed,
-                    from_flax("trunk", AfmoeTrunkAndHead(rest, 0, eps, dtype)),
-                    from_flax("head", AfmoeHeadStage(vocab, eps, dtype))),
-            owners=("client", "server", "client"))
-    return SplitPlan(
-        stages=(embed,
-                from_flax("trunk_head", AfmoeTrunkAndHead(
-                    rest, vocab, eps, dtype))),
-        owners=("client", "server"))
+    # the embedding times sqrt(d_model): the family's muP rule
+    embed = cut.EmbedStage(
+        vocab, d_model, _run_layers, span(0, layer_types[:client_depth]),
+        dtype, d_model ** 0.5)
+    return cut.split_plan(
+        mode, embed, span(client_depth, layer_types[client_depth:]),
+        cut.HeadStage(vocab, RMSNorm(eps, dtype), dtype))

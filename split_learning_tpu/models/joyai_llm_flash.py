@@ -46,10 +46,12 @@ statistics in float32, no bias anywhere)::
   client's embedding (a departure: a leaf shared across parties is
   ROADMAP.md M4b).
 
-Stages as the other families have them: split = client(embedding + the
-first ``client_depth`` layers) -> server(the rest + final norm + head +
-module); u_split moves norm, head and module back to the client;
-federated is the composition.
+Stages as the other families have them (models/cut.py's embedding and
+trunk stages; the stage that ends the model is :class:`HeadStage` here,
+for the objective it carries): split = client(embedding + the first
+``client_depth`` layers) -> server(the rest + final norm + head + module);
+u_split moves norm, head and module back to the client; federated is the
+composition.
 
 **What ``remat`` recomputes**, in the backward pass: the routed part of
 each expert layer (models/afmoe.py's header) and nothing else. Every
@@ -77,14 +79,14 @@ import jax
 import jax.numpy as jnp
 import optax
 
-from split_learning_tpu.core.stage import SplitPlan, from_flax
+from split_learning_tpu.core.stage import SplitPlan
+from split_learning_tpu.models import cut
 from split_learning_tpu.models.afmoe import RMSNorm, RoutedExperts, SwiGLU
 from split_learning_tpu.obs import spans
 from split_learning_tpu.ops.flash_attention import (
     flash_attention, select_attention)
 from split_learning_tpu.ops.ring_attention import full_attention
 
-_ATTN_IMPLS = ("auto", "full", "flash")
 _INIT = nn.initializers.normal(0.02)
 
 
@@ -209,51 +211,12 @@ class Layer(nn.Module):
         return h + y
 
 
-def _no_cache(cache_len, decode_cache):
-    if cache_len or decode_cache is not None:
-        raise NotImplementedError(
-            "joyai_llm_flash has no KV-cache decode: a latent cache holds "
-            "c_kv and k_r and never the keys (runtime/generate.py, "
-            "ROADMAP.md M7)")
-
-
 def _run_layers(h, sizes: Sizes, first: int, count: int, dense_layers: int):
     """Layers ``[first, first + count)`` of the model, named ``layer<i>``
     by their index in it (call inside a compact method)."""
     for i in range(first, first + count):
         h = Layer(sizes, i < dense_layers, name=f"layer{i}")(h)
     return h
-
-
-class EmbedStage(nn.Module):
-    """Client bottom stage: ``[B, T] int -> [B, T, d_model]``: the
-    embedding rows held (no scaling, no position table) and the first
-    layers. ``layers`` is what :func:`_run_layers` takes after ``sizes``."""
-
-    vocab: int
-    sizes: Sizes
-    layers: tuple
-
-    @nn.compact
-    def __call__(self, tokens, *, cache_len: int = 0, decode_cache=None,
-                 pos=None):
-        _no_cache(cache_len, decode_cache)
-        h = nn.Embed(self.vocab, self.sizes.d_model, dtype=self.sizes.dtype,
-                     embedding_init=_INIT, name="tok")(tokens)
-        return _run_layers(h, self.sizes, *self.layers)
-
-
-class TrunkStage(nn.Module):
-    """The U-shape's middle stage: the rest of the layers."""
-
-    sizes: Sizes
-    layers: tuple
-
-    @nn.compact
-    def __call__(self, h, *, cache_len: int = 0, decode_cache=None,
-                 pos=None):
-        _no_cache(cache_len, decode_cache)
-        return _run_layers(h, self.sizes, *self.layers)
 
 
 class PredictionModule(nn.Module):
@@ -309,7 +272,7 @@ class HeadStage(nn.Module):
 
     def __call__(self, h, *, cache_len: int = 0, decode_cache=None,
                  pos=None):
-        _no_cache(cache_len, decode_cache)
+        cut.no_cache(cache_len, decode_cache)
         return self._logits(self._normed(h))
 
     def losses(self, h, labels):
@@ -358,16 +321,9 @@ def joyai_llm_flash_plan(
     1) is ``num_nextn_predict_layers``: with 1 the final stage carries
     the module and its objective, whose second loss weighs
     ``mtp_lambda``. ``remat``: the module header."""
-    if attn not in _ATTN_IMPLS:
-        raise ValueError(f"Unknown attn impl: {attn!r} (expected {_ATTN_IMPLS})")
-    held = experts_total if experts_held is None else experts_held
-    if not (0 <= expert_offset and expert_offset + held <= experts_total
-            and held >= 1):
-        raise ValueError(
-            f"experts [{expert_offset}, {expert_offset + held}) are not "
-            f"among the router's {experts_total}")
-    if not 0 <= client_depth <= layers:
-        raise ValueError(f"client_depth {client_depth} of {layers} layers")
+    cut.check_attn(attn)
+    held = cut.held_experts(experts_total, experts_held, expert_offset)
+    cut.check_client_depth(client_depth, layers)
     if mtp_layers not in (0, 1):
         raise ValueError(f"mtp_layers {mtp_layers}: one prediction module "
                          "or none")
@@ -387,16 +343,11 @@ def joyai_llm_flash_plan(
         remat=bool(remat))
     span = lambda first, count: (first, count, dense_layers)
     rest = span(client_depth, layers - client_depth)
-    objective = "losses" if mtp_layers else None
-    embed = from_flax("embed", EmbedStage(vocab, sizes, span(0, client_depth)))
-    if mode == "u_split":
-        return SplitPlan(
-            stages=(embed, from_flax("trunk", TrunkStage(sizes, rest)),
-                    from_flax("head", HeadStage(
-                        vocab, sizes, span(layers, 0), bool(mtp_layers)),
-                        objective)),
-            owners=("client", "server", "client"))
-    return SplitPlan(
-        stages=(embed, from_flax("trunk_head", HeadStage(
-            vocab, sizes, rest, bool(mtp_layers)), objective)),
-        owners=("client", "server"))
+    # the head carries the objective over its own leaves, so the stage that
+    # ends the model is the family's in either mode
+    ends = lambda own: HeadStage(vocab, sizes, own, bool(mtp_layers))
+    return cut.split_plan(
+        mode, cut.EmbedStage(vocab, d_model, _run_layers,
+                             (sizes, *span(0, client_depth)), dtype),
+        (sizes, *rest), ends(span(layers, 0)), top=ends(rest),
+        objective="losses" if mtp_layers else None)

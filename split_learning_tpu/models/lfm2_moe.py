@@ -36,10 +36,11 @@ scale, statistics in float32, no bias anywhere)::
   the routing fills): ``y = sum_e w_e FFN_e(m)``. **No shared expert**
   beside it and no norm after it.
 
-The stages are models/afmoe.py's, given this family's layers (ROADMAP.md
-D16): split = client(embedding, unscaled, + the first ``client_depth``
-kept layers) -> server(the rest + final norm + untied head); u_split
-moves norm and head back to the client; federated is the composition.
+The stages are models/cut.py's, given this family's layers
+(:func:`_run_layers`) and its RMSNorm as the final norm: split =
+client(embedding, unscaled, + the first ``client_depth`` kept layers) ->
+server(the rest + final norm + untied head); u_split moves norm and head
+back to the client; federated is the composition.
 
 **What ``remat`` recomputes**, in the backward pass: the routed part of
 each routed layer (models/afmoe.py's header: it is what gives the three
@@ -60,17 +61,16 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from split_learning_tpu.core.stage import SplitPlan, from_flax
+from split_learning_tpu.core.stage import SplitPlan
+from split_learning_tpu.models import cut
 from split_learning_tpu.models.afmoe import (
-    AfmoeEmbedStage, AfmoeHeadStage, AfmoeTrunkAndHead, RMSNorm,
-    RoutedExperts, SwiGLU, rope)
+    RMSNorm, RoutedExperts, SwiGLU, rope)
 from split_learning_tpu.obs import spans
 from split_learning_tpu.ops.common import causal_depthwise_conv
 from split_learning_tpu.ops.flash_attention import (
     flash_attention, select_attention)
 from split_learning_tpu.ops.ring_attention import full_attention
 
-_ATTN_IMPLS = ("auto", "full", "flash")
 _LAYER_TYPES = ("conv", "full_attention")
 _INIT = nn.initializers.normal(0.02)
 _F32 = jnp.float32
@@ -177,7 +177,7 @@ class Lfm2Layer(nn.Module):
 
 def _run_layers(h, sizes: Sizes, indices: Sequence[int]):
     """The published layers ``indices`` in order, named ``layer<i>`` (call
-    inside a compact method: models/afmoe.py's stages do, as their
+    inside a compact method: models/cut.py's stages do, as their
     ``run``)."""
     for i in indices:
         h = Lfm2Layer(sizes, i, name=f"layer{i}")(h)
@@ -213,32 +213,15 @@ def lfm2_moe_plan(mode: str = "split", dtype: Any = jnp.float32, *,
     recomputes each routed layer's routed part in the backward pass, at
     the rows its routing fills, and keeps everything else (the module
     header)."""
-    if attn not in _ATTN_IMPLS:
-        raise ValueError(f"Unknown attn impl: {attn!r} (expected {_ATTN_IMPLS})")
+    cut.check_attn(attn)
     layer_types = tuple(layer_types)
     bad = sorted(set(layer_types) - set(_LAYER_TYPES))
     if bad:
         raise ValueError(f"Unknown layer types {bad} (expected {_LAYER_TYPES})")
-    kept = tuple(int(i) for i in layers_kept)
-    if list(kept) != sorted(set(kept)) or not kept or not (
-            0 <= kept[0] and kept[-1] < len(layer_types)):
-        raise ValueError(f"layers_kept {list(kept)} are not distinct rising "
-                         f"indices of {len(layer_types)} published layers")
-    dropped = sorted(set(layer_types) - {layer_types[i] for i in kept})
-    if dropped:
-        raise ValueError(f"layers_kept {list(kept)} keep no {dropped} layer, "
-                         "a kind that layer_types names")
-    held = experts_total if experts_held is None else experts_held
-    if not (0 <= expert_offset and expert_offset + held <= experts_total
-            and held >= 1):
-        raise ValueError(
-            f"experts [{expert_offset}, {expert_offset + held}) are not "
-            f"among the router's {experts_total}")
-    if not 0 <= client_depth <= len(kept):
-        raise ValueError(f"client_depth {client_depth} of {len(kept)} layers")
-    if num_heads % num_kv_heads:
-        raise ValueError(f"{num_kv_heads} key/value heads do not divide "
-                         f"{num_heads} query heads")
+    kept = cut.kept_layers(layers_kept, len(layer_types), layer_types)
+    held = cut.held_experts(experts_total, experts_held, expert_offset)
+    cut.check_client_depth(client_depth, len(kept))
+    cut.check_heads(num_heads, num_kv_heads)
     if head_dim % 2:
         raise ValueError(f"rotate-half needs an even head_dim, got {head_dim}")
     eps = float(norm_eps)
@@ -250,17 +233,8 @@ def lfm2_moe_plan(mode: str = "split", dtype: Any = jnp.float32, *,
         experts_per_token=experts_per_token, route_scale=float(route_scale),
         rope_theta=float(rope_theta), eps=eps, layer_types=layer_types,
         dense_layers=dense_layers, attn=attn, dtype=dtype, remat=bool(remat))
-    bottom, rest = (sizes, kept[:client_depth]), (sizes, kept[client_depth:])
-    embed = from_flax("embed", AfmoeEmbedStage(
-        vocab, d_model, bottom, dtype, run=_run_layers, mup=False))
-    if mode == "u_split":
-        return SplitPlan(
-            stages=(embed,
-                    from_flax("trunk", AfmoeTrunkAndHead(
-                        rest, 0, eps, dtype, run=_run_layers)),
-                    from_flax("head", AfmoeHeadStage(vocab, eps, dtype))),
-            owners=("client", "server", "client"))
-    return SplitPlan(
-        stages=(embed, from_flax("trunk_head", AfmoeTrunkAndHead(
-            rest, vocab, eps, dtype, run=_run_layers))),
-        owners=("client", "server"))
+    return cut.split_plan(
+        mode, cut.EmbedStage(vocab, d_model, _run_layers,
+                             (sizes, kept[:client_depth]), dtype),
+        (sizes, kept[client_depth:]),
+        cut.HeadStage(vocab, RMSNorm(eps, dtype), dtype))

@@ -66,10 +66,11 @@ adds to ``y`` is 0.06 % of the skip's part, where at the published taps it
 is a sixth, a thirteenth to a third by the head's decay: PERF.md,
 Findings PR 39.)
 
-The stages are models/afmoe.py's, given this family's layers (ROADMAP.md
-D16): split = client(embedding, unscaled, + the first ``client_depth``
-kept layers) -> server(the rest + final norm + untied head); u_split
-moves norm and head back to the client; federated is the composition.
+The stages are models/cut.py's, given this family's layers
+(:func:`_run_layers`) and its RMSNorm as the final norm: split =
+client(embedding, unscaled, + the first ``client_depth`` kept layers) ->
+server(the rest + final norm + untied head); u_split moves norm and head
+back to the client; federated is the composition.
 
 **What ``remat`` recomputes**, in the backward pass: the routed part of
 each ``E`` layer (models/afmoe.py's header: it is what gives the three rungs
@@ -110,15 +111,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from split_learning_tpu.core.stage import SplitPlan, from_flax
+from split_learning_tpu.core.stage import SplitPlan
+from split_learning_tpu.models import cut
 from split_learning_tpu.models.afmoe import (
-    AfmoeAttention, AfmoeEmbedStage, AfmoeHeadStage, AfmoeTrunkAndHead,
-    RMSNorm, RoutedExperts)
+    AfmoeAttention, RMSNorm, RoutedExperts)
 from split_learning_tpu.obs import spans
 from split_learning_tpu.ops.common import causal_depthwise_conv
 from split_learning_tpu.ops.ssd import ssd_chunked
 
-_ATTN_IMPLS = ("auto", "full", "flash")
 _KINDS = "ME*"
 _INIT = nn.initializers.normal(0.02)
 _F32 = jnp.float32
@@ -297,7 +297,7 @@ class NemotronLayer(nn.Module):
 
 def _run_layers(h, sizes: Sizes, indices: Sequence[int]):
     """The published layers ``indices`` in order, named ``layer<i>`` (call
-    inside a compact method: models/afmoe.py's stages do, as their
+    inside a compact method: models/cut.py's stages do, as their
     ``run``)."""
     for i in indices:
         h = NemotronLayer(sizes, i, name=f"layer{i}")(h)
@@ -337,32 +337,15 @@ def nemotron_h_plan(mode: str = "split", dtype: Any = jnp.float32, *,
     ``remat`` recomputes each ``E`` layer's routed part in the backward
     pass, at the rows its routing fills, and each ``M`` layer's two
     elementwise passes, and keeps everything else (the module header)."""
-    if attn not in _ATTN_IMPLS:
-        raise ValueError(f"Unknown attn impl: {attn!r} (expected {_ATTN_IMPLS})")
+    cut.check_attn(attn)
     bad = sorted(set(pattern) - set(_KINDS))
     if bad or not pattern:
         raise ValueError(f"Unknown layer letters {bad} in pattern "
                          f"{pattern!r} (expected some of {_KINDS!r})")
-    kept = tuple(int(i) for i in layers_kept)
-    if list(kept) != sorted(set(kept)) or not kept or not (
-            0 <= kept[0] and kept[-1] < len(pattern)):
-        raise ValueError(f"layers_kept {list(kept)} are not distinct rising "
-                         f"indices of {len(pattern)} published layers")
-    dropped = sorted(set(pattern) - {pattern[i] for i in kept})
-    if dropped:
-        raise ValueError(f"layers_kept {list(kept)} keep no {dropped} layer, "
-                         "a letter that the pattern names")
-    held = experts_total if experts_held is None else experts_held
-    if not (0 <= expert_offset and expert_offset + held <= experts_total
-            and held >= 1):
-        raise ValueError(
-            f"experts [{expert_offset}, {expert_offset + held}) are not "
-            f"among the router's {experts_total}")
-    if not 0 <= client_depth <= len(kept):
-        raise ValueError(f"client_depth {client_depth} of {len(kept)} layers")
-    if num_heads % num_kv_heads:
-        raise ValueError(f"{num_kv_heads} key/value heads do not divide "
-                         f"{num_heads} query heads")
+    kept = cut.kept_layers(layers_kept, len(pattern), pattern)
+    held = cut.held_experts(experts_total, experts_held, expert_offset)
+    cut.check_client_depth(client_depth, len(kept))
+    cut.check_heads(num_heads, num_kv_heads)
     if mamba_heads % ssm_groups:
         raise ValueError(f"{ssm_groups} groups do not divide {mamba_heads} "
                          "Mamba heads")
@@ -381,17 +364,8 @@ def nemotron_h_plan(mode: str = "split", dtype: Any = jnp.float32, *,
         expert_offset=expert_offset, experts_per_token=experts_per_token,
         route_scale=float(route_scale), eps=eps, attn=attn, dtype=dtype,
         remat=bool(remat))
-    bottom, rest = (sizes, kept[:client_depth]), (sizes, kept[client_depth:])
-    embed = from_flax("embed", AfmoeEmbedStage(
-        vocab, d_model, bottom, dtype, run=_run_layers, mup=False))
-    if mode == "u_split":
-        return SplitPlan(
-            stages=(embed,
-                    from_flax("trunk", AfmoeTrunkAndHead(
-                        rest, 0, eps, dtype, run=_run_layers)),
-                    from_flax("head", AfmoeHeadStage(vocab, eps, dtype))),
-            owners=("client", "server", "client"))
-    return SplitPlan(
-        stages=(embed, from_flax("trunk_head", AfmoeTrunkAndHead(
-            rest, vocab, eps, dtype, run=_run_layers))),
-        owners=("client", "server"))
+    return cut.split_plan(
+        mode, cut.EmbedStage(vocab, d_model, _run_layers,
+                             (sizes, kept[:client_depth]), dtype),
+        (sizes, kept[client_depth:]),
+        cut.HeadStage(vocab, RMSNorm(eps, dtype), dtype))

@@ -48,10 +48,10 @@ Every layer (LN = LayerNorm with scale and bias)::
 - **Cross-attention**: ``q = u W_q + b_q`` only; ``k``, ``v`` are the
   exporting layer's, paired as there; its own lambdas, norm and ``W_o``.
 
-Stages as the other families have them: split = client(embedding + the
-first ``client_depth`` kept layers) -> server(the rest + norm + head);
-u_split moves norm + head back to the client; federated is the
-composition. ``remat`` recomputes, in the backward pass, the two passes
+Stages as the other families have them (models/cut.py, with a LayerNorm
+as the final norm): split = client(embedding + the first ``client_depth``
+kept layers) -> server(the rest + norm + head); u_split moves norm + head
+back to the client; federated is the composition. ``remat`` recomputes, in the backward pass, the two passes
 around each layer's widest product and nothing else: an MLP keeps the
 output of its ``2 * mlp_width`` wide product ``LN_2(h) W_gu`` and makes
 its LayerNorm and ``silu(g) * u`` again. What costs a pass over memory
@@ -76,7 +76,8 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from split_learning_tpu.core.stage import SplitPlan, from_flax
+from split_learning_tpu.core.stage import SplitPlan
+from split_learning_tpu.models import cut
 from split_learning_tpu.models.afmoe import RMSNorm
 from split_learning_tpu.obs import spans
 from split_learning_tpu.ops.common import causal_depthwise_conv
@@ -85,7 +86,6 @@ from split_learning_tpu.ops.flash_attention import (
 from split_learning_tpu.ops.ring_attention import full_attention
 from split_learning_tpu.ops.selective_scan import selective_scan
 
-_ATTN_IMPLS = ("auto", "full", "flash")
 _INIT = nn.initializers.normal(0.02)
 _F32 = jnp.float32
 _KEPT = "mlp_gate_up"    # the one value a layer's ``remat`` keeps
@@ -314,13 +314,6 @@ class Phi4FlashLayer(nn.Module):
         return h + mlp(z_, name="mlp")(h), shared
 
 
-def _no_cache(cache_len, decode_cache):
-    if cache_len or decode_cache is not None:
-        raise NotImplementedError(
-            "phi4flash has no decode: it needs a recurrent state beside "
-            "a key/value cache (runtime/generate.py, ROADMAP.md M4)")
-
-
 def _run_layers(h, sizes: Sizes, indices: Sequence[int]):
     """The published layers ``indices`` in order, named ``layer<i>`` (call
     inside a compact method); the memory and the key/value set go from
@@ -333,61 +326,6 @@ def _run_layers(h, sizes: Sizes, indices: Sequence[int]):
         if i == sizes.kv_layer:
             kv = shared
     return h
-
-
-class Phi4FlashEmbedStage(nn.Module):
-    """Client bottom stage: ``[B, T] int -> [B, T, d_model]``: the
-    embedding rows held (no scaling, no positions) and the first kept
-    layers."""
-
-    vocab: int
-    sizes: Sizes
-    layers: tuple
-
-    @nn.compact
-    def __call__(self, tokens, *, cache_len: int = 0, decode_cache=None,
-                 pos=None):
-        _no_cache(cache_len, decode_cache)
-        h = nn.Embed(self.vocab, self.sizes.d_model, dtype=self.sizes.dtype,
-                     embedding_init=_INIT, name="tok")(tokens)
-        return _run_layers(h, self.sizes, self.layers)
-
-
-class Phi4FlashHeadStage(nn.Module):
-    """Final LayerNorm and the untied head over the vocabulary rows
-    held; products in the compute type, accumulated and returned in
-    float32, so the loss is a float32 softmax."""
-
-    vocab: int
-    sizes: Sizes
-
-    @nn.compact
-    def __call__(self, h, *, cache_len: int = 0, decode_cache=None,
-                 pos=None):
-        _no_cache(cache_len, decode_cache)
-        x = nn.LayerNorm(epsilon=self.sizes.eps, dtype=self.sizes.dtype,
-                         name="norm_f")(h)
-        kernel = self.param("lm_head", _INIT, (h.shape[-1], self.vocab))
-        return _product(x, kernel, self.sizes.dtype)
-
-
-class Phi4FlashTrunkAndHead(nn.Module):
-    """Server top stage of the 2-party split: the rest of the kept
-    layers, then (``vocab`` > 0) the final norm and the head. With
-    ``vocab`` 0 it is the U-shape's middle stage."""
-
-    sizes: Sizes
-    layers: tuple
-    vocab: int = 0
-
-    @nn.compact
-    def __call__(self, h, *, cache_len: int = 0, decode_cache=None,
-                 pos=None):
-        _no_cache(cache_len, decode_cache)
-        h = _run_layers(h, self.sizes, self.layers)
-        if not self.vocab:
-            return h
-        return Phi4FlashHeadStage(self.vocab, self.sizes, name="head")(h)
 
 
 def _check_sharing(sizes: Sizes, stages: Sequence[Sequence[int]]) -> None:
@@ -425,19 +363,12 @@ def phi4flash_plan(mode: str = "split", dtype: Any = jnp.float32, *,
     mlp_width`` wide product (the module header, with XLA's four figures:
     14.916 GB with nothing recomputed, 14.496 so, 12.822 with the product
     recomputed too, 10.282 with whole layers)."""
-    if attn not in _ATTN_IMPLS:
-        raise ValueError(f"Unknown attn impl: {attn!r} (expected {_ATTN_IMPLS})")
-    kept = tuple(int(i) for i in layers_kept)
-    if list(kept) != sorted(set(kept)) or not kept or not (
-            0 <= kept[0] and kept[-1] < layers_published):
-        raise ValueError(f"layers_kept {list(kept)} are not distinct rising "
-                         f"indices of {layers_published} published layers")
-    if not 0 <= client_depth <= len(kept):
-        raise ValueError(f"client_depth {client_depth} of {len(kept)} layers")
-    if num_heads % num_kv_heads or num_kv_heads % 2:
-        raise ValueError(
-            f"{num_heads} query heads over {num_kv_heads} key/value heads: "
-            "the key/value heads must divide the query heads and pair up")
+    cut.check_attn(attn)
+    kept = cut.kept_layers(layers_kept, layers_published)
+    cut.check_client_depth(client_depth, len(kept))
+    if num_kv_heads % 2:
+        raise ValueError(f"{num_kv_heads} key/value heads do not pair up")
+    cut.check_heads(num_heads, num_kv_heads)
     sizes = Sizes(
         d_model=d_model, num_heads=num_heads, num_kv_heads=num_kv_heads,
         head_dim=head_dim, mlp_width=mlp_width, window=window,
@@ -447,14 +378,8 @@ def phi4flash_plan(mode: str = "split", dtype: Any = jnp.float32, *,
         remat=bool(remat))
     bottom, rest = kept[:client_depth], kept[client_depth:]
     _check_sharing(sizes, (bottom, rest))
-    embed = from_flax("embed", Phi4FlashEmbedStage(vocab, sizes, bottom))
-    if mode == "u_split":
-        return SplitPlan(
-            stages=(embed,
-                    from_flax("trunk", Phi4FlashTrunkAndHead(sizes, rest, 0)),
-                    from_flax("head", Phi4FlashHeadStage(vocab, sizes))),
-            owners=("client", "server", "client"))
-    return SplitPlan(
-        stages=(embed, from_flax("trunk_head", Phi4FlashTrunkAndHead(
-            sizes, rest, vocab))),
-        owners=("client", "server"))
+    return cut.split_plan(
+        mode, cut.EmbedStage(vocab, d_model, _run_layers, (sizes, bottom),
+                             dtype), (sizes, rest),
+        cut.HeadStage(vocab, nn.LayerNorm(epsilon=sizes.eps, dtype=dtype),
+                      dtype))

@@ -23,6 +23,7 @@ from split_learning_tpu.core.losses import (
 from split_learning_tpu.core.stage import remat_plan
 from split_learning_tpu.models import get_plan
 from split_learning_tpu.models import joyai_llm_flash as family
+from split_learning_tpu.models.cut import TrunkStage
 from split_learning_tpu.obs import spans
 from split_learning_tpu.ops.flash_attention import flash_attention
 from split_learning_tpu.ops.ring_attention import full_attention
@@ -305,7 +306,7 @@ def test_the_objective_is_main_ce_plus_lambda_times_the_modules():
     eps = KW["rms_norm_eps"]
     norm = lambda scale, v: v * jax.lax.rsqrt(
         jnp.mean(v * v, -1, keepdims=True) + eps) * scale["scale"]
-    trunk = family.TrunkStage(sizes(), (1, 4, 1))
+    trunk = TrunkStage(family._run_layers, (sizes(), 1, 4, 1))
     g = norm(p["norm_f"], trunk.apply(
         {"params": {k: v for k, v in p.items() if k.startswith("layer")}}, h))
     m = p["mtp"]
