@@ -13,10 +13,12 @@ counts this one program, not what else a process keeps on the device
 (PERF.md section 4: two loaded executables once cost the reference its
 room).  It is the check of the fit rules of ``trinity-mini-fused-t8192``
 (13.0 GB or under), ``phi4flash-fused-t8192``,
-``joyai-flash-fused-t8192``, ``lfm2-moe-fused-t8192`` and
-``nemotronh-moe-fused-t8192`` (14.5).  ``--remat 0``
-compiles the step without the family's ``remat``, to see what the values it
-recomputes cost when kept.
+``joyai-flash-fused-t8192``, ``lfm2-moe-fused-t8192``,
+``nemotronh-moe-fused-t8192`` and ``ouro-loop-fused-t8192`` (14.5).
+``--remat 0`` compiles the step without the family's ``remat``, to see what
+the values it recomputes cost when kept; ``--remat-mlp-passes N`` sets in how
+many of a looped model's passes the MLPs' ``gate`` and ``up`` are made again
+(``models/ouro.py``: the fit rule of ``ouro-loop-fused-t8192`` walks it).
 """
 
 from __future__ import annotations
@@ -42,6 +44,8 @@ def main() -> int:
                         help="a cell of BENCHMARK.json on the fused path")
     parser.add_argument("--remat", type=int, choices=(0, 1), default=None,
                         help="override the configuration's plan.kwargs.remat")
+    parser.add_argument("--remat-mlp-passes", type=int, default=None,
+                        help="override plan.kwargs.remat_mlp_passes")
     args = parser.parse_args()
 
     import run
@@ -68,6 +72,8 @@ def main() -> int:
     kw = dict(spec["kwargs"])
     if args.remat is not None:
         kw["remat"] = bool(args.remat)
+    if args.remat_mlp_passes is not None:
+        kw["remat_mlp_passes"] = args.remat_mlp_passes
     plan = get_plan(spec["model"], spec["mode"], jnp.dtype(spec["dtype"]), **kw)
     tx = make_tx(run.program_config(config, job))
 
@@ -99,6 +105,7 @@ def main() -> int:
     text = compiled.as_text()
     print(json.dumps({
         "workload": args.workload, "remat": kw.get("remat"),
+        **{k: kw[k] for k in ("remat_mlp_passes",) if k in kw},
         "device": topo.devices[0].device_kind,
         "bytes": sizes,
         "gb": {k: round(v / 1e9, 3) for k, v in sizes.items()},

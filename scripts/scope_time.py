@@ -4,6 +4,8 @@
         [--scopes short_conv,attn_full,moe_route,moe_experts]
     python scripts/scope_time.py --workload nemotronh-moe-fused-t8192 --seed N
         [--scopes ssm_ssd,ssm_conv,moe_shared] [--top 8]
+    python scripts/scope_time.py --workload ouro-loop-fused-t8192 --seed N
+        [--scopes attn_full] [--top 8]
 
 Runs ``benchmarks/run.py``'s own ``main`` with ``--trace 1`` and, before the
 run deletes its trace, reads the xplane once more: the benchmark's reduction

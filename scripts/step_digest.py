@@ -12,7 +12,7 @@ the loss taken off and what only those needed removed
 (``state_and_loss_alone``: equal digests there say two steps differ by their
 extra outputs alone); a party cell's server loss with its gradients (scalar and
 per example) and its client's forward and recomputed backward.  One JSON line
-a cell; ``diff`` two runs.  About 5 minutes for the nine cells.  A function's
+a cell; ``diff`` two runs.  About 6 minutes for the ten cells.  A function's
 address in the text (a ``remat`` policy prints as ``<function ... at 0x...>``)
 is left out of what is hashed, so that two processes agree.
 ``tests/test_step_digest.py`` holds every cell's digest (``cell_digests``): a PR

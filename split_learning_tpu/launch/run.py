@@ -53,7 +53,7 @@ def _add_common(p: argparse.ArgumentParser) -> None:
     p.add_argument("--model", default=None,
                    help="split_cnn | resnet18 | resnet18_4stage | vit | "
                         "transformer | transformer_lm | afmoe | phi4flash | "
-                        "joyai_llm_flash | lfm2_moe | nemotron_h")
+                        "joyai_llm_flash | lfm2_moe | nemotron_h | ouro")
     p.add_argument("--dataset", default=None,
                    help="mnist | cifar10 | synthetic | tokens | lm")
     p.add_argument("--batch-size", type=int, default=None)

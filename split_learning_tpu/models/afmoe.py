@@ -173,7 +173,8 @@ class AfmoeAttention(nn.Module):
     """Grouped-head attention. ``plain`` (models/nemotron_h.py's) is the
     same layer without the output gate and without the norms of q and k:
     with ``window`` None it has no positions either, four products and
-    the kernel."""
+    the kernel. ``rope_always`` (models/ouro.py's) turns q and k in a
+    full layer too."""
 
     num_heads: int
     num_kv_heads: int
@@ -184,6 +185,7 @@ class AfmoeAttention(nn.Module):
     attn: str = "auto"
     dtype: Any = jnp.float32
     plain: bool = False
+    rope_always: bool = False
 
     @nn.compact
     def __call__(self, u):
@@ -196,7 +198,7 @@ class AfmoeAttention(nn.Module):
             gate = _linear(h * d, self.dtype, "gate")(u)
             q = RMSNorm(self.eps, self.dtype, name="q_norm")(q)
             k = RMSNorm(self.eps, self.dtype, name="k_norm")(k)
-        if self.window is not None:
+        if self.window is not None or self.rope_always:
             q, k = rope(q, self.rope_theta), rope(k, self.rope_theta)
         impl = self.attn
         if impl == "auto":

@@ -156,6 +156,15 @@ def _nemotron_h(mode: str, dtype: Any, **kw: Any) -> SplitPlan:
     return nemotron_h_plan(mode=mode, dtype=dtype, **kw)
 
 
+@register_model("ouro")
+def _ouro(mode: str, dtype: Any, **kw: Any) -> SplitPlan:
+    """A looped language model: one stack of sandwich-normed dense layers
+    run several times with the same weights, an exit gate and a loss read
+    after every pass through one head (models/ouro.py)."""
+    from split_learning_tpu.models.ouro import ouro_plan
+    return ouro_plan(mode=mode, dtype=dtype, **kw)
+
+
 def get_plan(model: str = "split_cnn", mode: str = "split",
              dtype: Any = jnp.float32, **size_kw: Any) -> SplitPlan:
     """Build the SplitPlan for a model family under a learning mode.

@@ -259,6 +259,10 @@ STEP_COUNTERS = "step_counters"
 MOE_PAIRS = "pairs"
 MOE_ROWS = "rows"
 MOE_LADDER = "ladder"
+# the looped model's objective (models/ouro.py LoopStage.losses) sows two,
+# one entry a pass: the step's mean exit probability and mean cross-entropy
+EXIT_MASS = "exit_mass"
+EXIT_LOSS = "exit_loss"
 
 # the client-level phases that tile a step — the denominator of the
 # compute-vs-wire fraction (encode/wire are sub-phases of transport and
