@@ -205,6 +205,52 @@ def coalesce_window_handoff(ctx: Ctx) -> Dict[str, Any]:
     return dict(co.counters())
 
 
+@scenario("coalesce_shed_close_race", invariants=("all_resolved",),
+          budget=300, bound=2)
+def coalesce_shed_close_race(ctx: Ctx) -> Dict[str, Any]:
+    """A window flusher whose measured step times say that a third
+    request only pads its bucket cuts a group of three: two are served,
+    the third heads the next group and waits out a window of its own.
+    During that window a fourth arrival and close() race each other and
+    the flusher's wake-up: every request comes back exactly once, served
+    or refused at the door of a closed coalescer, and the flush reasons
+    add up."""
+    from collections import deque
+    from split_learning_tpu.runtime.coalesce import (
+        FLUSH_REASONS, CoalesceRequest, RequestCoalescer)
+    window_s = 2.0 ** -4  # binary: the virtual clock's sums are exact
+    co = RequestCoalescer(_stub_dispatch(ctx), max_group=4,
+                          window_s=window_s, mode="window")
+    acts, labels = _tiny_batch()
+    # a step that costs its rows: three rows padded to four cost a fourth
+    key = CoalesceRequest(acts, labels, 0, 0).shape_key()
+    co._served.update({(key, n): deque([n / 32.0] * 2) for n in (1, 2, 4)})
+
+    def submit(client_id: int) -> None:
+        ctx.note("enqueue", key=(client_id, 0))
+        try:
+            co.submit(acts, labels, 0, client_id, timeout=60.0)
+        except RuntimeError as exc:
+            assert "closed" in str(exc), exc
+            ctx.note("resolved", key=(client_id, 0))
+
+    # a timeout fires only once nothing else can run: all three are in
+    # the first group when its window ends
+    workers = [ctx.spawn(submit, c, name=f"sub-{c}") for c in (1, 2, 3)]
+    ctx.sleep(1.5 * window_s)
+    assert co.counters().get("flush_shed", 0) == 1, co.counters()
+    workers += [ctx.spawn(submit, 4, name="late"),
+                ctx.spawn(co.close, 30.0, name="closer")]
+    for w in workers:
+        w.join()
+    co.close(timeout=30.0)
+    c = dict(co.counters())
+    assert c["requests_coalesced"] >= 3, c
+    assert c["groups_flushed"] == sum(
+        c.get(f"flush_{why}", 0) for why in FLUSH_REASONS), c
+    return c
+
+
 @scenario("continuous_edf",
           invariants=("edf_pickup_order", "all_resolved"),
           budget=400, bound=2)

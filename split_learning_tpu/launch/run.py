@@ -2307,7 +2307,11 @@ def main(argv: Optional[list] = None) -> int:
                     type=float, default=2.0,
                     help="how long a coalescing group waits for peers "
                          "after its first request before flushing partial "
-                         "(only with --coalesce-max > 1)")
+                         "(only with --coalesce-max > 1). A group is then "
+                         "cut back to the requests that fill a smaller row "
+                         "bucket exactly where the step times the server "
+                         "has measured say that answers its clients "
+                         "sooner (/health counts flush_shed)")
     ps.add_argument("--batching", choices=["window", "continuous"],
                     default="window",
                     help="coalescer flush policy (with --coalesce-max > "

@@ -20,9 +20,26 @@ servers skip this module entirely (bit-for-bit serialized path).
 Two flush policies share the queue (``mode`` ctor knob):
 
 - ``"window"`` (default, the original): block for a head request, then
-  wait out ``window_s`` from its arrival hoping peers show up. Best
-  batches under steady offered load, but every window is accelerator
-  idle time when traffic is bursty.
+  wait out ``window_s`` from its pickup hoping peers show up; the group
+  closes when it is full or the window ends. Before it is dispatched it
+  may be cut (*shed*), by what the flusher has measured: ``S(rows)`` is
+  the least of a padded row bucket's last few times from dispatch to
+  the first reply redeemed (a bucket counts from its second sample: a
+  shape's first call compiles; a group dispatched while the one before
+  it had no reply yet is not sampled: it queued behind that one). A
+  group of ``k`` requests is cut back to its longest proper prefix of
+  ``j`` that fills a bucket exactly, the rest going back to the head of
+  the queue for the next group, where
+  ``j (S(k) - S(j)) > (2k - j) (S(j) + S(rest) - S(k))``: what the
+  first ``j`` gain by their smaller step, against what the cut adds to
+  the device's time, which the rest wait now and all ``k`` wait again
+  on their next requests (closed-loop clients keep the device busy).
+  Where a step's time follows its rows (large models) padding three
+  requests to four costs a whole request's step and delays all three,
+  and two halves answer half the clients a step earlier for little
+  more device time than the whole; where a step is mostly fixed cost,
+  or a size is unmeasured, the test fails and the group stays whole.
+  Counter: ``flush_shed`` beside ``flush_full`` / ``flush_window``.
 - ``"continuous"`` (:class:`ContinuousBatcher`): the flusher NEVER
   sleeps on a timer while work is queued — the moment the previous
   group's dispatch returns (with async dispatch, PR 5, that is the
@@ -52,8 +69,9 @@ from __future__ import annotations
 
 import threading
 import time
+from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Callable, List, Optional, Tuple
+from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -68,6 +86,25 @@ def pow2_bucket(n: int) -> int:
     if n < 1:
         raise ValueError(f"bucket size must be positive (got {n})")
     return 1 << (n - 1).bit_length()
+
+
+# why a group closed: ``flush_<reason>`` counts each, and they sum to
+# ``groups_flushed``
+FLUSH_REASONS = ("full", "window", "shed", "continuous")
+
+
+@dataclass
+class _Flush:
+    """One dispatched group, as its waiters see it: the first of them to
+    hold its reply times the group (window mode's service-time sample),
+    unless the group went out while the one before it had no reply yet:
+    it queued behind that one on the device, and its time says more of
+    the queue than of its step."""
+
+    t0: float
+    bucket: int
+    queued: bool
+    timed: bool = False
 
 
 @dataclass
@@ -102,6 +139,8 @@ class CoalesceRequest:
     # rebuilt after every partial take, so index order only happens to
     # equal arrival order; equal-deadline pickup must not depend on that
     seq: int = 0
+    # window mode: the dispatched group this request went out with
+    flush: Optional[_Flush] = None
 
     def shape_key(self) -> tuple:
         """Requests coalesce only when everything but the batch row count
@@ -123,9 +162,11 @@ class RequestCoalescer:
 
     Counters (all under ``stats.counters``, reported by the server's
     /health): ``groups_flushed``, ``requests_coalesced``, ``flush_full`` /
-    ``flush_window`` (why each group closed), plus the dispatcher's own
-    ``compile_count``. ``stats.record`` times each flush, so the p50/p99
-    the summary reports are per-group dispatch latencies.
+    ``flush_window`` / ``flush_shed`` (why each group closed,
+    :data:`FLUSH_REASONS`; they sum to ``groups_flushed``), plus the
+    dispatcher's own ``compile_count``. ``stats.record`` times each
+    flush, so the p50/p99 the summary reports are per-group dispatch
+    latencies.
     """
 
     def __init__(self, dispatch: Callable[[List[CoalesceRequest], str], None],
@@ -148,6 +189,10 @@ class RequestCoalescer:
         self.stats = TransportStats()
         self._queue: List[CoalesceRequest] = []
         self._arrivals = 0  # next CoalesceRequest.seq
+        # window mode's observations: what the last few dispatched groups
+        # of a (shape class, padded row bucket) took; guarded by _cond
+        self._served: Dict[tuple, Deque[float]] = {}
+        self._last_flush: Optional[_Flush] = None  # the flusher's own
         self._cond = obs_locks.make_condition("RequestCoalescer._cond")
         self._closed = False
         self._thread = obs_locks.make_thread(
@@ -204,6 +249,7 @@ class RequestCoalescer:
             # d2h span is unknown until the transfer happens), so it
             # runs before the republish below.
             req.result = req.result()
+        self._note_served(req)
         if req.server_spans is not None:
             # lazy import: keeps the untraced module surface jax- and
             # obs-free for the pure queue unit tests
@@ -215,13 +261,44 @@ class RequestCoalescer:
         return req.result
 
     # ------------------------------------------------------------------ #
+    def _note_served(self, req: CoalesceRequest) -> None:
+        """On the waiter's thread, its reply in hand: the first reply of
+        a group says what the group took since its dispatch (window
+        mode's sample; a group that failed, or queued behind the one
+        before it, says nothing)."""
+        flush = req.flush
+        if flush is None or flush.timed:
+            return
+        with self._cond:
+            if flush.timed:
+                return
+            flush.timed = True
+            if not flush.queued and req.error is None:
+                self._served.setdefault(
+                    (req.shape_key(), flush.bucket), deque(maxlen=4)
+                ).append(time.monotonic() - flush.t0)
+
+    def _cost(self, key: tuple, rows: int) -> Optional[float]:
+        """Seconds from dispatch to the first redeemed reply for a group
+        of ``rows`` of shape class ``key``: the least of the bucket's
+        last few samples (one that held a compile, or met the clients'
+        own work on the device, reads high), None until it has two -- a
+        shape's first call is the one that compiles."""
+        samples = self._served.get((key, pow2_bucket(rows)))
+        if samples is None or len(samples) < 2:
+            return None
+        return min(samples)
+
+    # ------------------------------------------------------------------ #
     def _collect_group(self) -> Optional[Tuple[List[CoalesceRequest], str]]:
         """Block for a head request, then form the next group by mode:
         window mode gathers same-shape peers until the group is full or
-        the window since the head's arrival closes; continuous mode takes
-        whatever is queued RIGHT NOW (earliest-deadline-first head, then
-        its same-shape peers in EDF order) without ever sleeping on a
-        timer. Returns None only at shutdown."""
+        the window since the head's pickup closes, and cuts the group
+        where the measured step times say so (:meth:`_shed`);
+        continuous mode takes whatever is queued RIGHT NOW
+        (earliest-deadline-first head, then its same-shape peers in EDF
+        order) without ever sleeping on a timer. Returns None only at
+        shutdown."""
         with self._cond:
             while not self._queue and not self._closed:
                 self._cond.wait()
@@ -277,8 +354,36 @@ class RequestCoalescer:
                     break
                 self._cond.wait(timeout=budget)
                 take_matching(group)
+            if not self._closed and self._shed(key, group):
+                return group, "shed"
             reason = "full" if len(group) >= self.max_group else "window"
             return group, reason
+
+    def _shed(self, key: tuple, group: List[CoalesceRequest]) -> bool:
+        """Cut ``group`` back to its longest proper prefix that fills a
+        row bucket exactly, where the measured times say that pays: the
+        prefix answers after its own smaller step, ``S(k) - S(j)``
+        sooner each; the cut adds ``S(j) + S(rest) - S(k)`` to the
+        device's time (less than nothing where the group only padded
+        its bucket), which the rest wait now and everyone waits again on
+        the next request. The rest return to the head of the queue, in
+        order. A size not measured yet leaves the group whole."""
+        rows = [int(r.acts.shape[0]) for r in group]
+        fits = [j for j in range(1, len(group))
+                if sum(rows[:j]) == pow2_bucket(sum(rows[:j]))]
+        if not fits:
+            return False
+        j, k = fits[-1], len(group)
+        whole, first, rest = (self._cost(key, sum(rows)),
+                              self._cost(key, sum(rows[:j])),
+                              self._cost(key, sum(rows[j:])))
+        if whole is None or first is None or rest is None:
+            return False
+        if j * (whole - first) <= (2 * k - j) * (first + rest - whole):
+            return False
+        self._queue[:0] = group[j:]
+        del group[j:]
+        return True
 
     def _run(self) -> None:
         while True:
@@ -293,6 +398,14 @@ class RequestCoalescer:
                 fl.record(spans.FL_GROUP_PICKUP, step=int(group[0].step),
                           client_id=int(group[0].client_id),
                           party="server", size=len(group), reason=reason)
+            if self.mode == "window":
+                last = self._last_flush
+                flush = self._last_flush = _Flush(
+                    time.monotonic(),
+                    pow2_bucket(sum(int(r.acts.shape[0]) for r in group)),
+                    queued=last is not None and not last.timed)
+                for r in group:
+                    r.flush = flush
             t0 = time.perf_counter()
             try:
                 self._dispatch(group, reason)

@@ -75,7 +75,9 @@ class ServerRuntime(PartyRuntime):
         to ``coalesce_max`` per group (runtime/coalesce.py). 1 = the
         serialized path, bit-for-bit — the coalescer is never built.
         ``batching`` picks the flush policy for that coalescer:
-        ``"window"`` (the original fixed window/size flusher) or
+        ``"window"`` (the window/size flusher, which cuts a group
+        back to the requests that fill a smaller row bucket where the
+        step times it has measured say so, runtime/coalesce.py) or
         ``"continuous"`` (runtime/coalesce.py ContinuousBatcher — the
         next group is whatever is admitted the moment the previous
         group's jitted call is dispatched, picked EDF on admission

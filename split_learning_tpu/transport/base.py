@@ -63,7 +63,7 @@ class TransportStats:
     bytes_received: int = 0
     total_seconds: float = 0.0
     # free-form event counters (e.g. the server coalescer's
-    # groups_flushed / requests_coalesced / flush_full / flush_window /
+    # groups_flushed / requests_coalesced / flush_<reason> /
     # compile_count) — merged() sums them, summary() reports them
     counters: Dict[str, float] = dataclasses.field(default_factory=dict)
     _latencies: list = dataclasses.field(default_factory=list)

@@ -4,7 +4,10 @@ dispatch per group, with the serialized path pinned bit-for-bit at
 ``coalesce_max=1`` and a group of one reproducing serialized semantics
 (the acceptance criteria of the coalescing issue)."""
 
+import sys
 import threading
+import time
+from collections import deque
 
 import jax
 import numpy as np
@@ -14,7 +17,7 @@ from split_learning_tpu.models import get_plan
 from split_learning_tpu.runtime import (
     ProtocolError, ServerRuntime, SplitClientTrainer)
 from split_learning_tpu.runtime.coalesce import (
-    CoalesceRequest, RequestCoalescer, pow2_bucket)
+    FLUSH_REASONS, CoalesceRequest, RequestCoalescer, pow2_bucket)
 from split_learning_tpu.runtime.multi_client import MultiClientSplitRunner
 from split_learning_tpu.transport import LocalTransport
 from split_learning_tpu.transport.base import TransportStats
@@ -146,6 +149,215 @@ def test_coalescer_config_and_close_contract():
         c.submit(a[0], a[1], 0, 0)
 
 
+# --------------------------------------------------------------------- #
+# unit: the cut by measured step times (shed), still no jax
+# --------------------------------------------------------------------- #
+
+# seconds from dispatch to the first reply, by padded row bucket, of
+# requests of 8 rows: the party cell's (a step costs its rows), and a
+# step that is mostly fixed cost
+BY_ROWS = {8: [0.033] * 2, 16: [0.0505] * 2, 32: [0.095] * 2}
+FIXED = {8: [0.045] * 2, 16: [0.050] * 2, 32: [0.060] * 2}
+
+
+def _assert_reasons_sum(counters):
+    assert counters["groups_flushed"] == sum(
+        counters.get(f"flush_{why}", 0) for why in FLUSH_REASONS)
+
+
+def _planted(max_group, served, dispatch=_resolve_all, window_s=0.002):
+    """A coalescer with planted observations (no clock read): requests
+    of 8 rows from clients 0.., ``served`` the samples by bucket."""
+    c = RequestCoalescer(dispatch, max_group=max_group, window_s=window_s)
+    reqs = [CoalesceRequest(np.zeros((8, 4), np.float32),
+                            np.zeros((8,), np.int64), 0, i)
+            for i in range(max_group)]
+    c._served.update({(reqs[0].shape_key(), b): deque(v, maxlen=4)
+                      for b, v in served.items()})
+    return c, reqs
+
+
+@pytest.mark.parametrize("served, n, kept", [
+    # three pad to the step of four: 2 x (95 - 50.5) gained, and the
+    # device's time falls by 95 - 50.5 - 33
+    (BY_ROWS, 3, 2),
+    # four in two halves: 2 x 44.5 gained against 6 x (101 - 95)
+    (BY_ROWS, 4, 2),
+    # a pair in singles: 17.5 gained against 3 x (66 - 50.5)
+    (BY_ROWS, 2, 2),
+    # mostly fixed cost: 2 x 10 gained against 4 x 35, and 6 x 40
+    (FIXED, 3, 3),
+    (FIXED, 4, 4),
+    # the smaller buckets not measured yet
+    ({32: [0.095] * 2}, 3, 3),
+    # one sample is the call that compiled: the bucket does not count
+    ({**BY_ROWS, 8: [0.033]}, 3, 3),
+])
+def test_shed_cuts_a_group_where_the_measured_times_say_so(served, n, kept):
+    c, reqs = _planted(4, served)
+    try:
+        group = reqs[:n]
+        assert c._shed(reqs[0].shape_key(), group) == (kept < n)
+        assert group == reqs[:kept]
+        assert c._queue == reqs[kept:n]     # back at the head, in order
+    finally:
+        c._queue.clear()
+        c.close()
+
+
+@pytest.mark.parametrize("served, n, cuts", [
+    (BY_ROWS, 3, [(2, "shed"), (1, "window")]),
+    (BY_ROWS, 4, [(2, "shed"), (2, "window")]),
+    (FIXED, 3, [(3, "window")]),
+    (FIXED, 4, [(4, "full")]),
+])
+def test_a_round_is_cut_as_the_measured_times_say(served, n, cuts):
+    """``n`` clients arrive together at a server of ``max_group`` 4 whose
+    planted times decide before any of the round's own samples lands:
+    the round runs in exact halves where a step costs its rows, whole
+    where it is mostly fixed cost; the requests shed head the next group,
+    which waits a window of its own, and every client is answered."""
+    groups = []
+
+    def dispatch(group, reason):
+        groups.append(([r.client_id for r in group], reason))
+        _resolve_all(group, reason)
+
+    c, reqs = _planted(4, served, dispatch, window_s=0.25)
+    errors = []
+
+    def one(r):
+        try:
+            c.submit(r.acts, r.labels, 0, r.client_id, timeout=30.0)
+        except Exception as exc:  # propagate to the main thread
+            errors.append(exc)
+
+    try:
+        threads = [threading.Thread(target=one, args=(r,)) for r in reqs[:n]]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+        assert not errors and not any(t.is_alive() for t in threads)
+        assert [(len(g), why) for g, why in groups] == cuts
+        assert sorted(i for g, _ in groups for i in g) == list(range(n))
+        counters = c.counters()
+        assert counters.get("flush_shed", 0) == (len(cuts) - 1)
+        _assert_reasons_sum(counters)
+    finally:
+        c.close()
+
+
+@pytest.mark.parametrize("max_group", [2, 4])
+def test_lone_client_flushes_on_the_window_whatever_was_measured(max_group):
+    """A group of one has nothing to shed: a lone client's requests
+    flush on the window, as they always did."""
+    c, reqs = _planted(max_group, BY_ROWS)
+    try:
+        for step in range(5):
+            c.submit(reqs[0].acts, reqs[0].labels, step, 0, timeout=30.0)
+        counters = c.counters()
+        assert counters["flush_window"] == counters["groups_flushed"] == 5
+    finally:
+        c.close()
+
+
+def test_a_group_is_timed_once_and_a_compile_never_sets_the_cost():
+    """The first waiter to hold its reply times its group, once; a
+    bucket's cost is the least of its last four samples and counts from
+    the second (a shape's first call compiles); a group that failed, or
+    went out while the one before it had no reply yet, says nothing."""
+    slow, release = {"first": True}, threading.Event()
+
+    def dispatch(group, reason):
+        if group[0].step == 9:
+            raise RuntimeError("boom")
+
+        def redeem(r):
+            def thunk():
+                if slow.pop("first", False):
+                    time.sleep(0.2)     # the call that compiles
+                if r.step == 20:
+                    assert release.wait(timeout=30)  # still on the device
+                return r.acts, 1.0
+            return thunk
+
+        for r in group:
+            r.result = redeem(r)
+            r.done.set()
+
+    c = RequestCoalescer(dispatch, max_group=2, window_s=0.001)
+    a = np.zeros((1, 4), np.float32), np.zeros((1,), np.int64)
+    key = CoalesceRequest(a[0], a[1], 0, 0).shape_key()
+    try:
+        c.submit(a[0], a[1], 0, 0)
+        singles = c._served[(key, 1)]
+        assert len(singles) == 1 and c._cost(key, 1) is None
+        assert singles[0] >= 0.2
+        c.submit(a[0], a[1], 1, 0)
+        assert c._cost(key, 1) == min(singles) < 0.2
+        with pytest.raises(RuntimeError, match="boom"):
+            c.submit(a[0], a[1], 9, 0)
+        assert len(singles) == 2
+        # a full group of two: one sample for its bucket, not two
+        t = threading.Thread(target=c.submit, args=(a[0], a[1], 2, 1))
+        t.start()
+        c.submit(a[0], a[1], 2, 0)
+        t.join(timeout=10)
+        assert sum(len(v) for v in c._served.values()) == 3
+        # a single that goes out while the one before it is unanswered
+        # queued behind it: no sample; the one before it has its own
+        t = threading.Thread(target=c.submit, args=(a[0], a[1], 20, 0))
+        t.start()
+        while c.counters().get("groups_flushed", 0) < 5:
+            time.sleep(0.001)
+        c.submit(a[0], a[1], 21, 1)
+        assert len(singles) == 2
+        release.set()
+        t.join(timeout=10)
+        assert len(singles) == 3
+        for step in range(22, 28):
+            c.submit(a[0], a[1], step, 0)
+        assert len(singles) == 4
+    finally:
+        release.set()
+        c.close()
+
+
+def test_shed_bookkeeping_survives_many_threads():
+    """More submitters than cores under a short switch interval: every
+    request comes back and the flush reasons add up."""
+    n_threads, n_steps = 24, 25
+    c = RequestCoalescer(_resolve_all, max_group=4, window_s=0.0005)
+    acts, labels = np.zeros((1, 4), np.float32), np.zeros((1,), np.int64)
+    errors = []
+
+    def run(i):
+        try:
+            for step in range(n_steps):
+                c.submit(acts, labels, step, i % 12, timeout=30.0)
+        except Exception as exc:
+            errors.append((i, exc))
+
+    prev = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=run, args=(i,))
+                   for i in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(prev)
+        c.close()
+    assert not errors, errors
+    counters = c.counters()
+    assert counters["requests_coalesced"] == n_threads * n_steps
+    _assert_reasons_sum(counters)
+
+
 def test_transport_stats_counters_merge_and_summary():
     a, b = TransportStats(), TransportStats()
     a.incr("groups_flushed")
@@ -236,8 +448,7 @@ def test_concurrent_clients_form_groups_and_health_reports_counters():
     # barrier-released arrivals coalesce well above the 2.0 the bench
     # leg polices; exact grouping is scheduler-dependent
     assert c["mean_occupancy"] >= 2.0
-    assert c["groups_flushed"] == \
-        c.get("flush_full", 0) + c.get("flush_window", 0)
+    _assert_reasons_sum(c)
     # one padded pow2 shape (4*BATCH=32) -> one compile
     assert c["compile_count"] == 1
     server.close()

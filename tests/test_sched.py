@@ -262,7 +262,9 @@ def test_generic_invariants_are_registered():
 # ---------------------------------------------------------------------- #
 
 @pytest.mark.parametrize("scenario", ["replay_dup_storm",
-                                      "admission_bucket_race"])
+                                      "admission_bucket_race",
+                                      "coalesce_window_handoff",
+                                      "coalesce_shed_close_race"])
 def test_real_scenarios_are_clean(scenario):
     assert engine.main(["--check", "--scenario", scenario,
                         "--budget", "60"]) == 0

@@ -12,6 +12,7 @@ from split_learning_tpu.models import get_plan
 from split_learning_tpu.obs import spans
 from split_learning_tpu.obs import trace as obs_trace
 from split_learning_tpu.runtime import ServerRuntime, SplitClientTrainer
+from split_learning_tpu.runtime.coalesce import FLUSH_REASONS
 from split_learning_tpu.runtime.fused import FusedSplitTrainer
 from split_learning_tpu.runtime.multi_client import MultiClientSplitRunner
 from split_learning_tpu.transport import LocalTransport
@@ -287,7 +288,7 @@ def test_a_groups_dispatch_is_one_span_naming_all_its_requests():
     assert sorted(named) == sorted(w["trace_id"] for w in waits)
     for g in groups:
         assert g["party"] == "server" and g["parent_id"] is None
-        assert g["attrs"]["reason"] in ("full", "window")
+        assert g["attrs"]["reason"] in FLUSH_REASONS
         assert g["attrs"]["rows"] == 4 * g["attrs"]["group"]
         assert g["attrs"]["padded"] >= g["attrs"]["rows"]
         assert len(g["attrs"]["traces"]) == g["attrs"]["group"]
