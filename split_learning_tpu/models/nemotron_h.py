@@ -14,7 +14,9 @@ statistics in float32, no bias in any product)::
 - **``M``** (:class:`Mamba2Mixer`), ``d_inner = heads x head_dim``: ``[z |
   xBC | dt] = u W_in`` (``d_inner + (d_inner + 2 groups x state) + heads``
   columns); ``xBC = silu(conv(xBC) + b_conv)``, a causal depthwise
-  convolution over ``conv_taps`` tokens (``ops/common.causal_depthwise_conv``);
+  convolution over ``conv_taps`` tokens (ops/causal_conv.py's ``conv_silu``:
+  at the published sizes one Pallas pass forward and one backward, at a
+  test's or a rehearsal's the shifted sum of ops/common.py);
   ``x [T, heads, head_dim]``, ``B``, ``C`` ``[T, groups, state]``, head
   ``n`` reads group ``n // (heads / groups)``; ``dt = softplus(dt +
   dt_bias)``, no clamp; ``a_n = -exp(A_log_n)``, one scalar a head; the
@@ -76,7 +78,10 @@ back to the client; federated is the composition.
 each ``E`` layer (models/afmoe.py's header: it is what gives the three rungs
 of rows), and the two elementwise passes of each ``M`` layer, which are
 bound by bytes and not by arithmetic: ``silu(conv(xBC) + b_conv)`` is made
-again from the first product's output, the gate with the grouped norm
+again from the first product's output (by ``jax.checkpoint`` around the
+plain form; the kernels of ops/causal_conv.py keep their inputs alone and
+their backward makes the pre-activation again in registers, so no second
+forward call runs), the gate with the grouped norm
 from ``z`` and the recurrence's ``y``, so that of each the inputs are
 kept and none of the float32 values between (2.2 GB a step at the
 benchmark's sizes). Every product's output, the shared expert's hidden
@@ -116,7 +121,7 @@ from split_learning_tpu.models import cut
 from split_learning_tpu.models.afmoe import (
     AfmoeAttention, RMSNorm, RoutedExperts)
 from split_learning_tpu.obs import spans
-from split_learning_tpu.ops.common import causal_depthwise_conv
+from split_learning_tpu.ops import causal_conv
 from split_learning_tpu.ops.ssd import ssd_chunked
 
 _KINDS = "ME*"
@@ -214,8 +219,7 @@ def _conv_act(xbc, taps, bias):
     """``silu(conv(xbc) + bias)``: float32 from the product's output, the
     result back in its type."""
     with jax.named_scope(spans.SSM_CONV):
-        return jax.nn.silu(bias + causal_depthwise_conv(
-            xbc.astype(_F32), taps)).astype(xbc.dtype)
+        return causal_conv.conv_silu(xbc, taps, bias, xbc.dtype)
 
 
 class Mamba2Mixer(nn.Module):
@@ -237,8 +241,11 @@ class Mamba2Mixer(nn.Module):
         conv_act, norm = _conv_act, GatedGroupNorm
         if s.remat:
             # the two elementwise passes are made again from what the
-            # products gave (the module header)
-            conv_act, norm = jax.checkpoint(conv_act), nn.remat(norm)
+            # products gave (the module header); the convolution's kernels
+            # keep their inputs alone as it is
+            norm = nn.remat(norm)
+            if not causal_conv.fills_tiles(xbc, taps):
+                conv_act = jax.checkpoint(conv_act)
         x, b, c = jnp.split(
             conv_act(xbc, taps + tap_starts(s.conv_taps, wide), conv_bias),
             [inner, inner + g * n], axis=-1)

@@ -24,7 +24,8 @@ Every layer (LN = LayerNorm with scale and bias)::
     [g; u] = LN_2(h) W_gu
 
 - **Mamba** (Mamba-1): ``[x; z] = u W_in``; ``x' = silu(conv(x) +
-  b_c)``, a causal depthwise convolution over ``d_conv`` tokens; ``[r;
+  b_c)``, a causal depthwise convolution over ``d_conv`` tokens
+  (ops/causal_conv.py's ``conv_silu``, float32 from ``x`` as stored); ``[r;
   B; C] = x' W_x``; ``delta = softplus(r W_dt + b_dt)``; ``A =
   -exp(A_log)``; the selective scan (ops/selective_scan.py) gives ``y``;
   **m = y**, after the skip and before the gate; ``out = (y * silu(z))
@@ -80,7 +81,7 @@ from split_learning_tpu.core.stage import SplitPlan
 from split_learning_tpu.models import cut
 from split_learning_tpu.models.afmoe import RMSNorm
 from split_learning_tpu.obs import spans
-from split_learning_tpu.ops.common import causal_depthwise_conv
+from split_learning_tpu.ops.causal_conv import conv_silu
 from split_learning_tpu.ops.flash_attention import (
     flash_attention, select_attention)
 from split_learning_tpu.ops.ring_attention import full_attention
@@ -168,8 +169,7 @@ class Mamba(nn.Module):
         d_skip = self.param("D", nn.initializers.ones, (inner,))
         with jax.named_scope(spans.SSM_CONV):
             # x'_t from x_{t - d_conv + 1 .. t}: tap k weighs x_{t-(K-1)+k}
-            x = jax.nn.silu(conv_b + causal_depthwise_conv(
-                x.astype(_F32), conv_w))
+            x = conv_silu(x, conv_w, conv_b, _F32)
         r, b, c = jnp.split(_product(x, x_proj, dtype),
                             [z_.dt_rank, z_.dt_rank + n], axis=-1)
         delta = jax.nn.softplus(_product(r, dt_proj, dtype) + dt_bias)

@@ -39,8 +39,11 @@ DIGESTS = {
         "fused_step": "492dddd8ecbad3f4", "outputs": 294, "equations": 3857,
         "state_and_loss_alone": "2a6e85528d47f27b", "equations_alone": 3784},
     "phi4flash-fused-t8192": {
-        "fused_step": "09060d61752870bf", "outputs": 231, "equations": 2767,
-        "state_and_loss_alone": "cafee9375516a342", "equations_alone": 2750},
+        # PR 48: the Mamba layer's convolution and silu run as
+        # ops/causal_conv.py's two kernels (09060d61752870bf /
+        # cafee9375516a342 with the shifted sum, 2767 / 2750 equations)
+        "fused_step": "6e231b8982fec7be", "outputs": 231, "equations": 2701,
+        "state_and_loss_alone": "9f89872787eab6fe", "equations_alone": 2684},
     "joyai-flash-fused-t8192": {
         "fused_step": "4ed6ed722c498ecd", "outputs": 333, "equations": 4662,
         "state_and_loss_alone": "da37b672441bcd21", "equations_alone": 4560},
@@ -52,10 +55,12 @@ DIGESTS = {
     "nemotronh-moe-fused-t8192": {
         # PR 42: the three Mamba-2 layers' recurrence runs as ops/ssd.py's
         # two kernels (9e01ca06e572914f / 0611112aef509c8a with the plain
-        # form, 2511 / 2440 equations); the eight above are unedited: none
-        # imports ops/ssd.py
-        "fused_step": "c56e1e2fedbc254a", "outputs": 180, "equations": 2160,
-        "state_and_loss_alone": "659599157f1a1886", "equations_alone": 2098},
+        # form, 2511 / 2440 equations). PR 48: and their convolution and
+        # silu as ops/causal_conv.py's two, with no jax.checkpoint around
+        # them (c56e1e2fedbc254a / 659599157f1a1886 before, 2160 / 2098
+        # equations); the eight others are unedited: none imports either file
+        "fused_step": "0351a43695251779", "outputs": 180, "equations": 2088,
+        "state_and_loss_alone": "5ab3029b10236749", "equations_alone": 2026},
     # PR 45: the looped cell, new; the nine above are unedited (the field
     # models/afmoe.py's attention gained is in no jaxpr)
     "ouro-loop-fused-t8192": {
