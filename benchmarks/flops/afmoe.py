@@ -8,10 +8,12 @@ The routed experts are counted **at the expected number of pairs under even
 routing**: a token sends ``experts_per_token`` pairs to ``experts_total``
 experts, of which ``experts_held`` are here, so ``per_token * held / total``
 pairs a token reach this chip.  What a run really routes here differs by
-seed and layer (``scripts/afmoe_routing.py`` prints it); the model count and
-the kernels' least times stay at the expectation, so that the same work is
-asked of every run.  Recomputed work (each layer's forward again in the
-backward pass) is not counted.
+seed, layer and step (the step's ``pairs`` counter says it, and
+``moe_pairs_x_even_p50`` reads it); the model count stays at the
+expectation, so that the same work is asked of every run, and
+``moe_expert_mm_roofline_pct`` costs the grouped products at the pairs held.
+Recomputed work (each layer's forward again in the backward pass) is not
+counted.
 """
 
 from __future__ import annotations

@@ -10,6 +10,10 @@ paths' ``stage_backward`` runs a stage's forward twice) is not counted.
 from __future__ import annotations
 
 
+# bytes an element of the plan's ``dtype`` takes where a kernel reads it as stored
+ITEMSIZE = {"bfloat16": 2, "float32": 4}
+
+
 def block_matmul_params(d_model: int, mlp_ratio: int = 4) -> int:
     """Weights of one block that sit in a matrix product: q, k, v, out
     (4 d^2) and the MLP's two (2 * ratio * d^2)."""
@@ -47,6 +51,29 @@ def flash_bwd(batch: int, heads: int, t: int, head_dim: int, causal: bool,
     ops = 5 * 2 * batch * heads * t * t * head_dim * (0.5 if causal else 1.0)
     moved = 8 * batch * heads * t * head_dim * itemsize
     return ops, moved
+
+
+def conv_silu_fwd(batch: int, t: int, channels: int, taps: int,
+                  x_itemsize: int, y_itemsize: int) -> tuple:
+    """(operations, bytes) of one forward call of ``silu(bias + causal
+    depthwise convolution)`` (``ops/causal_conv.py``): ``x`` read and ``y``
+    written once over ``[t, channels]``, each in its stored type; the taps
+    and the bias are ``taps + 1`` rows.  A tap is a multiply and an add, the
+    silu about four more: none of it a matrix product, so the bytes bind."""
+    elements = batch * t * channels
+    return ((2 * taps + 4.0) * elements,
+            elements * (x_itemsize + y_itemsize) + (taps + 1) * channels * 4)
+
+
+def conv_silu_bwd(batch: int, t: int, channels: int, taps: int,
+                  x_itemsize: int, y_itemsize: int) -> tuple:
+    """The backward call: ``dy`` (in ``y``'s type) and ``x`` read, ``dx``
+    written in ``x``'s type, once over ``[t, channels]``; the pre-activation
+    is made again in registers, and the taps' and the bias's gradients leave
+    as float32 sums by sublane, ``8 (taps + 1)`` rows."""
+    elements = batch * t * channels
+    return ((6 * taps + 10.0) * elements,
+            elements * (2 * x_itemsize + y_itemsize) + 9 * (taps + 1) * channels * 4)
 
 
 def least_seconds(ops: float, moved: float, peak: dict) -> tuple:

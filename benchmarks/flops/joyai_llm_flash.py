@@ -17,6 +17,10 @@ routed part again in the backward pass) is not counted.
 
 from __future__ import annotations
 
+# the grouped products over the experts held are afmoe's (the routed layer is
+# that family's class), costed the same way at this configuration's 2048 x 768
+from .afmoe import expert_mm, expert_mm_shape  # noqa: F401
+
 
 def _kw(config: dict) -> dict:
     return config["plan"]["kwargs"]

@@ -26,6 +26,8 @@ from .afmoe import (  # noqa: F401
     expert_mm, expert_mm_shape, keys_seen)
 # q, k, v and out with no gate beside them, as that family's
 from .lfm2_moe import attention_params  # noqa: F401
+# the convolution's two kernel passes, costed by their bytes
+from .common import ITEMSIZE, conv_silu_bwd, conv_silu_fwd  # noqa: F401
 
 
 def mamba_params(kw: dict) -> int:
@@ -64,6 +66,47 @@ def ssd_shape(config: dict, rows: int, t: int) -> dict:
     kw = _kw(config)
     return dict(batch=rows, t=t, heads=kw["mamba_heads"], head_dim=kw["mamba_head_dim"],
                 groups=kw["ssm_groups"], state=kw["ssm_state"], chunk=kw["chunk"])
+
+
+def ssd_fwd(batch: int, t: int, heads: int, head_dim: int, groups: int,
+            state: int, chunk: int, itemsize: int = 2) -> tuple:
+    """(operations, bytes) of one layer's forward call (``ops/ssd.py``'s
+    ``ssd_fwd``): :func:`ssd_products`; ``x`` ``[t, heads x head_dim]``, ``B``
+    and ``C`` ``[t, groups x state]`` read as stored, ``dt`` and the summed
+    decays (in two layouts) ``[t, heads]`` in float32, ``y`` written in
+    float32 and every chunk's first state ``[state, head_dim]`` a head as
+    stored."""
+    tokens, inner = batch * t, heads * head_dim
+    wide = tokens * (inner + 2 * groups * state) * itemsize
+    small = 3 * tokens * heads * 4
+    moved = wide + small + tokens * inner * 4 + tokens // chunk * inner * state * itemsize
+    return ssd_products(batch, t, heads, head_dim, groups, state, chunk), moved
+
+
+def ssd_bwd(batch: int, t: int, heads: int, head_dim: int, groups: int,
+            state: int, chunk: int, itemsize: int = 2) -> tuple:
+    """The backward call (``ssd_bwd``): it makes the scores and the masked
+    product again and runs ten products where the forward runs four, 2.5
+    times :func:`ssd_products`; it reads what the forward read and the kept
+    states, ``dy`` in float32 in ``y``'s place, and writes ``dx``, ``dB``,
+    ``dC`` as stored, the three ``[t, heads]`` gradients in float32 and a
+    chunk's last decay's ``[heads x head_dim]`` a chunk."""
+    tokens, inner = batch * t, heads * head_dim
+    wide = tokens * (inner + 2 * groups * state) * itemsize
+    small = 3 * tokens * heads * 4
+    moved = (2 * wide + 2 * small + tokens * inner * 4
+             + tokens // chunk * inner * (state * itemsize + 4))
+    return 2.5 * ssd_products(batch, t, heads, head_dim, groups, state, chunk), moved
+
+
+def conv_silu_shape(config: dict, rows: int, t: int) -> dict:
+    """A Mamba-2 layer's convolution runs over ``xBC``, the first product's
+    output in the plan's type, and hands it back in that type."""
+    kw = _kw(config)
+    itemsize = ITEMSIZE[config["plan"]["dtype"]]
+    return dict(batch=rows, t=t, taps=kw["conv_taps"], x_itemsize=itemsize,
+                y_itemsize=itemsize, channels=kw["mamba_heads"] * kw["mamba_head_dim"]
+                + 2 * kw["ssm_groups"] * kw["ssm_state"])
 
 
 def layers_of(kw: dict, letter: str) -> int:

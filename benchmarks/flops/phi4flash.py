@@ -14,6 +14,8 @@ the convolution, the norms, the gates, the embedding lookup.  Recomputed work
 from __future__ import annotations
 
 from .afmoe import keys_seen  # mean keys a causal query sees, windowed or not
+# the convolution's two kernel passes, costed by their bytes
+from .common import ITEMSIZE, conv_silu_bwd, conv_silu_fwd  # noqa: F401
 
 
 def _kw(config: dict) -> dict:
@@ -98,6 +100,15 @@ def attn_bwd(batch: int, heads: int, kv_heads: int, t: int, head_dim: int,
     ops = 2 * 7 * head_dim * batch * heads * t * keys_seen(t, window)
     moved = (6 * heads + 4 * kv_heads) * batch * t * head_dim * itemsize
     return ops, moved
+
+
+def conv_silu_shape(config: dict, rows: int, t: int) -> dict:
+    """The Mamba layer's convolution reads ``x``, half of the first
+    product's output in the plan's type, and hands the scan float32."""
+    kw = _kw(config)
+    return dict(batch=rows, t=t, channels=kw["expand"] * kw["d_model"],
+                taps=kw["d_conv"], x_itemsize=ITEMSIZE[config["plan"]["dtype"]],
+                y_itemsize=4)
 
 
 def scan_shape(config: dict, rows: int, t: int) -> dict:

@@ -15,15 +15,44 @@ with ``None``."""
 
 import sys
 
+import trace_reduce
 from flops import common
+
+
+def _calls(trace: dict, needles: tuple) -> list:
+    """Names of the custom calls whose name holds every needle."""
+    return [n for n in trace["op_seconds"]
+            if "custom-call" in n and all(x in n for x in needles)]
 
 
 def events(trace: dict, *needles: str) -> tuple:
     """(calls, seconds) of the custom calls whose name holds every needle."""
-    names = [n for n in trace["op_seconds"]
-             if "custom-call" in n and all(x in n for x in needles)]
+    names = _calls(trace, needles)
     return (sum(trace["op_counts"][n] for n in names),
             sum(trace["op_seconds"][n] for n in names))
+
+
+def calls_in_hbm(trace: dict, cost: tuple, *needles: str) -> list:
+    """``share``'s parts for the custom calls whose name holds every needle,
+    one a name, for a kernel whose ``cost`` counts its bytes as the call's
+    operands and results in their stored types (``flops/``'s ``ssd_*`` and
+    ``conv_silu_*``: the count has to come to the arrays of the call's own
+    HLO line, which ``tests/test_kernel_readers.py`` holds it to within 1 %
+    on recorded lines).  The count is the one source of the bytes; the line
+    says where they lie: what it marks as kept in the chip's fast memory
+    (``trace_reduce.call_bytes``) the call does not move over HBM, and it
+    comes off the count (``phi4flash-fused-t8192``'s convolution is handed
+    ``x`` there and would read 118 % of a roofline that took it for HBM
+    traffic: PERF.md section 3).  Both are on stderr for every name."""
+    parts = []
+    ops, counted = cost
+    for name in _calls(trace, needles):
+        listed, fast = trace_reduce.call_bytes(trace.get("calls", {}).get(name, "")) or (None, 0)
+        print(f"{name}: {counted} bytes counted, {listed} in its line, {fast} of "
+              f"them in fast memory", file=sys.stderr)
+        parts.append(((trace["op_counts"][name], trace["op_seconds"][name]),
+                      (ops, counted - fast)))
+    return parts
 
 
 def share(label: str, run: dict, parts: list) -> float | None:

@@ -22,15 +22,15 @@ import traffic
 
 
 def samples(run: dict):
-    """[(pairs held here, rows of the rung run, rows of the lowest rung)], one
-    a (step, layer) of the window, or None."""
+    """[(pairs held here, rows of the rung run, rows of the ladder's last
+    rung)], one a (step, layer) of the window, or None."""
     recs = _spans.records(run)
     if recs is None:
         return None
     found = []
     for r in _spans.named(recs, "counters_read"):
         a = r["attrs"]
-        found.extend((sum(pairs), rows, min(ladder)) for pairs, rows, ladder
+        found.extend((sum(pairs), rows, max(ladder)) for pairs, rows, ladder
                      in zip(a["pairs"], a["rows"], a["ladder"]))
     return found if len(found) >= _spans.MIN_SPANS else None
 

@@ -3,9 +3,10 @@
 The program records one span a phase while a profiler session runs, on the
 profiler's clock; ``run.py`` opens its session around the window and nothing
 else, so the last session's records are the window's.  The readers take them
-from ``obs.recorded()`` and not from the xplane: ``trace_reduce.load`` keeps
-host events only by the benchmark's own names, and ``run.py`` deletes the
-trace before a reader runs.  A program without the recorder (a parent of
+from ``obs.recorded()`` and not from the xplane: a host event there has
+the span's name and times (``trace_reduce`` names the idle gaps by them) and
+none of its attributes or its parent, and ``run.py`` deletes the trace
+before a reader runs.  A program without the recorder (a parent of
 PR 24) has nothing to read: ``None``, and the line leaves the metric out.
 
 A record is a dict with ``name``, ``party`` (``client`` or ``server``),

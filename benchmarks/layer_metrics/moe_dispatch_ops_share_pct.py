@@ -1,6 +1,7 @@
-"""Share of the device's busy time in the operations that move tokens to
-their experts and back: events whose opcode (``trace_reduce.short_name``'s
-second word) is ``sort``, ``gather``, ``scatter`` or a top-k.  A lower
+"""Share of the device operations' summed time (containers left out: it
+comes to about the busy time, ``trace_reduce.reduce``) in the operations
+that move tokens to their experts and back: events whose opcode
+(``trace_reduce.short_name``'s second word) is ``sort``, ``gather``, ``scatter`` or a top-k.  A lower
 bound: a gather that XLA fused into a ``fusion`` is not seen.  And not the
 routed layer's alone: the embedding's gather and its gradient's scatter are
 in it.  Layer: device programs.  Moves tokens_per_s."""

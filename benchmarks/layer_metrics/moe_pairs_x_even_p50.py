@@ -1,7 +1,7 @@
 """Median over the window's (step, layer) samples of the pairs held here over
-the pairs even routing would send (``_routing.py``): the factor by which
-``moe_expert_mm_roofline_pct``, which costs its calls at the even count, reads
-low.  Layer: device programs.  Moves tokens_per_s."""
+the pairs even routing would send (``_routing.py``): how far the routing has
+drifted from the count ``mfu_pct`` costs the grouped products at
+(``moe_expert_mm_roofline_pct`` costs its calls at the pairs held).  Layer: device programs.  Moves tokens_per_s."""
 
 import os
 import statistics

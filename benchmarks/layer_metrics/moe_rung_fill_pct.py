@@ -1,8 +1,8 @@
 """Useful rows over rows run in the routed layers: over the window's (step,
 layer) samples, the pairs held here summed over the rows of the rung each
-layer ran (``_routing.py``).  A layer in its lower rung fills it by its pairs
-over twice the even share; one that passed it runs the worst case and fills a
-sixteenth or less.  Layer: device programs.  Moves tokens_per_s."""
+layer ran (``_routing.py``).  A layer in its lowest rung fills it by its pairs
+over twice the even share, one in the middle rung by its pairs over four even
+shares; one that passed both runs the worst case and fills a quarter or less.  Layer: device programs.  Moves tokens_per_s."""
 
 import os
 import sys

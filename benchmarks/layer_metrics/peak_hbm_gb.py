@@ -1,5 +1,8 @@
-"""``peak_bytes_in_use`` of the fullest chip after the window, in GB (1e9).
-Layer: device programs.  Moves tokens_per_s."""
+"""The most the fullest chip held at once, in GB (1e9): ``run.py``'s
+``peak_bytes_of`` over ``memory_stats()`` after the window, the larger of
+the live buffers' own peak and what a step holds while it runs, its
+temporaries (XLA's reservation) on top of the state and the code.  Layer:
+device programs.  Moves tokens_per_s."""
 
 
 def read(run: dict):

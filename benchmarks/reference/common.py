@@ -150,7 +150,7 @@ def named(tree) -> dict:
             float(val) for path, val in flat}
 
 
-def train(loss_fn, make_parties, steps: list, lr: float, row_block: int):
+def train(loss_fn, make_parties, steps: list, lr: float, row_block: int, watch=None):
     """Follow the parties through ``steps`` (per step, per client, ``(x, y)``).
 
     ``make_parties()`` gives ``(clients, server)``, the weights as the seed
@@ -163,6 +163,9 @@ def train(loss_fn, make_parties, steps: list, lr: float, row_block: int):
     through in blocks of ``row_block`` so that a batch the program holds at
     once fits here in float32.  Returns the per-step per-client losses, the
     per-leaf norms of the first gradients and of the parameters' change.
+    ``watch(count, parties, grads)``, where given, sees every step's
+    parameters and gradients before the update, and the parameters once more
+    after the last with no gradients (``calibrate.py``'s look).
     """
     grad = jax.jit(jax.value_and_grad(loss_fn, argnums=(0, 1)))
     add = jax.jit(lambda a, b, w: jax.tree_util.tree_map(
@@ -197,12 +200,16 @@ def train(loss_fn, make_parties, steps: list, lr: float, row_block: int):
         grads["server"] = g_server
         if grad_norms is None:
             grad_norms = {k: named(leaf_norms(g)) for k, g in grads.items()}
+        if watch is not None:
+            watch(count, parties, grads)
         for k in parties:
             parties[k], m[k], v[k] = adamw_step(
                 parties[k], m[k], v[k], grads[k], float(count), lr)
         del grads, g_clients, g_server
         losses.append(step_losses)
     del m, v
+    if watch is not None:
+        watch(len(steps) + 1, parties, None)
     first = as_parties()
     delta = {k: named(leaf_delta_norms(parties[k], first[k])) for k in parties}
     return {"losses": losses, "grad_norms": grad_norms, "delta_norms": delta}

@@ -36,8 +36,8 @@ sys.path.insert(0, HERE)
 
 OUT_DIR = os.path.join(ROOT, ".bench_out")
 TRACE_SECONDS = 4.0
-HOST_SPANS = ("fused.train_step", "party.train_round", "party.split_step",
-              "chain.step", "data.next_batch")
+HARNESS_SPANS = ("fused.train_step", "party.train_round", "party.split_step",
+                 "chain.step", "data.next_batch")
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 ADAM_B1 = 0.9
 
@@ -178,18 +178,40 @@ def end_to_end_values(tokens_per_s: float, reply_ms: list, peak_flops: float,
     return values
 
 
+def host_spans() -> tuple:
+    """The host spans that may name an idle gap: the harness's own wrappers
+    and, beneath them, the program's (imported where it is used, after
+    ``configure_jax``, not copied: a span the program gains is named too)."""
+    from split_learning_tpu.obs.spans import ALL_SPANS
+    return HARNESS_SPANS + tuple(ALL_SPANS)
+
+
 def reduce_trace(trace_dir: str, workload: str, chips: int) -> dict:
     """Reduce the run's trace, and leave its table of operations and a small
     slice under ``.bench_out/`` for whoever reads the run afterwards."""
     import trace_reduce
-    flat = trace_reduce.load(trace_reduce.newest_xplane(trace_dir), HOST_SPANS)
+    flat = trace_reduce.load(trace_reduce.newest_xplane(trace_dir), host_spans())
     reduced = trace_reduce.reduce(flat, devices=chips)
     with open(os.path.join(OUT_DIR, workload + ".trace_slice.json"), "w") as f:
         json.dump(trace_reduce.slice_of(flat, 300), f)
     with open(os.path.join(OUT_DIR, workload + ".trace_ops.json"), "w") as f:
         json.dump({k: reduced[k] for k in ("busy_s", "window_s", "op_seconds",
-                                           "op_counts", "idle_gaps")}, f)
+                                           "op_counts", "idle_gaps", "calls")}, f)
     return reduced
+
+
+def peak_bytes_of(stats: dict) -> int:
+    """The most a chip held at once, from its ``memory_stats()`` after the
+    window.  On this runtime ``peak_bytes_in_use`` counts live buffers and
+    loaded code, and a running program's temporaries are ``bytes_reserved``:
+    room set aside at the bottom of memory when the program is loaded, and
+    given up again when buffers need it (a probe on the chip, PERF.md
+    section 7, item 9), so the two peaks are no one moment's sum: set-up's
+    second copy of the weights and a step's temporaries never met.  What a
+    step held while it ran is what the chip holds now, the state and the
+    code, with the widest reservation on top."""
+    return max(stats.get("peak_bytes_in_use", 0),
+               stats.get("bytes_in_use", 0) + stats.get("peak_bytes_reserved", 0))
 
 
 def percentile(values: list, q: float) -> float:
@@ -297,8 +319,11 @@ def main() -> int:
         attempted = len(losses) + (failed - nonfinite)
         done_tokens = (len(losses) - nonfinite) * traffic.tokens_per_step(job) / (
             job["clients"] if driver.unit == "replies" else 1)
-        peak_bytes = max(d.memory_stats().get("peak_bytes_in_use", 0)
-                         for d in devices) if not rehearsal else 0
+        stats = [d.memory_stats() or {} for d in devices] if not rehearsal else []
+        peak_bytes = max((peak_bytes_of(s) for s in stats), default=0)
+        for d, s in zip(devices, stats):
+            log(f"memory_stats after the window, {d}: "
+                f"{ {k: v for k, v in sorted(s.items()) if 'bytes' in k} }")
     finally:
         driver.close()
     reply_seconds, wire_bytes = driver.reply_seconds, driver.wire_bytes
@@ -318,7 +343,8 @@ def main() -> int:
     numbers["nonfinite_losses"] = (float(nonfinite), "in the window")
     numbers["programs_built_in_window"] = (float(built_in_window), "compile events after window start")
     limits = {**job["limits"], "nonfinite_losses": 0, "programs_built_in_window": 0}
-    correct = check.verdict(numbers, limits, log) and failed == 0
+    check_lines = []
+    correct = check.verdict(numbers, limits, check_lines.append) and failed == 0
     compiled = [s for at, s in compile_events if at < t_window]
     log(f"backend compile events before the window: {sum(compiled):.1f} s in {len(compiled)}")
     log(f"reference {reference_s:.1f} s, after the window (not in setup_s)")
@@ -368,6 +394,15 @@ def main() -> int:
     if moved:
         log(f"server counters over the window: {moved}")
     result["device"] = device
+    # each number compared beside its limit: the last lines on stderr, and the
+    # last key of the result's line.  Their reader is the driver's record of a
+    # run that is not correct, which keeps the end of each and nothing else the
+    # run printed (the ledger's ``last_line_numbers``)
+    for line in check_lines:
+        log(line)
+    result["checks"] = {name: {"value": value if math.isfinite(value) else str(value),
+                               "limit": limits[name]}
+                        for name, (value, _) in numbers.items()}
     print(json.dumps(result), flush=True)
     return 0
 

@@ -90,12 +90,24 @@ def test_the_reference_imports_nothing_of_the_program():
     assert "split_learning_tpu" not in text
 
 
-def fake_run(config, ops):
+def counters(pairs: int, steps: int = 5) -> list:
+    """``counters_read`` records of four routed layers that hold ``pairs``
+    each, on the lowest rung of trinity-mini's ladder."""
+    return [{"name": "counters_read", "party": "client", "span_id": k, "parent_id": 0,
+             "duration": 1e-3, "start_ns": k,
+             "attrs": {"layers": [f"trunk_head/layer{i}/experts" for i in range(1, 5)],
+                       "pairs": [[pairs // 8] * 8] * 4, "rows": [8192] * 4,
+                       "ladder": [[8192, 16384, 65536]] * 4}} for k in range(steps)]
+
+
+def fake_run(config, ops, spans=None):
     import importlib
     seconds = {name: s for name, (_, s) in ops.items()}
     counts = {name: c for name, (c, _) in ops.items()}
     return {"trace": {"op_seconds": seconds, "op_counts": counts},
-            "job": {"rows_per_client": 1, "tokens_per_row": 8192}, "config": config,
+            "spans": counters(4096) if spans is None else spans,
+            "job": {"rows_per_client": 1, "tokens_per_row": 8192, "clients": 1},
+            "config": config,
             "flops": importlib.import_module("flops.afmoe"),
             "peak": {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}}
 
@@ -128,6 +140,14 @@ def test_the_readers_on_a_made_up_trace(config):
     assert weights > rows
     assert reader("moe_expert_mm_roofline_pct")(run) == pytest.approx(
         100 * (36 * rows + 12 * weights) / 0.048)
+    # the calls are costed at the pairs the step's records hold, not at the
+    # even count: the same trace with 6144 pairs a layer is held to more work
+    rows, weights = (2 * 6144 * 2048 * 1024 / 197e12,
+                     (6144 * 3072 * 2 + 8 * 2048 * 1024 * 4) / 819e9)
+    assert reader("moe_expert_mm_roofline_pct")(fake_run(config, ops, counters(6144))) == (
+        pytest.approx(100 * (36 * rows + 12 * max(rows, weights)) / 0.048))
+    # and without the records there is nothing to read
+    assert reader("moe_expert_mm_roofline_pct")(fake_run(config, ops, [])) is None
     total = sum(s for _, s in ops.values())
     assert reader("moe_dispatch_ops_share_pct")(run) == pytest.approx(100 * 0.008 / total)
     # a program without the scopes, or a rehearsal without a trace: nothing to read

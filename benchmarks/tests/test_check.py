@@ -68,6 +68,40 @@ def test_a_client_whose_rows_are_left_out_is_not_correct(monkeypatch):
     assert drive(monkeypatch, "vitl16-party-224")["correct"] is False
 
 
+def test_calibrate_plants_both_faults_and_neither_is_let_through(monkeypatch):
+    """``calibrate.py``'s loop at the rehearsal's sizes, as the chip runs it
+    at the cell's own (PERF.md, Findings PR 49): the sound program within the
+    limits, and under its step a state put back as it was and an update
+    applied twice, each read by ``delta_norm_gap`` as about 1."""
+    import calibrate
+    monkeypatch.setattr(sys, "argv", ["calibrate.py", "--workload", "phi4flash-fused-t8192",
+                                      "--seeds", "3", "--fault-seeds", "3",
+                                      "--look-seeds", "3", "--look", "layer17/attn/lambda"])
+    out = io.StringIO()
+    with redirect_stdout(out):
+        assert calibrate.main() == 0
+    row, summary = (json.loads(line) for line in out.getvalue().strip().splitlines())
+    assert row["program"]["correct"] is True
+    # the look follows layer 17's four lambda vectors through the three steps
+    # on both sides: a vector's gradient is one scalar along its partner
+    # (|cosine| 1), and the two sides' first gradients point the same way
+    look = row["look"]
+    assert sorted(k.rsplit("/", 1)[1] for k in look) == [
+        "lambda_k1", "lambda_k2", "lambda_q1", "lambda_q2"]
+    for leaf in look.values():
+        assert len(leaf["steps"]) == 3 and leaf["program_change"] > 0
+        for step in leaf["steps"]:
+            assert abs(step["reference_cos_to_partner"]) == pytest.approx(1.0, abs=1e-3)
+            assert step["reference_scalar"] * step["reference_cos_to_partner"] > 0
+    assert set(calibrate.FAULTS) == {"unchanged", "twice"}
+    for fault in calibrate.FAULTS:
+        assert row[fault]["correct"] is False
+        assert 0.99 < row[fault]["delta_norm_gap"] < 1.1
+        # the moments are the sound step's own: the first gradient reads as it did
+        assert row[fault]["grad_norm_gap"] == row["program"]["grad_norm_gap"]
+    assert summary["summary"]["twice.correct"] == "0 of 1"
+
+
 def reference_readings(workload, precision, seed=11):
     bench, cell, config = run.load_cell(workload)
     limits = traffic.load(cell["traffic"])["limits"]  # the cell's own, as on the chip

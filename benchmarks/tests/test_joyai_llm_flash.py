@@ -135,7 +135,7 @@ def test_the_plan_takes_the_files_arguments_and_counts_the_cut(config):
     assert round((count(client) + count(server)) / 1e6, 1) == 524.8
     assert plan.stages[1].objective is not None and plan.stages[0].objective is None
     from split_learning_tpu.models.afmoe import pair_rungs
-    assert pair_rungs(65536, 8, 256) == (4096, 65536)
+    assert pair_rungs(65536, 8, 256) == (4096, 8192, 65536)
 
 
 def test_the_reference_imports_nothing_of_the_program():
@@ -181,6 +181,32 @@ def test_the_reader_on_a_made_up_trace(config):
     assert read({**run, "trace": None}) is None
     assert read(fake_run(config, {"%fusion.1 fusion f32[8]": (1, 1.0)})) is None
     assert read(fake_run(config, dict(list(ops.items())[2:]), "flops.afmoe")) is None
+
+
+def test_the_expert_reader_costs_the_calls_at_this_width(config):
+    """``moe_expert_mm_roofline_pct`` takes the cell's own ``expert_mm_shape``
+    (2048 x 768, 2048 pairs under even routing) and the pairs the step's
+    ``counters_read`` records hold: five routed layers, the module's block
+    among them."""
+    assert flops.expert_mm_shape(config, 1, 8192) == dict(
+        pairs=2048.0, experts=8, d_model=2048, width=768)
+    least = lambda pairs, **kw: common.least_seconds(*flops.expert_mm(
+        pairs=pairs, experts=8, d_model=2048, width=768, **kw), PEAK)[0]
+    layers = [f"trunk_head/layer{i}/experts" for i in range(1, 5)] + ["trunk_head/mtp/block/experts"]
+    spans = [{"name": "counters_read", "party": "client", "span_id": k, "parent_id": 0,
+              "duration": 1e-3, "start_ns": k,
+              "attrs": {"layers": layers, "pairs": [[384] * 8] * 5, "rows": [4096] * 5,
+                        "ladder": [[4096, 8192, 65536]] * 5}} for k in range(4)]
+    ops = {"%gmm.3 custom-call bf16[4096,768] tpu_custom_call/4": (180, 4 * 180 * least(3072.0)),
+           "%tgmm.1 custom-call f32[8,2048,768] tpu_custom_call/4":
+           (60, 4 * 60 * least(3072.0, weight_itemsize=4)),
+           "%attn_latent.1 custom-call bf16[32,8192,128] tpu_custom_call/3": (24, 1.0)}
+    run = {**fake_run(config, ops), "spans": spans}
+    run["job"] = {**run["job"], "clients": 1}
+    read = reader("moe_expert_mm_roofline_pct")
+    assert read(run) == pytest.approx(25.0)
+    assert read({**run, "spans": []}) is None
+    assert read({**run, "trace": None}) is None
 
 
 def test_the_new_entries_of_the_benchmark():

@@ -165,7 +165,7 @@ def test_the_plan_takes_the_files_arguments_and_counts_the_cut(config):
     kw = spec["kwargs"]
     assert total == flops.model_params(kw, kw["layers_kept"], kw["experts_held"], kw["vocab"])
     from split_learning_tpu.models.afmoe import pair_rungs
-    assert pair_rungs(8192 * 4, 8, 64) == (8192, 32768)
+    assert pair_rungs(8192 * 4, 8, 64) == (8192, 16384, 32768)
 
 
 def test_the_reference_imports_nothing_of_the_program():
@@ -191,7 +191,7 @@ def counters(pairs_by_layer, steps):
              "duration": 1e-3, "start_ns": k,
              "attrs": {"layers": layers, "pairs": pairs_by_layer,
                        "rows": [8192] * len(layers),
-                       "ladder": [[8192, 32768]] * len(layers)}} for k in range(steps)]
+                       "ladder": [[8192, 16384, 32768]] * len(layers)}} for k in range(steps)]
 
 
 def reader(name):
@@ -213,7 +213,7 @@ def test_the_attention_reader_on_a_made_up_trace(config):
         "%gmm.3 custom-call bf16[8192,1536] tpu_custom_call/4": (36, 0.01),
         "%fusion.9 fusion bf16[8192,2048]": (100, 0.092),
     }
-    read = reader("lfm2_attn_roofline_pct")
+    read = reader("attn_full_roofline_pct")        # one reader, costed at this head of 64
     assert read(fake_run(config, ops)) == pytest.approx(100 / 3)
     only = dict(list(ops.items())[:1])
     assert read(fake_run(config, only)) == pytest.approx(100 / 3)
@@ -237,10 +237,10 @@ def test_the_expert_reader_costs_the_pairs_the_records_hold(config):
            "%tgmm.1 custom-call f32[8,2048,1536] tpu_custom_call/4":
            (60, 2 * 60 * least(8192.0, weight_itemsize=4)),
            "%attn_full.1 custom-call bf16[32,8192,128] tpu_custom_call/3": (5, 1.0)}
-    read = reader("lfm2_expert_mm_roofline_pct")
+    read = reader("moe_expert_mm_roofline_pct")
     got = read(fake_run(config, ops, spans=spans))
     assert got == pytest.approx(50.0)
-    # the accepted reader holds the same calls to the even count, 4096 pairs
+    # held to the even count, 4096 pairs, the same calls would read about half
     even = (180 * least(4096.0) + 60 * least(4096.0, weight_itemsize=4)) / (
         2 * 180 * least(8192.0) + 2 * 60 * least(8192.0, weight_itemsize=4))
     assert 100 * even < 0.6 * got
@@ -266,16 +266,21 @@ def test_the_new_entries_of_the_benchmark():
     assert sorted(entry["reduced"]) == ["num_dense_layers", "num_experts",
                                         "num_hidden_layers", "vocab_size"]
     assert len(entry["why"]) <= 200
-    assert [m["name"] for m in bench["per_layer"][-2:]] == [
-        "lfm2_attn_roofline_pct", "lfm2_expert_mm_roofline_pct"]
-    for metric in bench["per_layer"][-2:]:
-        assert metric == dict(name=metric["name"], unit="%", better="higher",
-                              source="device_trace", layer="kernels", moves="mfu_pct",
-                              workloads=[CELL])
-        assert os.path.exists(os.path.join(BENCH, "layer_metrics", metric["name"] + ".py"))
-    # no accepted metric's list gained the cell: a benchmark issue's to extend
+    # looked up by name: the cell reads the mechanisms it runs through the
+    # readers every routed cell shares (PR 49), none under a name of its own
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    assert not [name for name in by_name if name.startswith("lfm2_")]
+    for name in ("attn_full_roofline_pct", "moe_expert_mm_roofline_pct"):
+        metric = by_name[name]
+        assert {**metric, "workloads": None} == dict(
+            name=name, unit="%", better="higher", source="device_trace",
+            layer="kernels", moves="mfu_pct", workloads=None)
+        assert CELL in metric["workloads"]
+        assert os.path.exists(os.path.join(BENCH, "layer_metrics", name + ".py"))
     assert [m["name"] for m in bench["per_layer"] if CELL in m.get("workloads", [])] == [
-        "lfm2_attn_roofline_pct", "lfm2_expert_mm_roofline_pct"]
+        "attn_full_roofline_pct", "moe_expert_mm_roofline_pct",
+        "moe_dispatch_ops_share_pct", "moe_rung_fill_pct", "moe_top_rung_share_pct",
+        "moe_pairs_x_even_p50"]
     with open(os.path.join(BENCH, "traffic", CELL + ".json")) as f:
         job = json.load(f)
     assert (job["path"], job["clients"], job["rows_per_client"], job["tokens_per_row"],

@@ -20,7 +20,8 @@ ROOT = os.path.dirname(BENCH)
 CELL = "nemotronh-moe-fused-t8192"
 NAME = "nemotron-labs-twotower-30b-a3b-base"
 PEAK = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
-READERS = ["nemotronh_attn_roofline_pct", "nemotronh_expert_mm_roofline_pct"]
+READERS = ["attn_full_roofline_pct", "moe_expert_mm_roofline_pct"]   # shared (PR 49)
+KERNELS = ["ssd_roofline_pct", "conv_silu_roofline_pct"]
 PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
 
 
@@ -195,7 +196,7 @@ def test_the_plan_takes_the_files_arguments_and_counts_the_cut(config):
     kw = spec["kwargs"]
     assert total == flops.model_params(kw, kw["layers_kept"], kw["experts_held"], kw["vocab"])
     from split_learning_tpu.models.afmoe import pair_rungs
-    assert pair_rungs(8192 * 6, 8, 128) == (6144, 49152)
+    assert pair_rungs(8192 * 6, 8, 128) == (6144, 12288, 49152)
 
 
 def test_the_reference_imports_nothing_of_the_program_and_scans_the_tokens():
@@ -226,7 +227,7 @@ def counters(pairs_by_layer, steps):
              "duration": 1e-3, "start_ns": k,
              "attrs": {"layers": layers, "pairs": pairs_by_layer,
                        "rows": [6144] * len(layers),
-                       "ladder": [[6144, 49152]] * len(layers)}} for k in range(steps)]
+                       "ladder": [[6144, 12288, 49152]] * len(layers)}} for k in range(steps)]
 
 
 def reader(name):
@@ -299,13 +300,18 @@ def test_the_new_entries_of_the_benchmark():
     assert len(entry["why"]) <= 200
     # looked up by name: a later PR appends its own entries after these
     by_name = {m["name"]: m for m in bench["per_layer"]}
-    for name in READERS:
-        assert by_name[name] == dict(name=name, unit="%", better="higher",
-                                     source="device_trace", layer="kernels",
-                                     moves="mfu_pct", workloads=[CELL])
+    assert not [name for name in by_name if name.startswith("nemotronh_")]
+    for name in READERS + KERNELS:
+        assert {**by_name[name], "workloads": None} == dict(
+            name=name, unit="%", better="higher", source="device_trace",
+            layer="kernels", moves="mfu_pct", workloads=None)
+        assert CELL in by_name[name]["workloads"]
         assert os.path.exists(os.path.join(BENCH, "layer_metrics", name + ".py"))
-    # no accepted metric's list gained the cell: a benchmark issue's to extend
-    assert [m["name"] for m in bench["per_layer"] if CELL in m.get("workloads", [])] == READERS
+    assert by_name["ssd_roofline_pct"]["workloads"] == [CELL]
+    assert [m["name"] for m in bench["per_layer"] if CELL in m.get("workloads", [])] == [
+        "attn_full_roofline_pct", "moe_expert_mm_roofline_pct",
+        "moe_dispatch_ops_share_pct", "moe_rung_fill_pct", "moe_top_rung_share_pct",
+        "moe_pairs_x_even_p50"] + KERNELS
     with open(os.path.join(BENCH, "traffic", CELL + ".json")) as f:
         job = json.load(f)
     assert (job["path"], job["clients"], job["rows_per_client"], job["tokens_per_row"],

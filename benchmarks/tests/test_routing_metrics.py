@@ -19,7 +19,8 @@ sys.path.insert(0, os.path.join(BENCH, "layer_metrics"))
 import _spans
 
 METRICS = ("moe_rung_fill_pct", "moe_top_rung_share_pct", "moe_pairs_x_even_p50")
-CELLS = ["trinity-mini-fused-t8192", "joyai-flash-fused-t8192"]
+CELLS = ["trinity-mini-fused-t8192", "joyai-flash-fused-t8192",
+         "lfm2-moe-fused-t8192", "nemotronh-moe-fused-t8192"]
 LADDER = [8192, 65536]
 
 
@@ -60,6 +61,20 @@ def test_the_three_readers_on_a_hand_made_window():
     assert value("moe_pairs_x_even_p50", recs) == pytest.approx(1.25)
 
 
+def test_a_middle_rung_is_not_the_top_rung():
+    """Since PR 41 a ladder has a rung between the lowest and the worst case;
+    the share counts the samples that ran the ladder's last rung, as its name
+    says, and a sample on the middle rung only lowers the fill."""
+    recs = window(6, 3)
+    for k, r in enumerate(x for x in recs if x["name"] == "counters_read"):
+        r["attrs"]["ladder"] = [[8192, 16384, 65536]] * 2
+        r["attrs"]["rows"] = [8192, (8192, 8192, 8192, 16384, 16384, 65536)[k]]
+    assert value("moe_top_rung_share_pct", recs) == pytest.approx(100 * 1 / 12)
+    pairs = 6 * 4096 + 3 * 6144 + 3 * 12288
+    rows = 6 * 8192 + 3 * 8192 + 2 * 16384 + 65536
+    assert value("moe_rung_fill_pct", recs) == pytest.approx(100 * pairs / rows)
+
+
 def test_a_window_that_stays_low_reads_no_top_rung():
     recs = window(6, 6)
     assert value("moe_top_rung_share_pct", recs) == 0.0
@@ -77,11 +92,11 @@ def test_nothing_to_read_under_five_samples_or_without_counters(metric, monkeypa
     assert run.layer_reader(metric)({}) is None
 
 
-def test_the_entries_name_the_two_routed_cells():
+def test_the_entries_name_the_four_routed_cells():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     entries = {m["name"]: m for m in bench["per_layer"]}
-    assert [m["name"] for m in bench["per_layer"][-3:]] == list(METRICS)   # appended
+    # looked up by name: later PRs append their own entries after these
     for name in METRICS:
         m = entries[name]
         assert (m["source"], m["layer"], m["moves"], m["workloads"]) == (

@@ -88,6 +88,123 @@ def test_recorded_trace_against_a_sweep():
     assert 0 < busy < hi - lo
 
 
+def test_a_container_is_busy_time_and_no_operation():
+    # a routed layer's conditional [100,400) lies over its branch's two
+    # operations [120,200) and [250,390); a fusion [500,600) stands alone
+    trace = {"devices": {"/device:TPU:0": [
+        ["%cond.7 conditional f32[8,2048,1536]", 100, 300],
+        ["%gmm.3 custom-call bf16[8192,1536] tpu_custom_call/4", 120, 80],
+        ["%fusion.9 fusion bf16[8192,2048]", 250, 140],
+        ["%fusion.9 fusion bf16[8192,2048]", 500, 100],
+        ["%while.2 while (s32[], f32[8])", 700, 50], ["%call.1 call f32[8]", 800, 20]]},
+        "host": [["bench.window", 0, 1000]]}
+    out = trace_reduce.reduce(trace)
+    # the union holds every event: the conditional's own head and tail count
+    assert out["busy_s"] == pytest.approx((300 + 100 + 50 + 20) * 1e-9)
+    assert dict(out["idle_gaps"]) == {"uncovered": pytest.approx(530e-9)}
+    # the operations' seconds hold the branch once, and no container
+    assert out["op_seconds"] == {
+        "%gmm.3 custom-call bf16[8192,1536] tpu_custom_call/4": pytest.approx(80e-9),
+        "%fusion.9 fusion bf16[8192,2048]": pytest.approx(240e-9)}
+    assert out["op_counts"]["%fusion.9 fusion bf16[8192,2048]"] == 2
+    assert [name for name, _ in out["device_ops"]] == [
+        "%fusion.9 fusion bf16[8192,2048]",
+        "%gmm.3 custom-call bf16[8192,1536] tpu_custom_call/4"]
+    assert trace_reduce.is_container("%cond.98 conditional f32[8,2048,1536]")
+    assert trace_reduce.is_container("%cond.47.clone conditional bf16[8192,2688]")
+    assert not trace_reduce.is_container("%conditional_fusion.1 fusion f32[8]")
+    assert not trace_reduce.is_container("no equals sign")
+
+
+@pytest.mark.parametrize("name", ["recorded_trace.json", "recorded_trace_routed.json"])
+def test_the_container_rule_leaves_busy_window_and_gaps_to_the_nanosecond(name, monkeypatch):
+    """On a recorded trace (the ViT one with a conditional laid over a
+    stretch of it; ``recorded_trace_routed.json``: 300 operations from the
+    middle of a traced ``lfm2-moe-fused-t8192`` run on a TPU v5e, PR 49, whose
+    routed layers' conditionals are the trace's own) the reduction gives the
+    busy time, the window and the gaps it gave before the rule, to the
+    nanosecond, and the operations' seconds less the containers' alone."""
+    with open(os.path.join(HERE, name)) as f:
+        trace = json.load(f)
+    (plane, events), = trace["devices"].items()
+    if not any(trace_reduce.is_container(e[0]) for e in events):
+        ordered = sorted(events, key=lambda e: e[1])
+        first, last = ordered[20], ordered[280]
+        events.append(["%cond.1 conditional f32[8]", first[1], last[1] + last[2] - first[1]])
+    held = {e[0] for e in events if trace_reduce.is_container(e[0])}
+    after = trace_reduce.reduce(trace)
+    monkeypatch.setattr(trace_reduce, "CONTAINERS", ())      # the reduction before the rule
+    before = trace_reduce.reduce(trace)
+    for key in ("busy_s", "window_s", "idle_gaps"):
+        assert after[key] == before[key]
+    assert held and held <= set(before["op_seconds"]) and not held & set(after["op_seconds"])
+    assert after["op_seconds"] == {k: v for k, v in before["op_seconds"].items() if k not in held}
+    assert after["op_counts"] == {k: v for k, v in before["op_counts"].items() if k not in held}
+    assert not held & {n for n, _ in after["device_ops"]}
+    assert held & {n for n, _ in before["device_ops"]}
+    # what is left is every other event's own time, once
+    alone = sum(e[2] for e in events if e[0] not in held) * 1e-9
+    assert sum(after["op_seconds"].values()) == pytest.approx(alone)
+
+
+def test_gaps_are_named_by_the_latest_started_span_that_covers_them():
+    """The program's spans lie under the harness's wrappers: a gap inside
+    ``loss_wait`` inside ``step_total`` inside ``fused.train_step`` is
+    ``loss_wait``'s; spans that start together give the first in the list;
+    a span that has ended covers nothing."""
+    host = [["bench.window", 0, 1000], ["fused.train_step", 0, 900],
+            ["step_total", 10, 880], ["h2d", 20, 30], ["loss_wait", 100, 700],
+            ["counters_read", 800, 50], ["twin_a", 900, 50], ["twin_b", 900, 50]]
+    trace = {"devices": {"/device:TPU:0": [["a", 0, 30], ["a", 40, 60], ["a", 300, 100],
+                                           ["a", 810, 20], ["a", 860, 60], ["a", 960, 40]]},
+             "host": host}
+    gaps = dict(trace_reduce.reduce(trace)["idle_gaps"])
+    # [30,40) under h2d; [100,300) and [400,810) under loss_wait; [830,860)
+    # mid 845 under counters_read; [920,960) mid 940 under the twins
+    assert gaps == {"h2d": pytest.approx(10e-9), "loss_wait": pytest.approx(610e-9),
+                    "counters_read": pytest.approx(30e-9), "twin_a": pytest.approx(40e-9)}
+
+
+def test_a_calls_bytes_and_where_they_lie_are_read_from_its_own_line():
+    """The two convolution calls as the chip's traces name them (PR 49): in
+    ``phi4flash-fused-t8192`` XLA keeps ``x`` in the chip's fast memory
+    (``S(1)``), so of the forward's arrays ``y`` alone lies in HBM; in
+    ``nemotronh-moe-fused-t8192`` every large array lies in HBM, and ``x``,
+    handed over twice, counts once."""
+    pf = ('%conv_silu_fwd.1 = f32[1,8192,5120]{2,1,0:T(8,128)} custom-call('
+          'bf16[1,8192,5120]{2,1,0:T(8,128)(2,1)S(1)} %split.8, f32[4,5120]{1,0:T(4,128)S(1)} '
+          '%copy-done.202, f32[1,5120]{1,0:T(1,128)S(1)} %bitcast.1136), '
+          'custom_call_target="tpu_custom_call", operand_layout_constraints={bf16[1,8192,5120]{2,1,0}, '
+          'f32[4,5120]{1,0}, f32[1,5120]{1,0}}, frontend_attributes={kernel_metadata={}}')
+    small = 5 * 5120 * 4
+    assert trace_reduce.call_bytes(pf) == (8192 * 5120 * 6 + small, 8192 * 5120 * 2 + small)
+    nf = ('%conv_silu_bwd.3 = (bf16[1,8192,6144]{2,1,0:T(8,128)(2,1)}, '
+          'f32[1,5,8,6144]{3,2,1,0:T(8,128)S(1)}) custom-call(bf16[1,8192,6144]{2,1,0:T(8,128)(2,1)} '
+          '%split.12, bf16[1,8192,6144]{2,1,0:T(8,128)(2,1)} %split.12, f32[4,6144]{1,0:T(4,128)} %g, '
+          'f32[1,6144]{1,0:T(1,128)} %b, bf16[1,8192,6144]{2,1,0:T(8,128)(2,1)} %pad_maximum_fusion.18), '
+          'custom_call_target="tpu_custom_call", operand_layout_constraints={bf16[1,8192,6144]{2,1,0}}')
+    assert trace_reduce.call_bytes(nf) == (3 * 8192 * 6144 * 2 + 45 * 6144 * 4, 40 * 6144 * 4)
+    assert trace_reduce.call_bytes("%fusion.1 = f32[8]{0} fusion(f32[8]{0} %p)") is None
+    assert trace_reduce.call_bytes("no equals sign") is None
+    # the reader takes the cost's bytes and takes off what the line keeps in
+    # fast memory: the cost is the one source of the count, the line of the place
+    import sys
+    sys.path.insert(0, os.path.join(os.path.dirname(HERE), "layer_metrics"))
+    import _afmoe
+    name = trace_reduce.short_name(pf)
+    trace = {"op_seconds": {name: 1e-3}, "op_counts": {name: 4}, "calls": {name: pf}}
+    counted = 8192 * 5120 * 6 + small
+    (_, (ops, moved)), = _afmoe.calls_in_hbm(trace, (7.0, counted), "conv_silu_fwd")
+    assert (ops, moved) == (7.0, 8192 * 5120 * 4)
+    # a count that is too high stays too high: the line lowers it by no more
+    # than what lies in fast memory
+    (_, (_, moved)), = _afmoe.calls_in_hbm(trace, (7.0, 2 * counted), "conv_silu_fwd")
+    assert moved == counted + 8192 * 5120 * 4
+    (_, (_, moved)), = _afmoe.calls_in_hbm({**trace, "calls": {}}, (7.0, 10 ** 9), "conv_silu_fwd")
+    assert moved == 10 ** 9                      # a trace without the lines: the cost as it is
+    assert _afmoe.calls_in_hbm(trace, (7.0, 1000), "conv_silu_bwd") == []
+
+
 def test_short_names_tell_the_pallas_kernels_apart():
     fwd = ('%jvp__.1 = (bf16[32,1024,128]{2,1,0:T(8,128)(2,1)}, f32[32,1024,8]{2,1,0}) '
            'custom-call(bf16[32,1024,128]{2,1,0} %pad.4, bf16[32,1024,128]{2,1,0} %pad.0, '
