@@ -24,14 +24,12 @@ many of a looped model's passes the MLPs' ``gate`` and ``up`` are made again
 from __future__ import annotations
 
 import argparse
+import importlib
 import json
 import os
 import sys
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
-# T 8192 takes the one-pass flash backward on the chip, after a preflight
-# compile that cannot run here: name the form instead
-os.environ.setdefault("SLT_FLASH_ONEPASS_T", "8192")
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -65,6 +63,11 @@ def main() -> int:
 
     # the kernels as the chip compiles them, not their interpreter
     common._default_backend_platform = lambda: "tpu"
+    # T 8192 takes the one-pass flash backward on the chip, after a
+    # preflight compile that cannot run here: name the form instead (the
+    # module by its path: the package's attribute of that name is the op)
+    importlib.import_module(
+        "split_learning_tpu.ops.flash_attention").ONEPASS = True
     jax.config.update("jax_enable_compilation_cache", False)
 
     job = traffic.load(cell["traffic"])

@@ -82,9 +82,6 @@ def _add_common(p: argparse.ArgumentParser) -> None:
     p.add_argument("--tracking", default=None,
                    help="stdout | jsonl | mlflow | noop")
     p.add_argument("--tracking-uri", default=None)
-    p.add_argument("--kernels", choices=["xla", "pallas"], default=None,
-                   help="hot-path op implementation (pallas = "
-                        "split_learning_tpu.ops kernels)")
     p.add_argument("--dtype", choices=["float32", "bfloat16"], default=None,
                    help="compute dtype (params stay float32 — mixed "
                         "precision)")
@@ -140,7 +137,7 @@ def _config_from_args(args) -> "Config":
     for field in ("mode", "model", "dataset", "batch_size", "epochs", "lr",
                   "optimizer", "momentum", "weight_decay", "warmup_steps",
                   "decay_steps", "grad_clip_norm",
-                  "seed", "data_dir", "tracking", "tracking_uri", "kernels",
+                  "seed", "data_dir", "tracking", "tracking_uri",
                   "checkpoint_dir", "dtype", "remat"):
         val = getattr(args, field, None)
         if val is not None:

@@ -5,9 +5,9 @@
 // cut-layer tensor (src/client_part.py:117-131). Here the host-side wire
 // hot ops — int8 absmax quantize/dequantize (the 4x compression of that
 // tensor) and frame checksumming — run in C++ with a thread pool, bound
-// into Python via ctypes (split_learning_tpu/native/codec.py). The
-// in-jit counterparts live in split_learning_tpu/ops/quantize.py (Pallas);
-// both implement the same math and are parity-tested.
+// into Python via ctypes (split_learning_tpu/native/codec.py). The NumPy
+// form is split_learning_tpu/transport/codec.py; both implement the same
+// math and are parity-tested (tests/test_native.py).
 //
 // Semantics match the NumPy fallback bit-for-bit:
 //   scale = max(absmax(x) / 127, 1e-12)

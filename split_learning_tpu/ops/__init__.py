@@ -1,37 +1,33 @@
-"""Pallas TPU kernel layer — the framework's native-code slot.
+"""The kernel layer: the operations the models call beneath their layers.
 
-The reference contains zero native components (SURVEY.md §2: "there are
-zero C++/Rust/CUDA/native components"); its performance-critical layer is
-plain torch on CPU. In the TPU rebuild the idiomatic equivalent of "the
-fast layer beneath Python" is hand-written Pallas kernels for the ops on
-the split-step hot path (SURVEY.md §3.1):
+The reference has no native components (SURVEY.md §2); here the layer
+beneath Python is hand-written Pallas TPU kernels and explicitly
+scheduled collectives. Each file offers one form of its operation and
+chooses it from what it can see (shapes, types, the mask, a preflight
+compile); nothing a user sets picks a kernel:
 
-- :mod:`~split_learning_tpu.ops.cross_entropy` — fused softmax
-  cross-entropy forward+backward (the server-side loss,
-  ``src/server_part.py:49-51``) as one VMEM-resident kernel pair.
-- :mod:`~split_learning_tpu.ops.sgd` — fused SGD(+momentum) parameter
-  update (``optimizer.step()``, ``src/client_part.py:133`` /
-  ``src/server_part.py:52``): one read-modify-write pass over each leaf
-  instead of optax's multi-op update/apply chain.
-- :mod:`~split_learning_tpu.ops.quantize` — int8 symmetric-scale
-  quantize/dequantize for the cut-layer payload, shrinking the 5.28 MiB
-  activation/gradient hop (SURVEY.md §2 derived facts) 4x on the wire.
 - :mod:`~split_learning_tpu.ops.flash_attention` — blockwise-streamed
-  attention forward/backward kernels for the transformer family: VMEM-
-  resident online softmax, O(T*D) HBM traffic per head instead of the
-  dense path's O(T^2) score matrix.
+  attention forward/backward kernels: VMEM-resident online softmax,
+  O(T*D) HBM traffic per head instead of the dense path's O(T^2) score
+  matrix; ``select_attention`` resolves ``attn="auto"``.
 - :mod:`~split_learning_tpu.ops.ring_attention` — sequence/context-
   parallel attention (ring over ``ppermute``, Ulysses over
-  ``all_to_all``) for the long-context transformer family; not a Pallas
-  kernel but an explicitly-scheduled collective op in the same "fast
-  layer beneath the models" slot.
+  ``all_to_all``); not a Pallas kernel but a collective op in the same
+  slot.
+- :mod:`~split_learning_tpu.ops.grouped_matmul` — a routed layer's
+  grouped products.
+- :mod:`~split_learning_tpu.ops.selective_scan`,
+  :mod:`~split_learning_tpu.ops.ssd`,
+  :mod:`~split_learning_tpu.ops.causal_conv` — the Mamba and Mamba-2
+  recurrences and their convolution-and-silu, kernels where the shapes
+  fill their tiles and plain ``jax.numpy`` at any other shape.
 
-Every op has a pure-jnp reference implementation; kernels run compiled on
-TPU and in interpreter mode elsewhere (tests use the 8-device CPU mesh,
-SURVEY.md §4 item 4). Select with ``Config.kernels = "xla" | "pallas"``.
+Kernels run compiled on TPU and through the Mosaic interpreter elsewhere
+(:func:`~split_learning_tpu.ops.common.use_interpret`; tests use the
+8-device CPU mesh, SURVEY.md §4 item 4).
 """
 
-from split_learning_tpu.ops.common import pallas_available, use_interpret
+from split_learning_tpu.ops.common import use_interpret
 from split_learning_tpu.ops.flash_attention import (
     flash_attention, flash_attention_with_lse, select_attention)
 from split_learning_tpu.ops.ring_attention import (
@@ -39,19 +35,8 @@ from split_learning_tpu.ops.ring_attention import (
     ring_attention,
     ulysses_attention,
 )
-from split_learning_tpu.ops.cross_entropy import (
-    fused_cross_entropy,
-    reference_cross_entropy,
-)
-from split_learning_tpu.ops.sgd import fused_sgd_step, reference_sgd_step
-from split_learning_tpu.ops.quantize import (
-    dequantize_int8,
-    quantize_dequantize,
-    quantize_int8,
-)
 
 __all__ = [
-    "pallas_available",
     "use_interpret",
     "flash_attention",
     "flash_attention_with_lse",
@@ -59,11 +44,4 @@ __all__ = [
     "full_attention",
     "ring_attention",
     "ulysses_attention",
-    "fused_cross_entropy",
-    "reference_cross_entropy",
-    "fused_sgd_step",
-    "reference_sgd_step",
-    "quantize_int8",
-    "dequantize_int8",
-    "quantize_dequantize",
 ]

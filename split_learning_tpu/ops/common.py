@@ -7,6 +7,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax._src.pallas import core as pallas_core
 
 # float32 native tile: 8 sublanes x 128 lanes
 SUBLANE = 8
@@ -34,11 +35,6 @@ def use_interpret() -> bool:
     return _default_backend_platform() != "tpu"
 
 
-def pallas_available() -> bool:
-    """Kernels are importable everywhere jax is; gate only on env opt-out."""
-    return os.environ.get("SLT_DISABLE_PALLAS", "") != "1"
-
-
 def round_up(n: int, m: int) -> int:
     return (n + m - 1) // m * m
 
@@ -52,28 +48,26 @@ def pad_axis(x: jax.Array, axis: int, target: int) -> jax.Array:
     return jnp.pad(x, pad)
 
 
-def as_rows_of_lanes(flat: jax.Array, rows: int) -> jax.Array:
-    """[n] -> [rows, LANE] zero-padded — the canonical 2-D layout for
-    elementwise kernels over arbitrarily-shaped leaves."""
-    padded = pad_axis(flat, 0, rows * LANE)
-    return padded.reshape(rows, LANE)
-
-
 def traced_once(kernel):
-    """``kernel`` as a function whose Python runs once. A kernel body is a
-    pure function of its refs' shapes, and a model calls one kernel at
-    many sites (GPT-2's step its attention 24 times, and twice more while
-    the harness asks for its shapes; a Mamba-2 hybrid its recurrence once a
-    layer), each of which would trace the body anew: about 0.1 s a site on
-    the chip's host for the flash kernels' whole-pair bodies, and several
-    times that for a kernel with cut pairs (PR 31). So the first site's
-    jaxpr is kept and later sites replay it, one bind an equation."""
+    """``kernel`` as a function whose Python runs once a grid. A kernel body
+    is a pure function of its refs' types and of the grid it runs under
+    (``pl.num_programs`` is a constant of the trace), and a model calls one
+    kernel at many sites (GPT-2's step its attention 24 times, and twice
+    more while the harness asks for its shapes; a Mamba-2 hybrid its
+    recurrence once a layer), each of which would trace the body anew:
+    about 0.1 s a site on the chip's host for the flash kernels' whole-pair
+    bodies, and several times that for a kernel with cut pairs (PR 31). So
+    the first site's jaxpr is kept and later sites replay it, one bind an
+    equation."""
     kept = {}
 
     def run(*refs):
-        key = tuple(jax.typeof(r) for r in refs)
+        key = (pallas_core.axis_frame().grid,
+               *(jax.typeof(r) for r in refs))
         if key not in kept:
-            kept[key] = jax.make_jaxpr(kernel)(*refs)
+            # through a function of its own: jax keeps traces by function
+            # and types, and would answer with another grid's
+            kept[key] = jax.make_jaxpr(lambda *r: kernel(*r))(*refs)
         jax.core.eval_jaxpr(kept[key].jaxpr, kept[key].consts, *refs)
 
     return run

@@ -121,6 +121,13 @@ from split_learning_tpu.ops.common import (
 _BLOCK = 128   # minimum block edge (the MXU tile); see _pick_block
 _ROWW = 8      # lane width of the LSE/delta row vectors (tile-masked)
 
+# The backward's form, named from outside a model: None, and
+# :func:`_use_onepass` chooses from shapes and a preflight compile; True
+# or False, and it answers that. Python sets it and no operator can: a
+# test with ``monkeypatch.setattr``, scripts/fused_step_memory.py (which
+# compiles for a chip it does not have, so cannot preflight) by assignment.
+ONEPASS: bool | None = None
+
 
 def _pick_block(t: int) -> int:
     """Square block edge for both grid axes. 128x128 blocks drown in
@@ -136,17 +143,13 @@ def _pick_block(t: int) -> int:
     keeps every matmul MXU-shaped ([1024,128]x[128,1024]); the f32
     scores block is 4 MiB and the kernels' working set stays inside
     Mosaic's 16 MiB default (compiled and measured on-chip at
-    T=1024..8192). SLT_FLASH_BLOCK overrides for tuning.
+    T=1024..8192).
 
     The sweep predates PR 1 and nothing since has repeated it. What a
     1024-row block wastes where the mask cuts it (half of the diagonal
     pair, most of a window's far edge) is no longer paid by a smaller
     block but inside the kernel bodies: ``_TILE`` / :func:`_live_cols`
     (PR 31)."""
-    import os
-    env = os.environ.get("SLT_FLASH_BLOCK")
-    if env:
-        return int(env)
     tp128 = round_up(t, 128)
     b = 1024
     while b > 128 and tp128 % b:   # largest edge that adds no extra padding
@@ -224,12 +227,12 @@ _DEFAULT_LIMIT_SAFE = 12 * 1024 * 1024
 # four f32 [block,block] temporaries exceed Mosaic's 16 MiB default
 # at 1024-row edges; they now request the per-generation allowance
 # (same as the fwd/one-pass calls) and a blk-1024 split compiled and
-# ran on-chip 2026-08-01 (T=2048 b16, 78.3 steps/s, forced via
-# SLT_FLASH_ONEPASS_T=0) — but on generations where the allowance IS
-# the 16 MiB default (v2/v3, unknown kinds at their floor) a >512
-# split would still be a compile error, and the split is only ever
-# chosen where one-pass was refused, i.e. exactly the
-# VMEM-constrained regime. 512 stays the proven-everywhere edge.
+# ran on-chip 2026-08-01 (T=2048 b16, 78.3 steps/s, the split forced) —
+# but on generations where the allowance IS the 16 MiB default (v2/v3,
+# unknown kinds at their floor) a >512 split would still be a compile
+# error, and the split is only ever chosen where one-pass was refused,
+# i.e. exactly the VMEM-constrained regime. 512 stays the
+# proven-everywhere edge.
 _SPLIT_BLOCK_MAX = 512
 
 
@@ -239,14 +242,11 @@ def _resolve_block(t: int, d: int, dtype, bh: int = 2, group: int = 1,
     """(block, onepass) for a public entry point: the swept default
     edge when the one-pass backward (which preflight-confirms itself)
     carries the gradient, capped to :data:`_SPLIT_BLOCK_MAX` when the
-    two-kernel split must take over. An explicit ``SLT_FLASH_BLOCK``
-    tuning override is honored verbatim — sweeps must measure the edge
-    they asked for, cap included in what they signed up for. ``bh`` is
-    the program's batch*heads and ``mask`` its ``(causal, strict,
-    window)``, forwarded so the preflight probes the grid shape and the
-    kernel bodies the user will actually compile (see
-    :func:`_onepass_compile_ok`). ``d_v`` is the values' width where it
-    is not the keys' ``d``.
+    two-kernel split must take over. ``bh`` is the program's
+    batch*heads and ``mask`` its ``(causal, strict, window)``, forwarded
+    so the preflight probes the grid shape and the kernel bodies the
+    user will actually compile (see :func:`_onepass_compile_ok`).
+    ``d_v`` is the values' width where it is not the keys' ``d``.
 
     Cost note: resolving the backward form eagerly means even a
     forward-only call at a >512 edge pays the one-pass preflight
@@ -255,11 +255,9 @@ def _resolve_block(t: int, d: int, dtype, bh: int = 2, group: int = 1,
     and backward disagree on the block edge (the split cap changes
     BOTH kernels' padding), and a cached compile is cheap next to a
     user-path compile error."""
-    import os
     block = _pick_block(t)
     onepass = _use_onepass(t, block, d, dtype, bh, group, mask, d_v)
-    if (not onepass and block > _SPLIT_BLOCK_MAX
-            and not os.environ.get("SLT_FLASH_BLOCK")):
+    if not onepass and block > _SPLIT_BLOCK_MAX:
         block = _SPLIT_BLOCK_MAX
         onepass = _use_onepass(t, block, d, dtype, bh, group, mask, d_v)
     return block, onepass
@@ -273,8 +271,7 @@ def _use_onepass(t: int, block: int, d: int, dtype, bh: int = 2,
     device's scoped-VMEM limit, leaving the rest for the
     double-buffered K/V/dK/dV blocks and compiler temporaries — on a
     v4/v5 core (96 MiB limit, 64 MiB budget) bf16 d=128 passes through
-    T=16384. ``SLT_FLASH_ONEPASS_T`` overrides: one-pass at or below
-    that padded T, two-kernel above (0 = never).
+    T=16384. :data:`ONEPASS`, where something set it, is the answer.
 
     When the shape needs the *raised* scoped-VMEM limit (residency past
     :data:`_DEFAULT_LIMIT_SAFE`) and the kernel will actually be
@@ -285,12 +282,10 @@ def _use_onepass(t: int, block: int, d: int, dtype, bh: int = 2,
     hard compile error on-chip three times (scoped allocation 16.50M >
     16.00M default) because selection trusted the static budget; a
     user-path shape must never be a compile error."""
-    import os
+    if ONEPASS is not None:
+        return ONEPASS
     dtype = jnp.dtype(dtype)
     tp = round_up(t, block)
-    env = os.environ.get("SLT_FLASH_ONEPASS_T")
-    if env:   # empty string = unset, like SLT_FLASH_AUTO_T
-        return tp <= int(env)
     resident = _onepass_resident_bytes(tp, d, dtype.itemsize, d_v)
     if resident > _vmem_limit_bytes() * 2 // 3:
         return False
@@ -415,9 +410,6 @@ def select_attention(b: int, t: int, h: int, itemsize: int,
        than the slower kernel). Past that, flash is mandatory
        (measured: b16/h2/T=16384 bf16 fails to compile at 16G).
 
-    ``SLT_FLASH_AUTO_T`` overrides both: at or above that T, flash —
-    the knob for re-pinning the crossover when the kernels change.
-
     A ``window`` changes neither rule: the dense banded path
     (``full_attention(..., window=)``) still builds and saves the whole
     ``[B, H, T, T]`` scores and masks them, so its residency is counted
@@ -428,14 +420,8 @@ def select_attention(b: int, t: int, h: int, itemsize: int,
     ``t_kv`` generalizes the rule to asymmetric query/key extents (the
     sharded parallel forms — ops/ring_attention.py — resolve their
     per-rank shapes through here so the crossover has one home)."""
-    import os
     if t_kv is None:
         t_kv = t
-    env = os.environ.get("SLT_FLASH_AUTO_T")
-    if env:
-        # operator re-pin: absolute, on every backend (tests use it to
-        # force flash blocks onto the CPU mesh)
-        return "flash" if max(t, t_kv) >= int(env) else "full"
     if interpret is None:
         interpret = use_interpret()
     if not interpret and max(t, t_kv) >= _FLASH_SPEED_T:

@@ -279,7 +279,7 @@ def _resolve_block_impl(block_impl: str, b: int, t_q: int, t_kv: int,
     T); for ulysses it is the gathered [B_local, H/n, T, T] block.
     ``b`` must already be the per-rank batch. Delegates to
     :func:`...flash_attention.select_attention` so the crossover rule
-    (and its SLT_FLASH_AUTO_T override) has exactly one home."""
+    has exactly one home."""
     if block_impl != "auto":
         return block_impl
     from split_learning_tpu.ops.flash_attention import select_attention

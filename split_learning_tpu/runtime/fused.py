@@ -52,27 +52,10 @@ class FusedSplitTrainer:
         plan = self.plan  # grads recompute stage forwards under remat
         self.cfg = cfg
         self.mesh = mesh
-        use_pallas = cfg.kernels == "pallas"
         self._tx = make_tx(cfg)
-        # the hand-written fused_sgd_step implements exactly plain
-        # (momentum-)SGD at a constant lr; any other optimizer/schedule
-        # runs the optax update (the loss/attention kernels stay pallas)
-        fused_opt = (cfg.optimizer == "sgd" and not cfg.weight_decay
-                     and not cfg.warmup_steps and not cfg.decay_steps
-                     and not cfg.grad_clip_norm)
-        use_pallas_opt = use_pallas and fused_opt
 
         params = tuple(plan.init(rng, jnp.asarray(sample_input)))
-        if use_pallas_opt:
-            # the fused-kernel path owns its optimizer state: the momentum
-            # trace pytree (or () without momentum) instead of optax's
-            from split_learning_tpu.ops.sgd import init_trace
-            state = TrainState(
-                params=params,
-                opt_state=init_trace(params) if cfg.momentum else (),
-                step=jnp.zeros((), jnp.int32))
-        else:
-            state = make_state(params, self._tx)
+        state = make_state(params, self._tx)
         if mesh is not None:
             # batch sharded over 'data'; params replicated — except under
             # tensor parallelism, where weight matrices shard their output
@@ -99,27 +82,9 @@ class FusedSplitTrainer:
 
         microbatches = cfg.microbatches
         tx = self._tx
-        lr, momentum = cfg.lr, cfg.momentum
-
-        if use_pallas:
-            from split_learning_tpu.ops import fused_cross_entropy
-            from split_learning_tpu.ops.sgd import fused_sgd_step
-            loss_op = fused_cross_entropy
-        else:
-            loss_op = cross_entropy
 
         def loss_fn(params, x, y):
-            return plan_loss_with_counters(plan, params, x, y, loss_op)
-
-        def update(state: TrainState, grads) -> TrainState:
-            if not use_pallas_opt:
-                return apply_grads(tx, state, grads)
-            trace = state.opt_state if momentum else None
-            new_params, new_trace = fused_sgd_step(
-                state.params, grads, trace, lr, momentum)
-            return TrainState(params=new_params,
-                              opt_state=new_trace if momentum else (),
-                              step=state.step + 1)
+            return plan_loss_with_counters(plan, params, x, y, cross_entropy)
 
         def step_fn(state: TrainState, x, y):
             """``(state, loss, counters)``: what the plan's modules sowed
@@ -152,8 +117,7 @@ class FusedSplitTrainer:
                     micro, (zeros, jnp.zeros(())), (xs, ys))
                 grads = jax.tree_util.tree_map(lambda g: g / mb, g_sum)
                 loss = l_sum / mb
-            new_state = update(state, grads)
-            return new_state, loss, counters
+            return apply_grads(tx, state, grads), loss, counters
 
         def epoch_fn(state: TrainState, xs, ys):
             """T steps in one XLA program: lax.scan over the step axis.

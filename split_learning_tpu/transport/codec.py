@@ -90,9 +90,9 @@ def decode(data: bytes) -> Any:
 
 # --------------------------------------------------------------------- #
 # Optional int8 wire compression of the cut-layer payload: 4x fewer
-# bytes for the 5.28 MiB hop (SURVEY.md §2 derived facts). Same math as
-# the Pallas kernels in ops/quantize.py (parity-tested); this numpy path
-# runs at the host wire boundary, the kernels inside jit.
+# bytes for the 5.28 MiB hop (SURVEY.md §2 derived facts). This numpy
+# path runs at the host wire boundary; native/slt_codec.cc is the same
+# math in threaded C++ (parity-tested, tests/test_native.py).
 # --------------------------------------------------------------------- #
 _Q8_KEY = "__q8__"
 _Q8_EPS = 1e-12
@@ -177,8 +177,8 @@ def decompress_tree(obj: Any) -> Any:
 # dense q8). The sender keeps the compression error in a per-tensor
 # error-feedback residual (TopK8EF) that is added back before the next
 # step's selection, so dropped mass is delayed, not lost (Clapping,
-# arXiv:2509.19029). In-jit counterparts: ops/topk.py (Pallas); the
-# multithreaded host fast path: native/slt_codec.cc slt_topk8_*.
+# arXiv:2509.19029). The wire runs this NumPy form or the multithreaded
+# host fast path, native/slt_codec.cc slt_topk8_*; nothing runs in jit.
 #
 # Wire format ({__topk8__: True, ...}): the survivors' positions travel
 # either as explicit int32 indices ("idx", 4 B/survivor — cheaper below

@@ -470,7 +470,7 @@ class HttpTransport(Transport):
                  density_controller: Optional[Any] = None,
                  wire_id: Optional[str] = None) -> None:
         """``compress="int8"`` quantizes the cut-layer tensors on the wire
-        (4x fewer bytes; lossy — see ops/quantize.py). ``"topk8"`` ships
+        (4x fewer bytes; lossy — see transport/codec.py). ``"topk8"`` ships
         only the top ``density`` fraction of magnitudes as int8 with
         sender-side error feedback (~17x at density 0.1 — see
         transport/codec.py); ``"clapping"`` is the same selection with

@@ -52,7 +52,6 @@ _ENV_MAP = {
     "data_dir": "SLT_DATA_DIR",
     "checkpoint_dir": "SLT_CHECKPOINT_DIR",
     "tracking": "SLT_TRACKING",
-    "kernels": "SLT_KERNELS",
 }
 
 
@@ -98,10 +97,6 @@ class Config:
     # "1f1b" (warmup min(S, M) then 1-forward-1-backward steady state)
     schedule: str = "gpipe"
     remat: bool = False       # jax.checkpoint stage forwards (FLOPs for HBM)
-
-    # hot-path op implementation: "xla" (let the compiler fuse) or
-    # "pallas" (hand-written kernels, split_learning_tpu.ops)
-    kernels: str = "xla"
 
     # storage / tracking
     data_dir: str = os.path.expanduser("~/.cache/split_learning_tpu")
@@ -157,10 +152,6 @@ class Config:
                 "(expected 'gpipe' or '1f1b')")
         if self.batch_size % self.microbatches != 0:
             raise ValueError("batch_size must be divisible by microbatches")
-        if self.kernels not in ("xla", "pallas"):
-            raise ValueError(
-                f"Unknown kernels backend: {self.kernels!r} "
-                "(expected 'xla' or 'pallas')")
         if self.seq_parallel <= 0:
             raise ValueError("seq_parallel must be positive")
         if self.optimizer not in ("sgd", "adam", "adamw"):

@@ -533,7 +533,7 @@ def qkv(t, h, h_kv, b=2, d=16):
 
 # T 300 pads to three 128 blocks and is no multiple of the window; 640 is
 # five blocks with a band of three; window 129 is one past a block edge
-@pytest.mark.parametrize("onepass", ["", "0"], ids=["onepass", "split"])
+@pytest.mark.parametrize("onepass", [None, False], ids=["onepass", "split"])
 @pytest.mark.parametrize("t,h,h_kv,window", [
     (72, 4, 2, 8), (300, 4, 1, 100), (640, 2, 2, 200), (384, 2, 1, 129),
     (640, 4, 2, None)])
@@ -541,7 +541,9 @@ def test_window_and_grouped_heads_match_the_dense_band(
         monkeypatch, onepass, t, h, h_kv, window):
     """Forward and gradients of the interpreted kernels, both backward
     forms, against ``full_attention``'s dense banded path."""
-    monkeypatch.setenv("SLT_FLASH_ONEPASS_T", onepass)
+    import importlib
+    fa = importlib.import_module("split_learning_tpu.ops.flash_attention")
+    monkeypatch.setattr(fa, "ONEPASS", onepass)
     q, k, v, w = qkv(t, h, h_kv)
     f = lambda fn: (lambda a, b, c: jnp.sum(
         fn(a, b, c, causal=True, window=window) * w))
@@ -577,7 +579,7 @@ def test_cut_band_edges_match_the_dense_band(flash_tiled, onepass, t, tile,
         np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5)
 
 
-@pytest.mark.parametrize("onepass", ["", "0"], ids=["onepass", "split"])
+@pytest.mark.parametrize("onepass", [None, False], ids=["onepass", "split"])
 @pytest.mark.parametrize("kw", [
     dict(causal=True), dict(causal=True, window=2048),
     dict(causal=True, window=512), dict(causal=True, strict=True),
@@ -593,7 +595,7 @@ def test_tiled_kernels_keep_their_calls_and_operands(monkeypatch, onepass,
     with no causal mask traces to the letter as with one tile a block."""
     import importlib
     fa = importlib.import_module("split_learning_tpu.ops.flash_attention")
-    monkeypatch.setenv("SLT_FLASH_ONEPASS_T", onepass)
+    monkeypatch.setattr(fa, "ONEPASS", onepass)
     q = jnp.zeros((1, 4096, 2, 16))
     kv = jnp.zeros((1, 4096, 1, 16))
 
@@ -608,7 +610,7 @@ def test_tiled_kernels_keep_their_calls_and_operands(monkeypatch, onepass,
     fa._make_flash.cache_clear()
     operands = lambda j: [len(e.invars) for e in _pallas_calls(j.jaxpr, [])]
     assert operands(tiled) == operands(whole) == (
-        [3, 6, 6] if onepass else [3, 6])
+        [3, 6] if onepass is None else [3, 6, 6])
     if kw["causal"]:
         assert len(str(tiled)) > len(str(whole))   # the cut pairs' bodies
     else:

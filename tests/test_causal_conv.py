@@ -3,12 +3,14 @@ tiles and two channel tiles (``[2, 3072, 1024]``: the carried rows and the
 tap sums cross two edges a channel tile), against the plain form, and what
 the module header says of both: causal, zeros before a sequence, a sequence
 alone in its batch, the choice by shape, residuals without a float32 ``[T,
-C]`` array, one body for a step's three layers."""
+C]`` array, one body for a step's three layers, and a body kept by
+``ops/common.py:traced_once`` that follows the grid it runs under."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
 from split_learning_tpu.ops import causal_conv
 from split_learning_tpu.ops.causal_conv import TOKENS, conv_silu
@@ -74,6 +76,50 @@ def test_the_kernels_are_the_plain_form(monkeypatch, x_dtype, y_dtype, taps):
         np.testing.assert_array_less(
             np.abs(u - v), 2e-6 * np.abs(v).max() + one_rounding + 1e-30,
             err_msg=name)
+
+
+# each order under taps of its own, so that neither finds a body the other
+# (or another test of this process) has traced at these types
+@pytest.mark.parametrize("tiles,taps", [((3, 8), 5), ((8, 3), 6)],
+                         ids=["three-then-eight", "eight-then-three"])
+def test_the_kernels_follow_the_number_of_token_tiles(monkeypatch, tiles,
+                                                      taps):
+    """One process, two sequence lengths of the same blocks: the backward's
+    body reads the grid's last step (``pl.num_programs``), which is a
+    constant of the trace, so a body kept from the first length would zero
+    the rows before the wrong tile of the second (PR 50)."""
+    for n in tiles:
+        ops = operands(batch=1, t=n * TOKENS, c=128, taps=taps, seed=n)
+        with monkeypatch.context() as m:
+            got = output_and_gradients(conv_silu, ops, jnp.float32)
+            m.setattr(causal_conv, "fills_tiles", lambda *a: False)
+            want = output_and_gradients(conv_silu, ops, jnp.float32)
+        np.testing.assert_array_equal(got["y"], want["y"])
+        for name in NAMES:
+            np.testing.assert_allclose(
+                got[name], want[name], rtol=0,
+                atol=2e-6 * np.abs(want[name]).max(), err_msg=f"{name} {n}")
+
+
+@pytest.mark.parametrize("wrappers", ["one", "one-a-grid"])
+def test_a_kept_body_follows_the_grid(wrappers):
+    """``traced_once`` keeps a body's jaxpr by the grid as well as by the
+    refs' types: the same body under grids of three and of five steps, with
+    blocks of one type, through one wrapper or one a grid (jax's own cache
+    of traces knows the function and the types alone)."""
+    from split_learning_tpu.ops.common import traced_once
+
+    def body(o_ref):
+        o_ref[...] = jnp.full(o_ref.shape, pl.num_programs(0), jnp.int32)
+
+    kept = traced_once(body)
+    for steps in (3, 5, 3):
+        run = kept if wrappers == "one" else traced_once(body)
+        out = pl.pallas_call(
+            run, grid=(steps,), interpret=True,
+            out_shape=jax.ShapeDtypeStruct((8 * steps, 128), jnp.int32),
+            out_specs=pl.BlockSpec((8, 128), lambda i: (i, 0)))()
+        np.testing.assert_array_equal(out, steps)
 
 
 def test_the_plain_form_is_the_shifted_sum_in_float64():

@@ -275,13 +275,15 @@ def test_onepass_backward_matches_two_kernel(monkeypatch, causal):
     form is a perf choice, never a numerics choice. T=256 tiles as
     2x128 so the one-pass q loop and the causal start offset are both
     multi-block."""
-    from split_learning_tpu.ops.flash_attention import _make_flash
+    import importlib
+    fa = importlib.import_module("split_learning_tpu.ops.flash_attention")
+    _make_flash = fa._make_flash
     q, k, v = qkv(t=256, b=1, h=2, d=16)
     w = jax.random.normal(jax.random.PRNGKey(7), q.shape, jnp.float32)
 
     grads = {}
-    for name, flag in (("onepass", "8192"), ("twokernel", "0")):
-        monkeypatch.setenv("SLT_FLASH_ONEPASS_T", flag)
+    for name, flag in (("onepass", True), ("twokernel", False)):
+        monkeypatch.setattr(fa, "ONEPASS", flag)
         _make_flash.cache_clear()  # onepass is part of the build key
         f = lambda a, b, c: jnp.sum(
             flash_attention(a, b, c, causal=causal) * w)
@@ -362,8 +364,8 @@ def test_onepass_preflight_fallback(monkeypatch):
     monkeypatch.setattr(fa, "_onepass_compile_ok",
                         lambda *a: False)
     assert fa._use_onepass(1024, 512, 128, jnp.bfloat16)
-    # env override short-circuits everything, including the probe
-    monkeypatch.setenv("SLT_FLASH_ONEPASS_T", "0")
+    # a form named from outside short-circuits everything, the probe too
+    monkeypatch.setattr(fa, "ONEPASS", False)
     assert not fa._use_onepass(1024, 512, 128, jnp.bfloat16)
 
 
@@ -426,8 +428,7 @@ def test_resolve_block_caps_split_form(monkeypatch):
     """When the two-kernel split carries the gradient, the whole
     program drops to the proven _SPLIT_BLOCK_MAX edge (the blk-1024
     sweep legs all ran the one-pass backward, so 1024 evidence does
-    not cover _dq_kernel/_dkv_kernel); an explicit SLT_FLASH_BLOCK
-    tuning override is honored verbatim."""
+    not cover _dq_kernel/_dkv_kernel)."""
     import importlib
     fa = importlib.import_module(
         "split_learning_tpu.ops.flash_attention")
@@ -436,13 +437,10 @@ def test_resolve_block_caps_split_form(monkeypatch):
     monkeypatch.setattr(fa, "use_interpret", lambda: True)
     assert fa._resolve_block(2048, 128, jnp.bfloat16) == (1024, True)
     # force the split form: the edge must drop to the proven 512
-    monkeypatch.setenv("SLT_FLASH_ONEPASS_T", "0")
+    monkeypatch.setattr(fa, "ONEPASS", False)
     assert fa._resolve_block(2048, 128, jnp.bfloat16) == (512, False)
     # ragged T already below the cap: unchanged
     assert fa._resolve_block(640, 128, jnp.bfloat16) == (128, False)
-    # explicit tuning override rides through the cap untouched
-    monkeypatch.setenv("SLT_FLASH_BLOCK", "1024")
-    assert fa._resolve_block(2048, 128, jnp.bfloat16) == (1024, False)
 
 
 @pytest.mark.slow
@@ -480,12 +478,11 @@ def test_onepass_vmem_limit_reaches_mosaic():
     assert str(fa._vmem_limit_bytes()) in txt
 
 
-def test_auto_attention_selection(monkeypatch):
+def test_auto_attention_selection():
     """attn='auto' resolves per shape by two rules: flash at/past the
     measured round-4 speed crossover (_FLASH_SPEED_T, regardless of
     HBM headroom), and flash wherever dense's quadratic backward
-    buffers threaten HBM; dense otherwise. SLT_FLASH_AUTO_T re-pins
-    both."""
+    buffers threaten HBM; dense otherwise."""
     from split_learning_tpu.ops.flash_attention import select_attention
 
     hbm = 16 * 1024 ** 3
@@ -515,11 +512,6 @@ def test_auto_attention_selection(monkeypatch):
                             interpret=True) == "flash"
     assert select_attention(16, 16384, 2, 2, hbm_bytes=hbm,
                             interpret=True) == "flash"
-    # the operator env re-pin is absolute on every backend
-    monkeypatch.setenv("SLT_FLASH_AUTO_T", "2048")
-    assert select_attention(16, 2048, 2, 2, hbm_bytes=hbm) == "flash"
-    assert select_attention(16, 1024, 2, 2, hbm_bytes=hbm,
-                            interpret=False) == "full"
 
 
 @pytest.mark.slow

@@ -227,7 +227,7 @@ def mla_operands(t, heads, rank_q=48, rank_kv=32, d_n=16, d_r=8, d_v=16,
             0.2 * jax.random.normal(ks[4], (rank_kv, heads * (d_n + d_v)))), ks[5]
 
 
-@pytest.mark.parametrize("onepass", ["", "0"], ids=["onepass", "split"])
+@pytest.mark.parametrize("onepass", [None, False], ids=["onepass", "split"])
 @pytest.mark.parametrize("t", [300, 384], ids=["ragged", "whole-blocks"])
 def test_latent_attention_through_the_flash_kernels_equals_the_dense_form(
         monkeypatch, onepass, t):
@@ -238,7 +238,7 @@ def test_latent_attention_through_the_flash_kernels_equals_the_dense_form(
     path's."""
     import importlib
     fa = importlib.import_module("split_learning_tpu.ops.flash_attention")
-    monkeypatch.setenv("SLT_FLASH_ONEPASS_T", onepass)
+    monkeypatch.setattr(fa, "ONEPASS", onepass)
     fa._make_flash.cache_clear()
     operands, key = mla_operands(t, 4)
     make = lambda *ops: family._up_projection(sizes(rope_theta=1e4), *ops)
@@ -436,7 +436,7 @@ def test_remat_changes_no_number(attn):
     (dict(causal=True, window=512), 40, 20, 128),        # PF, window
     (dict(causal=True), 40, 20, 128),                    # PF, full and cross
 ], ids=["gpt2", "trinity-window", "trinity-full", "phi4-window", "phi4-full"])
-@pytest.mark.parametrize("onepass", ["", "0"], ids=["onepass", "split"])
+@pytest.mark.parametrize("onepass", [None, False], ids=["onepass", "split"])
 def test_equal_width_flash_calls_trace_as_without_a_value_width(
         monkeypatch, kw, heads, kv_heads, d, onepass):
     """A call whose values are as wide as its keys is the call every other
@@ -446,7 +446,7 @@ def test_equal_width_flash_calls_trace_as_without_a_value_width(
     for these shapes, forward and both backward forms: equal)."""
     import importlib
     fa = importlib.import_module("split_learning_tpu.ops.flash_attention")
-    monkeypatch.setenv("SLT_FLASH_ONEPASS_T", onepass)
+    monkeypatch.setattr(fa, "ONEPASS", onepass)
     fa._make_flash.cache_clear()
     t = 2048
     q = jnp.zeros((1, t, heads, d), jnp.bfloat16)

@@ -241,7 +241,7 @@ def test_short_conv_is_the_direct_sum_is_causal_and_starts_from_zeros():
     np.testing.assert_allclose(y[:, 0], np.asarray(taps4[3] * u[:, 0]), atol=1e-6)
 
 
-@pytest.mark.parametrize("onepass", ["", "0"], ids=["onepass", "split"])
+@pytest.mark.parametrize("onepass", [None, False], ids=["onepass", "split"])
 @pytest.mark.parametrize("t", [300, 384], ids=["ragged", "whole-blocks"])
 def test_grouped_heads_of_64_through_the_flash_kernels_equal_the_dense_form(
         monkeypatch, onepass, t):
@@ -251,7 +251,7 @@ def test_grouped_heads_of_64_through_the_flash_kernels_equal_the_dense_form(
     k and v equal the dense path's."""
     import importlib
     fa = importlib.import_module("split_learning_tpu.ops.flash_attention")
-    monkeypatch.setenv("SLT_FLASH_ONEPASS_T", onepass)
+    monkeypatch.setattr(fa, "ONEPASS", onepass)
     fa._make_flash.cache_clear()
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
     q = jax.random.normal(ks[0], (1, t, 8, 64))

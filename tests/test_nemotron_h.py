@@ -526,7 +526,7 @@ def test_the_plain_attention_has_no_gate_no_norms_and_no_positions():
         "q", "k", "v", "gate", "q_norm", "k_norm", "out"}
 
 
-@pytest.mark.parametrize("onepass", ["", "0"], ids=["onepass", "split"])
+@pytest.mark.parametrize("onepass", [None, False], ids=["onepass", "split"])
 def test_sixteen_query_heads_a_key_value_head_through_the_flash_kernels(
         monkeypatch, onepass):
     """32 query heads over 2 key/value heads of 128 (the published heads,
@@ -535,7 +535,7 @@ def test_sixteen_query_heads_a_key_value_head_through_the_flash_kernels(
     the dense path's."""
     import importlib
     fa = importlib.import_module("split_learning_tpu.ops.flash_attention")
-    monkeypatch.setenv("SLT_FLASH_ONEPASS_T", onepass)
+    monkeypatch.setattr(fa, "ONEPASS", onepass)
     fa._make_flash.cache_clear()
     t = 300
     ks = jax.random.split(jax.random.PRNGKey(0), 4)

@@ -129,26 +129,6 @@ def test_fused_trainer_adamw_learns_and_differs_from_sgd():
     assert not np.allclose(adamw, sgd_l)
 
 
-@pytest.mark.slow
-def test_pallas_kernels_with_adamw_fall_back_to_optax_update():
-    """kernels='pallas' + a non-SGD optimizer: the loss kernel stays
-    pallas but the update runs optax — and still learns."""
-    from split_learning_tpu.models import get_plan
-    from split_learning_tpu.runtime.fused import FusedSplitTrainer
-
-    rs = np.random.RandomState(4)
-    xb = rs.randn(16, 28, 28, 1).astype(np.float32)
-    yb = rs.randint(0, 10, (16,)).astype(np.int64)
-    cfg = Config(optimizer="adamw", lr=1e-3, kernels="pallas",
-                 batch_size=16)
-    tr = FusedSplitTrainer(get_plan(mode="split"), cfg,
-                           jax.random.PRNGKey(0), xb)
-    losses = [float(tr.train_step(xb, yb)) for _ in range(10)]
-    assert np.mean(losses[-3:]) < losses[0]
-    # optax adam state, not the pallas momentum trace
-    assert tr.state.opt_state != ()
-
-
 def test_momentum_rejected_off_sgd_and_env_parses():
     with pytest.raises(ValueError, match="momentum"):
         Config(optimizer="adamw", momentum=0.9)

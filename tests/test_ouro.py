@@ -389,7 +389,7 @@ def test_remat_runs_no_kernel_twice_and_only_the_named_products():
             kernels, products + p * KW["layers"] * 2)
 
 
-@pytest.mark.parametrize("onepass", ["", "0"], ids=["onepass", "split"])
+@pytest.mark.parametrize("onepass", [None, False], ids=["onepass", "split"])
 @pytest.mark.parametrize("t", [300, 384], ids=["ragged", "whole-blocks"])
 def test_ungrouped_heads_with_rotary_through_the_flash_kernels_equal_the_dense_form(
         monkeypatch, onepass, t):
@@ -398,7 +398,7 @@ def test_ungrouped_heads_with_rotary_through_the_flash_kernels_equal_the_dense_f
     the gradient of q, k and v equal the dense path's."""
     import importlib
     fa = importlib.import_module("split_learning_tpu.ops.flash_attention")
-    monkeypatch.setenv("SLT_FLASH_ONEPASS_T", onepass)
+    monkeypatch.setattr(fa, "ONEPASS", onepass)
     fa._make_flash.cache_clear()
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
     q, k, v, w = (jax.random.normal(key, (1, t, 16, 128)) for key in ks)

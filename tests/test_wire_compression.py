@@ -1,7 +1,8 @@
 """topk8 wire mode end-to-end: the none-path pin (--compress none must be
 bit-for-bit the legacy wire), error-feedback semantics (rollback on a lost
 POST, no rollback in-process), the bitmap/index encoding switch, and the
-compression-ratio accounting surfaced on /metrics."""
+compression-ratio accounting surfaced on /metrics; and the int8 mode's
+payload size, alone and over HTTP."""
 
 import math
 
@@ -223,4 +224,37 @@ def test_http_server_honors_client_requested_mode():
         assert dense.stats.summary().get("compression_ratio") is None
     finally:
         dense.close()
+        server.stop()
+
+
+def test_q8_wire_shrinks_payload(rng):
+    x = np.asarray(jax.random.normal(rng, (64, 26, 26, 32), np.float32))
+    raw = codec.encode(x)
+    compressed = codec.encode(codec.q8_compress(x))
+    assert len(compressed) < len(raw) / 3.5  # ~4x minus header overhead
+    back = codec.decompress_tree(codec.decode(compressed))
+    assert back.shape == x.shape and back.dtype == x.dtype
+
+
+def test_http_transport_int8_compression(rng, mnist_batch):
+    """End-to-end split step over HTTP with int8 wire compression."""
+    x, y = mnist_batch
+    x, y = np.asarray(x[:16]), np.asarray(y[:16])
+    cfg = Config(mode="split", batch_size=16)
+    plan = get_plan(mode="split")
+    runtime = ServerRuntime(plan, cfg, rng, x)
+    server = SplitHTTPServer(runtime).start()
+    try:
+        plain = HttpTransport(server.url)
+        lossy = HttpTransport(server.url, compress="int8")
+        c = SplitClientTrainer(plan, cfg, rng, lossy)
+        loss = c.train_step(x, y, 0)
+        assert np.isfinite(loss)
+        # cut tensor is [16, 26, 26, 32]; int8 wire ~1 byte/elem vs 4 fp32
+        acts_elems = 16 * 26 * 26 * 32
+        assert lossy.stats.bytes_sent < acts_elems * 1.1
+        assert lossy.stats.bytes_received < acts_elems * 1.1
+        plain.close()
+        lossy.close()
+    finally:
         server.stop()
